@@ -13,128 +13,24 @@ __version__ = "0.1.0"
 
 import os as _os
 
-# Persistent XLA compilation cache: first-compile of the jitted training
-# steps costs tens of seconds on TPU; caching compiled executables on disk
-# makes every later process (bench runs, notebooks, serving restarts) start
-# warm.  Opt out with SYNAPSEML_TPU_NO_COMPILE_CACHE=1.
-if not _os.environ.get("SYNAPSEML_TPU_NO_COMPILE_CACHE"):
-    _cache = _os.path.join(_os.path.expanduser("~"), ".cache",
-                           "synapseml_tpu", "xla_cache")
-    _os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _cache)
-    _os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-    try:
-        # if jax was imported before us its config already snapshotted the
-        # env — set the live config too (works regardless of import order)
-        import jax as _jax
-        if _jax.config.jax_compilation_cache_dir is None:
-            _jax.config.update("jax_compilation_cache_dir",
-                               _os.environ["JAX_COMPILATION_CACHE_DIR"])
-            _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-    except Exception:  # never let cache setup break import
-        pass
+# One compile cache, placed from outside.  Where JAX_COMPILATION_CACHE_DIR
+# is set the persistent XLA compilation cache lives there; where it is not,
+# it is ``<checkout>/.jax_cache`` (resolved from this package's own path,
+# so every process of one checkout — tests, bench children, gang workers,
+# which all inherit the environment — shares one directory at a fixed
+# path).  This is the only place the package decides the directory; the
+# variable is exported so children resolve the same one.
+_cache_dir = _os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR",
+    _os.path.join(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__))), ".jax_cache"))
 
-# jax version compat: the codebase targets the modern top-level
-# ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``;
-# on older jax that API lives at jax.experimental.shard_map with the
-# ``check_rep`` spelling — install an adapter so both environments work.
-# Deliberately a patch on the jax module (not an internal wrapper): the
-# package's call sites AND its test suite spell ``jax.shard_map``, and the
-# patch only installs where the modern name does not exist at all, so
-# modern environments are untouched.  Known tradeoff: on old jax, other
-# code in the process feature-detecting ``jax.shard_map`` will find this
-# adapter, which disables the (false-positive-prone) check_rep pass.
-try:
-    import jax as _jax
-    if not hasattr(_jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map_impl
+import jax as _jax  # noqa: E402
 
-        def _shard_map_compat(f, *, mesh, in_specs, out_specs,
-                              check_vma=True, **kw):
-            # old jax's check_rep has known false positives (e.g. scan
-            # carries under psum; its own error message suggests
-            # check_rep=False) — the modern check_vma flag has no faithful
-            # equivalent, so the compat path always disables the check
-            del check_vma
-            return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, check_rep=False,
-                                   **kw)
-
-        _jax.shard_map = _shard_map_compat
-    if not hasattr(_jax.lax, "axis_size"):
-        # lax.psum of a Python-int literal constant-folds to the concrete
-        # axis size — the documented pre-axis_size idiom
-        _jax.lax.axis_size = lambda axis_name: _jax.lax.psum(1, axis_name)
-except Exception:  # pragma: no cover - jax absent/newer layout
-    pass
-
-# flax compat: ``nn.with_partitioning`` boxes params with LOGICAL axis
-# names ("embed", "heads", "vocab", ...) that the trainers translate to
-# mesh axes through ``nn.logical_axis_rules`` at the jit boundary.  flax
-# 0.10's ``Partitioned.unbox`` applies the RAW names as a sharding
-# constraint whenever a global mesh is active — tracing any apply under
-# ``with mesh:`` then raises "Resource axis 'vocab' not found in mesh"
-# (the env failure carried since PR 3: DL text fits + llm TP forward on
-# this container).  The shim routes unbox's constraint through the
-# ACTIVE logical axis rules: names the rules (or the mesh itself) know
-# keep their mapping, unknown names mean "no constraint on this dim" —
-# exactly the semantics ``DLTrainer`` already sets up via
-# ``nn.logical_axis_rules(usable_rules(mesh))``.  Gated on the buggy
-# behavior being present so fixed flax versions are untouched.
-try:
-    import flax as _flax
-    import jax as _jax
-    from flax.core import meta as _flax_meta
-    from flax.linen import spmd as _flax_spmd
-
-    # version-ceiling gate: the raw-name constraint exists through flax
-    # 0.10.x; newer majors/minors are assumed fixed (or different enough
-    # that this shim must be re-validated, not silently kept)
-    _flax_ver = tuple(int(x) for x in _flax.__version__.split(".")[:2])
-    if _flax_ver <= (0, 10) \
-            and "logical" not in (_flax_meta.Partitioned.unbox.__doc__
-                                  or ""):
-        _orig_unbox = _flax_meta.Partitioned.unbox
-
-        def _unbox_logical(self, apply_constraint=True):
-            """Returns the wrapped value; the partitioning constraint is
-            applied through the active logical axis rules (compat shim —
-            translates logical names, drops unmapped ones)."""
-            try:
-                if not (apply_constraint and
-                        (_flax_meta._global_mesh_defined()
-                         or self.mesh is not None)):
-                    return self.value
-                mesh = self.mesh
-                if mesh is None:
-                    env = _jax._src.mesh.thread_resources.env
-                    mesh = env.physical_mesh
-                axes = set(getattr(mesh, "axis_names", ()) or ())
-                rules = dict(_flax_spmd.get_logical_axis_rules() or ())
-
-                def to_mesh(name):
-                    if name is None or name in axes:
-                        return name
-                    mapped = rules.get(name)
-                    return mapped if mapped in axes else None
-
-                spec = _jax.sharding.PartitionSpec(
-                    *(tuple(to_mesh(n) for n in ns)
-                      if isinstance(ns, tuple) else to_mesh(ns)
-                      for ns in self.names))
-                if self.mesh is not None:
-                    return _jax.lax.with_sharding_constraint(
-                        self.value,
-                        _jax.sharding.NamedSharding(self.mesh, spec))
-                return _jax.lax.with_sharding_constraint(self.value, spec)
-            except Exception:
-                # fail SOFT: the constraint is a layout hint — a private
-                # API moving under us must degrade to "unconstrained",
-                # never to a trace-time crash in every DL fit
-                return self.value
-
-        _flax_meta.Partitioned.unbox = _unbox_logical
-except Exception:  # pragma: no cover - flax absent/fixed layout
-    pass
+# jax snapshots the environment when it is first imported: a process that
+# imported jax before this package still holds the old value
+if _jax.config.jax_compilation_cache_dir != _cache_dir:
+    _jax.config.update("jax_compilation_cache_dir", _cache_dir)
 
 from . import resilience, telemetry
 from .core.dataset import Dataset

@@ -561,7 +561,17 @@ class DLTrainer:
 
         def local_grads(state, inputs, labels, dropout_key):
             def loss_of(params):
-                variables = {"params": params, **state.extra_vars}
+                # take the values out of their ``nn.Partitioned`` boxes
+                # WITHOUT the sharding constraint: inside a shard_map
+                # body jax has a mesh context set, so flax would apply
+                # the boxes' LOGICAL names ("vocab", "embed") as a
+                # constraint over a mesh whose only axis is ``data``
+                variables = {"params": jax.tree_util.tree_map(
+                    lambda x: (x.unbox(apply_constraint=False)
+                               if isinstance(x, nn.meta.AxisMetadata) else x),
+                    params,
+                    is_leaf=lambda x: isinstance(x, nn.meta.AxisMetadata)),
+                    **state.extra_vars}
                 kwargs = dict(train_flag)
                 # per-rank dropout stream: fold the rank in on top of the
                 # step (the pjit path's masks are position-dependent the

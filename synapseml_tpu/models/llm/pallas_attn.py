@@ -103,7 +103,10 @@ def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
     scale with ``max_query_span`` (the speculative verify step's S:
     its q/out blocks are ``(1, S, H, D)`` and its scratch rows
     ``S*H``), so a spec-enabled engine must gate at the WIDEST verify
-    it can launch, not at S=1.
+    it can launch, not at S=1.  Bytes are counted as Mosaic lays them
+    out: the last two dims of every block pad to the dtype's
+    (sublane, 128) tile, so a ``(KV=8, D=64)`` bf16 K/V row occupies a
+    ``(16, 128)`` tile — four times its logical bytes.
 
     ``tile`` pins a single candidate instead of the ladder — the tuned
     override path.  It passes through the SAME divisibility/VMEM gate:
@@ -113,15 +116,22 @@ def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
     itemsize = np.dtype(dtype).itemsize
     sub = _sublane(dtype)
     s = max(1, int(max_query_span))
+
+    def pad(n, m):
+        return -(-n // m) * m
+    d_pad = pad(d_head, 128)
+    rows = pad(s * num_heads, 8)                  # f32 scratch sublanes
     candidates = _TILE_CANDIDATES if tile is None else (int(tile),)
     for cand in candidates:
         if cand <= 0 or cand % sub or max_len % cand \
                 or cand > max_len // 2:
             continue
-        need = (2 * 2 * cand * num_kv_heads * d_head * itemsize  # K+V x2 buf
-                + s * 2 * num_heads * d_head * itemsize          # q + out
-                + s * num_heads * d_head * 4                     # f32 acc
-                + s * 2 * num_heads * 128 * 4)                   # m + l
+        need = (2 * 2 * cand * pad(num_kv_heads, sub) * d_pad
+                * itemsize                                       # K+V x2 buf
+                + 2 * 2 * s * pad(num_heads, sub) * d_pad
+                * itemsize                                       # q+out x2 buf
+                + rows * d_pad * 4                               # f32 acc
+                + 2 * rows * 128 * 4)                            # m + l
         if need <= _VMEM_BUDGET:
             return PagedGeometry(cand, max_len // cand, need)
     return None

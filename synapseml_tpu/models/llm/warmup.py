@@ -246,10 +246,14 @@ class CompilePlane:
 
     States: ``cold`` (created, not started) → ``warming`` (lattice
     running) → ``warm`` (every program compiled; ``ready_at`` set) or
-    ``failed`` (a spec raised — the engine still serves, programs
-    compile lazily, and the failure is in the snapshot).  ``/readyz``
-    serves :meth:`snapshot` and flips ready only at ``warm``
-    (:class:`~synapseml_tpu.resilience.health.HealthState.set_warmup`).
+    ``failed`` (a spec raised — the failure is in the snapshot; a
+    background warm leaves the engine serving with lazy compiles, a
+    synchronous one re-raises to the caller that was waiting on it).
+    ``/readyz`` serves :meth:`snapshot` and gates only on
+    ``cold``/``warming``
+    (:class:`~synapseml_tpu.resilience.health.HealthState.set_warmup`),
+    so ``is_warm`` means "no longer warming", not "succeeded": read
+    :attr:`status` for the verdict.
     """
 
     def __init__(self, engine, name: str = "llm"):
@@ -315,7 +319,8 @@ class CompilePlane:
     def start(self, background: bool = True) -> "CompilePlane":
         """Enumerate the lattice and compile it — on a daemon thread
         (``background=True``; gate traffic on :meth:`is_warm`) or
-        inline."""
+        inline, where a program that fails to compile or run raises
+        here, after the ``failed`` state is recorded."""
         with self._lock:
             if self._status != "cold":
                 return self
@@ -329,14 +334,14 @@ class CompilePlane:
                 daemon=True)
             self._thread.start()
         else:
-            self._warm_all()
+            self._warm_all(reraise=True)
         return self
 
     def _pop_next(self) -> Optional[ProgramSpec]:
         with self._lock:
             return self._pending.pop(0) if self._pending else None
 
-    def _warm_all(self) -> None:
+    def _warm_all(self, reraise: bool = False) -> None:
         hook = _PRE_WARM_HOOK
         if hook is not None:
             hook()
@@ -360,15 +365,17 @@ class CompilePlane:
                                     for s in self._pending)
                 if base_done:
                     self._base_ready.set()
-        except Exception as e:  # noqa: BLE001 — a failed warmup must
-            #                     not kill serving; programs compile
-            #                     lazily and the failure is visible
+        except Exception as e:  # noqa: BLE001 — a failed background
+            #                     warmup must not kill serving; programs
+            #                     compile lazily and the failure is visible
             with self._lock:
                 self._status = "failed"
                 self._error = f"{type(e).__name__}: {e}"
             self._g_state.set(-1.0, engine=self.name)
             self._base_ready.set()
             self._ready.set()       # gate must not wedge the replica
+            if reraise:
+                raise
             return
         self.warmup_seconds = time.monotonic() - t0
         with self._lock:

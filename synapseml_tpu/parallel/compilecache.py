@@ -1,54 +1,43 @@
-"""Persistent XLA compilation cache + compile attribution.
+"""Compile attribution over jax's persistent compilation cache.
 
-Two halves of the compile plane's substrate (the serving-side lattice
-warmup lives in :mod:`synapseml_tpu.models.llm.warmup`; this module is
-workload-agnostic — the DL/GBDT training steps reuse cached artifacts
-through the same knob):
+Where the cache lives is decided once, in ``synapseml_tpu/__init__.py``
+(``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``);
+gang workers and bench children inherit it through the environment.  This
+module only *reads* it (:func:`compilation_cache_dir`) and attributes what
+the compiler does (the serving-side lattice warmup lives in
+:mod:`synapseml_tpu.models.llm.warmup`; the DL/GBDT training steps are
+counted by the same listeners).
 
-- **persistent cache** — :func:`enable_compilation_cache` wires
-  ``jax_compilation_cache_dir`` (plus the min-size/min-time thresholds,
-  floored so even this CPU container's sub-second programs land in the
-  cache) so a relaunched or resized gang re-loads compiled executables
-  from disk instead of re-running XLA.  The directory threads through
-  :class:`~synapseml_tpu.parallel.supervisor.GangSupervisor` to every
-  worker as ``SMLTPU_COMPILE_CACHE_DIR``; workers call
-  :func:`enable_from_env` before their task compiles anything.
-
-- **attribution** — :func:`install_compile_listeners` registers
-  ``jax.monitoring`` listeners once per process: every backend compile
-  lands in the ``llm_compile_seconds{program}`` histogram (labelled by
-  the thread's current :func:`compile_label`, ``unattributed``
-  otherwise) and the ``xla_compiles_total{program}`` counter; the
-  persistent cache's own hit/miss events land in
-  ``xla_compile_cache_hits_total`` / ``xla_compile_cache_misses_total``
-  — so "how long did this replica spend in XLA, on which program, and
-  did the cache help" is answerable from ``/metrics`` alone.
-
-Everything degrades to a no-op when the running jax predates an API
-(monitoring, a cache threshold option): the plane loses attribution or
-cache coverage, never correctness.
+:func:`install_compile_listeners` registers ``jax.monitoring`` listeners
+once per process: every compile request lands in the
+``llm_compile_seconds{program}`` histogram (labelled by the thread's
+current :func:`compile_label`, ``unattributed`` otherwise) and the
+``xla_compiles_total{program}`` counter — a request the persistent cache
+answers counts too, with its short load time; the cache's own events land
+in ``xla_compile_cache_hits_total`` (executable loaded from disk) and
+``xla_compile_cache_misses_total`` (compiled, then stored) — so "how long
+did this replica spend in XLA, on which program, and did the cache help"
+is answerable from ``/metrics`` alone.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 from typing import Dict, Iterator, Optional
+
+import jax
+from jax import monitoring
 
 from ..telemetry import get_registry
 
 __all__ = [
-    "COMPILE_CACHE_ENV", "cache_stats", "compile_label",
-    "enable_compilation_cache", "enable_from_env",
+    "cache_stats", "compilation_cache_dir", "compile_label",
     "install_compile_listeners",
 ]
 
-#: env var carrying the persistent compilation cache directory to every
-#: gang worker (the ``SMLTPU_CKPT_DIR`` idiom)
-COMPILE_CACHE_ENV = "SMLTPU_COMPILE_CACHE_DIR"
-
-#: the jax.monitoring event one backend (XLA) compile emits
+#: the jax.monitoring event one compile request emits (it brackets the
+#: persistent-cache lookup, so a cache hit fires it too)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: persistent-cache verdict events (one per cacheable compile request)
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -61,7 +50,6 @@ _COMPILE_SECONDS_BUCKETS = (0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0, 30.0,
 
 _lock = threading.Lock()
 _listeners_installed = False
-_cache_dir: Optional[str] = None
 #: thread-local compile attribution label (see :func:`compile_label`)
 _tls = threading.local()
 #: process-wide raw tallies, readable without the registry (the bench
@@ -87,18 +75,12 @@ def compile_label(label: str) -> Iterator[None]:
         _tls.label = prev
 
 
-def install_compile_listeners() -> bool:
-    """Register the process-wide jax.monitoring listeners (idempotent).
-    Returns False when this jax has no monitoring API — attribution is
-    lost, nothing else."""
+def install_compile_listeners() -> None:
+    """Register the process-wide jax.monitoring listeners (idempotent)."""
     global _listeners_installed
     with _lock:
         if _listeners_installed:
-            return True
-        try:
-            from jax import monitoring
-        except Exception:  # noqa: BLE001 — jax too old / stripped
-            return False
+            return
         reg = get_registry()
         h_seconds = reg.histogram(
             "llm_compile_seconds",
@@ -134,77 +116,18 @@ def install_compile_listeners() -> bool:
                 _counts["cache_misses"] += 1
                 c_misses.inc(1)
 
-        try:
-            monitoring.register_event_duration_secs_listener(on_duration)
-            monitoring.register_event_listener(on_event)
-        except Exception:  # noqa: BLE001 — listener API drift
-            return False
+        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_listener(on_event)
         _listeners_installed = True
-        return True
 
 
 def cache_stats() -> Dict[str, int]:
-    """Raw process tallies: ``compiles`` / ``cache_hits`` /
-    ``cache_misses`` (zeros until :func:`install_compile_listeners` —
-    which every enable path runs — has been called)."""
+    """Raw process tallies: ``compiles`` (compile requests, persistent-
+    cache hits included) / ``cache_hits`` / ``cache_misses`` (zeros until
+    :func:`install_compile_listeners` has been called)."""
     return dict(_counts)
 
 
 def compilation_cache_dir() -> Optional[str]:
-    """The directory this process enabled, or None."""
-    return _cache_dir
-
-
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir`` and
-    floor the entry thresholds so every program caches (XLA's defaults
-    skip sub-second compiles — exactly the CPU-container regime, and
-    pointless filtering on TPU where the multi-second programs dominate
-    anyway).  Installs the attribution listeners as a side effect.
-    Idempotent per process; returns False (cache off, process fine)
-    when this jax has no persistent-cache support."""
-    global _cache_dir
-    install_compile_listeners()
-    try:
-        import jax
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                         ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:  # noqa: BLE001 — older jax: coarser cache
-                pass
-        # jax latches the cache state at the FIRST compile: a process
-        # that already compiled anything before this call (an engine
-        # constructed, then the knob turned on) has the cache pinned
-        # "disabled" and ignores the config update — reset so the next
-        # compile re-initializes against the new dir.  Private API,
-        # best-effort: without it, only enable-before-first-compile
-        # processes (the worker path) get the cache.
-        try:
-            from jax._src import compilation_cache as _jcc
-            _jcc.reset_cache()
-        except Exception:  # noqa: BLE001
-            pass
-    except Exception:  # noqa: BLE001 — no jax / no cache support
-        return False
-    with _lock:
-        _cache_dir = str(cache_dir)
-    try:
-        from ..telemetry.flight import record as flight_record
-        flight_record("compile_cache", dir=str(cache_dir))
-    except Exception:  # noqa: BLE001 — flight is advisory
-        pass
-    return True
-
-
-def enable_from_env() -> Optional[str]:
-    """Worker-side: enable the cache when the supervisor threaded
-    ``SMLTPU_COMPILE_CACHE_DIR`` through (returns the dir), else just
-    install the attribution listeners (returns None)."""
-    cache_dir = os.environ.get(COMPILE_CACHE_ENV)
-    if cache_dir:
-        return cache_dir if enable_compilation_cache(cache_dir) else None
-    install_compile_listeners()
-    return None
+    """The persistent compilation cache directory this process uses."""
+    return jax.config.jax_compilation_cache_dir

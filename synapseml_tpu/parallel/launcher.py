@@ -460,6 +460,16 @@ def run_on_local_cluster(task: str,
     table, and runs ``function(task_args)`` with collectives live across
     process boundaries.  The function must return something JSON-serializable.
 
+    This is a CPU-simulated gang by construction: every worker is started
+    with ``SMLTPU_PLATFORM=cpu`` and ``devices_per_process`` virtual host
+    devices (gloo collectives), so the workers never ask for an
+    accelerator and a driver that holds one (a chip belongs to one process
+    at a time) can launch them safely.  It exercises the multi-process
+    control plane — rendezvous, supervision, resize, checkpoint resume —
+    not device speed.  Workers inherit the driver's environment, and with
+    it the persistent compile cache directory
+    (``JAX_COMPILATION_CACHE_DIR``).
+
     Supervision is on by default (``heartbeat_interval_s=1.0``): every
     rank emits heartbeats, and a dead/hung rank fails the attempt within
     ``hang_intervals`` beats.  ``retry_policy``: on :class:`WorkerFailure`

@@ -297,7 +297,6 @@ class GangSupervisor:
                  resize_cooldown_s: float = 0.0,
                  max_resizes: int = 8,
                  capacity_fn: Optional[Callable[[], int]] = None,
-                 compile_cache_dir: Optional[str] = None,
                  tune_table_dir: Optional[str] = None):
         self.task = task
         self.n_processes = int(n_processes)
@@ -316,24 +315,11 @@ class GangSupervisor:
             checkpoint_dir = getattr(checkpoint_dir, "directory",
                                      checkpoint_dir)
         self.checkpoint_dir = checkpoint_dir
-        # persistent XLA compilation cache (ISSUE 15): the dir threads
-        # to every worker as SMLTPU_COMPILE_CACHE_DIR (the CKPT_DIR
-        # idiom) so relaunched AND resized gangs load compiled
-        # executables from disk instead of re-running XLA — the
-        # recompile-from-scratch tax was a visible slice of
-        # resize_recovery_seconds.  World-size-dependent programs
-        # (sharded train steps) key on their new shapes and simply
-        # miss; everything shape-stable hits.
-        self.compile_cache_dir = (str(compile_cache_dir)
-                                  if compile_cache_dir else None)
-        if self.compile_cache_dir:
-            from .compilecache import COMPILE_CACHE_ENV
-            self.env_extra.setdefault(COMPILE_CACHE_ENV,
-                                      self.compile_cache_dir)
-        # persisted autotune tuning tables (ISSUE 20): same threading as
-        # the compile cache — every worker (and every relaunch/resize
-        # generation) resolves its TunePlane against the shared dir, so
-        # a winner measured once serves the whole gang's lifetime
+        # persisted autotune tuning tables (ISSUE 20): every worker (and
+        # every relaunch/resize generation) resolves its TunePlane
+        # against the shared dir, so a winner measured once serves the
+        # whole gang's lifetime.  (The compile cache needs no threading:
+        # workers inherit JAX_COMPILATION_CACHE_DIR from the environment.)
         self.tune_table_dir = str(tune_table_dir) if tune_table_dir else None
         if self.tune_table_dir:
             from ..telemetry.tunetable import TUNE_TABLE_ENV

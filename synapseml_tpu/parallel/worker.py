@@ -20,10 +20,11 @@ Gang supervision hooks (all driver-controlled via env):
   indefinitely-hung rank.
 - ``SMLTPU_CKPT_DIR`` names the gang's checkpoint directory; tasks read
   it to resume elastically after a relaunch.
-- ``SMLTPU_COMPILE_CACHE_DIR`` points jax's persistent compilation
-  cache at a shared directory (enabled before the rendezvous, so even
-  rendezvous-time programs cache): a relaunched or resized gang loads
-  compiled executables from disk instead of re-running XLA.
+- the persistent compilation cache needs no hook: the worker inherits
+  ``JAX_COMPILATION_CACHE_DIR`` from the driver's environment (the
+  package import exports the resolved directory), so a relaunched or
+  resized gang loads compiled executables from disk instead of
+  re-running XLA.
 
 Gang observability hooks (see :mod:`synapseml_tpu.telemetry.gangplane`):
 
@@ -113,13 +114,11 @@ def main() -> int:
     flight_hooks = _install_flight_dump(rank)
     flight_dump = flight_hooks[0] if flight_hooks else None
 
-    # persistent XLA compilation cache: enabled BEFORE the rendezvous
-    # (and therefore before anything compiles) when the supervisor
-    # threaded SMLTPU_COMPILE_CACHE_DIR through — a relaunched or
-    # resized gang loads its compiled executables from disk.  Also
-    # installs the compile/cache-hit attribution listeners either way.
-    from synapseml_tpu.parallel.compilecache import enable_from_env
-    enable_from_env()
+    # compile/cache-hit attribution, before anything compiles (the cache
+    # directory itself came in through the environment)
+    from synapseml_tpu.parallel.compilecache import \
+        install_compile_listeners
+    install_compile_listeners()
 
     from synapseml_tpu.parallel.distributed import (ClusterConfig,
                                                     initialize_cluster,

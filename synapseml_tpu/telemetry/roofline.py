@@ -55,24 +55,24 @@ CHIP_HBM_BW = {
 }
 
 
-def chip_lookup(device, table: Dict[str, float],
-                default: Optional[float] = None) -> Optional[float]:
-    """Longest-prefix device-kind match into a spec table; ``default``
-    (None = "unknown backend, claim nothing") when no entry matches."""
+def chip_lookup(device, table: Dict[str, float]) -> Optional[float]:
+    """Longest-prefix device-kind match into a spec table; None when no
+    entry matches (unknown backend: claim nothing — a caller that needs
+    the number to compute a share or an MFU raises, it never guesses)."""
     kind = getattr(device, "device_kind", "") or ""
     best = None
     for name, val in table.items():
         if kind.startswith(name) and (best is None or len(name) > best[0]):
             best = (len(name), val)
-    return best[1] if best else default
+    return best[1] if best else None
 
 
-def chip_peak_flops(device, default: Optional[float] = None):
-    return chip_lookup(device, CHIP_PEAK_FLOPS, default)
+def chip_peak_flops(device) -> Optional[float]:
+    return chip_lookup(device, CHIP_PEAK_FLOPS)
 
 
-def chip_hbm_bw(device, default: Optional[float] = None):
-    return chip_lookup(device, CHIP_HBM_BW, default)
+def chip_hbm_bw(device) -> Optional[float]:
+    return chip_lookup(device, CHIP_HBM_BW)
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,7 @@ jitted entry points and records each search space's winner here; every
 later construction site (``SlotEngine``, the GBDT trainer,
 ``CollectiveConfig`` resolution, the collective planner) consults the
 SAME loader, so a fleet tunes once and every subsequent process loads
-the table — the ``SMLTPU_COMPILE_CACHE_DIR`` pattern, applied to kernel
+the table — the persistent-compile-cache pattern, applied to kernel
 geometry instead of compiled programs.  ``GangSupervisor`` threads the
 directory to workers as :data:`TUNE_TABLE_ENV`.
 
@@ -51,8 +51,7 @@ __all__ = [
 ]
 
 #: env var naming the tuning-table directory — threaded to workers by
-#: ``GangSupervisor`` exactly like ``SMLTPU_COMPILE_CACHE_DIR`` (store
-#: both in the same place: tables live beside the XLA compile cache)
+#: ``GangSupervisor``
 TUNE_TABLE_ENV = "SMLTPU_TUNE_TABLE_DIR"
 
 #: the single table file inside that directory

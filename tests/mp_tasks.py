@@ -73,8 +73,8 @@ def distributed_serving_roundtrip(args):
 
 def compile_cache_probe(args):
     """Compile a jitted program and report the persistent compilation
-    cache's verdict counters — the worker enabled the cache from
-    ``SMLTPU_COMPILE_CACHE_DIR`` before this task ran, so a FIRST gang
+    cache's verdict counters — the worker inherited
+    ``JAX_COMPILATION_CACHE_DIR`` from the driver, so a FIRST gang
     launch reports misses (compiled + stored) and a RELAUNCH over the
     same dir reports hits (loaded from disk, no XLA)."""
     import jax

@@ -1,15 +1,12 @@
 """Tier-1 guard: every bench/multichip artifact in the repo root must be
-parseable JSON, so a truncated write (the BENCH_r05 regression — its
-driver-captured stdout line was cut off and ``"parsed"`` is null) is
-caught at commit time instead of at read time rounds later.
+parseable JSON, so a truncated write (a driver-captured stdout line cut
+off, ``"parsed"`` null — how round 5's headline was lost) is caught at
+commit time instead of at read time rounds later.
 
-New artifacts are additionally held to the inner-record standard: when
-the driver wrapper carries a ``parsed`` field it must be a JSON object,
-and a ``tail`` that looks like it carries a JSON line must end in one
-that parses.  ``BENCH_r05.json`` predates the atomic artifact writer and
-is the known-truncated specimen this test exists to prevent recurring —
-it stays allowlisted (its loss is unrecoverable), everything after it
-must be clean.
+Artifacts are additionally held to the inner-record standard: when the
+driver wrapper carries a ``parsed`` field it must be a JSON object, and
+a ``tail`` that looks like it carries a JSON line must end in one that
+parses.
 """
 
 import glob
@@ -19,10 +16,6 @@ import os
 import pytest
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-#: artifacts that shipped broken BEFORE the atomic writer existed; never
-#: grows — new truncation is a bug this test must fail on
-KNOWN_TRUNCATED = {"BENCH_r05.json"}
 
 #: the continuous-batching serving block: when a bench record carries
 #: ANY ``llmserve_`` key it must carry the full acceptance-criteria set
@@ -277,8 +270,6 @@ def test_artifact_parses(path):
     with open(path, "r", encoding="utf-8") as f:
         obj = json.load(f)        # raises on any truncated/corrupt file
     name = os.path.basename(path)
-    if name in KNOWN_TRUNCATED:
-        return
     if isinstance(obj, dict) and "parsed" in obj:
         assert isinstance(obj["parsed"], dict), (
             f"{name}: driver wrapper carries parsed=null — the inner "
@@ -295,8 +286,6 @@ def _bench_records():
     top-level object when there is no driver wrapper)."""
     records = []
     for path in _artifact_paths():
-        if os.path.basename(path) in KNOWN_TRUNCATED:
-            continue
         with open(path, "r", encoding="utf-8") as f:
             try:
                 obj = json.load(f)
@@ -345,8 +334,7 @@ def _labeled_partial(rec):
     """A ``--only`` run with no prior BENCH_latest.json to merge over
     stamps its record ``metric: "partial bench (--only ...)"`` — a
     deliberate, labeled partial, exempt from block-completeness (the
-    label IS the honesty marker; committed BENCH_rXX artifacts come
-    from full sweeps and stay held to the full set)."""
+    label IS the honesty marker; full sweeps stay held to the full set)."""
     return str(rec.get("metric", "")).startswith("partial bench")
 
 

@@ -709,6 +709,9 @@ def test_training_instrumentation():
     assert m.compile_s <= m.training_s
     d = m.as_dict()
     assert "iterations_per_sec" in d and d["iterations_per_sec"] > 0
+    # the resolved histogram builder is on the record: off-TPU it is
+    # the XLA scatter path, never silently anything else
+    assert m.hist_path == "xla_scatter"
 
 
 def test_checkpoint_resume_matches_uninterrupted(tmp_path):

@@ -239,8 +239,13 @@ class TestEngineExactness:
         cold = SlotEngine(model, variables, n_slots=4, max_len=64,
                           min_prefix=8, attention_backend="dense")
         r_cold = cold.admit(p2, 4)
-        # prefill is the dense program under both backends
-        np.testing.assert_array_equal(r_warm.logits, r_cold.logits)
+        # prefill is the dense program under both backends, but the warm
+        # tail runs in a smaller bucket than the cold prompt: differently
+        # shaped XLA:CPU programs reassociate (2-3 ulp under jax 0.9.0;
+        # see test_llm_serving._assert_logits_match_cold) — tokens exact
+        np.testing.assert_allclose(
+            r_warm.logits, r_cold.logits, rtol=0,
+            atol=16 * np.spacing(np.float32(np.abs(r_cold.logits).max())))
         warm.run_to_completion()
         cold.run_to_completion()
         np.testing.assert_array_equal(warm.generated_ids(r_warm.slot),
@@ -325,6 +330,13 @@ class TestResolveAndGeometry:
         assert geo is not None
         assert 8192 % geo.tile == 0 and geo.tile <= 4096
         assert geo.tile % 16 == 0                 # bf16 sublane
+        # the estimate counts PADDED tiles: a (KV=8, D=64) bf16 K/V row
+        # occupies a (16, 128) tile, the same bytes as (KV=16, D=128)
+        small = paged_geometry(1024, 32, 8, 64, jnp.bfloat16)
+        padded = paged_geometry(1024, 32, 16, 128, jnp.bfloat16)
+        assert small.tile == padded.tile == 256
+        assert small.vmem_bytes == padded.vmem_bytes \
+            >= 2 * 2 * 256 * 16 * 128 * 2
         # a max_len no sublane-aligned tile divides: no geometry, and
         # the explicit backends refuse while auto falls back
         assert paged_geometry(100, 8, 4, 32, jnp.float32) is None
