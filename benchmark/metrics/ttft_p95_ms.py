@@ -1,0 +1,8 @@
+"""95th percentile of the time from sending a request to its first token,
+client clock, over the requests sent inside the window."""
+import numpy as np
+
+
+def read(facts, **_):
+    t = facts.get("ttft_s")
+    return 1e3 * float(np.percentile(t, 95)) if t else None
