@@ -1090,9 +1090,26 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
     path — the fused ``lax.scan`` dispatch admits no per-iteration
     boundary to time.
     """
+    with _telemetry.span("gbdt.fit", objective=config.objective) as fit_span:
+        return _train(fit_span, X, y, config, sample_weight, valid,
+                      feature_names, mesh, init_model, callbacks, group,
+                      valid_group, checkpoint_dir, checkpoint_interval,
+                      step_profiler)
+
+
+def _train(fit_span, X, y, config, sample_weight, valid, feature_names, mesh,
+           init_model, callbacks, group, valid_group, checkpoint_dir,
+           checkpoint_interval, step_profiler
+           ) -> Tuple[Booster, List[EvalRecord]]:
+    """:func:`train` under its ``gbdt.fit`` span.  The phases are spans
+    too (``gbdt.fit.bin``, ``.upload``, ``.compile``, ``.boost``,
+    ``.download``), opened and closed where ``InstrumentationMeasures``
+    reads its clock; none waits for the device, so one around
+    asynchronous uploads or dispatches times the enqueue."""
     import time as _time
     measures = InstrumentationMeasures()
     _t0 = _time.perf_counter()
+    _phase = _telemetry.span("gbdt.fit.bin", part="bin_mapper").start()
     # ``checkpoint_dir`` also accepts a core.checkpoint.CheckpointManager
     # (anything carrying ``.directory``): preemption-tolerant callers hand
     # the same manager to every trainer and the booster writes its
@@ -1327,6 +1344,7 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
                                 categorical_features=config.categorical_feature,
                                 y=np.asarray(y, np.float64))
     measures.binning_s = _time.perf_counter() - _t0
+    _phase.close()
     _t_prep = _time.perf_counter()
 
     # -- labels / weights --------------------------------------------------
@@ -1537,6 +1555,9 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
     # the upload, not the searchsorted, is the fixed cost that bounds short
     # training runs
     _t_bin2 = _time.perf_counter()
+    # host binning, chunk by chunk; each chunk's upload is enqueued as
+    # it is binned
+    _phase = _telemetry.span("gbdt.fit.bin", part="bin_rows").start()
 
     def bin_host(mat):
         if mapper.has_categorical:
@@ -1683,6 +1704,11 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
         carry = b[keep:].copy()    # view would pin the whole chunk
         if keep:
             dev_chunks.append(put_bins(b[:keep]))
+    _phase.close()
+    # the device-resident state assembled: the bins' tail, concatenate
+    # and transpose, labels, weights, margins, bounds (and what else
+    # prepares the loop, up to data_prep_s's end)
+    _phase = _telemetry.span("gbdt.fit.upload").start()
     tail_rows = (len(carry) if carry is not None else 0) + stream_pad
     if tail_rows:
         if rank_bundlers is not None:
@@ -1850,7 +1876,11 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
 
 
     measures.data_prep_s = _time.perf_counter() - _t_prep
+    _phase.close()
     _t_train = _time.perf_counter()
+    # up to the first dispatch: the wait for the warm thread's compile
+    # (a fit without one compiles inside its first dispatch)
+    _phase = _telemetry.span("gbdt.fit.compile").start()
     trees: List[Tree] = []
     tree_class: List[int] = []
     tree_weights: List[float] = []
@@ -1933,6 +1963,10 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
 
     if _warm_thread is not None:
         _warm_thread.join()
+    _phase.close()
+    # from the main thread's first dispatch (a cached factory call and a
+    # mask upload ahead of it) to the trees on the host
+    boost_span = _telemetry.span("gbdt.fit.boost").start()
 
     scan_start = 0          # iterations handled by scanned dispatches
     n_scan_chunks = config.num_iterations // SCAN_CHUNK if use_scan else 0
@@ -1953,11 +1987,15 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
         chunk_stacks = []
         sc = scores
         for ci in range(n_scan_chunks):
-            sc, tstacks = scan_fn(
-                bins_t, sc, labels, weights, base_bag_dev, bag_root_key,
-                fmask_dev, upper_bounds, num_bins, bundle_map_dev,
-                init_scores_dev if is_rf else scores,
-                jnp.asarray(prior_iters + ci * SCAN_CHUNK, jnp.int32))
+            # the dispatch of one scanned chunk: the enqueue (the first
+            # returns once compiled; the device runs behind)
+            with _telemetry.span("gbdt.boost.chunk", index=ci,
+                                 iterations=SCAN_CHUNK):
+                sc, tstacks = scan_fn(
+                    bins_t, sc, labels, weights, base_bag_dev, bag_root_key,
+                    fmask_dev, upper_bounds, num_bins, bundle_map_dev,
+                    init_scores_dev if is_rf else scores,
+                    jnp.asarray(prior_iters + ci * SCAN_CHUNK, jnp.int32))
             chunk_stacks.append(tstacks)
             if ci == 0:
                 # first dispatch returns once compiled; execution is async
@@ -1966,7 +2004,8 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
         # ONE readback for every tree of every chunk (per-field np.asarray
         # would pay a blocking transfer each, 11 fields x chunks); tree
         # ints fit f32 exactly (ids < 2^7, counts <= N < 2^24)
-        flat = np.asarray(_pack_flat(chunk_stacks))
+        with _telemetry.span("gbdt.fit.download", chunks=n_scan_chunks):
+            flat = np.asarray(_pack_flat(chunk_stacks))
         off = 0
         host_stacks = []
         for ts in chunk_stacks:
@@ -2158,11 +2197,13 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
         # one jitted computation for ALL fields: stacking field-by-field in
         # eager ops compiles 11 tiny XLA programs (~13 s on a cold cache);
         # a single fused stack compiles once
-        stacked = jax.jit(
-            lambda ts: Tree(*[jnp.stack([getattr(t, f) for t in ts])
-                              for f in Tree._fields]))(
-            [t for t, _ in pending_stacks])
-        all_fields = [np.asarray(a) for a in stacked]
+        with _telemetry.span("gbdt.fit.download",
+                             trees=len(pending_stacks)):
+            stacked = jax.jit(
+                lambda ts: Tree(*[jnp.stack([getattr(t, f) for t in ts])
+                                  for f in Tree._fields]))(
+                [t for t, _ in pending_stacks])
+            all_fields = [np.asarray(a) for a in stacked]
         for i, (_, per_class_weights) in enumerate(pending_stacks):
             for k in range(K):
                 trees.append(Tree(*[a[i, k] for a in all_fields]))
@@ -2170,6 +2211,8 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
                 tree_weights.append(per_class_weights[k])
     measures.training_s = _time.perf_counter() - _t_train
     measures.iterations = len(trees) // max(K, 1)  # this fit only — before
+    boost_span.set(iterations=measures.iterations)
+    boost_span.close()
     if init_model is not None:                     # the warm-start fold-in
         # continued training: carry previous trees forward (modelString
         # warm-start fold-in, LightGBMBase.scala:38-59)
@@ -2177,7 +2220,7 @@ def train(X: np.ndarray, y: np.ndarray, config: BoostingConfig,
         tree_class = init_model.tree_class + tree_class
         tree_weights = init_model.tree_weights + tree_weights
     measures.total_s = _time.perf_counter() - _t0
-    _publish_measures(measures, config, n_rows=n, n_features=F)
+    _publish_measures(measures, config, fit_span, n_rows=n, n_features=F)
     booster = Booster(trees, tree_class, tree_weights, K, config.objective,
                       init_sc, mapper, feature_names, config,
                       best_iteration=best_iter, bundler=bundler)
@@ -2191,12 +2234,13 @@ _PHASE_BUCKETS = (0.01, 0.05, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 30.0, 60.0,
 
 
 def _publish_measures(measures: "InstrumentationMeasures",
-                      config: "BoostingConfig", n_rows: int,
+                      config: "BoostingConfig", fit_span, n_rows: int,
                       n_features: int) -> None:
     """Mirror one fit's InstrumentationMeasures into the process
     telemetry: a per-phase histogram (the round-over-round "which boost
     phase regressed" answer), an iteration counter, the resolved
-    two-level-mode gauge, and one retrospective ``gbdt.train`` span."""
+    two-level-mode gauge, and the fit's attribution on its ``gbdt.fit``
+    span."""
     try:
         reg = _telemetry.get_registry()
         hist = reg.histogram(
@@ -2216,9 +2260,9 @@ def _publish_measures(measures: "InstrumentationMeasures",
                   "1 when the finished fit trained with coarse-then-"
                   "refine histograms", ()).set(
                       1.0 if config.two_level_hist in ("on", True) else 0.0)
-        _telemetry.get_tracer().record(
-            "gbdt.train", measures.total_s, rows=n_rows,
-            features=n_features, objective=config.objective,
+        fit_span.set(
+            rows=n_rows, features=n_features,
+            iterations=measures.iterations, hist_path=measures.hist_path,
             two_level=str(config.two_level_hist),
             **{k: round(v, 4) for k, v in measures.as_dict().items()
                if isinstance(v, float)})

@@ -73,7 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ...telemetry import get_registry
+from ...telemetry import get_registry, step_span
 from ...telemetry.flight import record as _flight_record
 from .drafter import NgramDrafter
 from .kvtier import ChecksumError, RadixPrefixIndex, kvtier_metrics
@@ -245,7 +245,9 @@ class AdmitResult:
     (f32 host copy — the prefix-reuse exactness surface).  ``bucket``
     (the padded prefill bucket) and ``reason`` (the finish verdict,
     when ``finished``) feed the request-scoped trace the serving loop
-    keeps per request."""
+    keeps per request; ``path`` says where the prompt's K/V came from
+    (``cold``: all prefilled, ``reuse``: a device-resident prefix,
+    ``restore``: the host arena)."""
     slot: int
     token: int
     finished: bool
@@ -253,6 +255,7 @@ class AdmitResult:
     logits: np.ndarray
     bucket: int = 0
     reason: Optional[str] = None
+    path: str = "cold"
 
 
 @dataclasses.dataclass
@@ -328,6 +331,9 @@ class SlotEngine:
         #: committed span sizes).  None costs one attribute check per
         #: slot per step.
         self.trace_sink = trace_sink
+        #: key of the program the last step dispatched (the
+        #: ``engine.step`` span's ``program``)
+        self.last_program: Optional[str] = None
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.top_p = float(top_p)
@@ -708,80 +714,97 @@ class SlotEngine:
         slot = self._pick_slot()
         if slot is None:
             return None
+        with step_span("engine.admit") as sp:
+            res = self._admit_into(slot, prompt, max_new, str(tenant))
+            if sp.live:
+                sp.set(bucket=res.bucket, prompt_tokens=len(prompt),
+                       reused_tokens=res.reused_tokens, path=res.path)
+            return res
+
+    def _admit_into(self, slot: int, prompt: np.ndarray, max_new: int,
+                    tenant: str) -> AdmitResult:
+        """The admission itself, once a slot is picked: look for reusable
+        K/V, prefill the rest, sample the first token, fill the slot."""
         t0 = time.perf_counter()
-        tenant = str(tenant)
-        # the slot's tenant is set BEFORE any cache lookup: _best_prefix
-        # and _register_prefix scope themselves by it
-        self._slot_tenant[slot] = tenant
-        src, lcp = self._best_prefix(prompt, slot)
-        restored = False
-        if self.kv_arena is not None:
-            # host tier: a spilled span longer than any device-resident
-            # prefix restores instead (device reuse is free-er, so it
-            # wins ties); every failure here degrades to the device/
-            # cold path below — never a wrong token
-            akey, alcp = self.kv_arena.longest_prefix(prompt,
-                                                      tenant=tenant)
-            alcp = self._clamp_reuse(int(min(alcp, len(prompt) - 1)),
-                                     len(prompt))
-            if akey is not None and alcp >= self.min_prefix \
-                    and alcp > lcp:
-                restored = self._restore_from_arena(akey, alcp, slot,
-                                                    tenant=tenant)
-                if restored:
-                    src, lcp = None, alcp
-        if restored or (src is not None and lcp > 0):
-            if not restored and src != slot:
-                with self._program_region("prefix_copy"):
-                    self.cache = _copy_prefix_jit(self.cache, src, slot,
-                                                  lcp)
-            # src == slot: in-place resume — the reclaimed slot already
-            # holds this conversation's prefix K/V, no copy needed
-            self.prefix_hits += 1
-            self.prefix_tokens_reused += lcp
-            self._m_reuse.inc(1, engine=self.name)
-            self._m_reuse_tok.inc(lcp, engine=self.name)
-        else:
-            lcp = 0
-        tail = prompt[lcp:]
-        pb = self._bucket(len(tail))
-        padded = np.full(pb, self.pad_id, np.int32)
-        padded[:len(tail)] = tail
-        with self._program_region(_prefill_program_key(pb)):
-            self.cache, last = _prefill_slot_jit(
-                self.model, self.variables, self.cache,
-                jnp.asarray(padded), len(tail), slot, lcp)
-        logits = np.asarray(last, np.float32)
-        tok = self._sample_host(logits)
-        plen = len(prompt)
-        self.ctx[slot, :plen] = prompt
-        self.ctx[slot, plen] = tok
-        self.lengths[slot] = plen + 1
-        self.kv_len[slot] = plen
-        self.active[slot] = True
-        self._max_new[slot] = max_new
-        self._generated[slot] = 1
-        self._register_prefix(slot, prompt)
-        if self._drafter is not None:
-            # (re)build the slot's n-gram tables from prompt + first
-            # token — a REUSED prefix feeds the table identically (the
-            # tables index tokens, which admit always has in full)
-            self._spec_k[slot] = self._spec_k0
-            self._spec_ewma[slot] = 1.0
-            self._drafter.begin(slot, self.ctx[slot], plen + 1)
-        self.admissions += 1
-        self._m_admit.inc(1, engine=self.name, tenant=tenant)
-        self.tokens_generated += 1
-        self._m_tokens.inc(1, engine=self.name)
-        finished, reason = self._finish_reason(slot, tok)
-        if finished:
-            self._retire(slot, reason)
-        self._m_occ.set(self.active_count / self.n_slots, engine=self.name)
-        self._mkv.admit_latency.observe(
-            time.perf_counter() - t0, engine=self.name,
-            path="restore" if restored else "cold")
-        return AdmitResult(slot, tok, finished, lcp, logits,
-                           bucket=pb, reason=reason)
+        with step_span("engine.admit.lookup"):
+            # the slot's tenant is set BEFORE any cache lookup:
+            # _best_prefix and _register_prefix scope themselves by it
+            self._slot_tenant[slot] = tenant
+            src, lcp = self._best_prefix(prompt, slot)
+            restored = False
+            if self.kv_arena is not None:
+                # host tier: a spilled span longer than any device-
+                # resident prefix restores instead (device reuse is
+                # free-er, so it wins ties); every failure here degrades
+                # to the device/cold path below — never a wrong token
+                akey, alcp = self.kv_arena.longest_prefix(prompt,
+                                                          tenant=tenant)
+                alcp = self._clamp_reuse(int(min(alcp, len(prompt) - 1)),
+                                         len(prompt))
+                if akey is not None and alcp >= self.min_prefix \
+                        and alcp > lcp:
+                    restored = self._restore_from_arena(akey, alcp, slot,
+                                                        tenant=tenant)
+                    if restored:
+                        src, lcp = None, alcp
+            if restored or (src is not None and lcp > 0):
+                if not restored and src != slot:
+                    with self._program_region("prefix_copy"):
+                        self.cache = _copy_prefix_jit(self.cache, src, slot,
+                                                      lcp)
+                # src == slot: in-place resume — the reclaimed slot
+                # already holds this conversation's prefix K/V, no copy
+                self.prefix_hits += 1
+                self.prefix_tokens_reused += lcp
+                self._m_reuse.inc(1, engine=self.name)
+                self._m_reuse_tok.inc(lcp, engine=self.name)
+            else:
+                lcp = 0
+        with step_span("engine.admit.prefill"):
+            # pad, upload, dispatch, and the blocking read of the logits
+            tail = prompt[lcp:]
+            pb = self._bucket(len(tail))
+            padded = np.full(pb, self.pad_id, np.int32)
+            padded[:len(tail)] = tail
+            with self._program_region(_prefill_program_key(pb)):
+                self.cache, last = _prefill_slot_jit(
+                    self.model, self.variables, self.cache,
+                    jnp.asarray(padded), len(tail), slot, lcp)
+            logits = np.asarray(last, np.float32)
+        with step_span("engine.admit.commit"):
+            tok = self._sample_host(logits)
+            plen = len(prompt)
+            self.ctx[slot, :plen] = prompt
+            self.ctx[slot, plen] = tok
+            self.lengths[slot] = plen + 1
+            self.kv_len[slot] = plen
+            self.active[slot] = True
+            self._max_new[slot] = max_new
+            self._generated[slot] = 1
+            self._register_prefix(slot, prompt)
+            if self._drafter is not None:
+                # (re)build the slot's n-gram tables from prompt + first
+                # token — a REUSED prefix feeds the table identically
+                # (the tables index tokens, which admit always has in
+                # full)
+                self._spec_k[slot] = self._spec_k0
+                self._spec_ewma[slot] = 1.0
+                self._drafter.begin(slot, self.ctx[slot], plen + 1)
+            self.admissions += 1
+            self._m_admit.inc(1, engine=self.name, tenant=tenant)
+            self.tokens_generated += 1
+            self._m_tokens.inc(1, engine=self.name)
+            finished, reason = self._finish_reason(slot, tok)
+            if finished:
+                self._retire(slot, reason)
+            self._m_occ.set(self.active_count / self.n_slots,
+                            engine=self.name)
+            self._mkv.admit_latency.observe(
+                time.perf_counter() - t0, engine=self.name,
+                path="restore" if restored else "cold")
+            return AdmitResult(
+                slot, tok, finished, lcp, logits, bucket=pb, reason=reason,
+                path="restore" if restored else "reuse" if lcp else "cold")
 
     # -- stepping ----------------------------------------------------------
     def _finish_reason(self, slot: int,
@@ -1038,12 +1061,23 @@ class SlotEngine:
         miss costs nothing."""
         if not self.active.any():
             return []
-        if self._drafter is not None:
-            s_cap = self._spec_headroom()
-            drafts = self._collect_drafts(s_cap)
-            if drafts:
-                return self._finish_step(self._verify_step(drafts, s_cap))
-        return self._finish_step(self._plain_step())
+        with step_span("engine.step") as sp:
+            if sp.live:
+                act = self.active
+                sp.set(slots=int(act.sum()),
+                       kv_span_sum=int(self.lengths[act].sum()))
+            events = None
+            if self._drafter is not None:
+                with step_span("engine.step.draft"):
+                    s_cap = self._spec_headroom()
+                    drafts = self._collect_drafts(s_cap)
+                if drafts:
+                    events = self._verify_step(drafts, s_cap)
+            if events is None:
+                events = self._plain_step()
+            if sp.live:
+                sp.set(tokens=len(events), program=self.last_program)
+            return events
 
     def _finish_step(self, events: List[StepEvent]) -> List[StepEvent]:
         """Common step epilogue: retirement, counters, and the
@@ -1063,54 +1097,64 @@ class SlotEngine:
 
     def _plain_step(self) -> List[StepEvent]:
         """The one-token step (the pre-spec decode path)."""
-        idx = np.arange(self.n_slots)
-        kw, lengths = self._decode_step_args()
-        tokens = np.where(self.active,
-                          self.ctx[idx, np.maximum(self.lengths - 1, 0)],
-                          self.pad_id).astype(np.int32)
-        prof = self.step_profiler
-        if prof is not None:
-            if getattr(prof, "capture_xla", False):
-                nt = kw["paged_num_tiles"]
-                prof.capture_cost(
-                    f"llm_decode_step_{self.attention_backend}"
-                    + (f"_nt{nt}" if nt is not None else ""),
-                    _decode_step_jit, self.model, self.variables,
-                    self.cache, jnp.asarray(tokens),
-                    jnp.asarray(lengths.astype(np.int32)),
-                    jnp.asarray(self.active), self._key, self.temperature,
-                    self.top_k, self.top_p,
-                    items=float(self.active_count), **kw)
-            prof.step_begin()
-        with self._program_region(_decode_program_key(
-                self.attention_backend, kw["paged_num_tiles"])):
-            self.cache, nxt, self._key = _decode_step_jit(
-                self.model, self.variables, self.cache,
-                jnp.asarray(tokens), jnp.asarray(lengths.astype(np.int32)),
-                jnp.asarray(self.active), self._key, self.temperature,
-                self.top_k, self.top_p, **kw)
-            nxt = np.asarray(nxt)
+        with step_span("engine.step.prepare"):
+            # host arrays, their uploads and the dispatch (asynchronous:
+            # what is timed there is the enqueue)
+            idx = np.arange(self.n_slots)
+            kw, lengths = self._decode_step_args()
+            tokens = np.where(self.active,
+                              self.ctx[idx, np.maximum(self.lengths - 1, 0)],
+                              self.pad_id).astype(np.int32)
+            prof = self.step_profiler
+            if prof is not None:
+                if getattr(prof, "capture_xla", False):
+                    nt = kw["paged_num_tiles"]
+                    prof.capture_cost(
+                        f"llm_decode_step_{self.attention_backend}"
+                        + (f"_nt{nt}" if nt is not None else ""),
+                        _decode_step_jit, self.model, self.variables,
+                        self.cache, jnp.asarray(tokens),
+                        jnp.asarray(lengths.astype(np.int32)),
+                        jnp.asarray(self.active), self._key,
+                        self.temperature, self.top_k, self.top_p,
+                        items=float(self.active_count), **kw)
+                prof.step_begin()
+            self.last_program = _decode_program_key(
+                self.attention_backend, kw["paged_num_tiles"])
+            with step_span("engine.step.prepare.upload"):
+                step_in = (jnp.asarray(tokens),
+                           jnp.asarray(lengths.astype(np.int32)),
+                           jnp.asarray(self.active))
+            with self._program_region(self.last_program), \
+                    step_span("engine.step.prepare.dispatch"):
+                self.cache, nxt, self._key = _decode_step_jit(
+                    self.model, self.variables, self.cache, *step_in,
+                    self._key, self.temperature, self.top_k, self.top_p,
+                    **kw)
+        with step_span("engine.step.wait"):
+            nxt = np.asarray(nxt)     # the step's one blocking call
         if prof is not None:
             prof.mark("compute")      # np.asarray synchronized the step
             prof.step_end()
-        self._account_decode_bytes(lengths, int(self.active.sum()))
-        events: List[StepEvent] = []
-        for slot in np.flatnonzero(self.active):
-            slot = int(slot)
-            tok = int(nxt[slot])
-            ln = int(self.lengths[slot])
-            self.ctx[slot, ln] = tok
-            self.lengths[slot] = ln + 1
-            self.kv_len[slot] = ln        # the fed token's K/V just landed
-            self._generated[slot] += 1
-            self.tokens_generated += 1
-            if self._drafter is not None:
-                self._drafter.extend(slot, self.ctx[slot], ln, ln + 1)
-            if self.trace_sink is not None:
-                self.trace_sink(slot, "decode", tokens=1)
-            finished, reason = self._finish_reason(slot, tok)
-            events.append(StepEvent(slot, tok, finished, reason))
-        return events
+        with step_span("engine.step.commit"):
+            self._account_decode_bytes(lengths, int(self.active.sum()))
+            events: List[StepEvent] = []
+            for slot in np.flatnonzero(self.active):
+                slot = int(slot)
+                tok = int(nxt[slot])
+                ln = int(self.lengths[slot])
+                self.ctx[slot, ln] = tok
+                self.lengths[slot] = ln + 1
+                self.kv_len[slot] = ln    # the fed token's K/V just landed
+                self._generated[slot] += 1
+                self.tokens_generated += 1
+                if self._drafter is not None:
+                    self._drafter.extend(slot, self.ctx[slot], ln, ln + 1)
+                if self.trace_sink is not None:
+                    self.trace_sink(slot, "decode", tokens=1)
+                finished, reason = self._finish_reason(slot, tok)
+                events.append(StepEvent(slot, tok, finished, reason))
+            return self._finish_step(events)
 
     # -- speculative decoding ----------------------------------------------
     def _spec_headroom(self) -> int:
@@ -1168,42 +1212,57 @@ class SlotEngine:
         exact-greedy prefix, commit accepted + 1 tokens through the
         slot_mask-gated scatter (already landed — only COMMITTED
         positions become attendable via ``lengths``/``kv_len``)."""
-        idx = np.arange(self.n_slots)
-        S = self._spec_bucket(max(len(d) for d in drafts.values()), s_cap)
-        kw, lengths = self._decode_step_args(extra_span=S - 1)
-        tokens = np.full((self.n_slots, S), self.pad_id, np.int32)
-        tokens[:, 0] = np.where(
-            self.active, self.ctx[idx, np.maximum(self.lengths - 1, 0)],
-            self.pad_id)
-        klen = np.zeros(self.n_slots, np.int64)
-        for slot, d in drafts.items():
-            d = d[:S - 1]
-            tokens[slot, 1:1 + len(d)] = d
-            klen[slot] = len(d)
-        prof = self.step_profiler
-        if prof is not None:
-            if getattr(prof, "capture_xla", False):
-                nt = kw["paged_num_tiles"]
-                prof.capture_cost(
-                    f"llm_verify_step_{self.attention_backend}_s{S}"
-                    + (f"_nt{nt}" if nt is not None else ""),
-                    _verify_step_jit, self.model, self.variables,
-                    self.cache, jnp.asarray(tokens),
-                    jnp.asarray(lengths.astype(np.int32)),
-                    jnp.asarray(self.active),
-                    items=float(self.active_count), **kw)
-            prof.step_begin()
-        with self._program_region(_verify_program_key(
-                self.attention_backend, S, kw["paged_num_tiles"])):
-            self.cache, g = _verify_step_jit(
-                self.model, self.variables, self.cache,
-                jnp.asarray(tokens),
-                jnp.asarray(lengths.astype(np.int32)),
-                jnp.asarray(self.active), **kw)
-            g = np.asarray(g)
+        with step_span("engine.step.prepare"):
+            idx = np.arange(self.n_slots)
+            S = self._spec_bucket(max(len(d) for d in drafts.values()),
+                                  s_cap)
+            kw, lengths = self._decode_step_args(extra_span=S - 1)
+            tokens = np.full((self.n_slots, S), self.pad_id, np.int32)
+            tokens[:, 0] = np.where(
+                self.active, self.ctx[idx, np.maximum(self.lengths - 1, 0)],
+                self.pad_id)
+            klen = np.zeros(self.n_slots, np.int64)
+            for slot, d in drafts.items():
+                d = d[:S - 1]
+                tokens[slot, 1:1 + len(d)] = d
+                klen[slot] = len(d)
+            prof = self.step_profiler
+            if prof is not None:
+                if getattr(prof, "capture_xla", False):
+                    nt = kw["paged_num_tiles"]
+                    prof.capture_cost(
+                        f"llm_verify_step_{self.attention_backend}_s{S}"
+                        + (f"_nt{nt}" if nt is not None else ""),
+                        _verify_step_jit, self.model, self.variables,
+                        self.cache, jnp.asarray(tokens),
+                        jnp.asarray(lengths.astype(np.int32)),
+                        jnp.asarray(self.active),
+                        items=float(self.active_count), **kw)
+                prof.step_begin()
+            self.last_program = _verify_program_key(
+                self.attention_backend, S, kw["paged_num_tiles"])
+            with step_span("engine.step.prepare.upload"):
+                step_in = (jnp.asarray(tokens),
+                           jnp.asarray(lengths.astype(np.int32)),
+                           jnp.asarray(self.active))
+            with self._program_region(self.last_program), \
+                    step_span("engine.step.prepare.dispatch"):
+                self.cache, g = _verify_step_jit(
+                    self.model, self.variables, self.cache, *step_in, **kw)
+        with step_span("engine.step.wait"):
+            g = np.asarray(g)         # the step's one blocking call
         if prof is not None:
             prof.mark("compute")      # np.asarray synchronized the step
             prof.step_end()
+        with step_span("engine.step.commit"):
+            return self._finish_step(
+                self._commit_verified(tokens, g, klen, lengths, S))
+
+    def _commit_verified(self, tokens: np.ndarray, g: np.ndarray,
+                         klen: np.ndarray, lengths: np.ndarray,
+                         S: int) -> List[StepEvent]:
+        """Accept each slot's longest exact-greedy draft prefix plus the
+        model's bonus token; the host half of a verify step."""
         self.spec_steps += 1
         events: List[StepEvent] = []
         served = 0

@@ -49,7 +49,7 @@ import numpy as np
 
 from ...parallel.compilecache import (cache_stats, compile_label,
                                       install_compile_listeners)
-from ...telemetry import get_registry
+from ...telemetry import get_registry, span
 from .model import init_cache
 from .slots import (_copy_prefix_jit, _decode_program_key,
                     _decode_step_jit, _next_pow2, _prefill_program_key,
@@ -347,6 +347,7 @@ class CompilePlane:
             hook()
         t0 = time.monotonic()
         cfg = self.engine.cfg
+        warm_span = span("llm.warmup", engine=self.name).start()
         try:
             # scratch state shaped exactly like the engine's cache: the
             # jitted programs donate their cache argument, so one
@@ -374,9 +375,13 @@ class CompilePlane:
             self._g_state.set(-1.0, engine=self.name)
             self._base_ready.set()
             self._ready.set()       # gate must not wedge the replica
+            warm_span.set(error=self._error)
             if reraise:
                 raise
             return
+        finally:
+            warm_span.set(programs=len(self._warmed))
+            warm_span.close()
         self.warmup_seconds = time.monotonic() - t0
         with self._lock:
             self._status = "warm"
@@ -394,8 +399,12 @@ class CompilePlane:
 
     def _run_spec(self, spec: ProgramSpec, cache):
         t0 = time.monotonic()
-        with compile_label(spec.key):
-            cache = spec.run(cache)
+        before = cache_stats()["compiles"]
+        with span("llm.warmup.program", key=spec.key) as sp:
+            with compile_label(spec.key):
+                cache = spec.run(cache)
+            sp.set(seconds=round(time.monotonic() - t0, 4),
+                   compiled=cache_stats()["compiles"] > before)
         with self._lock:
             self._warmed.add(spec.key)
         self._m_warmed.inc(1, engine=self.name, kind=spec.kind)

@@ -52,7 +52,7 @@ from ..resilience.health import HealthState, retry_after_from_depth
 from ..telemetry import (PROMETHEUS_CONTENT_TYPE, SERVING_TOKEN_LATENCY_BUCKETS,
                          SERVING_TTFT_BUCKETS, check_sloz, get_registry,
                          get_request_tracer, get_slo_store, render_json,
-                         render_prometheus)
+                         render_prometheus, step_span)
 from ..telemetry.flight import record as _flight_record
 
 #: request header (lower-cased, as the listener normalizes) carrying a
@@ -1591,8 +1591,8 @@ class _DecodeLoop:
             # trace minted here (admission into the serving plane) or
             # adopted from the upstream hop (always sampled: a
             # propagated request is never half-traced)
-            seq.trace_id = self._tracer.begin(req.trace_id,
-                                              api=self.api.path)
+            seq.trace_id = self._tracer.begin(
+                req.trace_id, started_at=req.enqueued_at, api=self.api.path)
             self._tracer.event(seq.trace_id, "queued",
                                prompt_tokens=len(ids), max_new=max_new,
                                stream=seq.stream)
@@ -1785,8 +1785,13 @@ class _DecodeLoop:
             {"Retry-After": str(max(1, int(math.ceil(retry_after_s)))),
              **self._trace_headers(seq)}))
 
-    def _admit_waiting(self) -> None:
+    def _admit_waiting(self, tick_span) -> None:
+        """Admit what the engine has room for.  ``tick_span`` is the
+        tick's ``loop.admit``: it carries the trace id of the request
+        being admitted, so that request's ``engine.admit`` hangs under
+        it with the same id."""
         keep: List[_DecodeSeq] = []
+        admitted = 0
         ready_fn = getattr(self.engine, "admission_ready", None)
         # per-tenant rate budgets first (charged ONCE per request, in
         # tokens = the requested budget, through the PR-2 token-bucket
@@ -1864,6 +1869,9 @@ class _DecodeLoop:
                                    outcome="fallback", error=True)
                 self._tracer.event(seq.trace_id, "disagg_handoff",
                                    outcome=seq.handoff_outcome)
+            if tick_span.live:
+                tick_span.trace_id = seq.trace_id
+            admit_at = time.monotonic()
             try:
                 res = (self.engine.admit(seq.ids, seq.max_new,
                                          tenant=seq.tenant)
@@ -1879,9 +1887,13 @@ class _DecodeLoop:
                 starved.append(seq)
                 keep.append(seq)
                 continue
+            admitted += 1
             seq.slot = res.slot
             seq.first_token_at = time.monotonic()
             ttft = seq.first_token_at - seq.req.enqueued_at
+            self._tracer.annotate(
+                seq.trace_id, ttft_s=ttft,
+                queue_wait_s=admit_at - seq.req.enqueued_at)
             self._m_ttft.observe(ttft, api=self.api.path)
             self._slo.observe_ttft(ttft)
             self._slo.count("admitted")
@@ -1923,6 +1935,8 @@ class _DecodeLoop:
         self._waiting = [s for s in keep if s.ticket is None]
         self._parked = [s for s in keep if s.ticket is not None]
         self._maybe_preempt(starved)
+        if tick_span.live:
+            tick_span.set(admitted=admitted, waiting=len(keep))
 
     def _maybe_preempt(self, starved: List[_DecodeSeq]) -> None:
         """Preemption policy: when capacity-starved demand includes a
@@ -2105,15 +2119,27 @@ class _DecodeLoop:
                 #                     not spin the loop hot
 
     def _tick(self) -> None:
-        self._pump_queue()
-        self._admit_waiting()
-        self._cancel_expired()
-        self._export_slo()
-        if not self.engine.active_count:
-            return
-        t0 = time.perf_counter()
-        events = self.engine.step()
-        dt = time.perf_counter() - t0
+        with step_span("loop.tick"):
+            with step_span("loop.pump"):
+                self._pump_queue()
+            with step_span("loop.admit") as sp:
+                self._admit_waiting(sp)
+            with step_span("loop.expire"):
+                self._cancel_expired()
+                self._export_slo()
+            if not self.engine.active_count:
+                return
+            t0 = time.perf_counter()
+            events = self.engine.step()
+            dt = time.perf_counter() - t0
+            with step_span("loop.emit") as sp:
+                self._emit(events, dt)
+                if sp.live:
+                    sp.set(events=len(events))
+
+    def _emit(self, events, dt: float) -> None:
+        """A step's tokens to their requests: the latency observations,
+        the QoS charge, the stream push."""
         self._step_ewma = (dt if self._step_ewma is None
                            else 0.8 * self._step_ewma + 0.2 * dt)
         # a speculative engine commits a SPAN per slot per step: the

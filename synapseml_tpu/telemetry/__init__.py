@@ -7,8 +7,9 @@ telemetry plus ``LightGBMPerformance.scala`` phase measures:
 
 - :mod:`.registry` — process-wide ``Counter``/``Gauge``/``Histogram``
   with label sets; thread-safe, resettable (``get_registry()``).
-- :mod:`.tracing` — nested host-side spans with Chrome-trace export
-  (``span(name, **attrs)``, ``get_tracer()``).
+- :mod:`.tracing` — nested host-side spans on the monotonic clock that
+  also lie in any profiler capture, with Chrome-trace export
+  (``span(name, **attrs)``, ``step_span(name)``, ``get_tracer()``).
 - :mod:`.exposition` — Prometheus text + JSON rendering; served by
   ``ServingServer`` at ``GET /metrics``.
 - :mod:`.artifact` — atomic, round-trip-verified JSON artifact writes
@@ -81,7 +82,7 @@ from .slo import (SLO_METRICS, SLOZ_SCHEMA, SLOZ_SCHEMA_VERSION, SloStore,
                   SloWindow, WindowedCounter, WindowedHistogram, check_sloz,
                   get_slo_store, plane_tenant, tenant_plane_name)
 from .tracing import (RequestTraceStore, Span, Tracer, get_request_tracer,
-                      get_tracer, mint_trace_id, span)
+                      get_tracer, mint_trace_id, span, step_span)
 from .tunetable import (TUNE_TABLE_ENV, TUNE_TABLE_SCHEMA_VERSION, TunePlane,
                         check_tune_table, check_tunez, device_kind,
                         geometry_key, get_tuneplane, set_tuneplane)
@@ -90,7 +91,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
     "DEFAULT_BUCKETS", "SERVING_TTFT_BUCKETS",
     "SERVING_TOKEN_LATENCY_BUCKETS", "bucket_quantile",
-    "Span", "Tracer", "get_tracer", "span",
+    "Span", "Tracer", "get_tracer", "span", "step_span",
     "RequestTraceStore", "get_request_tracer", "mint_trace_id",
     "SloStore", "SloWindow", "WindowedCounter", "WindowedHistogram",
     "check_sloz", "get_slo_store", "SLOZ_SCHEMA", "SLOZ_SCHEMA_VERSION",

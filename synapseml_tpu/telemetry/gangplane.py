@@ -46,7 +46,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 from .artifact import SchemaError, write_json
 from .flight import get_flight, sanitize_floats as _sanitize
 from .registry import MetricsRegistry, get_registry
-from .tracing import get_tracer
+from .tracing import chrome_event, get_tracer
 
 __all__ = ["TM_MARKER", "TM_INTERVAL_ENV", "OBS_DIR_ENV",
            "TelemetryEmitter", "start_emitter", "parse_telemetry",
@@ -101,29 +101,16 @@ def _compact_snapshot(registry: Optional[MetricsRegistry] = None
             for name, m in snap.items()}
 
 
-def _chrome_event(span) -> Dict[str, Any]:
-    """One finished Span → a pid-less Chrome complete event (the driver
-    assigns ``pid`` = rank when stitching)."""
-    return {"name": span.name, "ph": "X", "cat": "host",
-            "ts": span.start_wall_s * 1e6,
-            "dur": (span.end_s - span.start_s) * 1e6,
-            "tid": span.thread_id,
-            "args": {**span.attrs, "span_id": span.span_id,
-                     "parent_id": span.parent_id}}
-
-
 def telemetry_batch(rank: int, *, span_cursor: int = 0,
                     flight_seq: int = 0, seq: int = 0,
                     final: bool = False) -> Tuple[Dict[str, Any], int, int]:
     """Build one wire batch → ``(payload, new_span_cursor,
     new_flight_seq)``.  The payload's metric snapshot is cumulative
     (mirrors are SET, not added, so re-sends are idempotent); spans and
-    flight events are incremental since the given cursors."""
-    tracer = get_tracer()
-    spans = tracer.spans()
-    if span_cursor > len(spans):        # tracer was reset mid-run
-        span_cursor = 0
-    new_spans = [s for s in spans[span_cursor:] if s.end_s is not None]
+    flight events are incremental since the given cursors (the span
+    cursor counts spans ever finished, so it survives the tracer's ring
+    wrapping and a reset mid-run)."""
+    new_spans, span_cursor = get_tracer().spans_since(span_cursor)
     if len(new_spans) > MAX_SPANS_PER_BATCH:
         new_spans = new_spans[-MAX_SPANS_PER_BATCH:]
     flight = get_flight()
@@ -132,11 +119,12 @@ def telemetry_batch(rank: int, *, span_cursor: int = 0,
         "rank": int(rank), "seq": int(seq), "ts": time.time(),
         "final": bool(final),
         "metrics": _compact_snapshot(),
-        "spans": [_chrome_event(s) for s in new_spans],
+        # pid-less: the driver assigns ``pid`` = rank when stitching
+        "spans": [chrome_event(s) for s in new_spans],
         "flight": events,
     }
     new_flight_seq = events[-1]["seq"] if events else flight_seq
-    return payload, len(spans), new_flight_seq
+    return payload, span_cursor, new_flight_seq
 
 
 def parse_telemetry(line: str) -> Optional[dict]:

@@ -1,130 +1,244 @@
-"""Span tracing: nested, queryable, Chrome-trace-exportable.
+"""Span tracing: nested, queryable, Chrome-trace-exportable, and on the
+profiler's clock.
 
 Horovod's timeline (Sergeev & Del Balso, arXiv:1802.05799) made the
 per-op schedule of a distributed run *visible*; the analogue here is a
-host-side span tracer: ``with span("gbdt.train", rows=n):`` produces an
+host-side span tracer: ``with span("gbdt.fit", rows=n):`` produces an
 in-memory record with parent/child nesting (thread-local stack),
-host/process-index attribution, and wall+monotonic timestamps, and the
-whole trace exports as Chrome-trace JSON (load in ``chrome://tracing``
-or Perfetto).
+host/process-index attribution and ``time.monotonic_ns()`` timestamps
+(the clock of ``ServingRequest.enqueued_at`` and of any load
+generator's stamps), and the whole trace exports as Chrome-trace JSON
+(load in ``chrome://tracing`` or Perfetto).
 
-Device-side op scheduling stays the job of
-:func:`synapseml_tpu.core.profiling.trace` (the XLA profiler); spans
-cover everything the profiler cannot see — host phases, serving loops,
-binning, checkpoint writes — cheaply enough to stay on in production.
+While a profiler session is on (:func:`synapseml_tpu.core.profiling.
+trace`, ``jax.profiler.start_trace``) every open span also holds a
+``jax.profiler.TraceAnnotation`` of its name, so the same interval lies
+on the host plane of that capture, on the profiler's clock, beside the
+device's operations.
+
+Two entry points, chosen by the call site:
+
+``span(name, **attrs)``
+    always recorded: work that happens a few times per fit, request or
+    warm-up.
+``step_span(name)``
+    work inside a decode step or a loop tick: live only while a
+    profiler session is on (per-step spans exist to explain a device
+    trace, so they exist when there is one).  Off, it returns one
+    shared no-op whose ``live`` is False; attributes that cost
+    something to compute go under ``if sp.live:``.
+
+No span synchronises with the device: one around an asynchronous
+dispatch times the enqueue.
 """
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import socket
+import sys
 import threading
 import time
 import uuid
-from collections import OrderedDict
-from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from collections import OrderedDict, deque
+from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "get_tracer", "span",
+__all__ = ["Span", "Tracer", "get_tracer", "span", "step_span",
            "RequestTraceStore", "get_request_tracer", "mint_trace_id"]
 
 _ids = itertools.count(1)
 _tls = threading.local()
+_HOST = socket.gethostname()
+#: ``jax.profiler.TraceAnnotation`` once jax is imported — looked up in
+#: ``sys.modules`` so importing telemetry never drags in jax
+_annotation_cls = None
+
+
+def _annotation():
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation_cls = getattr(profiler, "TraceAnnotation", None)
+    return _annotation_cls
+
+
+def _profiling() -> bool:
+    """Is a profiler session on?  (24 ns; False before jax is imported.)"""
+    cls = _annotation_cls or _annotation()
+    return cls is not None and cls.is_enabled()
 
 
 def _process_index() -> int:
-    """jax.process_index() when jax is up, else 0 — resolved lazily so
-    importing telemetry never drags in (or initializes) jax."""
+    """``jax.process_index()`` when jax is up, else 0: read when a span
+    is exported, never on the path that records one."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return 0
     try:
-        import sys
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return 0
         return int(jax.process_index())
-    except Exception:
+    except Exception:  # noqa: BLE001 — no backend to ask
         return 0
 
 
-@dataclass
 class Span:
-    """One finished (or live) span."""
-    name: str
-    span_id: int
-    parent_id: Optional[int]
-    start_wall_s: float                  # epoch seconds (chrome ts base)
-    start_s: float                       # perf_counter
-    end_s: Optional[float] = None        # perf_counter; None while live
-    attrs: Dict[str, Any] = field(default_factory=dict)
-    thread_id: int = 0
-    process_index: int = 0
-    host: str = ""
+    """One span: live between ``__enter__`` and ``__exit__`` (or
+    :meth:`start` and :meth:`close`, for a region of a long function
+    that a ``with`` block would have to re-indent), finished after."""
+
+    __slots__ = ("name", "span_id", "parent_id", "trace_id", "start_ns",
+                 "end_ns", "start_wall_s", "attrs", "thread_id", "live",
+                 "_tracer", "_annotation")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 attrs: Optional[Dict[str, Any]] = None,
+                 trace_id: Optional[str] = None):
+        self._tracer = tracer
+        self.live = tracer is not None      # False: the shared no-op
+        self.name = name
+        self.attrs = attrs
+        self.trace_id = trace_id
+        self.span_id = self.parent_id = None
+        self.start_ns = self.end_ns = None
+        self._annotation = None
+
+    def __enter__(self) -> "Span":
+        if not self.live:
+            return self
+        stack: List[Span] = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        if stack:
+            parent = stack[-1]
+            self.parent_id = parent.span_id
+            if self.trace_id is None:       # a request's work is its own
+                self.trace_id = parent.trace_id
+        self.span_id = next(_ids)
+        self.thread_id = threading.get_ident()
+        stack.append(self)
+        if _profiling():
+            self._annotation = _annotation_cls(self.name)
+            self._annotation.__enter__()
+        self.start_wall_s = time.time()
+        self.start_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if not self.live or self.end_ns is not None:
+            return False
+        end_ns = time.monotonic_ns()
+        stack = getattr(_tls, "stack", ())
+        if self in stack:
+            # a region an exception left open ends with its ancestor
+            while stack.pop()._finish(end_ns) is not self:
+                pass
+        else:                   # closed on another thread than opened
+            self._finish(end_ns)
+        return False
+
+    def _finish(self, end_ns: int) -> "Span":
+        self.end_ns = end_ns
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        self._tracer._append(self)
+        return self
+
+    start = __enter__
+
+    def close(self) -> None:
+        self.__exit__(None, None, None)
+
+    def set(self, **attrs) -> None:
+        """Add attributes; until the span closes."""
+        if self.live:
+            self.attrs.update(attrs)
+
+    @property
+    def process_index(self) -> int:
+        return _process_index()
+
+    @property
+    def host(self) -> str:
+        return _HOST
 
     @property
     def duration_s(self) -> float:
-        return (self.end_s or time.perf_counter()) - self.start_s
+        if self.start_ns is None:
+            return 0.0
+        return ((self.end_ns or time.monotonic_ns()) - self.start_ns) / 1e9
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, id={self.span_id}, "
+                f"parent={self.parent_id}, {self.duration_s:.6f}s)")
+
+
+#: what :func:`step_span` returns while no profiler session is on
+_NO_SPAN = Span(None, "")
 
 
 class Tracer:
-    """Bounded in-memory trace; one per process is plenty."""
+    """Bounded in-memory trace: a ring of the newest ``max_spans``
+    finished spans; one per process is plenty."""
 
     def __init__(self, max_spans: int = 100_000):
         self.max_spans = max_spans
         self._lock = threading.Lock()
-        self._spans: List[Span] = []
-        self._dropped = 0
-        self._host = socket.gethostname()
+        self._spans: "deque[Span]" = deque(maxlen=max_spans)
+        self._appended = 0
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[Span]:
-        stack: List[Span] = getattr(_tls, "stack", None)
-        if stack is None:
-            stack = _tls.stack = []
-        sp = Span(name=name, span_id=next(_ids),
-                  parent_id=stack[-1].span_id if stack else None,
-                  start_wall_s=time.time(), start_s=time.perf_counter(),
-                  attrs=dict(attrs), thread_id=threading.get_ident(),
-                  process_index=_process_index(), host=self._host)
-        stack.append(sp)
-        try:
-            yield sp
-        finally:
-            stack.pop()
-            sp.end_s = time.perf_counter()
-            with self._lock:
-                if len(self._spans) < self.max_spans:
-                    self._spans.append(sp)
-                else:
-                    self._dropped += 1
+    def span(self, name: str, trace_id: Optional[str] = None,
+             **attrs) -> Span:
+        return Span(self, name, attrs, trace_id)
+
+    def _append(self, sp: Span) -> None:
+        with self._lock:
+            self._spans.append(sp)
+            self._appended += 1
 
     def record(self, name: str, duration_s: float, *,
                start_wall_s: Optional[float] = None,
-               parent_id: Optional[int] = None, **attrs) -> Span:
-        """Append an already-measured interval as a finished span — for
-        call sites that keep their own perf_counter bookkeeping (e.g. the
-        GBDT ``InstrumentationMeasures``) and publish retrospectively."""
-        now_perf = time.perf_counter()
+               start_ns: Optional[int] = None,
+               parent_id: Optional[int] = None,
+               trace_id: Optional[str] = None, **attrs) -> Span:
+        """Append an already-measured interval as a finished span: the
+        one that began on another thread (a request, from the
+        listener's enqueue to its retirement in the decode loop).
+        ``start_ns`` is on the ``time.monotonic_ns()`` clock; without
+        it the interval ends now."""
+        dur_ns = int(duration_s * 1e9)
+        if start_ns is None:
+            start_ns = time.monotonic_ns() - dur_ns
         if start_wall_s is None:
-            start_wall_s = time.time() - duration_s
-        sp = Span(name=name, span_id=next(_ids), parent_id=parent_id,
-                  start_wall_s=start_wall_s,
-                  start_s=now_perf - duration_s, end_s=now_perf,
-                  attrs=dict(attrs), thread_id=threading.get_ident(),
-                  process_index=_process_index(), host=self._host)
-        with self._lock:
-            if len(self._spans) < self.max_spans:
-                self._spans.append(sp)
-            else:
-                self._dropped += 1
+            start_wall_s = time.time() \
+                - (time.monotonic_ns() - start_ns) / 1e9
+        sp = Span(self, name, attrs, trace_id)
+        sp.span_id, sp.parent_id = next(_ids), parent_id
+        sp.start_wall_s = start_wall_s
+        sp.start_ns, sp.end_ns = int(start_ns), int(start_ns) + dur_ns
+        sp.thread_id = threading.get_ident()
+        self._append(sp)
         return sp
 
     # -- queries -----------------------------------------------------------
     def spans(self, name: Optional[str] = None) -> List[Span]:
+        """The finished spans still in the ring, oldest first."""
         with self._lock:
             out = list(self._spans)
         if name is not None:
             out = [s for s in out if s.name == name]
         return out
+
+    def spans_since(self, cursor: int = 0) -> Tuple[List[Span], int]:
+        """(the spans finished after ``cursor``, the new cursor): the
+        cursor counts every span ever finished, so it survives a ring
+        that wraps (what fell off is skipped) and a :meth:`reset`
+        (a cursor ahead of the count starts over)."""
+        with self._lock:
+            spans, appended = list(self._spans), self._appended
+        if cursor > appended:
+            cursor = 0
+        fresh = min(appended - cursor, len(spans))
+        return spans[len(spans) - fresh:], appended
 
     def children(self, parent: Span) -> List[Span]:
         return [s for s in self.spans() if s.parent_id == parent.span_id]
@@ -134,13 +248,14 @@ class Tracer:
 
     @property
     def dropped(self) -> int:
+        """Spans that fell off the ring's old end."""
         with self._lock:
-            return self._dropped
+            return self._appended - len(self._spans)
 
     def reset(self) -> None:
         with self._lock:
             self._spans.clear()
-            self._dropped = 0
+            self._appended = 0
 
     # -- export ------------------------------------------------------------
     def chrome_trace(self) -> Dict[str, Any]:
@@ -148,17 +263,10 @@ class Tracer:
         events, pid = process index, tid = OS thread id, ts/dur in us."""
         events = []
         for s in self.spans():
-            if s.end_s is None:
-                continue
-            events.append({
-                "name": s.name, "ph": "X", "cat": "host",
-                "ts": s.start_wall_s * 1e6,
-                "dur": (s.end_s - s.start_s) * 1e6,
-                "pid": s.process_index, "tid": s.thread_id,
-                "args": {**s.attrs, "host": s.host,
-                         "span_id": s.span_id,
-                         "parent_id": s.parent_id},
-            })
+            ev = chrome_event(s)
+            ev["pid"] = s.process_index
+            ev["args"]["host"] = s.host
+            events.append(ev)
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def export_chrome(self, path: str) -> Dict[str, Any]:
@@ -169,6 +277,17 @@ class Tracer:
                           schema=("traceEvents",))
 
 
+def chrome_event(s: Span) -> Dict[str, Any]:
+    """One finished span as a pid-less Chrome complete event."""
+    args = {**s.attrs, "span_id": s.span_id, "parent_id": s.parent_id}
+    if s.trace_id is not None:
+        args["trace_id"] = s.trace_id
+    return {"name": s.name, "ph": "X", "cat": "host",
+            "ts": s.start_wall_s * 1e6,
+            "dur": (s.end_ns - s.start_ns) / 1e3,
+            "tid": s.thread_id, "args": args}
+
+
 _default_tracer = Tracer()
 
 
@@ -176,9 +295,16 @@ def get_tracer() -> Tracer:
     return _default_tracer
 
 
-def span(name: str, **attrs):
-    """``with span("phase", key=val):`` on the process-default tracer."""
-    return _default_tracer.span(name, **attrs)
+def span(name: str, trace_id: Optional[str] = None, **attrs) -> Span:
+    """``with span("phase", key=val) as sp:`` on the process-default
+    tracer; always recorded."""
+    return Span(_default_tracer, name, attrs, trace_id)
+
+
+def step_span(name: str) -> Span:
+    """``with step_span("engine.step") as sp:`` on the process-default
+    tracer; recorded only while a profiler session is on."""
+    return Span(_default_tracer, name, {}) if _profiling() else _NO_SPAN
 
 
 # ---------------------------------------------------------------------------
@@ -231,10 +357,16 @@ class RequestTraceStore:
 
     # -- producing ---------------------------------------------------------
     def begin(self, trace_id: Optional[str] = None,
+              started_at: Optional[float] = None,
               **attrs) -> Optional[str]:
         """Start a timeline.  ``trace_id=None`` mints one subject to
         sampling (None returned ⇒ not sampled); a caller-provided id
-        (the propagated cross-hop case) is always sampled."""
+        (the propagated cross-hop case) is always sampled.
+        ``started_at`` (``time.monotonic()`` seconds, the listener's
+        ``enqueued_at``) dates the request from before this call, so
+        its span holds the wait in the listener's queue."""
+        now = time.monotonic()
+        age = 0.0 if started_at is None else max(0.0, now - started_at)
         with self._lock:
             if trace_id is None:
                 self._seen += 1
@@ -244,8 +376,8 @@ class RequestTraceStore:
                 trace_id = mint_trace_id()
             self.sampled += 1
             self._traces[trace_id] = {
-                "trace_id": trace_id, "started_unix": time.time(),
-                "started_s": time.perf_counter(), "attrs": dict(attrs),
+                "trace_id": trace_id, "started_unix": time.time() - age,
+                "started_s": now - age, "attrs": dict(attrs),
                 "events": [], "dropped_events": 0,
                 "outcome": None, "duration_s": None}
             self._traces.move_to_end(trace_id)
@@ -267,8 +399,18 @@ class RequestTraceStore:
                 self.dropped_events += 1
                 return
             tr["events"].append(
-                {"t_s": time.perf_counter() - tr["started_s"],
+                {"t_s": time.monotonic() - tr["started_s"],
                  "name": name, **attrs})
+
+    def annotate(self, trace_id: Optional[str], **attrs) -> None:
+        """Attributes for the request's ``serving.request`` span (its
+        ``queue_wait_s``, ``ttft_s``), known before it finishes."""
+        if trace_id is None:
+            return
+        with self._lock:
+            tr = self._traces.get(trace_id)
+            if tr is not None:
+                tr["attrs"].update(attrs)
 
     def finish(self, trace_id: Optional[str], outcome: str,
                **attrs) -> None:
@@ -282,13 +424,14 @@ class RequestTraceStore:
             if tr is None or tr["outcome"] is not None:
                 return
             tr["outcome"] = outcome
-            tr["duration_s"] = time.perf_counter() - tr["started_s"]
+            tr["duration_s"] = time.monotonic() - tr["started_s"]
             tr["attrs"].update(attrs)
             started_wall, dur = tr["started_unix"], tr["duration_s"]
-            span_attrs = {"trace_id": trace_id, "outcome": outcome,
-                          **tr["attrs"]}
-        get_tracer().record("serving.request", dur,
-                            start_wall_s=started_wall, **span_attrs)
+            start_ns = int(tr["started_s"] * 1e9)
+            span_attrs = {"outcome": outcome, **tr["attrs"]}
+        get_tracer().record("serving.request", dur, start_ns=start_ns,
+                            start_wall_s=started_wall, trace_id=trace_id,
+                            **span_attrs)
         try:
             from .flight import record as flight_record
             flight_record("request", trace_id=trace_id, outcome=outcome,
@@ -336,7 +479,7 @@ class RequestTraceStore:
             base_us = tr["started_unix"] * 1e6
             dur_s = tr["duration_s"]
             if dur_s is None:                     # live: span up to now
-                dur_s = time.perf_counter() - tr["started_s"]
+                dur_s = time.monotonic() - tr["started_s"]
             outcome = tr["outcome"]
             attrs = dict(tr["attrs"])
             timeline = [dict(e) for e in tr["events"]]
@@ -363,7 +506,7 @@ def _copy_trace(tr: Dict[str, Any]) -> Dict[str, Any]:
     out = dict(tr)
     out["attrs"] = dict(tr["attrs"])
     out["events"] = [dict(e) for e in tr["events"]]
-    out.pop("started_s", None)          # perf_counter base is internal
+    out.pop("started_s", None)          # the monotonic base is internal
     return out
 
 

@@ -299,7 +299,7 @@ class TestRequestTraceStore:
         s.event(tid, "queued")
         s.finish(tid, "retired", tokens=4)
         spans = [sp for sp in get_tracer().spans("serving.request")
-                 if sp.attrs.get("trace_id") == tid]
+                 if sp.trace_id == tid]
         assert len(spans) == 1
         assert spans[0].attrs["outcome"] == "retired"
         assert spans[0].attrs["tokens"] == 4

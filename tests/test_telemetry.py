@@ -3,6 +3,8 @@ Prometheus exposition through the serving server, collectives counters
 on the simulated mesh, instrumented trainers, and artifact-writer
 crash-safety (the BENCH_r05 truncation regression class)."""
 
+import contextlib
+import glob
 import json
 import os
 import signal
@@ -18,7 +20,35 @@ import pytest
 from synapseml_tpu.telemetry import (MetricsRegistry, SchemaError, Tracer,
                                      dumps_checked, get_registry, get_tracer,
                                      read_json, render_prometheus, span,
-                                     write_json)
+                                     step_span, write_json)
+
+
+@contextlib.contextmanager
+def profiler_session(directory):
+    """A jax profiler session on the CPU (no Python frames: cheap)."""
+    import jax
+    from jax.profiler import ProfileOptions
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(directory), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def host_plane_names(directory):
+    """The event names on the ``/host:CPU`` plane of the capture."""
+    from jax.profiler import ProfileData
+    pb, = glob.glob(os.path.join(str(directory), "plugins", "profile", "*",
+                                 "*.xplane.pb"))
+    names = set()
+    for plane in ProfileData.from_file(pb).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    return names
 
 
 # -- registry ----------------------------------------------------------------
@@ -207,6 +237,295 @@ class TestTracing:
         with span("default_span_test"):
             pass
         assert len(get_tracer().spans("default_span_test")) == before + 1
+
+    def test_ring_keeps_the_newest_and_counts_the_dropped(self):
+        tr = Tracer(max_spans=3)
+        for i in range(10):
+            with tr.span("s", i=i):
+                pass
+        assert [s.attrs["i"] for s in tr.spans()] == [7, 8, 9]
+        assert tr.dropped == 7
+        tr.record("late", 0.5)
+        assert [s.name for s in tr.spans()] == ["s", "s", "late"]
+        assert tr.dropped == 8
+
+    def test_clock_is_monotonic_ns(self):
+        tr = Tracer()
+        a = time.monotonic_ns()
+        with tr.span("timed") as sp:
+            b = time.monotonic_ns()
+        c = time.monotonic_ns()
+        assert a <= sp.start_ns <= b <= sp.end_ns <= c
+        assert sp.duration_s == (sp.end_ns - sp.start_ns) / 1e9
+        assert abs(sp.start_wall_s - time.time()) < 5.0
+        # an interval measured elsewhere lands on the same clock
+        rec = tr.record("request", 0.25, start_ns=a, trace_id="abc")
+        assert (rec.start_ns, rec.end_ns) == (a, a + 250_000_000)
+        assert rec.trace_id == "abc"
+        ev = {e["name"]: e for e in tr.chrome_trace()["traceEvents"]}
+        assert ev["request"]["args"]["trace_id"] == "abc"
+        assert ev["request"]["dur"] == pytest.approx(0.25e6)
+
+    def test_attrs_until_close_and_trace_id_down_the_stack(self):
+        tr = Tracer()
+        with tr.span("admit") as outer:
+            outer.trace_id = "req-1"
+            with tr.span("prefill", trace_id=None) as inner:
+                inner.set(bucket=8)
+            with tr.span("other", trace_id="req-2"):
+                pass
+        assert tr.spans("prefill")[0].trace_id == "req-1"
+        assert tr.spans("prefill")[0].attrs == {"bucket": 8}
+        assert tr.spans("other")[0].trace_id == "req-2"
+
+    def test_a_region_left_open_ends_with_its_ancestor(self):
+        tr = Tracer()
+        with pytest.raises(RuntimeError):
+            with tr.span("fit") as fit:
+                phase = tr.span("fit.bin").start()
+                raise RuntimeError("binning failed")
+        assert phase.end_ns == fit.end_ns
+        assert [s.name for s in tr.spans()] == ["fit.bin", "fit"]
+        with tr.span("next") as nxt:        # the stack is clean again
+            pass
+        assert nxt.parent_id is None
+        phase.close()                       # closing twice records once
+        assert len(tr.spans("fit.bin")) == 1
+
+    def test_step_span_without_a_session_is_the_shared_noop(self):
+        import tracemalloc
+        tr = get_tracer()
+        before = len(tr.spans())
+        first = step_span("engine.step")
+        assert first.live is False
+        assert step_span("loop.tick") is first      # one object, ever
+
+        def site():
+            with step_span("engine.step") as sp:
+                if sp.live:
+                    sp.set(never=1)
+        site()
+        tracemalloc.start()
+        try:
+            a = tracemalloc.take_snapshot()
+            for _ in range(1000):
+                site()
+            b = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+        here = [st for st in b.compare_to(a, "filename")
+                if st.traceback[0].filename.endswith("tracing.py")]
+        assert sum(st.size_diff for st in here) == 0
+        assert len(tr.spans()) == before
+
+    def test_step_span_records_inside_a_profiler_session(self, tmp_path):
+        tr = get_tracer()
+        with profiler_session(tmp_path):
+            with span("always.outer") as outer:
+                with step_span("step.inner") as inner:
+                    assert inner.live
+                    inner.set(tokens=3)
+        assert step_span("step.after").live is False
+        got = [s for s in tr.spans("step.inner")
+               if s.parent_id == outer.span_id]
+        assert len(got) == 1 and got[0].attrs == {"tokens": 3}
+        assert outer.start_ns <= got[0].start_ns <= got[0].end_ns \
+            <= outer.end_ns
+        # both lie on the profiler's host plane, under their own names
+        assert {"always.outer", "step.inner"} <= host_plane_names(tmp_path)
+
+
+    def test_gang_plane_cursor_survives_a_wrapped_ring(self, monkeypatch):
+        from synapseml_tpu.telemetry import gangplane
+        tr = Tracer(max_spans=4)
+        monkeypatch.setattr(gangplane, "get_tracer", lambda: tr)
+
+        def work(*tags):
+            for t in tags:
+                with tr.span("w", tag=t):
+                    pass
+
+        def tags(payload):
+            return [e["args"]["tag"] for e in payload["spans"]]
+
+        work(1, 2, 3)
+        payload, cur, _ = gangplane.telemetry_batch(0)
+        assert tags(payload) == [1, 2, 3] and cur == 3
+        work(4, 5)                            # the ring wraps: 2..5 remain
+        payload, cur, _ = gangplane.telemetry_batch(0, span_cursor=cur)
+        assert tags(payload) == [4, 5] and cur == 5
+        work(6, 7, 8, 9, 10, 11)              # more than a ring between polls
+        payload, cur, _ = gangplane.telemetry_batch(0, span_cursor=cur)
+        assert tags(payload) == [8, 9, 10, 11] and cur == 11
+        payload, cur, _ = gangplane.telemetry_batch(0, span_cursor=cur)
+        assert tags(payload) == [] and cur == 11
+        tr.reset()                            # a reset mid-run starts over
+        work(12)
+        payload, cur, _ = gangplane.telemetry_batch(0, span_cursor=cur)
+        assert tags(payload) == [12] and cur == 1
+
+
+# -- spans where the work happens ----------------------------------------------
+
+def _names_under(tr, parent):
+    return [s.name for s in sorted(tr.children(parent),
+                                   key=lambda s: s.start_ns)]
+
+
+class TestProgramSpans:
+    @pytest.fixture(scope="class")
+    def tiny_model(self):
+        import jax
+        import jax.numpy as jnp
+        from synapseml_tpu.models.llm import LlamaConfig, LlamaModel
+        cfg = LlamaConfig.tiny(num_layers=2, max_len=96, dtype=jnp.float32)
+        model = LlamaModel(cfg)
+        variables = model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((2, 8), jnp.int32))
+        return cfg, model, variables
+
+    def test_engine_step_and_admit_spans(self, tiny_model, tmp_path):
+        from synapseml_tpu.models.llm import SlotEngine
+        cfg, model, variables = tiny_model
+        rng = np.random.default_rng(0)
+        ids = rng.integers(1, cfg.vocab_size, (2, 7)).astype(np.int32)
+        eng = SlotEngine(model, variables, n_slots=4, max_len=64)
+        tr = get_tracer()
+        tr.reset()
+        # no profiler session: a step and an admission record no span
+        eng.admit(ids[0], 8)
+        eng.step()
+        assert tr.spans() == []
+        with profiler_session(tmp_path):
+            res = eng.admit(ids[1], 8)
+            events = eng.step()
+        admit, = tr.spans("engine.admit")
+        assert _names_under(tr, admit) == [
+            "engine.admit.lookup", "engine.admit.prefill",
+            "engine.admit.commit"]
+        assert admit.attrs == {"bucket": res.bucket, "prompt_tokens": 7,
+                               "reused_tokens": 0, "path": "cold"}
+        step, = tr.spans("engine.step")
+        assert _names_under(tr, step) == [
+            "engine.step.prepare", "engine.step.wait", "engine.step.commit"]
+        prepare, = tr.spans("engine.step.prepare")
+        assert _names_under(tr, prepare) == [
+            "engine.step.prepare.upload", "engine.step.prepare.dispatch"]
+        assert step.attrs["tokens"] == len(events) == 2
+        assert step.attrs["slots"] == 2
+        assert step.attrs["kv_span_sum"] == 2 * 8 + 1   # one stepped before
+        assert step.attrs["program"] == "decode_dense"
+        assert sum(s.duration_s for s in tr.children(step)) \
+            <= step.duration_s
+        assert {"engine.step", "engine.step.wait", "engine.admit.prefill"} \
+            <= host_plane_names(tmp_path)
+
+    def test_speculative_step_has_a_draft_span(self, tiny_model, tmp_path):
+        from synapseml_tpu.models.llm import SlotEngine
+        cfg, model, variables = tiny_model
+        eng = SlotEngine(model, variables, n_slots=2, max_len=64,
+                         spec_draft_len=2)
+        eng.admit(np.array([5, 6, 5, 6, 5, 6, 5], np.int32), 8)
+        tr = get_tracer()
+        tr.reset()
+        with profiler_session(tmp_path):
+            events = eng.step()
+        step, = tr.spans("engine.step")
+        names = _names_under(tr, step)
+        assert names[0] == "engine.step.draft"
+        assert names[1:] == ["engine.step.prepare", "engine.step.wait",
+                             "engine.step.commit"]
+        assert step.attrs["tokens"] == len(events)
+        assert step.attrs["program"] == eng.last_program
+
+    def test_a_served_request_starts_at_the_listeners_enqueue(
+            self, tiny_model, tmp_path):
+        from synapseml_tpu.serving import LLMServer
+        cfg, model, variables = tiny_model
+        srv = LLMServer(model, variables, n_slots=2, max_len=64,
+                        engine_kwargs={"name": "t-spans"})
+        seen = []
+        parse = srv._loop.input_parser
+        srv._loop.input_parser = lambda req: (seen.append(req), parse(req))[1]
+        tr = get_tracer()
+        tr.reset()
+        try:
+            with profiler_session(tmp_path):
+                req = urllib.request.Request(
+                    srv.url, data=json.dumps(
+                        {"ids": [3, 4, 5, 6, 7], "max_new_tokens": 4}).encode(),
+                    method="POST",
+                    headers={"Content-Type": "application/json"})
+                with urllib.request.urlopen(req, timeout=60) as r:
+                    assert len(json.loads(r.read())["ids"]) == 4
+        finally:
+            srv.close()
+        request, = tr.spans("serving.request")
+        assert request.start_ns == int(seen[0].enqueued_at * 1e9)
+        assert request.trace_id and request.attrs["outcome"] == "retired"
+        assert request.attrs["tokens"] == 4
+        assert 0.0 <= request.attrs["queue_wait_s"] \
+            <= request.attrs["ttft_s"] <= request.duration_s
+        # the request's admission hangs under the tick's loop.admit, by
+        # parent and by trace id
+        admit, = tr.spans("engine.admit")
+        loop_admit, = [s for s in tr.spans("loop.admit")
+                       if s.span_id == admit.parent_id]
+        assert admit.trace_id == loop_admit.trace_id == request.trace_id
+        assert loop_admit.attrs == {"admitted": 1, "waiting": 0}
+        tick, = [s for s in tr.spans("loop.tick")
+                 if s.span_id == loop_admit.parent_id]
+        assert _names_under(tr, tick)[:3] == ["loop.pump", "loop.admit",
+                                              "loop.expire"]
+        emits = tr.spans("loop.emit")
+        assert len(emits) == 3 and all(e.attrs == {"events": 1}
+                                       for e in emits)
+        steps = tr.spans("engine.step")
+        assert [s.parent_id for s in steps] == [e.parent_id for e in emits]
+
+    def test_warmup_spans_one_per_program(self, tiny_model):
+        from synapseml_tpu.models.llm import SlotEngine
+        cfg, model, variables = tiny_model
+        tr = get_tracer()
+        tr.reset()
+        eng = SlotEngine(model, variables, n_slots=2, max_len=32,
+                         warmup="sync", name="t-warm-spans")
+        warm, = tr.spans("llm.warmup")
+        programs = tr.children(warm)
+        assert {s.name for s in programs} == {"llm.warmup.program"}
+        assert warm.attrs["programs"] == len(programs) \
+            == eng.compile_plane.snapshot()["programs_total"]
+        for s in programs:
+            assert s.attrs["key"] and s.attrs["seconds"] >= 0
+            assert isinstance(s.attrs["compiled"], bool)
+        assert sum(s.duration_s for s in programs) <= warm.duration_s
+
+    def test_gbdt_fit_spans(self):
+        from synapseml_tpu.models.gbdt import BoostingConfig, train
+        from synapseml_tpu.models.gbdt.booster import SCAN_CHUNK
+        tr = get_tracer()
+        tr.reset()
+        rng = np.random.default_rng(0)
+        X = rng.normal(size=(600, 4)).astype(np.float32)
+        y = (X[:, 0] > 0).astype(np.float64)
+        iters = SCAN_CHUNK + 2              # one scanned chunk, two looped
+        booster, _ = train(X, y, BoostingConfig(
+            objective="binary", num_iterations=iters, num_leaves=7))
+        fit, = tr.spans("gbdt.fit")
+        assert _names_under(tr, fit) == [
+            "gbdt.fit.bin", "gbdt.fit.bin", "gbdt.fit.upload",
+            "gbdt.fit.compile", "gbdt.fit.boost"]
+        assert fit.attrs["rows"] == 600 and fit.attrs["features"] == 4
+        assert fit.attrs["iterations"] == iters
+        assert fit.attrs["hist_path"] == booster.measures.hist_path
+        assert fit.attrs["two_level"] and fit.attrs["objective"] == "binary"
+        boost, = tr.spans("gbdt.fit.boost")
+        assert boost.attrs["iterations"] == iters == booster.measures.iterations
+        assert _names_under(tr, boost) == [
+            "gbdt.boost.chunk", "gbdt.fit.download", "gbdt.fit.download"]
+        chunk, = tr.spans("gbdt.boost.chunk")
+        assert chunk.attrs == {"index": 0, "iterations": SCAN_CHUNK}
+        assert tr.spans("gbdt.train") == []
 
 
 # -- artifact writer ---------------------------------------------------------
@@ -425,8 +744,8 @@ class TestTrainerMetrics:
         tl = reg.get("gbdt_two_level_resolved")
         assert tl is not None and tl.value() == 0.0
         assert reg.get("gbdt_two_level_active").value() == 0.0
-        # the retrospective span carries the fit's attribution
-        spans = [s for s in get_tracer().spans("gbdt.train")
+        # the fit's span carries its attribution
+        spans = [s for s in get_tracer().spans("gbdt.fit")
                  if s.attrs.get("rows") == 400]
         assert spans and spans[-1].attrs["objective"] == "binary"
 
