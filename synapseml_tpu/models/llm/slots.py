@@ -49,6 +49,23 @@ Mechanics:
   token-exact greedy: a draft token is committed ONLY when it equals
   the model's argmax.
 
+- **one step in flight** — an engine without a drafter hands the
+  device step N+1 before it reads step N's tokens: N+1 takes its input
+  tokens from N's output array, which never leaves the device, and its
+  lengths and active mask from host state that does not depend on
+  those tokens (a length grows by one a step; a slot that reaches its
+  budget at N retires there).  The host reads, commits and returns N
+  while the device runs N+1.  ``active``/``lengths``/``ctx``/
+  ``kv_len`` always mean the COMMITTED state — what the returned events
+  have made true; the dispatched-but-unread step is private
+  (:class:`_Flight`).  EOS is known a step late: the slot rides N+1
+  once more, its output is dropped and its K/V write lands at the EOS
+  token's own position (junk-write invariant below).  A slot cancelled,
+  preempted or handed to another request while a step is in flight has
+  that step's output dropped (an admission epoch per slot).  A drafter
+  needs the host's tokens BEFORE a step, so a speculative engine
+  dispatches and reads each step in one call.
+
 Junk-write safety: padded prefill rows and pre-copy leftovers only ever
 land at positions strictly beyond a slot's current length; decode writes
 position ``q`` BEFORE attending ``<= q``, so every attendable key was
@@ -119,15 +136,25 @@ def _decode_step_jit(model: LlamaModel, variables: Any, cache: Any,
                      temperature: float, top_k: int, top_p: float,
                      attention_backend: str = "dense",
                      paged_num_tiles: Optional[int] = None,
-                     paged_tile: Optional[int] = None):
+                     paged_tile: Optional[int] = None,
+                     prev_nxt: Optional[jnp.ndarray] = None,
+                     feed_host: Optional[jnp.ndarray] = None):
     """One decode step for every slot: feed each slot's pending token at
     its own position (vector ``cache_index``), sample the next.  Inactive
     slots compute a throwaway row and write nothing (``slot_mask``).
+
+    ``prev_nxt`` is the previous step's output, still on the device:
+    where ``feed_host`` is false a slot's pending token is
+    ``prev_nxt[slot]`` (the host has not read it yet), elsewhere
+    ``tokens[slot]``.  The engine always passes both, so one program
+    exists per span bucket.
 
     ``attention_backend``/``paged_num_tiles`` (static — one compiled
     program per span bucket) select the Pallas paged-read attention:
     each slot's K/V read covers only its live span instead of the full
     ``max_len`` row (see :mod:`~synapseml_tpu.models.llm.pallas_attn`)."""
+    if prev_nxt is not None:
+        tokens = jnp.where(feed_host, tokens, prev_nxt)
     positions = (lengths - 1)[:, None]
     logits, cache = model.apply(variables, tokens[:, None],
                                 positions=positions, cache=cache,
@@ -267,6 +294,16 @@ class StepEvent:
     reason: Optional[str] = None      # "eos" | "length" when finished
 
 
+@dataclasses.dataclass
+class _Flight:
+    """A one-token step the device has and the host has not read."""
+    nxt: Any                  # (n_slots,) int32, on the device
+    slots: np.ndarray         # the slots it advances
+    epoch: np.ndarray         # their admission epochs at dispatch
+    lengths: np.ndarray       # the lengths it was fed (other slots: 1)
+    program: str
+
+
 class SlotEngine:
     """Continuous-batching decode engine over a slotted KV cache.
 
@@ -274,7 +311,11 @@ class SlotEngine:
     the engine and interleaves :meth:`admit` / :meth:`step` freely — a
     sequence admitted mid-flight decodes next to longer-running
     neighbors in the same jitted step.  Greedy output is token-exact
-    with the dense-cache ``generate`` path.
+    with the dense-cache ``generate`` path.  The public state
+    (``active``, ``lengths``, ``ctx``, ``kv_len``) is what the returned
+    events have made true; a step already handed to the device is not
+    in it, and a sequence admitted while one is in flight joins the
+    step after it.
     """
 
     def __init__(self, model: LlamaModel, variables: Any,
@@ -331,7 +372,7 @@ class SlotEngine:
         #: committed span sizes).  None costs one attribute check per
         #: slot per step.
         self.trace_sink = trace_sink
-        #: key of the program the last step dispatched (the
+        #: key of the program that ran the step last returned (the
         #: ``engine.step`` span's ``program``)
         self.last_program: Optional[str] = None
         self.temperature = float(temperature)
@@ -379,6 +420,12 @@ class SlotEngine:
         self._retired_at = np.full(n, -np.inf)             # reclaim recency
         self._max_new = np.zeros(n, np.int64)
         self._generated = np.zeros(n, np.int64)
+        #: the step dispatched and not yet read, and what tells its
+        #: output apart from a slot's later occupant: a count of the
+        #: slot's admissions and resumes
+        self._flight: Optional[_Flight] = None
+        self._epoch = np.zeros(n, np.int64)
+        self._no_prev = jnp.zeros(n, jnp.int32)   # ``prev_nxt`` of a first step
         # radix prefix indices over slot contexts, ONE PER TENANT:
         # longest_prefix is exact by construction (tokens, not hashes),
         # so reuse finds the TRUE longest match with no candidate probe
@@ -431,6 +478,11 @@ class SlotEngine:
             "prefilled", ("engine",))
         self._m_occ = reg.gauge(
             "llm_slot_occupancy", "active slots / total slots", ("engine",))
+        self._m_overlap = reg.counter(
+            "llm_steps_overlapped_total",
+            "decode steps dispatched before the previous step's tokens "
+            "were read (the device ran them under the host's work)",
+            ("engine",))
         self._m_decode_bytes = reg.gauge(
             "llm_decode_bytes_per_token",
             "decode-attention K/V bytes read per generated token this "
@@ -481,8 +533,10 @@ class SlotEngine:
         self.decode_attn_bytes = 0
         #: speculative-decode accounting (bench's llmserve_spec_* /
         #: llama1b_spec_* fields read these): steps_run counts EVERY
-        #: engine step (plain or verify), spec_* only drafted work
+        #: engine step (plain or verify), spec_* only drafted work;
+        #: steps_overlapped those of them dispatched a step ahead
         self.steps_run = 0
+        self.steps_overlapped = 0
         self.spec_steps = 0
         self.spec_drafted = 0
         self.spec_accepted = 0
@@ -779,6 +833,7 @@ class SlotEngine:
             self.lengths[slot] = plen + 1
             self.kv_len[slot] = plen
             self.active[slot] = True
+            self._epoch[slot] += 1
             self._max_new[slot] = max_new
             self._generated[slot] = 1
             self._register_prefix(slot, prompt)
@@ -966,6 +1021,7 @@ class SlotEngine:
         self.lengths[slot] = ln
         self.kv_len[slot] = span
         self.active[slot] = True
+        self._epoch[slot] += 1
         self._max_new[slot] = int(ticket["max_new"])
         self._generated[slot] = int(ticket["generated"])
         self._register_prefix(slot, ids[:span])
@@ -993,6 +1049,7 @@ class SlotEngine:
         loop answers their 500s and calls this)."""
         for slot in np.flatnonzero(self.active):
             self._retire(int(slot), "reset")
+        self._flight = None
         self.cache = init_cache(self.cfg, self.n_slots, self.max_len)
         # all cached K/V died with the old buffers: nothing is a valid
         # prefix source anymore
@@ -1007,15 +1064,16 @@ class SlotEngine:
             self._spec_ewma[:] = 1.0
         self._m_occ.set(0.0, engine=self.name)
 
-    def _decode_step_args(self, extra_span: int = 0):
-        """(jit kwargs, spans) for THIS step: the span-bucketed grid
-        length for the paged backends (one compiled program per power-
-        of-two tile bucket, so short batches never iterate a long
-        cache's grid) and the per-slot live spans the byte ledger
-        prices.  ``extra_span`` is the verify step's S-1 additional
-        written positions — the bucket must cover the LAST query's key
-        count, ``lengths + S - 1``."""
-        lengths = np.where(self.active, self.lengths, 1)
+    def _decode_step_args(self, active: np.ndarray, lengths: np.ndarray,
+                          extra_span: int = 0):
+        """(jit kwargs, spans) for a step that advances ``active`` at
+        ``lengths``: the span-bucketed grid length for the paged
+        backends (one compiled program per power-of-two tile bucket, so
+        short batches never iterate a long cache's grid) and the
+        per-slot live spans the byte ledger prices.  ``extra_span`` is
+        the verify step's S-1 additional written positions — the bucket
+        must cover the LAST query's key count, ``lengths + S - 1``."""
+        lengths = np.where(active, lengths, 1)
         kw = {"attention_backend": self.attention_backend,
               "paged_num_tiles": None, "paged_tile": None}
         if self._paged_geo is not None:
@@ -1052,20 +1110,33 @@ class SlotEngine:
         """One decode step across every active slot.  Returns the
         per-slot events (token + retirement verdicts, possibly SEVERAL
         per slot when a drafted span is accepted); empty when no slot
-        is active.
+        is active.  Every call returns one whole step's events, in step
+        order.
+
+        Without a drafter the call first hands the device the step
+        AFTER the one it returns (where any slot will still be active),
+        fed from the returned step's tokens on the device, and then
+        reads, commits and returns its own: the host's work runs under
+        the next step's program.
 
         With ``spec_draft_len > 0`` the engine asks the n-gram drafter
         for a span per slot first: any hit upgrades the step to a
         multi-token VERIFY (every slot advances by its accepted span);
         an all-miss step falls back to the plain one-token step — a
-        miss costs nothing."""
-        if not self.active.any():
+        miss costs nothing.  The drafter reads the host's tokens, so
+        such an engine dispatches and reads each step in one call."""
+        flight = self._flight
+        if flight is not None and not self._live(flight).any():
+            # cancelled or re-admitted under it: nothing waits on it
+            flight = self._flight = None
+        if flight is None and not self.active.any():
             return []
         with step_span("engine.step") as sp:
             if sp.live:
-                act = self.active
+                act = self.active if flight is None else self._live(flight)
                 sp.set(slots=int(act.sum()),
-                       kv_span_sum=int(self.lengths[act].sum()))
+                       kv_span_sum=int(self.lengths[act].sum()),
+                       overlapped=flight is not None)
             events = None
             if self._drafter is not None:
                 with step_span("engine.step.draft"):
@@ -1095,16 +1166,37 @@ class SlotEngine:
         self._m_occ.set(self.active_count / self.n_slots, engine=self.name)
         return events
 
-    def _plain_step(self) -> List[StepEvent]:
-        """The one-token step (the pre-spec decode path)."""
+    def _live(self, flight: _Flight) -> np.ndarray:
+        """The slots whose output of ``flight`` still counts: active,
+        and held by the occupant they were dispatched for."""
+        return flight.slots & self.active & (flight.epoch == self._epoch)
+
+    def _dispatch(self, prev: Optional[_Flight]) -> Optional[_Flight]:
+        """Hand the device one one-token step (the pre-spec decode
+        path).  ``prev`` is the step before it where that one has not
+        been read: its live slots feed from its output on the device
+        at their next position, but for those that reach their budget
+        in it; every other active slot (admitted, resumed or restored
+        since) feeds the host's pending token.  None when no slot would
+        be active."""
         with step_span("engine.step.prepare"):
             # host arrays, their uploads and the dispatch (asynchronous:
             # what is timed there is the enqueue)
             idx = np.arange(self.n_slots)
-            kw, lengths = self._decode_step_args()
-            tokens = np.where(self.active,
+            carried = (np.zeros(self.n_slots, bool) if prev is None
+                       else self._live(prev))
+            active = self.active & (
+                ~carried | (self._generated + 1 < self._max_new))
+            if not active.any():
+                return None
+            kw, lengths = self._decode_step_args(active,
+                                                 self.lengths + carried)
+            tokens = np.where(active & ~carried,
                               self.ctx[idx, np.maximum(self.lengths - 1, 0)],
                               self.pad_id).astype(np.int32)
+            prev_nxt = self._no_prev if prev is None else prev.nxt
+            program = _decode_program_key(
+                self.attention_backend, kw["paged_num_tiles"])
             prof = self.step_profiler
             if prof is not None:
                 if getattr(prof, "capture_xla", False):
@@ -1115,31 +1207,51 @@ class SlotEngine:
                         _decode_step_jit, self.model, self.variables,
                         self.cache, jnp.asarray(tokens),
                         jnp.asarray(lengths.astype(np.int32)),
-                        jnp.asarray(self.active), self._key,
+                        jnp.asarray(active), self._key,
                         self.temperature, self.top_k, self.top_p,
-                        items=float(self.active_count), **kw)
-                prof.step_begin()
-            self.last_program = _decode_program_key(
-                self.attention_backend, kw["paged_num_tiles"])
+                        prev_nxt=prev_nxt, feed_host=jnp.asarray(~carried),
+                        items=float(active.sum()), **kw)
+                if prev is None:    # else its step runs on from prev's read
+                    prof.step_begin()
             with step_span("engine.step.prepare.upload"):
                 step_in = (jnp.asarray(tokens),
                            jnp.asarray(lengths.astype(np.int32)),
-                           jnp.asarray(self.active))
-            with self._program_region(self.last_program), \
+                           jnp.asarray(active))
+                feed_host = jnp.asarray(~carried)
+            with self._program_region(program), \
                     step_span("engine.step.prepare.dispatch"):
                 self.cache, nxt, self._key = _decode_step_jit(
                     self.model, self.variables, self.cache, *step_in,
                     self._key, self.temperature, self.top_k, self.top_p,
-                    **kw)
+                    prev_nxt=prev_nxt, feed_host=feed_host, **kw)
+            return _Flight(nxt, active, self._epoch.copy(), lengths, program)
+
+    def _plain_step(self) -> List[StepEvent]:
+        """The one-token step (the pre-spec decode path): make sure it
+        is dispatched, dispatch the one after it where the engine may
+        (no drafter waits for this step's tokens), then read its tokens
+        and make them the engine's state."""
+        overlapped = self._flight is not None   # only a step ahead waits there
+        flight = self._flight or self._dispatch(None)
+        self._flight = (self._dispatch(flight) if self._drafter is None
+                        else None)
         with step_span("engine.step.wait"):
-            nxt = np.asarray(nxt)     # the step's one blocking call
+            nxt = np.asarray(flight.nxt)      # the step's one blocking call
+        prof = self.step_profiler
         if prof is not None:
             prof.mark("compute")      # np.asarray synchronized the step
             prof.step_end()
+            if self._flight is not None:
+                prof.step_begin()     # the next one is already running
         with step_span("engine.step.commit"):
-            self._account_decode_bytes(lengths, int(self.active.sum()))
+            self.last_program = flight.program
+            if overlapped:
+                self.steps_overlapped += 1
+                self._m_overlap.inc(1, engine=self.name)
+            live = self._live(flight)
+            self._account_decode_bytes(flight.lengths, int(live.sum()))
             events: List[StepEvent] = []
-            for slot in np.flatnonzero(self.active):
+            for slot in np.flatnonzero(live):
                 slot = int(slot)
                 tok = int(nxt[slot])
                 ln = int(self.lengths[slot])
@@ -1154,7 +1266,11 @@ class SlotEngine:
                     self.trace_sink(slot, "decode", tokens=1)
                 finished, reason = self._finish_reason(slot, tok)
                 events.append(StepEvent(slot, tok, finished, reason))
-            return self._finish_step(events)
+            events = self._finish_step(events)
+            ahead = self._flight
+            if ahead is not None and not self._live(ahead).any():
+                self._flight = None   # an EOS emptied it: nothing waits on it
+            return events
 
     # -- speculative decoding ----------------------------------------------
     def _spec_headroom(self) -> int:
@@ -1216,7 +1332,8 @@ class SlotEngine:
             idx = np.arange(self.n_slots)
             S = self._spec_bucket(max(len(d) for d in drafts.values()),
                                   s_cap)
-            kw, lengths = self._decode_step_args(extra_span=S - 1)
+            kw, lengths = self._decode_step_args(self.active, self.lengths,
+                                                 extra_span=S - 1)
             tokens = np.full((self.n_slots, S), self.pad_id, np.int32)
             tokens[:, 0] = np.where(
                 self.active, self.ctx[idx, np.maximum(self.lengths - 1, 0)],

@@ -172,7 +172,8 @@ def program_lattice(engine) -> List[ProgramSpec]:
             cache, nxt, _ = _decode_step_jit(
                 model, variables, cache, tokens, lengths, active,
                 jax.random.PRNGKey(0), engine.temperature, engine.top_k,
-                engine.top_p, **step_kwargs(nt))
+                engine.top_p, prev_nxt=jnp.zeros(n, jnp.int32),
+                feed_host=jnp.asarray(np.ones(n, bool)), **step_kwargs(nt))
             jax.block_until_ready(nxt)
             return cache
         specs.append(ProgramSpec(_decode_program_key(backend, nt),

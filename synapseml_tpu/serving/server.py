@@ -1400,6 +1400,8 @@ class _DecodeLoop:
         self._journal_tenant_kw = journal is not None and _takes_tenant(
             getattr(journal, "begin", lambda: None))
         self._step_ewma: Optional[float] = None
+        #: when the last step returned, while the engine has stayed busy
+        self._stepped_at: Optional[float] = None
         self._retired_window: List[float] = []
         # request-scoped tracing: the process store by default (so the
         # listener's /tracez sees this loop's requests); the sampling
@@ -2128,10 +2130,15 @@ class _DecodeLoop:
                 self._cancel_expired()
                 self._export_slo()
             if not self.engine.active_count:
+                self._stepped_at = None
                 return
-            t0 = time.perf_counter()
+            # a step's period is the time between two steps' returns: the
+            # engine may hand back a step the device finished under the
+            # last tick's work, so the call alone says nothing of it
+            since = self._stepped_at or time.perf_counter()
             events = self.engine.step()
-            dt = time.perf_counter() - t0
+            self._stepped_at = time.perf_counter()
+            dt = self._stepped_at - since
             with step_span("loop.emit") as sp:
                 self._emit(events, dt)
                 if sp.live:

@@ -396,8 +396,11 @@ class TestProgramSpans:
         eng.admit(ids[0], 8)
         eng.step()
         assert tr.spans() == []
+        # that step's call handed the device the next one too, so the slot
+        # admitted now joins the step after it
         with profiler_session(tmp_path):
             res = eng.admit(ids[1], 8)
+            alone = eng.step()
             events = eng.step()
         admit, = tr.spans("engine.admit")
         assert _names_under(tr, admit) == [
@@ -405,16 +408,23 @@ class TestProgramSpans:
             "engine.admit.commit"]
         assert admit.attrs == {"bucket": res.bucket, "prompt_tokens": 7,
                                "reused_tokens": 0, "path": "cold"}
-        step, = tr.spans("engine.step")
-        assert _names_under(tr, step) == [
-            "engine.step.prepare", "engine.step.wait", "engine.step.commit"]
-        prepare, = tr.spans("engine.step.prepare")
-        assert _names_under(tr, prepare) == [
-            "engine.step.prepare.upload", "engine.step.prepare.dispatch"]
+        first, step = tr.spans("engine.step")
+        for s in (first, step):
+            # the prepare is the next step's, the wait and commit this one's
+            assert _names_under(tr, s) == [
+                "engine.step.prepare", "engine.step.wait",
+                "engine.step.commit"]
+        for prepare in tr.spans("engine.step.prepare"):
+            assert _names_under(tr, prepare) == [
+                "engine.step.prepare.upload", "engine.step.prepare.dispatch"]
+        assert first.attrs["tokens"] == len(alone) == 1
+        assert first.attrs["slots"] == 1
+        assert first.attrs["kv_span_sum"] == 8 + 1      # one stepped before
         assert step.attrs["tokens"] == len(events) == 2
         assert step.attrs["slots"] == 2
-        assert step.attrs["kv_span_sum"] == 2 * 8 + 1   # one stepped before
+        assert step.attrs["kv_span_sum"] == (8 + 2) + 8
         assert step.attrs["program"] == "decode_dense"
+        assert first.attrs["overlapped"] and step.attrs["overlapped"]
         assert sum(s.duration_s for s in tr.children(step)) \
             <= step.duration_s
         assert {"engine.step", "engine.step.wait", "engine.admit.prefill"} \
