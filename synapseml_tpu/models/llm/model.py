@@ -1,4 +1,14 @@
-"""Decoder-only causal LLM (Llama-3 family architecture), TP-sharded.
+"""Decoder-only causal LLM: one model description, TP-sharded.
+
+:class:`LlamaConfig` describes a decoder by its widths and a per-layer
+pattern (``layer_types``, the key ``transformers`` configs use): each layer
+kind is one mixer class that declares its own cache entry
+(:data:`MIXERS`, :func:`init_cache`).  The default, every layer
+``full_attention`` with rotary embeddings in pre-norm blocks, is the Llama-3
+family; ``linear_attention`` layers (gated delta rule, a recurrent state in
+place of K/V rows), the OLMo block order, query/key normalisation and a
+decoder without rotary embeddings are rows of the same description
+(:meth:`LlamaConfig.from_hf`).
 
 The reference has no LLM training/serving of its own — its OpenAI stages
 call out to a remote service (reference: cognitive/.../openai/OpenAI.scala
@@ -45,7 +55,8 @@ class LlamaConfig:
     num_kv_heads: int = 8
     d_ff: int = 14_336
     max_len: int = 8192
-    rope_theta: float = 500_000.0
+    #: None: no rotary embedding
+    rope_theta: Optional[float] = 500_000.0
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     tie_embeddings: bool = False
@@ -55,10 +66,103 @@ class LlamaConfig:
     #: the knob that fits 8B-class models on one 16 GB chip.  Pair with
     #: :func:`synapseml_tpu.models.llm.quantize_int8`
     weight_quant: str = "none"
+    #: mixer kind of each layer (a key of :data:`MIXERS`), as
+    #: ``transformers`` configs give it; None: every layer full attention
+    layer_types: Optional[Tuple[str, ...]] = None
+    #: "pre": ``x + mixer(norm(x))`` (Llama); "post": ``x + norm(mixer(x))``
+    #: (OLMo 2 and 3), the same for the MLP
+    norm_order: str = "pre"
+    #: RMSNorm with a learned scale over the whole width of q and of k
+    #: before the split into heads (OLMo 2 and 3)
+    qk_norm: bool = False
+    # linear-attention layers (gated delta rule): heads, their key and
+    # value sizes, taps of the causal depthwise convolution, and whether
+    # beta spans (0, 2) (``linear_allow_neg_eigval``) or (0, 1)
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)      # hashable
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"num_layers is {self.num_layers}")
+            unknown = set(self.layer_types) - set(MIXERS)
+            if unknown:
+                raise ValueError(f"unknown layer kinds {sorted(unknown)}; "
+                                 f"the model has {sorted(MIXERS)}")
+        if self.norm_order not in ("pre", "post"):
+            raise ValueError(f"norm_order={self.norm_order!r}")
 
     @property
     def d_head(self) -> int:
         return self.d_model // self.num_heads
+
+    @property
+    def kv_cache_heads(self) -> int:
+        """K/V heads a cache row holds: ``num_kv_heads``, rounded up to the
+        cache dtype's sublane multiple where they pass one tile of it and
+        do not fill their last (30 bfloat16 heads -> 32).  Unpadded, XLA
+        lays such a cache out with positions minor for the step's scatter
+        and copies all of it, every layer and step, into the row-major
+        order the paged kernel reads (6 GB of temporaries at 30 heads,
+        32 x 1536)."""
+        sub = max(8, 32 // np.dtype(self.dtype).itemsize)
+        kv = self.num_kv_heads
+        return kv if kv <= sub or kv % sub == 0 else -(-kv // sub) * sub
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return self.layer_types or ("full_attention",) * self.num_layers
+
+    @property
+    def num_attention_layers(self) -> int:
+        return self.layer_kinds.count("full_attention")
+
+    @property
+    def num_recurrent_layers(self) -> int:
+        return self.layer_kinds.count("linear_attention")
+
+    @staticmethod
+    def from_hf(hc: Dict[str, Any], **kw) -> "LlamaConfig":
+        """The description from a ``transformers`` ``config.json``'s keys
+        (``kw`` overrides: ``max_len``, ``dtype``...).  ``model_type``
+        ``olmo_hybrid`` brings the family's block order and query/key
+        norm, which its config does not spell out."""
+        rope = hc.get("rope_parameters") or {}
+        theta = rope["rope_theta"] if "rope_theta" in rope \
+            else hc.get("rope_theta", 10_000.0)   # HF's default (Llama-1/2)
+        olmo = hc.get("model_type") == "olmo_hybrid"
+        args = dict(
+            vocab_size=hc["vocab_size"], d_model=hc["hidden_size"],
+            num_layers=hc["num_hidden_layers"],
+            num_heads=hc["num_attention_heads"],
+            num_kv_heads=hc.get("num_key_value_heads",
+                                hc["num_attention_heads"]),
+            d_ff=hc["intermediate_size"],
+            max_len=int(hc.get("max_position_embeddings", 8192)),
+            rope_theta=None if theta is None else float(theta),
+            rms_norm_eps=float(hc.get("rms_norm_eps", 1e-5)),
+            tie_embeddings=bool(hc.get("tie_word_embeddings", False)),
+            layer_types=hc.get("layer_types"),
+            norm_order="post" if olmo else "pre", qk_norm=olmo)
+        if "linear_num_value_heads" in hc:
+            if hc.get("linear_num_key_heads") != hc["linear_num_value_heads"]:
+                raise ValueError("linear layers with fewer key heads than "
+                                 "value heads are not supported")
+            args.update(
+                linear_num_heads=hc["linear_num_value_heads"],
+                linear_key_head_dim=hc["linear_key_head_dim"],
+                linear_value_head_dim=hc["linear_value_head_dim"],
+                linear_conv_kernel_dim=hc.get("linear_conv_kernel_dim", 4),
+                linear_allow_neg_eigval=bool(
+                    hc.get("linear_allow_neg_eigval", False)))
+        args.update(kw)
+        return LlamaConfig(**args)
 
     @staticmethod
     def llama3_8b(**kw) -> "LlamaConfig":
@@ -179,21 +283,28 @@ def _dense(features, axes, name, dtype, quant: str = "none"):
 
 
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> List[Dict]:
-    """Per-layer KV cache pytree.
+    """Per-layer cache pytree: each layer's entry is what its mixer kind
+    declares (``MIXERS[kind].cache_entry``): K/V rows by token position
+    for full attention, a recurrent state and a convolution window,
+    whatever ``max_len``, for linear attention.
 
     ``batch`` doubles as the SLOT axis for continuous-batching serving
     (:mod:`synapseml_tpu.models.llm.slots`): each row is one independent
     sequence slot, written at its own per-slot offset via the vector
     ``cache_index`` path and protected by ``slot_mask`` so retired slots
     keep their K/V intact as prefix-cache source material."""
-    shape = (batch, max_len, cfg.num_kv_heads, cfg.d_head)
-    return [{"k": jnp.zeros(shape, cfg.dtype),
-             "v": jnp.zeros(shape, cfg.dtype)}
-            for _ in range(cfg.num_layers)]
+    return [MIXERS[kind].cache_entry(cfg, batch, max_len)
+            for kind in cfg.layer_kinds]
 
 
 class CausalAttention(nn.Module):
     cfg: LlamaConfig
+
+    @staticmethod
+    def cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> Dict:
+        shape = (batch, max_len, cfg.kv_cache_heads, cfg.d_head)
+        return {"k": jnp.zeros(shape, cfg.dtype),
+                "v": jnp.zeros(shape, cfg.dtype)}
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict],
@@ -201,7 +312,10 @@ class CausalAttention(nn.Module):
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
                  paged_num_tiles: Optional[int] = None,
-                 paged_tile: Optional[int] = None):
+                 paged_tile: Optional[int] = None,
+                 valid_len: Optional[jnp.ndarray] = None):
+        # ``valid_len`` is the recurrent mixer's: a padded row's K/V lands
+        # beyond the slot's length and is overwritten before it is read
         cfg = self.cfg
         B, S, _ = x.shape
         H, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.d_head
@@ -211,12 +325,20 @@ class CausalAttention(nn.Module):
                    cfg.weight_quant)(x)
         v = _dense(KV * D, ("embed", "kv"), "v_proj", cfg.dtype,
                    cfg.weight_quant)(x)
-        q = apply_rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
-        k = apply_rope(k.reshape(B, S, KV, D), positions, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
+        q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
+        if cfg.rope_theta is not None:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
         v = v.reshape(B, S, KV, D)
 
         new_cache = None
         if cache is not None:
+            if cfg.kv_cache_heads != KV:          # the row's padding heads
+                pad = ((0, 0), (0, 0), (0, cfg.kv_cache_heads - KV), (0, 0))
+                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
             if jnp.ndim(cache_index) == 0:
                 # write this step's K/V at cache_index, attend over prefix
                 k_all = jax.lax.dynamic_update_slice(
@@ -251,7 +373,7 @@ class CausalAttention(nn.Module):
                 k_all = cache["k"].at[bidx, wpos].set(k_w)
                 v_all = cache["v"].at[bidx, wpos].set(v_w)
             new_cache = {"k": k_all, "v": v_all}
-            k_att, v_att = k_all, v_all
+            k_att, v_att = k_all[:, :, :KV], v_all[:, :, :KV]
             T = k_all.shape[1]
             key_pos = jnp.arange(T)[None, :]                    # (1, T)
             qpos = positions[:, :, None]                        # (B, S, 1)
@@ -289,7 +411,7 @@ class CausalAttention(nn.Module):
             # fewer each inside the kernel (the in-span causal mask)
             spans = positions[:, -1].astype(jnp.int32) + 1
             out = paged_decode_attention(
-                q, k_all, v_all, spans, tile=tile,
+                q, k_all, v_all, spans, tile=tile, kv_heads=KV,
                 num_tiles=(paged_num_tiles or T // tile),
                 interpret=(attention_backend == "interpret")
             ).reshape(B, S, H * D)
@@ -310,21 +432,172 @@ class CausalAttention(nn.Module):
         return out, new_cache
 
 
+def _l2norm(x):
+    """FLA's: x / sqrt(sum x^2 + 1e-6), over the last axis."""
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+
+class GatedDeltaNet(nn.Module):
+    """Linear-attention mixer: the gated delta rule (Yang et al., "Gated
+    Delta Networks"; FLA's ``GatedDeltaNet``).  Per head a state ``S`` of
+    ``(d_k, d_v)`` float32 takes each token as
+
+        S <- alpha (S - beta k (k^T S)) + beta k v^T,    o = S^T q
+
+    with q, k, v from a 4-tap causal depthwise convolution and SiLU, q and
+    k L2-normalised, ``alpha = exp(-exp(A_log) softplus(x w_a + dt_bias))``
+    and ``beta = sigmoid(x w_b)``, doubled where ``linear_allow_neg_eigval``;
+    the output is RMS-normalised per head and gated by ``silu(x W_g)``.
+
+    Its cache entry is the state (packed as
+    :mod:`~synapseml_tpu.models.llm.pallas_gdn` lays it out) and the last
+    ``taps - 1`` inputs of the convolution: a fixed size whatever the
+    sequence length, which no token position can slice.  Every position
+    that is not valid (``slot_mask`` false, or at or after ``valid_len``)
+    leaves both exactly as they were, and a pass from position 0 starts
+    from zeros, not from what the slot's last tenant left."""
+    cfg: LlamaConfig
+
+    @staticmethod
+    def cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> Dict:
+        from .pallas_gdn import state_shape
+        H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                     cfg.linear_value_head_dim)
+        return {"state": jnp.zeros((batch,) + state_shape(H, dk, dv),
+                                   jnp.float32),
+                "conv": jnp.zeros((batch, cfg.linear_conv_kernel_dim - 1,
+                                   H * (2 * dk + dv)), cfg.dtype)}
+
+    @nn.compact
+    def __call__(self, x, positions, cache: Optional[Dict],
+                 cache_index: Optional[jnp.ndarray],
+                 slot_mask: Optional[jnp.ndarray] = None,
+                 attention_backend: str = "dense",
+                 paged_num_tiles: Optional[int] = None,
+                 paged_tile: Optional[int] = None,
+                 valid_len: Optional[jnp.ndarray] = None):
+        from . import pallas_gdn as gdn
+        cfg = self.cfg
+        B, S, _ = x.shape
+        H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                     cfg.linear_value_head_dim)
+        taps = cfg.linear_conv_kernel_dim
+        pack = gdn.gdn_pack(H, dv)
+        per_slot = cache is not None and jnp.ndim(cache_index) != 0
+        if per_slot and S != 1:
+            raise NotImplementedError(
+                "a multi-token step at per-slot positions (speculative "
+                "verify) would have to roll a recurrent state back over "
+                "its rejected tokens; linear-attention layers keep no "
+                "snapshot to roll back to")
+
+        def proj(n, name, axes=("embed", "heads")):
+            return _dense(n, axes, name, cfg.dtype, cfg.weight_quant)(x)
+        mixed = jnp.concatenate([proj(H * dk, "q_proj"), proj(H * dk, "k_proj"),
+                                 proj(H * dv, "v_proj")], axis=-1)
+        C = mixed.shape[-1]
+
+        # the tokens of this pass that are real: (B,) counts
+        n_valid = jnp.full((B,), S, jnp.int32) if valid_len is None else \
+            jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32), (B,))
+        if slot_mask is not None:
+            n_valid = jnp.where(slot_mask, n_valid, 0)
+        if cache is None:
+            window = jnp.zeros((B, taps - 1, C), mixed.dtype)
+            state = jnp.zeros((B,) + gdn.state_shape(H, dk, dv), jnp.float32)
+        else:
+            window, state = cache["conv"], cache["state"]
+            if not per_slot:
+                fresh = jnp.asarray(cache_index) == 0
+                window = jnp.where(fresh, jnp.zeros_like(window), window)
+                state = jnp.where(fresh, jnp.zeros_like(state), state)
+
+        # causal depthwise convolution over [window | this pass]
+        w = self.param("conv", nn.with_partitioning(
+            nn.initializers.normal(0.5), (None, "heads")),
+            (taps, C)).astype(jnp.float32)
+        ext = jnp.concatenate([window, mixed], axis=1)      # (B, taps-1+S, C)
+        conv = sum(ext[:, j:j + S].astype(jnp.float32) * w[j]
+                   for j in range(taps))
+        conv = nn.silu(conv)
+        new_window = jax.vmap(
+            lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, taps - 1, 0)
+        )(ext, n_valid)
+
+        q, k, v = jnp.split(conv, [H * dk, 2 * H * dk], axis=-1)
+        q = _l2norm(q.reshape(B, S, H, dk)) * (dk ** -0.5)
+        k = _l2norm(k.reshape(B, S, H, dk))
+        v = v.reshape(B, S, H, dv)
+        a_log = self.param("A_log", nn.initializers.zeros_init(), (H,),
+                           jnp.float32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros_init(), (H,),
+                             jnp.float32)
+        alpha = jnp.exp(-jnp.exp(a_log) * jax.nn.softplus(
+            proj(H, "a_proj").astype(jnp.float32) + dt_bias))
+        beta = jax.nn.sigmoid(proj(H, "b_proj").astype(jnp.float32)) \
+            * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+
+        kernel = attention_backend in ("paged", "interpret") \
+            and cache is not None
+        interpret = attention_backend == "interpret"
+        if kernel and per_slot:
+            state, o = gdn.gated_delta_decode(
+                state, q[:, 0], k[:, 0], v[:, 0], alpha[:, 0], beta[:, 0],
+                n_valid > 0, pack=pack, interpret=interpret)
+            o = o[:, None]
+        elif kernel and B == 1:
+            st, o = gdn.gated_delta_prefill(
+                state[0], q[0], k[0], v[0], alpha[0], beta[0], n_valid[0],
+                pack=pack, interpret=interpret)
+            state, o = st[None], o[None]
+        else:
+            valid = jnp.arange(S)[None, :] < n_valid[:, None]
+            o, st = gdn.gated_delta_scan(
+                q, k, v, alpha, beta, gdn.unpack_state(state, pack), valid)
+            state = gdn.pack_state(st, pack)
+
+        # per-head RMSNorm over d_v with a learned scale, gated
+        scale = self.param("o_norm", nn.initializers.ones_init(), (dv,),
+                           jnp.float32)
+        var = jnp.mean(jnp.square(o), -1, keepdims=True)
+        o = o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * scale
+        gate = proj(H * dv, "g_proj").astype(jnp.float32)
+        o = (o.reshape(B, S, H * dv) * nn.silu(gate)).astype(cfg.dtype)
+        out = _dense(cfg.d_model, ("heads", "embed"), "o_proj", cfg.dtype,
+                     cfg.weight_quant)(o)
+        new_cache = None if cache is None else {"state": state,
+                                                "conv": new_window}
+        return out, new_cache
+
+
+#: mixer kind (a ``layer_types`` entry) -> its class; each declares its
+#: own cache entry and takes the same call
+MIXERS = {"full_attention": CausalAttention,
+          "linear_attention": GatedDeltaNet}
+#: the name of a kind's parameters inside a block
+_MIXER_NAME = {"full_attention": "attn", "linear_attention": "gdn"}
+
+
 class DecoderBlock(nn.Module):
     cfg: LlamaConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions, cache, cache_index, slot_mask=None,
                  attention_backend: str = "dense",
                  paged_num_tiles: Optional[int] = None,
-                 paged_tile: Optional[int] = None):
+                 paged_tile: Optional[int] = None,
+                 valid_len: Optional[jnp.ndarray] = None):
         cfg = self.cfg
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_attn")(x)
-        a, new_cache = CausalAttention(cfg, name="attn")(
-            h, positions, cache, cache_index, slot_mask,
-            attention_backend, paged_num_tiles, paged_tile)
-        x = x + a
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_mlp")(x)
+        pre = cfg.norm_order == "pre"
+        ln_attn = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_attn")
+        ln_mlp = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_mlp")
+        a, new_cache = MIXERS[self.kind](cfg, name=_MIXER_NAME[self.kind])(
+            ln_attn(x) if pre else x, positions, cache, cache_index,
+            slot_mask, attention_backend, paged_num_tiles, paged_tile,
+            valid_len)
+        x = x + (a if pre else ln_attn(a))
+        h = ln_mlp(x) if pre else x
         gate = _dense(cfg.d_ff, ("embed", "mlp"), "gate_proj", cfg.dtype,
                       cfg.weight_quant)(h)
         up = _dense(cfg.d_ff, ("embed", "mlp"), "up_proj", cfg.dtype,
@@ -332,7 +605,7 @@ class DecoderBlock(nn.Module):
         h = nn.silu(gate) * up                                  # SwiGLU
         h = _dense(cfg.d_model, ("mlp", "embed"), "down_proj", cfg.dtype,
                    cfg.weight_quant)(h)
-        return x + h, new_cache
+        return x + (h if pre else ln_mlp(h)), new_cache
 
 
 class LlamaModel(nn.Module):
@@ -346,7 +619,11 @@ class LlamaModel(nn.Module):
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
                  paged_num_tiles: Optional[int] = None,
-                 paged_tile: Optional[int] = None):
+                 paged_tile: Optional[int] = None,
+                 valid_len: Optional[jnp.ndarray] = None):
+        """``valid_len`` (scalar or ``(B,)``): how many of the ``S`` tokens
+        are real, the rest a bucket's padding; a recurrent layer must not
+        take a padded token into its state (attention layers ignore it)."""
         cfg = self.cfg
         B, S = input_ids.shape
         if positions is None:
@@ -362,11 +639,11 @@ class LlamaModel(nn.Module):
                              name="tok_embed")
         x = embed(input_ids)
         new_caches = []
-        for i in range(cfg.num_layers):
+        for i, kind in enumerate(cfg.layer_kinds):
             layer_cache = cache[i] if cache is not None else None
-            x, nc = DecoderBlock(cfg, name=f"layer_{i}")(
+            x, nc = DecoderBlock(cfg, kind, name=f"layer_{i}")(
                 x, positions, layer_cache, cache_index, slot_mask,
-                attention_backend, paged_num_tiles, paged_tile)
+                attention_backend, paged_num_tiles, paged_tile, valid_len)
             new_caches.append(nc)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_final")(x)
         if cfg.tie_embeddings:
@@ -423,20 +700,9 @@ def llama_from_pretrained(path: str, dtype: Any = jnp.bfloat16,
                 f"no config.json beside {path!r}; pass config= explicitly")
         with open(cfg_path) as f:
             hc = json.load(f)
-        config = LlamaConfig(
-            vocab_size=hc["vocab_size"],
-            d_model=hc["hidden_size"],
-            num_layers=hc["num_hidden_layers"],
-            num_heads=hc["num_attention_heads"],
-            num_kv_heads=hc.get("num_key_value_heads",
-                                hc["num_attention_heads"]),
-            d_ff=hc["intermediate_size"],
-            max_len=max_len or int(hc.get("max_position_embeddings", 8192)),
-            # HF's default when config.json omits it (Llama-1/2 era)
-            rope_theta=float(hc.get("rope_theta", 10_000.0)),
-            rms_norm_eps=float(hc.get("rms_norm_eps", 1e-5)),
-            tie_embeddings=bool(hc.get("tie_word_embeddings", False)),
-            dtype=dtype)
+        config = LlamaConfig.from_hf(hc, dtype=dtype)
+        if max_len:
+            config = dataclasses.replace(config, max_len=int(max_len))
     model = LlamaModel(config)
     probe = jnp.zeros((1, 8), jnp.int32)
     params = model.init(jax.random.PRNGKey(rng_seed), probe)["params"]

@@ -321,14 +321,15 @@ def _make_decode_kernel(kv_heads: int, group: int, tile: int, d_head: int,
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "num_tiles",
-                                             "interpret"))
+                                             "interpret", "kv_heads"))
 def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
                            k: jnp.ndarray,      # (B, max_len, KV, D)
                            v: jnp.ndarray,      # (B, max_len, KV, D)
                            spans: jnp.ndarray,  # (B,) int32 live lengths
                            tile: int,
                            num_tiles: int,
-                           interpret: bool = False) -> jnp.ndarray:
+                           interpret: bool = False,
+                           kv_heads: Optional[int] = None) -> jnp.ndarray:
     """One decode step's attention for every slot, reading only each
     slot's live K/V span: → same shape as ``q``, in ``q.dtype``.
 
@@ -343,14 +344,18 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
     ``num_tiles`` is the static bucketed grid length from
     :func:`span_bucket_tiles`; spans beyond ``num_tiles * tile`` would
     be silently truncated, so the caller's bucket must cover the
-    longest live span."""
+    longest live span.  ``kv_heads``: the heads of ``k``/``v`` that are
+    real, the first ones, where a cache row is padded past them
+    (``LlamaConfig.kv_cache_heads``); the padding is fetched with its
+    tile and never read."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     B, S, H, D = q.shape
     KV = k.shape[2]
-    assert H % KV == 0, (H, KV)
-    group = H // KV
+    heads = kv_heads or KV
+    assert H % heads == 0 and heads <= KV, (H, heads, KV)
+    group = H // heads
 
     def kv_index_map(s, t, spans_ref):
         # tiles past the live span clamp to the slot's LAST live tile:
@@ -377,7 +382,7 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
         ],
     )
     out = pl.pallas_call(
-        _make_decode_kernel(KV, group, tile, D, S),
+        _make_decode_kernel(heads, group, tile, D, S),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
         interpret=interpret,

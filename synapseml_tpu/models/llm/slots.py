@@ -1,14 +1,37 @@
-"""Slotted KV cache + continuous-batching decode engine.
+"""Slotted cache + continuous-batching decode engine.
 
 The Orca-style in-flight batching / vLLM-style paged-KV pattern (Yu et
 al., OSDI'22; Kwon et al., SOSP'23) adapted to XLA's static-shape world:
-instead of dynamically-sized pages, the cache is a FIXED tensor of
-``n_slots`` independent rows — ``(n_slots, max_len, kv_heads, d_head)``
-per layer — and one jitted decode step advances every ACTIVE slot by one
-token.  Admission and eviction happen between steps on the host, so the
-scheduler serves heterogeneous sequence lengths with exactly three
-compiled programs: one decode step, one prefill per prompt-length
-bucket, and one prefix copy.
+instead of dynamically-sized pages, the cache is a FIXED tree of
+``n_slots`` independent rows per layer and one jitted decode step
+advances every ACTIVE slot by one token.  Admission and eviction happen
+between steps on the host, so the scheduler serves heterogeneous
+sequence lengths with exactly three compiled programs: one decode step,
+one prefill per prompt-length bucket, and one prefix copy.
+
+**Two kinds of state.**  What a layer keeps for a slot is what its mixer
+kind declares (``model.MIXERS[kind].cache_entry``):
+
+- a *full-attention* layer keeps rows of keys and values by token
+  position, ``(n_slots, max_len, kv_heads, d_head)``.  A position can be
+  sliced, copied, overwritten and rolled back, and everything below that
+  reuses or moves a slot's history works on such rows;
+- a *linear-attention* layer keeps a recurrent state and a convolution
+  window of a fixed size whatever the length
+  (``{"state": (n_slots, heads/pack, d_k, pack*d_v) f32, "conv":
+  (n_slots, taps-1, channels)}``).  It holds every token the slot ever
+  took and none of them separately: it can be kept or zeroed, not sliced.
+  So for a model with such layers (``engine.recurrent``) the engine takes
+  no token into a state that is not the slot's own next one: padded
+  positions of a prefill bucket and inactive slots of a decode step leave
+  state and window exactly as they were, a prefill from position 0 starts
+  from zeros, and the paths that slice or roll back by position say so:
+  prefix reuse is skipped and counted
+  (``llm_prefix_reuse_skipped_total``), :meth:`SlotEngine.resume`
+  cold-prefills, and a drafter, a ``kv_arena`` or a
+  :class:`~synapseml_tpu.serving.disagg.PrefillWorker` over the engine is
+  an error at construction (``docs/api/serving.md``, "Models with
+  recurrent layers").
 
 Mechanics:
 
@@ -17,23 +40,28 @@ Mechanics:
   slot's K/V at its own offset, the causal mask (``key_pos <= qpos``)
   confines each slot to its own prefix, and ``slot_mask`` gates writes
   so inactive slots' rows stay untouched (they are live prefix-cache
-  material).  ``attention_backend`` selects the attention READ: dense
+  material; a recurrent state is gated the same way).
+  ``attention_backend`` selects the attention READ: dense
   (full ``max_len`` rows, masked) or the Pallas paged kernel
   (:mod:`~synapseml_tpu.models.llm.pallas_attn` — only each slot's
   live span, span-bucketed so one compiled step exists per power-of-
   two tile bucket; ``'auto'`` = paged on TPU when the geometry fits
-  VMEM).
+  VMEM), and with it how a recurrent layer runs (the kernels of
+  :mod:`~synapseml_tpu.models.llm.pallas_gdn` where attention is paged,
+  a ``lax.scan`` where it is dense).
 - **prefill-into-slot** — the prompt is padded to a power-of-two bucket
   (bounded compile count), its K/V lands in ONE slot row (sliced out,
   filled batch-1, written back), and the true-last-token logits come
   back for the first sampled token.  ``start > 0`` resumes a prefill
   after a prefix copy.
-- **prefix reuse** — prompts are indexed by a hash of their first
-  ``min_prefix`` tokens; on admit the engine finds the slot (retired or
-  active) with the longest common prefix, verifies it token-by-token
-  (hash collisions can't corrupt output), copies that K/V span into the
-  new slot, and prefills only the tail.  Reuse is capped at
-  ``len(prompt) - 1`` so the prefill always produces next-token logits.
+- **prefix reuse** — every slot's context (prompt plus generated
+  tokens, active or retired) lies in a radix tree of token ids, one per
+  tenant (:class:`~synapseml_tpu.models.llm.kvtier.RadixPrefixIndex`);
+  on admit one walk finds the slot with the TRUE longest common prefix
+  (tokens are compared, not hashes), the engine copies that K/V span
+  into the new slot, or leaves it where the slot is its own source, and
+  prefills only the tail.  Reuse is capped at ``len(prompt) - 1`` so
+  the prefill always produces next-token logits.
 - **retirement** — EOS or the per-request token budget frees the slot;
   its K/V and token buffer persist as prefix-cache until the slot is
   reclaimed (least-recently-retired first).
@@ -60,7 +88,9 @@ Mechanics:
   have made true; the dispatched-but-unread step is private
   (:class:`_Flight`).  EOS is known a step late: the slot rides N+1
   once more, its output is dropped and its K/V write lands at the EOS
-  token's own position (junk-write invariant below).  A slot cancelled,
+  token's own position (junk-write invariant below); a recurrent state
+  takes that junk token, which is why nothing ever reads a retired
+  slot's state: the next prefill starts it from zeros.  A slot cancelled,
   preempted or handed to another request while a step is in flight has
   that step's output dropped (an admission epoch per slot).  A drafter
   needs the host's tokens BEFORE a step, so a speculative engine
@@ -99,24 +129,33 @@ from .model import LlamaModel, init_cache
 from .pallas_attn import (dense_read_bytes, paged_geometry,
                           paged_read_bytes, resolve_attention_backend,
                           span_bucket_tiles)
+from .pallas_gdn import resolve_recurrent_backend, slot_state_bytes
 
 
-@functools.partial(jax.jit, static_argnames=("model",),
+@functools.partial(jax.jit, static_argnames=("model", "attention_backend"),
                    donate_argnums=(2,))
 def _prefill_slot_jit(model: LlamaModel, variables: Any, cache: Any,
                       tokens: jnp.ndarray, plen: jnp.ndarray,
-                      slot: jnp.ndarray, start: jnp.ndarray):
+                      slot: jnp.ndarray, start: jnp.ndarray,
+                      attention_backend: str = "dense"):
     """Prefill ``plen`` real tokens (``tokens`` is padded to a static
     bucket length) into row ``slot`` starting at position ``start``.
     Returns ``(new_cache, last_logits (V,) f32)`` where ``last_logits``
-    is the row for the prompt's true last token."""
+    is the row for the prompt's true last token.
+
+    Padding rows of K/V are junk that is overwritten before it is read;
+    a recurrent layer takes ``valid_len=plen`` and leaves its state as
+    token ``plen - 1`` made it (from zeros where ``start`` is 0).
+    ``attention_backend`` only says how such a layer runs: prefill
+    attention is dense whatever it is."""
     pb = tokens.shape[0]
     row = jax.tree.map(
         lambda c: lax.dynamic_slice_in_dim(c, slot, 1, axis=0), cache)
     positions = (start + jnp.arange(pb))[None, :]
     logits, row = model.apply(variables, tokens[None, :],
                               positions=positions, cache=row,
-                              cache_index=start)
+                              cache_index=start, valid_len=plen,
+                              attention_backend=attention_backend)
     new_cache = jax.tree.map(
         lambda c, r: lax.dynamic_update_slice_in_dim(c, r, slot, axis=0),
         cache, row)
@@ -274,7 +313,9 @@ class AdmitResult:
     when ``finished``) feed the request-scoped trace the serving loop
     keeps per request; ``path`` says where the prompt's K/V came from
     (``cold``: all prefilled, ``reuse``: a device-resident prefix,
-    ``restore``: the host arena)."""
+    ``restore``: the host arena, ``cold_recurrent``: a prefix was there to
+    reuse and was prefilled again, because the model's recurrent state
+    after it was not)."""
     slot: int
     token: int
     finished: bool
@@ -348,6 +389,26 @@ class SlotEngine:
             num_kv_heads=self.cfg.num_kv_heads,
             d_head=self.cfg.d_head, dtype=self.cfg.dtype,
             max_query_span=spec_span)
+        #: layers whose state cannot be sliced by token position (module
+        #: docstring, "Two kinds of state")
+        self.recurrent = self.cfg.num_recurrent_layers > 0
+        if self.recurrent:
+            if spec_draft_len:
+                raise ValueError(
+                    "spec_draft_len > 0 with linear-attention layers: a "
+                    "verify step writes its whole drafted span and rolls "
+                    "the rejected part back by position; a recurrent state "
+                    "has taken those tokens for good and keeps no snapshot "
+                    "to return to")
+            if kv_arena is not None:
+                raise ValueError(
+                    "kv_arena with linear-attention layers: the host arena "
+                    "spills and restores K/V rows by token position; a "
+                    "recurrent state can be snapshotted at a position, not "
+                    "sliced, and the engine builds no snapshots")
+            resolve_recurrent_backend(
+                self.attention_backend, self.cfg.linear_num_heads,
+                self.cfg.linear_key_head_dim, self.cfg.linear_value_head_dim)
         self._paged_geo = (None if self.attention_backend == "dense"
                           else paged_geometry(
                               self.max_len, self.cfg.num_heads,
@@ -476,8 +537,31 @@ class SlotEngine:
             "llm_prefix_tokens_reused_total",
             "prompt tokens copied from a cached prefix instead of "
             "prefilled", ("engine",))
+        self._m_reuse_skipped = reg.counter(
+            "llm_prefix_reuse_skipped_total",
+            "admissions and resumes that found a reusable prefix and "
+            "prefilled it anyway (reason recurrent_state: the model keeps "
+            "a state that cannot be sliced by token position)",
+            ("engine", "reason"))
         self._m_occ = reg.gauge(
             "llm_slot_occupancy", "active slots / total slots", ("engine",))
+        #: bytes of recurrent state and convolution window one slot holds
+        #: over all linear-attention layers (0 for a model without them)
+        self.slot_state_bytes = self.cfg.num_recurrent_layers * \
+            slot_state_bytes(
+                self.cfg.linear_num_heads, self.cfg.linear_key_head_dim,
+                self.cfg.linear_value_head_dim,
+                self.cfg.linear_conv_kernel_dim - 1,
+                self.cfg.linear_num_heads * (
+                    2 * self.cfg.linear_key_head_dim
+                    + self.cfg.linear_value_head_dim),
+                np.dtype(self.cfg.dtype).itemsize)
+        reg.gauge(
+            "llm_recurrent_state_bytes",
+            "device bytes of recurrent state and convolution windows the "
+            "engine holds beside its K/V cache (every slot, every "
+            "linear-attention layer)", ("engine",)
+        ).set(self.n_slots * self.slot_state_bytes, engine=name)
         self._m_overlap = reg.counter(
             "llm_steps_overlapped_total",
             "decode steps dispatched before the previous step's tokens "
@@ -505,6 +589,7 @@ class SlotEngine:
         self.evictions = 0
         self.prefix_hits = 0
         self.prefix_tokens_reused = 0
+        self.prefix_reuse_skipped = 0
         self.tokens_generated = 0
         # the compile plane (ISSUE 15): 'sync' blocks construction until
         # the full program lattice — every prefill bucket, decode span
@@ -722,6 +807,14 @@ class SlotEngine:
             return None, 0
         return src, lcp
 
+    def _count_reuse_skipped(self) -> None:
+        """A prefix was there to reuse and is prefilled again: its K/V
+        rows could be copied, the recurrent state after its last token
+        was never kept."""
+        self.prefix_reuse_skipped += 1
+        self._m_reuse_skipped.inc(1, engine=self.name,
+                                  reason="recurrent_state")
+
     # -- admission ---------------------------------------------------------
     def _pick_slot(self) -> Optional[int]:
         free = np.flatnonzero(~self.active)
@@ -785,6 +878,10 @@ class SlotEngine:
             # _best_prefix and _register_prefix scope themselves by it
             self._slot_tenant[slot] = tenant
             src, lcp = self._best_prefix(prompt, slot)
+            skipped = self.recurrent and src is not None
+            if skipped:
+                self._count_reuse_skipped()
+                src, lcp = None, 0
             restored = False
             if self.kv_arena is not None:
                 # host tier: a spilled span longer than any device-
@@ -823,7 +920,8 @@ class SlotEngine:
             with self._program_region(_prefill_program_key(pb)):
                 self.cache, last = _prefill_slot_jit(
                     self.model, self.variables, self.cache,
-                    jnp.asarray(padded), len(tail), slot, lcp)
+                    jnp.asarray(padded), len(tail), slot, lcp,
+                    attention_backend=self.attention_backend)
             logits = np.asarray(last, np.float32)
         with step_span("engine.admit.commit"):
             tok = self._sample_host(logits)
@@ -859,7 +957,8 @@ class SlotEngine:
                 path="restore" if restored else "cold")
             return AdmitResult(
                 slot, tok, finished, lcp, logits, bucket=pb, reason=reason,
-                path="restore" if restored else "reuse" if lcp else "cold")
+                path="restore" if restored else "reuse" if lcp
+                else "cold_recurrent" if skipped else "cold")
 
     # -- stepping ----------------------------------------------------------
     def _finish_reason(self, slot: int,
@@ -970,8 +1069,10 @@ class SlotEngine:
         from the host arena when possible, copied from a device-
         resident prefix otherwise, and cold-prefilled as the last
         resort — all three paths reproduce the identical K/V, so the
-        continuation is token-exact regardless.  Returns the slot, or
-        None when every slot is busy."""
+        continuation is token-exact regardless.  A model with recurrent
+        layers always takes the last: its state after the span was not
+        kept (counted in ``llm_prefix_reuse_skipped_total``).  Returns
+        the slot, or None when every slot is busy."""
         ids = np.asarray(ticket["ids"], np.int32).reshape(-1)
         span = int(ticket["kv_len"])
         if len(ids) == 0 or span < 1 or span >= len(ids):
@@ -998,7 +1099,9 @@ class SlotEngine:
             if src is not None:
                 dlcp = self._clamp_reuse(
                     int(min(dlcp, self.kv_len[src], span)), span)
-                if dlcp >= self.min_prefix:
+                if dlcp >= self.min_prefix and self.recurrent:
+                    self._count_reuse_skipped()     # rebuilt from 0
+                elif dlcp >= self.min_prefix:
                     if src != slot:
                         with self._program_region("prefix_copy"):
                             self.cache = _copy_prefix_jit(
@@ -1015,7 +1118,8 @@ class SlotEngine:
             with self._program_region(_prefill_program_key(pb)):
                 self.cache, _ = _prefill_slot_jit(
                     self.model, self.variables, self.cache,
-                    jnp.asarray(padded), len(tail), slot, est)
+                    jnp.asarray(padded), len(tail), slot, est,
+                    attention_backend=self.attention_backend)
         ln = len(ids)
         self.ctx[slot, :ln] = ids
         self.lengths[slot] = ln
@@ -1096,11 +1200,11 @@ class SlotEngine:
         if self._paged_geo is not None:
             nbytes = paged_read_bytes(
                 spans, self._paged_geo.tile, self.cfg.num_kv_heads,
-                self.cfg.d_head, itemsize, self.cfg.num_layers)
+                self.cfg.d_head, itemsize, self.cfg.num_attention_layers)
         else:
             nbytes = dense_read_bytes(
                 self.n_slots, self.max_len, self.cfg.num_kv_heads,
-                self.cfg.d_head, itemsize, self.cfg.num_layers)
+                self.cfg.d_head, itemsize, self.cfg.num_attention_layers)
         self.decode_attn_bytes += nbytes
         self._m_decode_bytes.set(nbytes / max(1, served),
                                  engine=self.name,
@@ -1136,6 +1240,7 @@ class SlotEngine:
                 act = self.active if flight is None else self._live(flight)
                 sp.set(slots=int(act.sum()),
                        kv_span_sum=int(self.lengths[act].sum()),
+                       state_bytes=2 * int(act.sum()) * self.slot_state_bytes,
                        overlapped=flight is not None)
             events = None
             if self._drafter is not None:

@@ -70,6 +70,10 @@ REGISTERED_ENTRY_POINTS = {
         "_copy_prefix_jit", "_restore_span_jit"}),
     "synapseml_tpu.models.llm.pallas_attn": frozenset({
         "paged_decode_attention"}),
+    # the recurrence of linear-attention layers: inside the decode and
+    # prefill programs as the paged kernel is inside decode
+    "synapseml_tpu.models.llm.pallas_gdn": frozenset({
+        "gated_delta_decode", "gated_delta_prefill"}),
     # non-LLM tunable entry points: not part of the serving lattice, but
     # the autotune source-scan lint requires every registered search
     # space to time a program listed here — the registry doubles as the
@@ -183,7 +187,8 @@ def program_lattice(engine) -> List[ProgramSpec]:
         cache = _copy_prefix_jit(cache, 0, min(1, n - 1), 1)
         jax.block_until_ready(jax.tree.leaves(cache)[0])
         return cache
-    specs.append(ProgramSpec("prefix_copy", "prefix_copy", run_copy))
+    if not engine.recurrent:       # such an engine never copies a prefix
+        specs.append(ProgramSpec("prefix_copy", "prefix_copy", run_copy))
 
     if engine.spec_draft_len:
         s_max = max(2, _next_pow2(1 + engine.spec_draft_len))
@@ -208,7 +213,8 @@ def program_lattice(engine) -> List[ProgramSpec]:
         def run_prefill(cache, pb=pb):
             tokens = jnp.asarray(np.full(pb, engine.pad_id, np.int32))
             cache, last = _prefill_slot_jit(model, variables, cache,
-                                            tokens, 1, 0, 0)
+                                            tokens, 1, 0, 0,
+                                            attention_backend=backend)
             jax.block_until_ready(last)
             return cache
         specs.append(ProgramSpec(_prefill_program_key(pb), "prefill",

@@ -116,6 +116,12 @@ class PrefillWorker:
     cache-native dtype (the same shape ``HostKVArena.put`` stores)."""
 
     def __init__(self, engine: Any):
+        if getattr(engine, "recurrent", False):
+            raise ValueError(
+                "a prefill worker over a model with linear-attention "
+                "layers: the handoff ships K/V rows by token position; a "
+                "recurrent state can be snapshotted at a position, not "
+                "sliced, and the engine builds no snapshots")
         self.engine = engine
 
     def prefill(self, ids, tenant: str = "default"
