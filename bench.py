@@ -2166,8 +2166,8 @@ def bench_llm_serving(spec_only: bool = False):
         through the kernel (a custom call on TPU; an interpreter loop —
         whose cost analysis counts one grid step — on CPU), so the
         after bytes substitute the kernel's exact DMA ledger
-        (``paged_read_bytes``, exact by construction of the clamped-
-        index grid) for the dense read model (``dense_read_bytes``)
+        (``paged_read_bytes``: one copy of K and of V a live tile, none
+        else) for the dense read model (``dense_read_bytes``)
         inside the captured step total; the non-attention remainder
         (weights, scatter, logits) is identical between legs.
         ``measured_ms`` for the after side is real only where the
@@ -2225,7 +2225,7 @@ def bench_llm_serving(spec_only: bool = False):
         item = np.dtype(cfg.dtype).itemsize
         dense_kv = dense_read_bytes(N_SLOTS, cfg.max_len, cfg.num_kv_heads,
                                     cfg.d_head, item, cfg.num_layers)
-        paged_kv = paged_read_bytes(spans, geo.tile, cfg.num_kv_heads,
+        paged_kv = paged_read_bytes(spans, geo.tile, cfg.kv_cache_heads,
                                     cfg.d_head, item, cfg.num_layers)
         after_bytes = max(0.0, step_bytes - dense_kv) + paged_kv
         # the compiled kernel's wall time exists only where it compiles

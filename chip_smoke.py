@@ -83,7 +83,7 @@ def full_sizes() -> Sizes:
     return Sizes(
         llama=lambda: LlamaConfig.llama3_1b(max_len=1024),
         n_slots=16,
-        # prefill buckets 32..1024, paged span buckets 1, 2 and 4 tiles
+        # prefill buckets 32..1024, spans of one tile to most of the cache
         prompt_lens=(150, 160, 20, 45, 90, 260, 400, 700),
         shared_prefix=100, max_new=(16, 32),
         # Llama-3-1B at the served cache shape, and the 8B head geometry
@@ -176,7 +176,7 @@ def kernel_parity(sz: Sizes) -> None:
     import jax.numpy as jnp
 
     from synapseml_tpu.models.llm.pallas_attn import (
-        paged_decode_attention, paged_geometry, span_bucket_tiles)
+        paged_decode_attention, paged_geometry)
 
     for (B, L, H, KV, D) in sz.kernel_geometries:
         ragged = np.array([1, 2, 3, 17, 100, L // 4 - 1, L // 4, L // 4 + 1,
@@ -186,18 +186,17 @@ def kernel_parity(sz: Sizes) -> None:
         for S in (1, 4):
             geo = paged_geometry(L, H, KV, D, jnp.bfloat16, max_query_span=S)
             check(geo is not None, f"no paged geometry for L={L} D={D} S={S}")
-            # full grid, plus (S == 1) each smaller span bucket
-            buckets = [geo.total_tiles]
+            # spans to the end of the cache, plus (S == 1) batches whose
+            # longest span ends at each smaller power-of-two tile count
+            longest = [geo.total_tiles]
             if S == 1:
                 nt = geo.total_tiles // 2
                 while nt >= 1:
-                    buckets.append(nt)
+                    longest.append(nt)
                     nt //= 2
-            for nt in buckets:
+            for nt in longest:
                 spans = np.clip(np.resize(ragged, B), S, nt * geo.tile)
                 spans[0], spans[-1] = S, nt * geo.tile     # both extremes
-                check(span_bucket_tiles(int(spans.max()), geo) == nt,
-                      "span bucket arithmetic")
                 q = rng.normal(size=(B, S, H, D)).astype(np.float32)
                 k = rng.normal(size=(B, L, KV, D)).astype(np.float32)
                 v = rng.normal(size=(B, L, KV, D)).astype(np.float32)
@@ -210,7 +209,7 @@ def kernel_parity(sz: Sizes) -> None:
                 out = paged_decode_attention(
                     qb[:, 0] if S == 1 else qb, kb, vb,
                     jnp.asarray(spans, jnp.int32), tile=geo.tile,
-                    num_tiles=nt, interpret=sz.kernel_interpret)
+                    interpret=sz.kernel_interpret)
                 out = np.asarray(out.astype(jnp.float32)).reshape(B, S, H, D)
                 ref = dense_reference(*(np.asarray(a.astype(jnp.float32))
                                         for a in (qb, kb, vb)), spans)

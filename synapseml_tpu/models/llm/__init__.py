@@ -20,7 +20,7 @@ from .kvtier import (KVTIER_METRICS, TRANSFER_MAGIC, ChecksumError,
 from .pallas_attn import (ATTENTION_BACKENDS, PagedGeometry,
                           dense_read_bytes, paged_decode_attention,
                           paged_geometry, paged_read_bytes,
-                          resolve_attention_backend, span_bucket_tiles)
+                          resolve_attention_backend)
 from .slots import AdmitResult, SlotEngine, StepEvent
 from .stage import LLMTransformer
 from .warmup import (CompilePlane, ProgramSpec, engine_jit_cache_size,
@@ -48,6 +48,6 @@ __all__ = [
     "program_lattice",
     "quantize_int8",
     "resolve_attention_backend", "rope_frequencies", "sample_logits",
-    "span_bucket_tiles", "spec_unpack",
+    "spec_unpack",
     "templated_log_corpus",
 ]

@@ -111,9 +111,8 @@ class LlamaConfig:
         and copies all of it, every layer and step, into the row-major
         order the paged kernel reads (6 GB of temporaries at 30 heads,
         32 x 1536)."""
-        sub = max(8, 32 // np.dtype(self.dtype).itemsize)
-        kv = self.num_kv_heads
-        return kv if kv <= sub or kv % sub == 0 else -(-kv // sub) * sub
+        from .pallas_attn import cache_row_heads
+        return cache_row_heads(self.num_kv_heads, self.dtype)
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -311,7 +310,6 @@ class CausalAttention(nn.Module):
                  cache_index: Optional[jnp.ndarray],
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
-                 paged_num_tiles: Optional[int] = None,
                  paged_tile: Optional[int] = None,
                  valid_len: Optional[jnp.ndarray] = None):
         # ``valid_len`` is the recurrent mixer's: a padded row's K/V lands
@@ -412,7 +410,6 @@ class CausalAttention(nn.Module):
             spans = positions[:, -1].astype(jnp.int32) + 1
             out = paged_decode_attention(
                 q, k_all, v_all, spans, tile=tile, kv_heads=KV,
-                num_tiles=(paged_num_tiles or T // tile),
                 interpret=(attention_backend == "interpret")
             ).reshape(B, S, H * D)
         else:
@@ -473,7 +470,6 @@ class GatedDeltaNet(nn.Module):
                  cache_index: Optional[jnp.ndarray],
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
-                 paged_num_tiles: Optional[int] = None,
                  paged_tile: Optional[int] = None,
                  valid_len: Optional[jnp.ndarray] = None):
         from . import pallas_gdn as gdn
@@ -585,7 +581,6 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache, cache_index, slot_mask=None,
                  attention_backend: str = "dense",
-                 paged_num_tiles: Optional[int] = None,
                  paged_tile: Optional[int] = None,
                  valid_len: Optional[jnp.ndarray] = None):
         cfg = self.cfg
@@ -594,8 +589,7 @@ class DecoderBlock(nn.Module):
         ln_mlp = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_mlp")
         a, new_cache = MIXERS[self.kind](cfg, name=_MIXER_NAME[self.kind])(
             ln_attn(x) if pre else x, positions, cache, cache_index,
-            slot_mask, attention_backend, paged_num_tiles, paged_tile,
-            valid_len)
+            slot_mask, attention_backend, paged_tile, valid_len)
         x = x + (a if pre else ln_attn(a))
         h = ln_mlp(x) if pre else x
         gate = _dense(cfg.d_ff, ("embed", "mlp"), "gate_proj", cfg.dtype,
@@ -618,7 +612,6 @@ class LlamaModel(nn.Module):
                  cache_index=None, deterministic: bool = True,
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
-                 paged_num_tiles: Optional[int] = None,
                  paged_tile: Optional[int] = None,
                  valid_len: Optional[jnp.ndarray] = None):
         """``valid_len`` (scalar or ``(B,)``): how many of the ``S`` tokens
@@ -643,7 +636,7 @@ class LlamaModel(nn.Module):
             layer_cache = cache[i] if cache is not None else None
             x, nc = DecoderBlock(cfg, kind, name=f"layer_{i}")(
                 x, positions, layer_cache, cache_index, slot_mask,
-                attention_backend, paged_num_tiles, paged_tile, valid_len)
+                attention_backend, paged_tile, valid_len)
             new_caches.append(nc)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_final")(x)
         if cfg.tie_embeddings:

@@ -9,32 +9,36 @@ scatter eliminated.  This kernel is the vLLM paged-KV read pattern
 (Kwon et al., PagedAttention) adapted to XLA static shapes, held to the
 Flash-style online-softmax contract (Dao et al., FlashAttention):
 
-- **grid** ``(n_slots, num_tiles)`` with the tile dimension fastest; the
-  per-slot live span (``spans[slot]`` tokens) is covered by
-  ``ceil(span / tile)`` sublane-aligned K/V tiles.  Tiles past a slot's
-  live span CLAMP their block index to the slot's last live tile
-  (scalar-prefetched ``spans`` drives the index map), so Pallas's
-  revisited-block elision skips their DMA entirely and a ``pl.when``
-  gate skips their compute — a short sequence's dead tiles cost neither
-  bytes nor flops.
-- **span bucketing** — ``num_tiles`` is the bucketed (next power of two)
-  tile count of the LONGEST live span in the batch, so a batch of short
-  sequences does not even iterate a long cache's grid; one compiled
-  program per bucket, O(log(max_len / tile)) programs total (the
-  prefill-bucket idiom of :mod:`~synapseml_tpu.models.llm.slots`).
+- **the kernel walks live tiles itself** — grid ``(n_slots,)``; K and V
+  stay in HBM and a slot's ``ceil(span / tile)`` live tiles arrive
+  through a ring of ``_RING`` VMEM buffers filled by ``make_async_copy``.
+  A cursor over the flat list of (slot, tile) pairs, kept in SMEM across
+  grid steps, runs ``_RING - 1`` tiles ahead of the compute, over slot
+  boundaries too, so the DMA engine always has a tile in flight.  No
+  grid step, loop trip or byte is spent on a tile past a live span, and
+  one compiled program serves every span (there is no span bucket).
+- **all K/V heads of a tile in one contraction, in the layout the tile
+  arrives in** — the cache row ``(KV, D)`` of a position is one
+  ``(8, 128)`` memory tile, so a K/V tile viewed as flat rows
+  ``(tile * KV, D)`` is the same bytes (the reshape of the cache is a
+  bitcast; nothing of cache size is copied).  ``q (S*H, D) x rows^T``
+  gives logits ``(S*H, tile*KV)`` with the MXU taking each row once;
+  column ``(t, kv)`` belongs to query head ``h`` where ``kv == h //
+  group``, every other column is masked like a dead key.  The MXU does
+  ``KV`` times the needed products and has the room; no per-head
+  sublane slice, which is what bound the kernel before (PERF.md §6,
+  PR 30).  Operands go to the MXU in the cache's dtype with float32
+  accumulation, probabilities are cast to the cache's dtype before the
+  PV product, as the dense path does (``model.py``).
 - **online softmax** — f32 running (max, sum, accumulator) in VMEM
   scratch across tiles; masking uses ``finfo(f32).min`` exactly like the
   dense path, so a masked key underflows to probability 0.0 in both.
-- **GQA head grouping** — queries reshape ``(kv_heads, group, d_head)``
-  and each kv head's ``(group, d_head) x (d_head, tile)`` contraction
-  rides the MXU with the group dimension batched, reading each K/V tile
-  once per kv head (not per query head).
 
 Correctness runs the kernel in INTERPRET mode on CPU (the
 ``pallas_hist`` pattern): greedy decode through
 :class:`~synapseml_tpu.models.llm.slots.SlotEngine` is pinned
 token-exact vs the dense path, and kernel-vs-dense logits parity is
-pinned ulp-tolerant across span buckets (tests/test_llm_paged.py).
+pinned ulp-tolerant across spans and tiles (tests/test_llm_paged.py).
 Speed is measured where the hardware is; the byte ledger below
 (:func:`paged_read_bytes` / :func:`dense_read_bytes`) is the kernel's
 exact DMA accounting by construction — it feeds the
@@ -59,10 +63,26 @@ from jax.experimental.pallas import tpu as pltpu
 #: slack — same bar as models/gbdt/pallas_hist._VMEM_BUDGET)
 _VMEM_BUDGET = 13 * 1024 * 1024
 
-#: key-tile candidates, largest first: 128-256 keeps the logits lane
-#: dimension MXU-wide on real caches; the small tail exists for test
-#: geometries (every candidate is sublane-aligned for f32)
+#: key-tile candidates, largest first.  A tile is the unit of DMA and of
+#: span rounding: the kernel fetches ``ceil(span / tile)`` of them a
+#: slot, so a smaller tile wastes fewer bytes past the span and a larger
+#: one amortises a loop trip over more bytes; the small tail exists for
+#: test geometries (every candidate is sublane-aligned for f32)
 _TILE_CANDIDATES = (256, 128, 64, 32, 16, 8)
+
+#: K/V bytes of ONE tile (K alone) the ladder aims at: the largest
+#: candidate at or under it is the default tile (PERF.md §6, PR 30 has
+#: the chip readings behind the number)
+_TILE_BYTES = 256 * 1024
+
+#: depth of the DMA ring: ``_RING - 1`` tiles are in flight while one is
+#: computed on
+_RING = 3
+
+#: elements of one logits chunk ``(S*H, C)``: a tile's flat rows are
+#: contracted ``C`` at a time so the f32 logits and probabilities of a
+#: wide verify step stay near 256 KiB each
+_CHUNK_ELEMS = 64 * 1024
 
 #: the attention_backend switch values (the booster.py use_pallas
 #: idiom: 'auto' gates on backend + geometry, 'interpret' is the CPU
@@ -85,6 +105,32 @@ class PagedGeometry:
     vmem_bytes: int
 
 
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def cache_row_heads(num_kv_heads: int, dtype) -> int:
+    """K/V heads a cache row holds (``LlamaConfig.kv_cache_heads``):
+    ``num_kv_heads``, padded to whole sublane tiles of ``dtype`` where
+    they pass one tile and do not fill their last (30 bfloat16 heads ->
+    32), so that a row is whole memory tiles and the kernel's flat-row
+    view of the cache is a bitcast."""
+    sub = _sublane(dtype)
+    return (num_kv_heads if num_kv_heads <= sub or num_kv_heads % sub == 0
+            else _pad(num_kv_heads, sub))
+
+
+def _chunk_rows(tile: int, row_heads: int, q_rows: int) -> int:
+    """Flat K/V rows one contraction takes: the whole tile, halved while
+    the ``(q_rows, C)`` logits pass ``_CHUNK_ELEMS`` and the halves stay
+    whole positions and lane-aligned."""
+    p = tile
+    while (q_rows * p * row_heads > _CHUNK_ELEMS and p % 2 == 0
+           and (p // 2 * row_heads) % 128 == 0):
+        p //= 2
+    return p * row_heads
+
+
 def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
                    d_head: int, dtype: Any = jnp.bfloat16,
                    max_query_span: int = 1,
@@ -94,19 +140,24 @@ def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
     geometry fits (the 'auto' backend then stays dense — the
     ``fused_geometry`` idiom of the GBDT kernel).
 
-    The tile must divide ``max_len`` (blocks never run past the cache
+    The tile must divide ``max_len`` (a tile never runs past the cache
     row), be a sublane multiple for the cache dtype, and leave at least
     two tiles of span granularity (``tile <= max_len // 2``) — a
     one-tile "paged" read would just be the dense row with extra
-    steps.  Working set: double-buffered K and V tiles plus the q/out
-    blocks and the f32 online-softmax scratch — the latter three all
-    scale with ``max_query_span`` (the speculative verify step's S:
-    its q/out blocks are ``(1, S, H, D)`` and its scratch rows
-    ``S*H``), so a spec-enabled engine must gate at the WIDEST verify
-    it can launch, not at S=1.  Bytes are counted as Mosaic lays them
-    out: the last two dims of every block pad to the dtype's
-    (sublane, 128) tile, so a ``(KV=8, D=64)`` bf16 K/V row occupies a
-    ``(16, 128)`` tile — four times its logical bytes.
+    steps.  Of the candidates that fit VMEM the default is the largest
+    whose K tile is at most ``_TILE_BYTES`` (else the smallest that
+    fits): the kernel pays a loop trip a tile and the bytes a tile
+    runs past the span, and that size is where the two meet on the chip.
+
+    Working set, as Mosaic lays it out (last two dims of every buffer
+    pad to the dtype's (sublane, 128) tile): the ring of ``_RING`` K and
+    ``_RING`` V tiles of flat rows ``(tile * row_heads, D)``; the
+    double-buffered q and out blocks ``(1, S, H, D)``; the query rows,
+    f32 accumulator, running max and normaliser at ``S * pad(H, 8)``
+    rows; and one chunk's f32 logits, probabilities and their cast.
+    All but the ring scale with ``max_query_span`` (the speculative
+    verify step's S), so a spec-enabled engine must gate at the WIDEST
+    verify it can launch, not at S=1.
 
     ``tile`` pins a single candidate instead of the ladder — the tuned
     override path.  It passes through the SAME divisibility/VMEM gate:
@@ -116,25 +167,30 @@ def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
     itemsize = np.dtype(dtype).itemsize
     sub = _sublane(dtype)
     s = max(1, int(max_query_span))
+    d_pad = _pad(d_head, 128)
+    row_heads = cache_row_heads(num_kv_heads, dtype)
+    q_rows = s * _pad(num_heads, 8)
 
-    def pad(n, m):
-        return -(-n // m) * m
-    d_pad = pad(d_head, 128)
-    rows = pad(s * num_heads, 8)                  # f32 scratch sublanes
-    candidates = _TILE_CANDIDATES if tile is None else (int(tile),)
-    for cand in candidates:
-        if cand <= 0 or cand % sub or max_len % cand \
-                or cand > max_len // 2:
-            continue
-        need = (2 * 2 * cand * pad(num_kv_heads, sub) * d_pad
-                * itemsize                                       # K+V x2 buf
-                + 2 * 2 * s * pad(num_heads, sub) * d_pad
+    def need(cand):
+        chunk = _chunk_rows(cand, row_heads, q_rows)
+        return (2 * _RING * _pad(cand * row_heads, sub) * d_pad
+                * itemsize                                       # K+V ring
+                + 2 * 2 * s * _pad(num_heads, sub) * d_pad
                 * itemsize                                       # q+out x2 buf
-                + rows * d_pad * 4                               # f32 acc
-                + 2 * rows * 128 * 4)                            # m + l
-        if need <= _VMEM_BUDGET:
-            return PagedGeometry(cand, max_len // cand, need)
-    return None
+                + _pad(q_rows, sub) * d_pad * itemsize           # query rows
+                + q_rows * d_pad * 4                             # f32 acc
+                + 2 * q_rows * 128 * 4                           # m + l
+                + q_rows * _pad(chunk, 128) * (4 + 4 + itemsize))  # logits, p
+
+    fits = [c for c in (_TILE_CANDIDATES if tile is None else (int(tile),))
+            if c > 0 and c % sub == 0 and max_len % c == 0
+            and c <= max_len // 2 and need(c) <= _VMEM_BUDGET]
+    if not fits:
+        return None
+    small = [c for c in fits
+             if c * row_heads * d_pad * itemsize <= _TILE_BYTES]
+    cand = small[0] if small else fits[-1]
+    return PagedGeometry(cand, max_len // cand, need(cand))
 
 
 def paged_geometry_key(max_len: int, num_kv_heads: int, d_head: int,
@@ -196,37 +252,33 @@ def resolve_attention_backend(backend: str, *, max_len: int,
     return backend
 
 
-def span_bucket_tiles(max_span: int, geo: PagedGeometry) -> int:
-    """Bucketed grid length for the step: the next power of two >= the
-    longest live span's tile count, clamped to the cache's total tiles
-    — O(log) compiled programs, and a batch of short sequences never
-    iterates a long cache's grid."""
-    nt = -(-max(1, int(max_span)) // geo.tile)
-    b = 1
-    while b < nt:
-        b *= 2
-    return min(b, geo.total_tiles)
-
-
 # ---------------------------------------------------------------------------
 # the byte ledger (exact DMA accounting, shared by telemetry and bench)
 # ---------------------------------------------------------------------------
 
+def paged_live_tiles(spans, tile: int) -> int:
+    """Tiles ONE layer's kernel call fetches, and loop trips it makes,
+    for ``spans``: ``ceil(span / tile)`` a slot, at least one."""
+    return int(np.ceil(np.maximum(np.asarray(spans, np.float64), 1.0)
+                       / tile).sum())
+
+
 def paged_read_bytes(spans, tile: int, num_kv_heads: int, d_head: int,
                      itemsize: int, num_layers: int = 1) -> int:
     """K/V bytes ONE paged decode step DMAs for ``spans``: each slot
-    reads ``ceil(span / tile)`` tiles of K and of V per layer — dead
-    tiles are elided by the clamped index map, so this is exact by
-    construction of the grid, not an estimate.
+    reads ``ceil(span / tile)`` tiles of K and of V per layer — the
+    kernel starts one copy of K and one of V a live tile and none
+    else, so this is exact by construction, not an estimate
+    (``tests/test_llm_paged.py`` counts the copies).
 
-    ``spans`` must cover EVERY slot in the launch, not just the active
-    ones: the grid iterates all ``n_slots`` rows and block elision only
-    skips revisits WITHIN a slot, so an inactive slot (span 1) still
-    DMAs one K and one V tile per layer when the grid crosses into it."""
-    tiles = np.ceil(np.maximum(np.asarray(spans, np.float64), 1.0)
-                    / tile).astype(np.int64)
-    return int(num_layers * 2 * tiles.sum() * tile
-               * num_kv_heads * d_head * itemsize)
+    ``num_kv_heads`` is the heads a cache ROW holds
+    (``LlamaConfig.kv_cache_heads``: a padded row's padding arrives with
+    its tile), and a head narrower than 128 lanes is fetched at 128 (the
+    kernel's wrapper pads it).  ``spans`` must cover EVERY slot in the
+    launch, not just the active ones: an inactive slot (span 1) still
+    fetches its first tile."""
+    return int(num_layers * 2 * paged_live_tiles(spans, tile) * tile
+               * num_kv_heads * _pad(d_head, 128) * itemsize)
 
 
 def dense_read_bytes(n_slots: int, max_len: int, num_kv_heads: int,
@@ -243,79 +295,126 @@ def dense_read_bytes(n_slots: int, max_len: int, num_kv_heads: int,
 # the kernel
 # ---------------------------------------------------------------------------
 
-def _make_decode_kernel(kv_heads: int, group: int, tile: int, d_head: int,
-                        s_len: int):
+def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
+                        tile: int, total_tiles: int, d_head: int, chunk: int):
+    """``heads`` query heads in groups of ``group`` over the first K/V
+    heads of a cache row of ``row_heads``; ``d_head`` the model's (the
+    lane width the kernel sees may be padded past it)."""
     neg = float(np.finfo(np.float32).min)
+    hp = _pad(heads, 8)               # rows a query position takes
+    q_rows = s_len * hp
+    rows = tile * row_heads           # flat K/V rows of a tile
+    scale = 1.0 / np.sqrt(d_head)
 
-    def kernel(spans_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-               l_ref):
-        """Grid ``(n_slots, num_tiles)``, tile fastest.  q/out blocks
-        ``(1, S, H, D)`` constant per slot (S == 1 is the plain decode
-        step; S > 1 the speculative-verify span, whose S query
-        positions amortize ONE span-bucketed K/V read); K/V blocks
-        ``(1, tile, KV, D)`` span-clamped (see ``_kv_index_map``);
-        scratch: f32 accumulator ``(S*H, D)`` plus running max /
-        normalizer ``(S*H, 128)`` (lane 0 carries the value), rows
-        HEAD-major — head h owns rows ``[h*S*group, (h+1)*S*group)`` so
-        each kv head's update touches one contiguous block — revisited
-        across the tile dimension."""
+    def kernel(spans_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+               cur_ref, qs_ref, acc_ref, m_ref, l_ref):
+        """Grid ``(n_slots,)``.  q/out blocks ``(1, S, H, D)`` (S == 1
+        is the plain decode step; S > 1 the speculative-verify span,
+        whose S query positions amortize ONE read of the span); K and V
+        whole in HBM as flat rows ``(n_slots, max_len * row_heads, D)``;
+        ``kbuf``/``vbuf`` the ring; ``cur_ref`` (SMEM, kept across grid
+        steps): tiles consumed, tiles issued, and the (slot, tile) the
+        next DMA fetches.  Scratch rows: query position j's head h at
+        ``j * hp + h`` (``hp`` is H padded to 8; a padding row is a
+        zero query of K/V head 0, finite and never written out)."""
         s = pl.program_id(0)
-        t = pl.program_id(1)
+        n_slots = pl.num_programs(0)
         span = spans_ref[s]
-        n_tiles = lax.div(span + (tile - 1), tile)
 
-        @pl.when(t == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, neg)
-            l_ref[...] = jnp.zeros_like(l_ref)
+        def live_tiles(slot):
+            # at least the first (an idle slot's span is 1), never past
+            # the cache row
+            return jnp.clip(lax.div(spans_ref[slot] + (tile - 1), tile),
+                            1, total_tiles)
 
-        @pl.when(t < n_tiles)
-        def _tile():
-            # ``span`` counts the keys the LAST query attends: query j
-            # sits at position span-S+j and attends keys <= itself,
-            # i.e. key < span-(S-1)+j — for S == 1 the causal mask
-            # degenerates to the live-span mask (same finfo-min fill as
-            # the dense path: exp underflows to probability 0.0 either
-            # way)
-            kpos = t * tile + lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-            qidx = lax.broadcasted_iota(jnp.int32, (s_len * group, tile),
-                                        0) // group       # query j per row
-            valid = kpos < span - (s_len - 1) + qidx      # (S*g, tile)
-            for h in range(kv_heads):
-                rows = slice(h * s_len * group, (h + 1) * s_len * group)
-                q = q_ref[0, :, h * group:(h + 1) * group, :].reshape(
-                    s_len * group, d_head).astype(jnp.float32)
-                k = k_ref[0, :, h, :].astype(jnp.float32)    # (tile, D)
+        def copies(slot, t, buf):
+            src = pl.ds(t * rows, rows)
+            return (pltpu.make_async_copy(k_hbm.at[slot, src], kbuf.at[buf],
+                                          sem.at[0, buf]),
+                    pltpu.make_async_copy(v_hbm.at[slot, src], vbuf.at[buf],
+                                          sem.at[1, buf]))
+
+        def issue():
+            # fetch the cursor's tile into the ring (if one is left) and
+            # move the cursor to the next live tile, this slot's or the
+            # next slot's first
+            issued, slot, t = cur_ref[1], cur_ref[2], cur_ref[3]
+
+            @pl.when(slot < n_slots)
+            def _():
+                for c in copies(slot, t, lax.rem(issued, _RING)):
+                    c.start()
+            last = t + 1 >= live_tiles(jnp.minimum(slot, n_slots - 1))
+            cur_ref[1] = issued + 1
+            cur_ref[2] = jnp.where(last, slot + 1, slot)
+            cur_ref[3] = jnp.where(last, 0, t + 1)
+
+        @pl.when(s == 0)
+        def _first():
+            for i in range(4):
+                cur_ref[i] = 0
+            for _ in range(_RING - 1):
+                issue()
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, neg)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        if hp != heads:
+            qs_ref[...] = jnp.zeros_like(qs_ref)
+        for j in range(s_len):
+            qs_ref[j * hp:j * hp + heads, :] = q_ref[0, j]
+
+        # ``span`` counts the keys the LAST query attends: query j sits at
+        # position span-S+j and attends keys <= itself, i.e. key <
+        # span-(S-1)+j — for S == 1 the causal mask degenerates to the
+        # live-span mask (same finfo-min fill as the dense path: exp
+        # underflows to probability 0.0 either way).  A flat row c of a
+        # chunk is key ``c // row_heads`` of K/V head ``c % row_heads``:
+        # a query attends the rows of its own K/V head alone
+        r = lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0)
+        kv_of_row = jnp.where(r % hp < heads, (r % hp) // group, 0)
+        limit = span - (s_len - 1) + r // hp                 # (S*hp, 1)
+        c = lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        own = c % row_heads == kv_of_row                     # (S*hp, C)
+        key_of_col = c // row_heads                          # (1, C)
+
+        def tile_body(t, carry):
+            issue()
+            buf = lax.rem(cur_ref[0], _RING)
+            for cp in copies(s, t, buf):
+                cp.wait()
+            q = qs_ref[...]
+            for c0 in range(0, rows, chunk):
+                k = kbuf[buf, c0:c0 + chunk, :]              # (C, D)
                 logits = lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) / np.sqrt(d_head)
-                logits = jnp.where(valid, logits, neg)       # (S*g, tile)
-                m_prev = m_ref[rows, 0:1]                    # (S*g, 1)
-                l_prev = l_ref[rows, 0:1]
+                    preferred_element_type=jnp.float32) * scale
+                kpos = t * tile + c0 // row_heads + key_of_col
+                logits = jnp.where(jnp.logical_and(own, kpos < limit),
+                                   logits, neg)              # (S*hp, C)
+                m_prev = m_ref[:, 0:1]
                 m_new = jnp.maximum(
                     m_prev, jnp.max(logits, -1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(logits - m_new)                  # (S*g, tile)
-                v = v_ref[0, :, h, :].astype(jnp.float32)    # (tile, D)
-                pv = lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-                acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
-                m_ref[rows, 0:1] = m_new
-                l_ref[rows, 0:1] = (l_prev * alpha
-                                    + jnp.sum(p, -1, keepdims=True))
+                p = jnp.exp(logits - m_new)
+                v = vbuf[buf, c0:c0 + chunk, :]              # (C, D)
+                pv = lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                acc_ref[...] = acc_ref[...] * alpha + pv
+                l_ref[:, 0:1] = (l_ref[:, 0:1] * alpha
+                                 + jnp.sum(p, -1, keepdims=True))
+                m_ref[:, 0:1] = m_new
+            cur_ref[0] = cur_ref[0] + 1
+            return carry
 
-        @pl.when(t == pl.num_programs(1) - 1)
-        def _out():
-            # every live query attends >= 1 unmasked key whose
-            # probability at the running max is exp(0) = 1, so l >= 1;
-            # the floor only guards the impossible all-masked row
-            for h in range(kv_heads):
-                rows = slice(h * s_len * group, (h + 1) * s_len * group)
-                l = jnp.maximum(l_ref[rows, 0:1], 1e-30)
-                o_ref[0, :, h * group:(h + 1) * group, :] = (
-                    acc_ref[rows, :] / l).reshape(
-                        s_len, group, d_head).astype(o_ref.dtype)
+        lax.fori_loop(0, live_tiles(s), tile_body, 0)
+        # every live query attends >= 1 unmasked key whose probability
+        # at the running max is exp(0) = 1, so l >= 1; the floor only
+        # guards the impossible all-masked row
+        out = acc_ref[...] / jnp.maximum(l_ref[:, 0:1], 1e-30)
+        for j in range(s_len):
+            o_ref[0, j] = out[j * hp:j * hp + heads, :].astype(o_ref.dtype)
 
     return kernel
 
@@ -327,7 +426,7 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
                            v: jnp.ndarray,      # (B, max_len, KV, D)
                            spans: jnp.ndarray,  # (B,) int32 live lengths
                            tile: int,
-                           num_tiles: int,
+                           num_tiles: Optional[int] = None,
                            interpret: bool = False,
                            kv_heads: Optional[int] = None) -> jnp.ndarray:
     """One decode step's attention for every slot, reading only each
@@ -339,52 +438,66 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
     step.  ``spans[b]`` is slot b's live length INCLUDING this step's
     S written positions (the LAST query attends keys ``[0, spans[b])``;
     earlier queries attend one key fewer each — the in-span causal
-    mask).  The queries' own K/V must already be written — the
-    engine's scatter runs BEFORE attention, as in the dense path.
-    ``num_tiles`` is the static bucketed grid length from
-    :func:`span_bucket_tiles`; spans beyond ``num_tiles * tile`` would
-    be silently truncated, so the caller's bucket must cover the
-    longest live span.  ``kv_heads``: the heads of ``k``/``v`` that are
-    real, the first ones, where a cache row is padded past them
-    (``LlamaConfig.kv_cache_heads``); the padding is fetched with its
-    tile and never read."""
+    mask), at least S and at most ``max_len``.  The queries' own K/V
+    must already be written — the engine's scatter runs BEFORE
+    attention, as in the dense path.  ``kv_heads``: the heads of
+    ``k``/``v`` that are real, the first ones, where a cache row is
+    padded past them (``LlamaConfig.kv_cache_heads``); the padding is
+    fetched with its tile and weighs nothing (it must be finite: the
+    cache's zeros).  ``num_tiles`` is accepted and ignored: the kernel
+    walks each slot's live tiles itself, there is no span bucket (the
+    benchmark harness's naming test still passes it; PERF.md §7).
+
+    A head width that is no multiple of 128 lanes is padded to one here:
+    at such a width XLA already relays the whole cache for the kernel
+    every step (PERF.md §6, PR 22), and the padding rides that copy."""
+    del num_tiles
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
-    B, S, H, D = q.shape
-    KV = k.shape[2]
-    heads = kv_heads or KV
-    assert H % heads == 0 and heads <= KV, (H, heads, KV)
-    group = H // heads
-
-    def kv_index_map(s, t, spans_ref):
-        # tiles past the live span clamp to the slot's LAST live tile:
-        # the block index repeats, Pallas elides the DMA, and the
-        # pl.when gate in the kernel skips the compute — a dead tile
-        # costs nothing (the paged read)
-        nt = lax.div(spans_ref[s] + (tile - 1), tile)
-        return (s, jnp.minimum(t, jnp.maximum(nt - 1, 0)), 0, 0)
-
+    B, S, H, d_head = q.shape
+    T, row_heads = k.shape[1], k.shape[2]
+    heads = kv_heads or row_heads
+    assert H % heads == 0 and heads <= row_heads, (H, heads, row_heads)
+    D = _pad(d_head, 128)
+    if D != d_head:
+        lanes = ((0, 0),) * 3 + ((0, D - d_head),)
+        q, k, v = (jnp.pad(a, lanes) for a in (q, k, v))
+    q_rows = S * _pad(H, 8)
+    rows = tile * row_heads
+    chunk = _chunk_rows(tile, row_heads, q_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, num_tiles),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, S, H, D), lambda s, t, *_: (s, 0, 0, 0)),
-            pl.BlockSpec((1, tile, KV, D), kv_index_map),
-            pl.BlockSpec((1, tile, KV, D), kv_index_map),
+            pl.BlockSpec((1, S, H, D), lambda s, *_: (s, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, S, H, D),
-                               lambda s, t, *_: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, S, H, D), lambda s, *_: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((S * H, D), jnp.float32),   # online-softmax acc
-            pltpu.VMEM((S * H, 128), jnp.float32),  # running max (lane 0)
-            pltpu.VMEM((S * H, 128), jnp.float32),  # normalizer (lane 0)
+            pltpu.VMEM((_RING, rows, D), k.dtype),   # K ring
+            pltpu.VMEM((_RING, rows, D), v.dtype),   # V ring
+            pltpu.SemaphoreType.DMA((2, _RING)),
+            pltpu.SMEM((4,), jnp.int32),             # the DMA cursor
+            pltpu.VMEM((q_rows, D), q.dtype),        # query rows
+            pltpu.VMEM((q_rows, D), jnp.float32),    # online-softmax acc
+            pltpu.VMEM((q_rows, 128), jnp.float32),  # running max (lane 0)
+            pltpu.VMEM((q_rows, 128), jnp.float32),  # normalizer (lane 0)
         ],
     )
+    # a position's (row_heads, D) cache row is whole (8, 128) memory
+    # tiles, so the flat-row view is the same bytes: a bitcast
     out = pl.pallas_call(
-        _make_decode_kernel(heads, group, tile, D, S),
+        _make_decode_kernel(S, H, H // heads, row_heads, tile, T // tile,
+                            d_head, chunk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+        # the ring's cursor runs from one slot into the next: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(spans.astype(jnp.int32), q, k, v)
+    )(spans.astype(jnp.int32), q, k.reshape(B, T * row_heads, D),
+      v.reshape(B, T * row_heads, D))
+    out = out[..., :d_head]
     return out[:, 0] if squeeze else out

@@ -44,9 +44,8 @@ Mechanics:
   ``attention_backend`` selects the attention READ: dense
   (full ``max_len`` rows, masked) or the Pallas paged kernel
   (:mod:`~synapseml_tpu.models.llm.pallas_attn` — only each slot's
-  live span, span-bucketed so one compiled step exists per power-of-
-  two tile bucket; ``'auto'`` = paged on TPU when the geometry fits
-  VMEM), and with it how a recurrent layer runs (the kernels of
+  live span, one compiled step for every span; ``'auto'`` = paged on
+  TPU when the geometry fits VMEM), and with it how a recurrent layer runs (the kernels of
   :mod:`~synapseml_tpu.models.llm.pallas_gdn` where attention is paged,
   a ``lax.scan`` where it is dense).
 - **prefill-into-slot** — the prompt is padded to a power-of-two bucket
@@ -127,8 +126,8 @@ from .kvtier import ChecksumError, RadixPrefixIndex, kvtier_metrics
 from .generate import sample_logits
 from .model import LlamaModel, init_cache
 from .pallas_attn import (dense_read_bytes, paged_geometry,
-                          paged_read_bytes, resolve_attention_backend,
-                          span_bucket_tiles)
+                          paged_live_tiles, paged_read_bytes,
+                          resolve_attention_backend)
 from .pallas_gdn import resolve_recurrent_backend, slot_state_bytes
 
 
@@ -186,12 +185,15 @@ def _decode_step_jit(model: LlamaModel, variables: Any, cache: Any,
     where ``feed_host`` is false a slot's pending token is
     ``prev_nxt[slot]`` (the host has not read it yet), elsewhere
     ``tokens[slot]``.  The engine always passes both, so one program
-    exists per span bucket.
+    exists per backend.
 
-    ``attention_backend``/``paged_num_tiles`` (static — one compiled
-    program per span bucket) select the Pallas paged-read attention:
-    each slot's K/V read covers only its live span instead of the full
-    ``max_len`` row (see :mod:`~synapseml_tpu.models.llm.pallas_attn`)."""
+    ``attention_backend`` (static) selects the Pallas paged-read
+    attention: each slot's K/V read covers only its live span instead of
+    the full ``max_len`` row (see
+    :mod:`~synapseml_tpu.models.llm.pallas_attn`).  ``paged_num_tiles``
+    is accepted and ignored (the engine no longer passes it: the kernel
+    walks live tiles itself, so one program serves every span; the
+    benchmark harness's naming test still does, PERF.md §7)."""
     if prev_nxt is not None:
         tokens = jnp.where(feed_host, tokens, prev_nxt)
     positions = (lengths - 1)[:, None]
@@ -199,7 +201,6 @@ def _decode_step_jit(model: LlamaModel, variables: Any, cache: Any,
                                 positions=positions, cache=cache,
                                 cache_index=lengths - 1, slot_mask=active,
                                 attention_backend=attention_backend,
-                                paged_num_tiles=paged_num_tiles,
                                 paged_tile=paged_tile)
     key, sub = jax.random.split(key)
     nxt = sample_logits(logits[:, 0], sub, temperature, top_k, top_p)
@@ -228,13 +229,14 @@ def _verify_step_jit(model: LlamaModel, variables: Any, cache: Any,
     step; a REJECTED draft position's K/V lands beyond the committed
     length, where the junk-write invariant already holds (overwritten
     before it is ever attendable).  Greedy only: acceptance compares
-    argmax, which is exactly the temperature-0 sampling rule."""
+    argmax, which is exactly the temperature-0 sampling rule.
+    ``paged_num_tiles``: accepted and ignored, as in
+    :func:`_decode_step_jit`."""
     positions = (lengths - 1)[:, None] + jnp.arange(tokens.shape[1])[None, :]
     logits, cache = model.apply(variables, tokens, positions=positions,
                                 cache=cache, cache_index=lengths - 1,
                                 slot_mask=active,
                                 attention_backend=attention_backend,
-                                paged_num_tiles=paged_num_tiles,
                                 paged_tile=paged_tile)
     return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -276,18 +278,18 @@ def _next_pow2(n: int) -> int:
     return p
 
 
-def _decode_program_key(backend: str, nt: Optional[int]) -> str:
-    """Stable label for one compiled decode-step program — THE naming
+def _decode_program_key(backend: str) -> str:
+    """Stable label for the compiled decode-step program — THE naming
     contract between the step dispatch below and the warmup lattice
     (:mod:`~synapseml_tpu.models.llm.warmup` imports these, so the
     lattice can never warm under one name what serving runs under
     another)."""
-    return f"decode_{backend}" + ("" if nt is None else f"_nt{nt}")
+    return f"decode_{backend}"
 
 
-def _verify_program_key(backend: str, s: int, nt: Optional[int]) -> str:
-    """Stable label for one compiled (S, span-bucket) verify program."""
-    return f"verify_{backend}_s{s}" + ("" if nt is None else f"_nt{nt}")
+def _verify_program_key(backend: str, s: int) -> str:
+    """Stable label for the compiled verify program of span bucket S."""
+    return f"verify_{backend}_s{s}"
 
 
 def _prefill_program_key(pb: int) -> str:
@@ -616,6 +618,9 @@ class SlotEngine:
         #: cumulative decode-attention K/V bytes (the ledger feeding the
         #: gauge above; bench reads it for the paired roofline block)
         self.decode_attn_bytes = 0
+        #: the last accounted step's tile counts (paged backends), for
+        #: the ``engine.step`` span
+        self._step_tiles: Dict[str, int] = {}
         #: speculative-decode accounting (bench's llmserve_spec_* /
         #: llama1b_spec_* fields read these): steps_run counts EVERY
         #: engine step (plain or verify), spec_* only drafted work;
@@ -1168,43 +1173,40 @@ class SlotEngine:
             self._spec_ewma[:] = 1.0
         self._m_occ.set(0.0, engine=self.name)
 
-    def _decode_step_args(self, active: np.ndarray, lengths: np.ndarray,
-                          extra_span: int = 0):
+    def _decode_step_args(self, active: np.ndarray, lengths: np.ndarray):
         """(jit kwargs, spans) for a step that advances ``active`` at
-        ``lengths``: the span-bucketed grid length for the paged
-        backends (one compiled program per power-of-two tile bucket, so
-        short batches never iterate a long cache's grid) and the
-        per-slot live spans the byte ledger prices.  ``extra_span`` is
-        the verify step's S-1 additional written positions — the bucket
-        must cover the LAST query's key count, ``lengths + S - 1``."""
+        ``lengths``: the backend and the engine's resolved K/V tile (it
+        rides the jit statics so the kernel and the byte ledger can
+        never price different geometries), and the per-slot live spans
+        the byte ledger prices (an inactive slot's is 1)."""
         lengths = np.where(active, lengths, 1)
-        kw = {"attention_backend": self.attention_backend,
-              "paged_num_tiles": None, "paged_tile": None}
-        if self._paged_geo is not None:
-            # the engine's resolved tile rides the jit statics so the
-            # kernel and the byte ledger can never price different
-            # geometries
-            kw["paged_num_tiles"] = span_bucket_tiles(
-                int(lengths.max()) + extra_span, self._paged_geo)
-            kw["paged_tile"] = self._paged_geo.tile
-        return kw, lengths
+        geo = self._paged_geo
+        return {"attention_backend": self.attention_backend,
+                "paged_tile": None if geo is None else geo.tile}, lengths
 
     def _account_decode_bytes(self, spans: np.ndarray, served: int) -> None:
         """Per-step decode-attention K/V read accounting → the
-        ``llm_decode_bytes_per_token`` gauge (exact for the paged
-        kernel by construction of its clamped-index grid — ``spans``
-        covers ALL slots, inactive ones at span 1, because every grid
-        row DMAs at least its first tile; the full-capacity model for
-        dense)."""
+        ``llm_decode_bytes_per_token`` gauge (exact for the paged kernel:
+        it fetches a slot's live tiles and nothing else — ``spans``
+        covers ALL slots, inactive ones at span 1, because every slot
+        fetches at least its first tile; the full-capacity model for
+        dense) and the step's tile counts for the ``engine.step`` span."""
         itemsize = np.dtype(self.cfg.dtype).itemsize
+        layers = self.cfg.num_attention_layers
         if self._paged_geo is not None:
+            tile = self._paged_geo.tile
             nbytes = paged_read_bytes(
-                spans, self._paged_geo.tile, self.cfg.num_kv_heads,
-                self.cfg.d_head, itemsize, self.cfg.num_attention_layers)
+                spans, tile, self.cfg.kv_cache_heads, self.cfg.d_head,
+                itemsize, layers)
+            # the kernel makes one loop trip a tile it fetches: walked
+            # over live is 1.0 while no dead tile is walked
+            live = layers * paged_live_tiles(spans, tile)
+            self._step_tiles = {"paged_tiles_live": live,
+                                "paged_tiles_walked": live}
         else:
             nbytes = dense_read_bytes(
                 self.n_slots, self.max_len, self.cfg.num_kv_heads,
-                self.cfg.d_head, itemsize, self.cfg.num_attention_layers)
+                self.cfg.d_head, itemsize, layers)
         self.decode_attn_bytes += nbytes
         self._m_decode_bytes.set(nbytes / max(1, served),
                                  engine=self.name,
@@ -1252,7 +1254,8 @@ class SlotEngine:
             if events is None:
                 events = self._plain_step()
             if sp.live:
-                sp.set(tokens=len(events), program=self.last_program)
+                sp.set(tokens=len(events), program=self.last_program,
+                       **self._step_tiles)
             return events
 
     def _finish_step(self, events: List[StepEvent]) -> List[StepEvent]:
@@ -1300,15 +1303,12 @@ class SlotEngine:
                               self.ctx[idx, np.maximum(self.lengths - 1, 0)],
                               self.pad_id).astype(np.int32)
             prev_nxt = self._no_prev if prev is None else prev.nxt
-            program = _decode_program_key(
-                self.attention_backend, kw["paged_num_tiles"])
+            program = _decode_program_key(self.attention_backend)
             prof = self.step_profiler
             if prof is not None:
                 if getattr(prof, "capture_xla", False):
-                    nt = kw["paged_num_tiles"]
                     prof.capture_cost(
-                        f"llm_decode_step_{self.attention_backend}"
-                        + (f"_nt{nt}" if nt is not None else ""),
+                        f"llm_decode_step_{self.attention_backend}",
                         _decode_step_jit, self.model, self.variables,
                         self.cache, jnp.asarray(tokens),
                         jnp.asarray(lengths.astype(np.int32)),
@@ -1419,8 +1419,8 @@ class SlotEngine:
     def _spec_bucket(self, max_k: int, s_cap: int) -> int:
         """Static S for this verify step: the next power of two
         covering pending + longest draft, shrunk to the cache headroom
-        — one compiled verify program per (S, span-bucket) pair,
-        O(log(spec_draft_len) * log(max_len/tile)) programs total."""
+        — one compiled verify program per S, O(log(spec_draft_len))
+        programs total."""
         s = max(2, _next_pow2(1 + max_k))
         while s > s_cap and s > 2:
             s //= 2
@@ -1437,8 +1437,7 @@ class SlotEngine:
             idx = np.arange(self.n_slots)
             S = self._spec_bucket(max(len(d) for d in drafts.values()),
                                   s_cap)
-            kw, lengths = self._decode_step_args(self.active, self.lengths,
-                                                 extra_span=S - 1)
+            kw, lengths = self._decode_step_args(self.active, self.lengths)
             tokens = np.full((self.n_slots, S), self.pad_id, np.int32)
             tokens[:, 0] = np.where(
                 self.active, self.ctx[idx, np.maximum(self.lengths - 1, 0)],
@@ -1451,10 +1450,8 @@ class SlotEngine:
             prof = self.step_profiler
             if prof is not None:
                 if getattr(prof, "capture_xla", False):
-                    nt = kw["paged_num_tiles"]
                     prof.capture_cost(
-                        f"llm_verify_step_{self.attention_backend}_s{S}"
-                        + (f"_nt{nt}" if nt is not None else ""),
+                        f"llm_verify_step_{self.attention_backend}_s{S}",
                         _verify_step_jit, self.model, self.variables,
                         self.cache, jnp.asarray(tokens),
                         jnp.asarray(lengths.astype(np.int32)),
@@ -1462,7 +1459,7 @@ class SlotEngine:
                         items=float(self.active_count), **kw)
                 prof.step_begin()
             self.last_program = _verify_program_key(
-                self.attention_backend, S, kw["paged_num_tiles"])
+                self.attention_backend, S)
             with step_span("engine.step.prepare.upload"):
                 step_in = (jnp.asarray(tokens),
                            jnp.asarray(lengths.astype(np.int32)),

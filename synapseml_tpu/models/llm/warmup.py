@@ -2,9 +2,9 @@
 
 Every compiled program a :class:`~synapseml_tpu.models.llm.slots.
 SlotEngine` can ever need is enumerable from its STATIC config: one
-prefill per power-of-two prompt bucket, one decode step per paged
-span-bucket (one total when dense), one verify per ``(S, span-bucket)``
-pair when speculative decoding is armed, and the prefix-copy transfer.
+prefill per power-of-two prompt bucket, one decode step, one verify per
+span bucket ``S`` when speculative decoding is armed, and the
+prefix-copy transfer.
 Orca/vLLM-class schedulers treat that finite lattice as something to
 warm *before admission*, not to discover lazily inside the decode loop
 — a lazy first hit stalls every active slot for the full XLA compile
@@ -122,23 +122,12 @@ class ProgramSpec:
     run: Callable[[Any], Any]
 
 
-def _paged_tile_buckets(total_tiles: int) -> List[int]:
-    """Every grid length ``span_bucket_tiles`` can produce: the powers
-    of two below ``total_tiles`` plus the clamp itself."""
-    out, b = [], 1
-    while b < total_tiles:
-        out.append(b)
-        b *= 2
-    out.append(total_tiles)
-    return out
-
-
 def program_lattice(engine) -> List[ProgramSpec]:
     """Enumerate the engine's full program lattice from its static
     config.  Ordered so a background warm makes the engine useful
     earliest: decode steps first (every active slot needs one), then
     the prefix copy, then the verify lattice (a speculative engine's
-    first step can dispatch ANY (S, span) pair, so admission must wait
+    first step can dispatch ANY span bucket S, so admission must wait
     on all of them — they are part of the base, and warming them
     before the prefills keeps that wait minimal), then prefill buckets
     ascending — last, because a held request's bucket is bumped to the
@@ -155,10 +144,8 @@ def program_lattice(engine) -> List[ProgramSpec]:
     backend = engine.attention_backend
     geo = engine._paged_geo
 
-    def step_kwargs(nt):
-        return {"attention_backend": backend,
-                "paged_num_tiles": nt,
-                "paged_tile": geo.tile if geo is not None else None}
+    step_kwargs = {"attention_backend": backend,
+                   "paged_tile": geo.tile if geo is not None else None}
 
     def decode_inputs():
         tokens = jnp.asarray(np.full(n, engine.pad_id, np.int32))
@@ -166,22 +153,19 @@ def program_lattice(engine) -> List[ProgramSpec]:
         active = jnp.asarray(np.zeros(n, bool))
         return tokens, lengths, active
 
-    nts = ([None] if geo is None
-           else _paged_tile_buckets(geo.total_tiles))
     specs: List[ProgramSpec] = []
 
-    for nt in nts:
-        def run_decode(cache, nt=nt):
-            tokens, lengths, active = decode_inputs()
-            cache, nxt, _ = _decode_step_jit(
-                model, variables, cache, tokens, lengths, active,
-                jax.random.PRNGKey(0), engine.temperature, engine.top_k,
-                engine.top_p, prev_nxt=jnp.zeros(n, jnp.int32),
-                feed_host=jnp.asarray(np.ones(n, bool)), **step_kwargs(nt))
-            jax.block_until_ready(nxt)
-            return cache
-        specs.append(ProgramSpec(_decode_program_key(backend, nt),
-                                 "decode", run_decode))
+    def run_decode(cache):
+        tokens, lengths, active = decode_inputs()
+        cache, nxt, _ = _decode_step_jit(
+            model, variables, cache, tokens, lengths, active,
+            jax.random.PRNGKey(0), engine.temperature, engine.top_k,
+            engine.top_p, prev_nxt=jnp.zeros(n, jnp.int32),
+            feed_host=jnp.asarray(np.ones(n, bool)), **step_kwargs)
+        jax.block_until_ready(nxt)
+        return cache
+    specs.append(ProgramSpec(_decode_program_key(backend), "decode",
+                             run_decode))
 
     def run_copy(cache):
         cache = _copy_prefix_jit(cache, 0, min(1, n - 1), 1)
@@ -194,19 +178,17 @@ def program_lattice(engine) -> List[ProgramSpec]:
         s_max = max(2, _next_pow2(1 + engine.spec_draft_len))
         s = 2
         while s <= s_max:
-            for nt in nts:
-                def run_verify(cache, s=s, nt=nt):
-                    tokens = jnp.asarray(
-                        np.full((n, s), engine.pad_id, np.int32))
-                    _, lengths, active = decode_inputs()
-                    cache, g = _verify_step_jit(
-                        model, variables, cache, tokens, lengths, active,
-                        **step_kwargs(nt))
-                    jax.block_until_ready(g)
-                    return cache
-                specs.append(ProgramSpec(
-                    _verify_program_key(backend, s, nt), "verify",
-                    run_verify))
+            def run_verify(cache, s=s):
+                tokens = jnp.asarray(
+                    np.full((n, s), engine.pad_id, np.int32))
+                _, lengths, active = decode_inputs()
+                cache, g = _verify_step_jit(
+                    model, variables, cache, tokens, lengths, active,
+                    **step_kwargs)
+                jax.block_until_ready(g)
+                return cache
+            specs.append(ProgramSpec(_verify_program_key(backend, s),
+                                     "verify", run_verify))
             s *= 2
 
     for pb in engine._buckets:

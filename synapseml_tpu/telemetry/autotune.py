@@ -292,7 +292,7 @@ def _build_paged_attn_tile(max_len: int = 256, num_heads: int = 4,
                            n_slots: int = 4, span: int = 1):
     """Candidates: every tile the VMEM/divisibility gate admits at this
     geometry; runner: one decode step of the paged kernel over full
-    spans (the worst-case bucketed grid)."""
+    spans (every tile of the cache live)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -319,15 +319,13 @@ def _build_paged_attn_tile(max_len: int = 256, num_heads: int = 4,
         if geo is None:
             continue
 
-        def runner(tile=tile, nt=geo.total_tiles):
+        def runner(tile=tile):
             jax.block_until_ready(pallas_attn.paged_decode_attention(
-                q, k, v, spans, tile=tile, num_tiles=nt,
-                interpret=interpret))
+                q, k, v, spans, tile=tile, interpret=interpret))
 
-        def cost(tile=tile, nt=geo.total_tiles):
+        def cost(tile=tile):
             return _cost_of(pallas_attn.paged_decode_attention,
-                            q, k, v, spans, tile=tile, num_tiles=nt,
-                            interpret=interpret)
+                            q, k, v, spans, tile=tile, interpret=interpret)
 
         trials.append(({"tile": int(tile)}, runner, cost))
     return geometry, trials

@@ -35,6 +35,7 @@ reads 1e-2 and more here).
 import dataclasses
 import json
 import os
+import re
 import sys
 
 import jax
@@ -628,13 +629,14 @@ def test_the_uncut_pattern_builds_from_the_published_keys(benchmark_config):
 def test_paged_kernel_reads_30_heads_of_a_row_padded_to_32():
     B, T, H, D, tile = 2, 64, 30, 128, 16
     geo = paged_geometry(1536, 30, 30, 128, jnp.bfloat16)
-    assert geo is not None and geo.tile == 256       # 30 counted as 32 rows
+    # 30 counted as rows of 32: a K tile of 32 positions is 256 KiB
+    assert geo is not None and geo.tile == 32
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, T, 32, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, T, 32, D), jnp.float32)
     spans = jnp.asarray([37, 5], jnp.int32)
-    out = paged_decode_attention(q, k, v, spans, tile=tile, num_tiles=4,
+    out = paged_decode_attention(q, k, v, spans, tile=tile,
                                  interpret=True, kv_heads=30)
     s = jnp.einsum("bhd,bthd->bht", q, k[:, :, :30]) / np.sqrt(D)
     s = jnp.where(jnp.arange(T)[None, None] < spans[:, None, None], s, -1e30)
@@ -678,3 +680,33 @@ def test_both_kernels_compile_for_the_v5e_at_the_published_geometry(one_chip):
             sd((T, H)), sd((T, H)), sd((), jnp.int32), pack=pack
         ).compile().as_text()
         assert "tpu_custom_call" in text and "gated_delta_prefill" in text
+
+
+@pytest.mark.parametrize("name,n,max_len,heads,kv,row_heads,span", [
+    ("mistral", 32, 2048, 32, 8, 8, 1),        # group 4
+    ("olmo", 32, 1536, 30, 30, 32, 1),         # group 1, rows padded to 32
+    ("verify8", 32, 2048, 32, 8, 8, 8),        # the widest verify step
+])
+def test_paged_kernel_compiles_for_the_v5e_with_no_copy_of_the_cache(
+        one_chip, name, n, max_len, heads, kv, row_heads, span):
+    """At the cells' geometries the tile the gate picks fits the chip's
+    VMEM (the compiler refuses what does not), and the flat-row view of
+    the cache reaches the kernel as a bitcast: a copy of cache size
+    would be 0.3-0.8 GB of temporaries a layer and step."""
+    geo = paged_geometry(max_len, heads, kv, 128, jnp.bfloat16,
+                         max_query_span=span)
+    assert geo is not None
+
+    def sd(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sd((n, heads, 128) if span == 1 else (n, span, heads, 128))
+    cache = sd((n, max_len, row_heads, 128))
+    compiled = paged_decode_attention.lower(
+        q, cache, cache, sd((n,), jnp.int32), tile=geo.tile,
+        kv_heads=kv).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert f"bf16[{n},{max_len * row_heads},128]" in text     # flat rows ...
+    assert not re.search(                                       # ... by bitcast
+        rf"bf16\[{n},{max_len * row_heads},128\]\S* (copy|fusion)\(", text)

@@ -185,7 +185,7 @@ def test_tokens_are_exact_through_what_happens_under_a_step(tiny_model,
     d.run()
     assert d.tokens["g"] == ref["g"]
     assert 0 < eng.steps_overlapped < eng.steps_run
-    # one program per span bucket still: none compiled since the warm-up
+    # one decode program still: none compiled since the warm-up
     assert engine_jit_cache_size() == programs
 
 

@@ -3,7 +3,7 @@
 The contract under test (ISSUE 11 acceptance criteria):
 
 - the kernel's online-softmax output is ulp-close to the dense masked-
-  softmax math across span buckets — including span 1, the full
+  softmax math across spans and tiles — including span 1, the full
   ``max_len`` row, and the PR-8 repro shape (58 live tokens in a
   64-row cache);
 - greedy decode through :class:`SlotEngine` with
@@ -34,8 +34,8 @@ from synapseml_tpu.models.llm import (LlamaConfig, LlamaModel, SlotEngine,
                                       dense_read_bytes, generate,
                                       paged_decode_attention,
                                       paged_geometry, paged_read_bytes,
-                                      resolve_attention_backend,
-                                      span_bucket_tiles)
+                                      resolve_attention_backend)
+from synapseml_tpu.models.llm import pallas_attn
 
 pytestmark = pytest.mark.pallas
 
@@ -54,23 +54,31 @@ def _prompts(cfg, n, length, seed=0):
     return rng.integers(1, cfg.vocab_size, (n, length)).astype(np.int32)
 
 
-def _dense_reference(q, k, v, spans):
-    """The model.py dense decode math (S=1): full-row masked softmax."""
-    B, H, D = q.shape
+def _dense_cache_dtype(q, k, v, spans):
+    """The dense path's own arithmetic in the cache's dtype
+    (``model.py`` ``CausalAttention``): operands as stored, float32
+    logits and softmax, probabilities cast before the PV product.
+    q (B, S, H, D); query j of slot b sits at ``spans[b]-S+j``."""
+    B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
-    group = H // KV
-    qg = q.reshape(B, 1, KV, group, D)
+    qg = q.reshape(B, S, KV, H // KV, D)
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, k,
                         preferred_element_type=jnp.float32) / np.sqrt(D)
-    causal = jnp.arange(T)[None, None, :] < spans[:, None, None]
-    mask = jnp.broadcast_to(causal[:, None, None, :, :], logits.shape)
-    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+    qpos = spans[:, None] - S + jnp.arange(S)[None, :]
+    causal = jnp.arange(T)[None, None, :] <= qpos[:, :, None]    # (B, S, T)
+    logits = jnp.where(causal[:, None, None], logits,
+                       jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgst,btkd->bskgd", probs, v).reshape(B, H, D)
+    return jnp.einsum("bkgst,btkd->bskgd", probs, v).reshape(B, S, H, D)
+
+
+def _dense_reference(q, k, v, spans):
+    """The same at S=1: full-row masked softmax; q (B, H, D)."""
+    return _dense_cache_dtype(q[:, None], k, v, spans)[:, 0]
 
 
 class TestKernelParity:
-    """Direct kernel-vs-dense logits parity, every span bucket."""
+    """Direct kernel-vs-dense logits parity across spans and tiles."""
 
     B, T, KV, GROUP, D = 5, 96, 4, 2, 32
 
@@ -96,35 +104,20 @@ class TestKernelParity:
         q, k, v = self._operands()
         sp = jnp.asarray(spans, jnp.int32)
         ref = _dense_reference(q, k, v, sp)
-        geo = paged_geometry(self.T, self.KV * self.GROUP, self.KV,
-                             self.D, jnp.float32)
-        assert geo is not None and self.T % tile == 0
-        nt = span_bucket_tiles(
-            max(spans), type(geo)(tile, self.T // tile, geo.vmem_bytes))
-        out = paged_decode_attention(q, k, v, sp, tile=tile, num_tiles=nt,
-                                     interpret=True)
+        assert self.T % tile == 0
+        out = paged_decode_attention(q, k, v, sp, tile=tile, interpret=True)
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
-    def test_every_bucket_size_exact(self):
-        """One compiled program per power-of-two bucket: each bucket
-        that can cover its spans agrees with dense."""
+    def test_one_program_serves_long_and_short_batches(self):
+        """No span bucket: the same call covers a batch that runs to the
+        last of twelve tiles and one whose every slot ends in its first
+        (the ring's cursor crosses a slot boundary at every tile)."""
         q, k, v = self._operands(seed=1)
-        tile, total = 8, self.T // 8
-        spans_np = [5, 17, 40, 63, 96]
-        sp = jnp.asarray(spans_np, jnp.int32)
-        ref = _dense_reference(q, k, v, sp)
-        for nt in (12,):              # clamped: next pow2 of 12 is 16 > 12
-            out = paged_decode_attention(q, k, v, sp, tile=tile,
-                                         num_tiles=nt, interpret=True)
-            np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
-        # short batch in a small bucket: the grid never iterates the
-        # long cache's tiles
-        sp_short = jnp.asarray([5, 3, 8, 1, 7], jnp.int32)
-        ref_short = _dense_reference(q, k, v, sp_short)
-        out_short = paged_decode_attention(q, k, v, sp_short, tile=tile,
-                                           num_tiles=1, interpret=True)
-        np.testing.assert_allclose(out_short, ref_short, rtol=1e-5,
-                                   atol=1e-6)
+        for spans_np in ([5, 17, 40, 63, 96], [5, 3, 8, 1, 7]):
+            sp = jnp.asarray(spans_np, jnp.int32)
+            out = paged_decode_attention(q, k, v, sp, tile=8, interpret=True)
+            np.testing.assert_allclose(out, _dense_reference(q, k, v, sp),
+                                       rtol=1e-5, atol=1e-6)
 
     def test_pr8_repro_shape_58_at_64(self):
         """58 live tokens in a 64-row cache — the shape that exposed
@@ -132,9 +125,48 @@ class TestKernelParity:
         q, k, v = self._operands(seed=2, T=64)
         sp = jnp.asarray([58, 64, 1, 58, 33], jnp.int32)
         ref = _dense_reference(q, k, v, sp)
-        out = paged_decode_attention(q, k, v, sp, tile=32, num_tiles=2,
-                                     interpret=True)
+        out = paged_decode_attention(q, k, v, sp, tile=32, interpret=True)
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+
+
+class TestCacheDtypeParity:
+    """bfloat16 operands go to the products as stored, as in the dense
+    path; every key past a live span, and every padding head of a cache
+    row, holds POISON (a key aligned with the queries, a value of 100:
+    one of them let through moves the output by tens)."""
+
+    T, TILE, D = 64, 16, 32
+
+    @pytest.mark.parametrize("S", [1, 4])
+    @pytest.mark.parametrize("heads,kv,row_heads", [
+        (32, 8, 8),            # group 4 over 8 K/V heads (Mistral's)
+        (30, 30, 32),          # group 1, rows of 30 heads padded to 32
+    ])
+    def test_matches_the_dense_paths_bfloat16(self, heads, kv, row_heads, S):
+        rng = np.random.default_rng(heads + S)
+        # spans that end inside a tile, on a tile edge, one past it, and
+        # at max_len; the shortest a verify step may see
+        spans = np.asarray([S, 21, 32, 33, 63, 64])
+        B, T, D = len(spans), self.T, self.D
+        q = rng.normal(size=(B, S, heads, D)).astype(np.float32)
+        k = rng.normal(size=(B, T, row_heads, D)).astype(np.float32)
+        v = rng.normal(size=(B, T, row_heads, D)).astype(np.float32)
+        dead = (np.arange(T)[None, :] >= spans[:, None])[:, :, None, None]
+        pad = (np.arange(row_heads) >= kv)[None, None, :, None]
+        aligned = 8.0 * q.mean((1, 2))[:, None, None, :]      # (B, 1, 1, D)
+        k = np.where(dead | pad, aligned, k)
+        v = np.where(dead | pad, 100.0, v)
+        qb, kb, vb = (jnp.asarray(a, jnp.bfloat16) for a in (q, k, v))
+        sp = jnp.asarray(spans, jnp.int32)
+        out = paged_decode_attention(qb[:, 0] if S == 1 else qb, kb, vb, sp,
+                                     tile=self.TILE, kv_heads=kv,
+                                     interpret=True)
+        assert out.dtype == jnp.bfloat16
+        out = np.asarray(out.astype(jnp.float32)).reshape(B, S, heads, D)
+        ref = np.asarray(_dense_cache_dtype(
+            qb, kb[:, :, :kv], vb[:, :, :kv], sp).astype(jnp.float32))
+        # two roundings of a bfloat16 output of size about 1 apart
+        np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
 
 
 class TestModelDispatch:
@@ -251,10 +283,10 @@ class TestEngineExactness:
         np.testing.assert_array_equal(warm.generated_ids(r_warm.slot),
                                       cold.generated_ids(r_cold.slot))
 
-    def test_span_growth_across_tile_and_bucket_boundary(self, tiny_model):
+    def test_span_growth_across_tile_boundaries(self, tiny_model):
         """A sequence decoding from span 30 to span 70 crosses the
-        32-token tile boundary AND the 1-tile -> 2-tile bucket
-        boundary; every token stays exactly greedy."""
+        32-token tile boundary twice (one live tile, two, three); every
+        token stays exactly greedy."""
         cfg, model, variables = tiny_model
         ids = _prompts(cfg, 1, 30, seed=7)
         ref = generate(model, variables, ids, max_new_tokens=40)[0]
@@ -330,13 +362,13 @@ class TestResolveAndGeometry:
         assert geo is not None
         assert 8192 % geo.tile == 0 and geo.tile <= 4096
         assert geo.tile % 16 == 0                 # bf16 sublane
-        # the estimate counts PADDED tiles: a (KV=8, D=64) bf16 K/V row
-        # occupies a (16, 128) tile, the same bytes as (KV=16, D=128)
+        # the estimate counts PADDED lanes: a (KV=8, D=64) bf16 K/V row
+        # is fetched at 128 lanes, the same bytes as (KV=8, D=128)
         small = paged_geometry(1024, 32, 8, 64, jnp.bfloat16)
-        padded = paged_geometry(1024, 32, 16, 128, jnp.bfloat16)
-        assert small.tile == padded.tile == 256
+        padded = paged_geometry(1024, 32, 8, 128, jnp.bfloat16)
+        assert small.tile == padded.tile
         assert small.vmem_bytes == padded.vmem_bytes \
-            >= 2 * 2 * 256 * 16 * 128 * 2
+            >= 2 * pallas_attn._RING * small.tile * 8 * 128 * 2
         # a max_len no sublane-aligned tile divides: no geometry, and
         # the explicit backends refuse while auto falls back
         assert paged_geometry(100, 8, 4, 32, jnp.float32) is None
@@ -348,20 +380,34 @@ class TestResolveAndGeometry:
             "auto", max_len=100, num_heads=8, num_kv_heads=4,
             d_head=32, dtype=jnp.float32) == "dense"
 
-    def test_bucket_tiles_power_of_two_clamped(self):
-        from synapseml_tpu.models.llm import PagedGeometry
-        geo = PagedGeometry(tile=32, total_tiles=3, vmem_bytes=0)
-        assert span_bucket_tiles(1, geo) == 1
-        assert span_bucket_tiles(32, geo) == 1
-        assert span_bucket_tiles(33, geo) == 2
-        assert span_bucket_tiles(65, geo) == 3    # pow2=4 clamps to 3
-        assert span_bucket_tiles(96, geo) == 3
+    @pytest.mark.parametrize("name,max_len,heads,kv,span", [
+        ("mistral", 2048, 32, 8, 1), ("olmo", 1536, 30, 30, 1),
+        ("verify8", 2048, 32, 8, 8)])
+    def test_geometry_estimate_covers_the_kernels_buffers(
+            self, name, max_len, heads, kv, span):
+        """The VMEM gate prices what the kernel allocates: the ring of
+        K and V tiles, the query rows, the softmax state and one chunk
+        of logits and probabilities, all at the verify step's width."""
+        geo = paged_geometry(max_len, heads, kv, 128, jnp.bfloat16,
+                             max_query_span=span)
+        assert geo is not None and max_len % geo.tile == 0
+        row_heads = 32 if kv == 30 else kv        # a row of whole tiles
+        q_rows = span * -(-heads // 8) * 8
+        ring = 2 * pallas_attn._RING * geo.tile * row_heads * 128 * 2
+        state = q_rows * 128 * (2 + 4 + 4 + 4)    # queries, acc, m, l
+        chunk = pallas_attn._chunk_rows(geo.tile, row_heads, q_rows)
+        assert chunk % row_heads == 0 and (geo.tile * row_heads) % chunk == 0
+        assert geo.vmem_bytes >= ring + state + q_rows * chunk * 8
+        assert geo.vmem_bytes <= pallas_attn._VMEM_BUDGET
+        # the tile the ladder picks moves the K tile at or under its aim
+        assert geo.tile * row_heads * 128 * 2 <= pallas_attn._TILE_BYTES \
+            or geo.tile == 16
 
 
 class TestByteLedger:
     def test_paged_under_dense_and_exact_formula(self):
         spans = np.asarray([1, 33, 96, 58, 7])
-        tile, KV, D, item, L = 32, 4, 32, 4, 2
+        tile, KV, D, item, L = 32, 4, 128, 4, 2
         paged = paged_read_bytes(spans, tile, KV, D, item, L)
         dense = dense_read_bytes(5, 96, KV, D, item, L)
         expect = L * 2 * int(np.ceil(spans / tile).sum()) * tile \
@@ -371,9 +417,57 @@ class TestByteLedger:
         # all-full spans round to exactly the dense read
         assert paged_read_bytes([96] * 5, tile, KV, D, item, L) == dense
 
-    def test_engine_accounts_and_exports_bytes(self, tiny_model):
+    @pytest.mark.parametrize("D", [128, 64])
+    def test_read_bytes_equal_the_copies_the_kernel_starts(self, monkeypatch,
+                                                           D):
+        """``paged_read_bytes`` against the kernel itself: every DMA it
+        starts is counted as it runs (interpret mode), for a ragged
+        batch with an inactive slot (span 1) and padded cache rows; a
+        head of 64 lanes is fetched, and priced, at 128."""
+        B, T, heads, kv, row_heads, tile = 5, 64, 6, 6, 8, 16
+        spans = np.asarray([1, 17, 64, 16, 33])        # slot 0 inactive
+        started = []
+        real = pallas_attn.pltpu.make_async_copy
+
+        def counting(src, dst, sem):
+            copy = real(src, dst, sem)
+            nbytes = int(np.prod(dst.shape)) * np.dtype(dst.dtype).itemsize
+            start = copy.start
+
+            def start_and_count(*a, **kw):
+                jax.debug.callback(lambda: started.append(nbytes))
+                return start(*a, **kw)
+            copy.start = start_and_count
+            return copy
+
+        monkeypatch.setattr(pallas_attn.pltpu, "make_async_copy", counting)
+        paged_decode_attention.clear_cache()
+        try:
+            rng = np.random.default_rng(0)
+            q = jnp.asarray(rng.normal(size=(B, heads, D)), jnp.bfloat16)
+            kv_rows = jnp.asarray(rng.normal(size=(B, T, row_heads, D)),
+                                  jnp.bfloat16)
+            jax.block_until_ready(paged_decode_attention(
+                q, kv_rows, kv_rows, jnp.asarray(spans, jnp.int32),
+                tile=tile, kv_heads=kv, interpret=True))
+            jax.effects_barrier()
+        finally:
+            paged_decode_attention.clear_cache()
+        tiles = int(np.ceil(spans / tile).sum())
+        assert tiles == pallas_attn.paged_live_tiles(spans, tile) == 11
+        assert len(started) == 2 * tiles                # one of K, one of V
+        assert sum(started) == paged_read_bytes(spans, tile, row_heads, D, 2)
+
+    def test_engine_accounts_and_exports_bytes(self):
         from synapseml_tpu.telemetry import get_registry
-        cfg, model, variables = tiny_model
+        # heads of 128 lanes, as served models have: a narrower head is
+        # fetched at 128 and the ledger prices that
+        cfg = LlamaConfig.tiny(num_layers=1, d_model=256, num_heads=2,
+                               num_kv_heads=1, max_len=64,
+                               dtype=jnp.float32)
+        model = LlamaModel(cfg)
+        variables = model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((2, 8), jnp.int32))
         eng = SlotEngine(model, variables, n_slots=4, max_len=64,
                          attention_backend="interpret", name="t-paged")
         dns = SlotEngine(model, variables, n_slots=4, max_len=64,

@@ -301,8 +301,7 @@ class TestPagedVerify:
         v = jnp.asarray(rng.standard_normal((B, T, KV, D)), jnp.float32)
         spans = jnp.asarray([4, 17, 33, 64], jnp.int32)
         got = np.asarray(paged_decode_attention(
-            q, k, v, spans, tile=tile, num_tiles=T // tile,
-            interpret=True))
+            q, k, v, spans, tile=tile, interpret=True))
         group = H // KV
         for b in range(B):
             for j in range(S):

@@ -87,9 +87,9 @@ class TestLatticeCompleteness:
 
     def test_lattice_enumerates_the_engine_config(self, tiny_model):
         """Lattice contents follow from static config alone: every
-        prefill bucket, one decode per span bucket (one total when
-        dense), every (S, span) verify pair, and the prefix copy —
-        with keys matching the engine's step-dispatch labels."""
+        prefill bucket, one decode step, one verify per span bucket S,
+        and the prefix copy — with keys matching the engine's
+        step-dispatch labels."""
         cfg, model, variables = tiny_model
         eng = SlotEngine(model, variables, n_slots=2, max_len=64,
                          spec_draft_len=4)
@@ -105,7 +105,7 @@ class TestLatticeCompleteness:
     def test_verify_lattice_warms_before_prefill_buckets(self,
                                                          tiny_model):
         """A speculative engine's first step after admission can
-        dispatch ANY (S, span) verify pair, so the verify lattice is
+        dispatch ANY span bucket S of the verify, so the verify lattice is
         part of the admission base: it must be enumerated BEFORE the
         prefill buckets (which admission bumps to the front on demand)
         — otherwise a request admitted mid-warm stalls the whole loop
@@ -117,21 +117,17 @@ class TestLatticeCompleteness:
         assert max(i for i, k in enumerate(kinds) if k == "verify") \
             < min(i for i, k in enumerate(kinds) if k == "prefill")
 
-    def test_paged_lattice_covers_span_buckets(self, tiny_model):
+    def test_paged_lattice_has_one_decode_program(self, tiny_model):
+        """The paged kernel walks live tiles itself: one decode program
+        and one verify program a span bucket S, whatever the spans."""
         cfg, model, variables = tiny_model
         eng = SlotEngine(model, variables, n_slots=2, max_len=64,
-                         attention_backend="interpret")
+                         attention_backend="interpret", spec_draft_len=2)
         keys = {s.key for s in program_lattice(eng)}
-        geo = eng._paged_geo
-        assert geo is not None
-        expected_nts = set()
-        b = 1
-        while b < geo.total_tiles:
-            expected_nts.add(b)
-            b *= 2
-        expected_nts.add(geo.total_tiles)
-        assert {k for k in keys if k.startswith("decode_")} == {
-            f"decode_interpret_nt{nt}" for nt in expected_nts}
+        assert eng._paged_geo is not None and eng._paged_geo.total_tiles > 1
+        assert {k for k in keys if not k.startswith("prefill_")} == {
+            "decode_interpret", "prefix_copy",
+            "verify_interpret_s2", "verify_interpret_s4"}
 
 
 class TestZeroInLoopCompiles:

@@ -430,6 +430,31 @@ class TestProgramSpans:
         assert {"engine.step", "engine.step.wait", "engine.admit.prefill"} \
             <= host_plane_names(tmp_path)
 
+    def test_paged_step_span_counts_live_and_walked_tiles(self, tiny_model,
+                                                          tmp_path):
+        """``engine.step`` of a paged engine: tiles the kernel fetched
+        for the returned step, over every attention layer, and the loop
+        trips it made for them (one a tile: no dead tile is walked)."""
+        from synapseml_tpu.models.llm import SlotEngine
+        cfg, model, variables = tiny_model
+        rng = np.random.default_rng(1)
+        eng = SlotEngine(model, variables, n_slots=3, max_len=96,
+                         attention_backend="interpret")
+        tile = eng._paged_geo.tile
+        eng.admit(rng.integers(1, cfg.vocab_size, 2 * tile + 3)
+                  .astype(np.int32), 4)
+        eng.admit(rng.integers(1, cfg.vocab_size, 5).astype(np.int32), 4)
+        tr = get_tracer()
+        tr.reset()
+        with profiler_session(tmp_path):
+            eng.step()
+        step, = tr.spans("engine.step")
+        # three live tiles, one, and the idle slot's first
+        live = cfg.num_attention_layers * (3 + 1 + 1)
+        assert step.attrs["paged_tiles_live"] == live
+        assert step.attrs["paged_tiles_walked"] == live
+        assert step.attrs["program"] == "decode_interpret"
+
     def test_speculative_step_has_a_draft_span(self, tiny_model, tmp_path):
         from synapseml_tpu.models.llm import SlotEngine
         cfg, model, variables = tiny_model
@@ -461,6 +486,9 @@ class TestProgramSpans:
         tr.reset()
         try:
             with profiler_session(tmp_path):
+                # the idle tick that was waiting for a request when the
+                # session began is no live span: let it run out first
+                time.sleep(3 * srv._loop.idle_timeout_s)
                 req = urllib.request.Request(
                     srv.url, data=json.dumps(
                         {"ids": [3, 4, 5, 6, 7], "max_new_tokens": 4}).encode(),
