@@ -405,7 +405,7 @@ def dl_phase(sz: Sizes, ph: Phase) -> None:
 # -- gbdt ------------------------------------------------------------------
 
 def _gbdt_labels(rng, X):
-    """bench.py's label concept for train AND holdout."""
+    """One label concept for train AND holdout."""
     return (X[:, 0] * 2 - X[:, 1] + X[:, 2] * X[:, 3]
             + rng.normal(scale=0.5, size=len(X)) > 0).astype(np.float64)
 

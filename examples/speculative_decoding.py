@@ -1,8 +1,9 @@
 """Speculative decoding: prompt-lookup drafts, exact greedy output.
 
-Each loop step drafts ``draft_len`` tokens by n-gram lookup in the
-sequence's own context and verifies them in ONE (B, draft_len+1) forward.
-At small batch the verify matmuls use B·(K+1) of the MXU's 128 rows, so
+``SlotEngine(spec_draft_len=K)`` drafts up to K tokens per slot by n-gram
+lookup in the slot's own context (``NgramDrafter``: host tables, nothing
+drafted on a miss) and verifies every slot's draft in ONE multi-token
+forward.  At small batch the verify matmuls use few of the MXU's rows, so
 accepted draft tokens ride the same row-bound step for free — and because
 a draft only survives when it equals the model's argmax, the output is
 bit-identical to plain greedy decoding.
@@ -13,8 +14,18 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from synapseml_tpu.models.llm import (LlamaConfig, LlamaModel, generate,
-                                      generate_speculative)
+from synapseml_tpu.models.llm import (LlamaConfig, LlamaModel, SlotEngine,
+                                      generate)
+
+
+def spec_decode(model, variables, prompts, max_new_tokens, draft_len):
+    """Decode every prompt in its own slot → (tokens (B, max_new_tokens),
+    the engine)."""
+    eng = SlotEngine(model, variables, n_slots=len(prompts),
+                     max_len=model.cfg.max_len, spec_draft_len=draft_len)
+    slots = [eng.admit(p, max_new_tokens).slot for p in prompts]
+    eng.run_to_completion()
+    return np.stack([eng.generated_ids(s) for s in slots]), eng
 
 
 def main():
@@ -27,12 +38,10 @@ def main():
     prompt = np.concatenate([base, base, base])[None, :].repeat(2, 0)
 
     ref = generate(model, variables, prompt, max_new_tokens=24)
-    out, stats = generate_speculative(model, variables, prompt,
-                                      max_new_tokens=24, draft_len=5)
+    out, eng = spec_decode(model, variables, prompt, 24, draft_len=5)
     assert np.array_equal(ref, out), "speculative decode must equal greedy"
-    print(f"greedy-exact in {stats['steps']} verify steps, "
-          f"{stats['tokens_per_step']:.2f} tokens/step, "
-          f"acceptance {stats['acceptance_rate']:.2f}")
+    print(f"greedy-exact in {eng.steps_run} steps ({eng.spec_steps} of them "
+          f"verify steps), acceptance {eng.spec_acceptance_rate:.2f}")
 
 
 def target_regime():
@@ -55,11 +64,10 @@ def target_regime():
                                   learning_rate=1e-3)
     prompts = templated_log_corpus(rng, 4, 3, field_range=(64, 256))
     ref = generate(model, variables, prompts, max_new_tokens=32)
-    out, stats = generate_speculative(model, variables, prompts,
-                                      max_new_tokens=32)
+    out, eng = spec_decode(model, variables, prompts, 32, draft_len=7)
     assert np.array_equal(ref, out)
-    print(f"fine-tuned (loss {loss:.2f}): "
-          f"{stats['tokens_per_step']:.2f} tokens/step, still greedy-exact")
+    print(f"fine-tuned (loss {loss:.2f}): {out.size} tokens in "
+          f"{eng.steps_run} steps of 4 slots, still greedy-exact")
 
 
 if __name__ == "__main__":

@@ -16,7 +16,7 @@ import os as _os
 # One compile cache, placed from outside.  Where JAX_COMPILATION_CACHE_DIR
 # is set the persistent XLA compilation cache lives there; where it is not,
 # it is ``<checkout>/.jax_cache`` (resolved from this package's own path,
-# so every process of one checkout — tests, bench children, gang workers,
+# so every process of one checkout — tests, the benchmark, gang workers,
 # which all inherit the environment — shares one directory at a fixed
 # path).  This is the only place the package decides the directory; the
 # variable is exported so children resolve the same one.

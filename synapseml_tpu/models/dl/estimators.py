@@ -142,8 +142,8 @@ class _DLParamsBase(Params):
             "chains) | 'full'/'blocks' (save only block inputs — O(1)-"
             "block activation memory for ~1/3 more FLOPs).  Bit-exact vs "
             "'none' by construction (the recompute re-runs the identical "
-            "ops); the byte-diet lever for bandwidth-bound fine-tunes "
-            "(BENCH roofline)", default="none",
+            "ops); the byte-diet lever for bandwidth-bound fine-tunes",
+        default="none",
         allowed=("none", "dots_saveable", "full", "blocks"))
     precision = StringParam(
         doc="mixed-precision policy (models/dl/precision.py): 'bf16' "

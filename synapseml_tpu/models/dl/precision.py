@@ -1,8 +1,7 @@
 """Mixed-precision + rematerialization policies for DL training.
 
-The roofline work (ROADMAP item 4, BENCH_r05: ResNet-50 fine-tune at 93%
-of its *bandwidth* roofline) needs two byte-diet levers with explicit,
-testable contracts:
+A bandwidth-bound fine-tune step has two byte-diet levers, each with an
+explicit, testable contract:
 
 - :class:`PrecisionPolicy` — which dtype the forward/backward compute
   runs in (``compute_dtype``), which dtype gradient leaves carry across

@@ -74,9 +74,9 @@ class ResNet(nn.Module):
     act: Callable = nn.relu
     #: rematerialize each residual block in the backward pass
     #: ("none" | "dots_saveable" | "full"/"blocks", see
-    #: models/dl/precision.py:remat_policy): the fine-tune step is
-    #: bandwidth-bound (BENCH_r05 roofline), so trading HBM round trips
-    #: of saved activations for recompute FLOPs is the byte-diet lever.
+    #: models/dl/precision.py:remat_policy): where the fine-tune step
+    #: is bandwidth-bound, trading HBM round trips of saved activations
+    #: for recompute FLOPs is the byte-diet lever.
     #: Bit-exact vs "none" by construction — the recomputation re-runs
     #: the identical ops (pinned in tests/test_perf_roofline.py).
     remat: str = "none"

@@ -51,8 +51,8 @@ class _InstrumentedStep:
     and tracks a dispatch-rate gauge (the interval between successive
     step calls).  Dispatch is async, so single-call rates overstate the
     device; in a steady training loop the device queue backpressures the
-    host and the dispatch rate converges to true step throughput — the
-    same reasoning the bench's pipelined windows rely on.  Delegates
+    host and the dispatch rate converges to true step throughput.
+    Delegates
     everything else (``.lower`` for AOT compiles, jit introspection) to
     the wrapped callable, so existing callers are unchanged."""
 

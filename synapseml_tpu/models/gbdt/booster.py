@@ -150,11 +150,11 @@ class BoostingConfig:
     #: the objective and the histogram kernel (compute-and-quantize;
     #: accumulation stays f32/int32 so bin sums are exact over the
     #: rounded values).  "auto" (default) = on; False restores the f32
-    #: ingest bit-for-bit.  NOT bit-identical to the f32 ingest — the
-    #: bench pins holdout-AUC parity (|delta| <= 0.005) and tier-1 pins
-    #: fused-vs-unfused parity + preempt->resume bit-exactness WITH the
-    #: fused path on.  A checkpoint records its ingest (the resume guard
-    #: below refuses a silent fused/unfused mix mid-model).
+    #: ingest bit-for-bit.  NOT bit-identical to the f32 ingest — tier-1
+    #: pins fused-vs-unfused holdout-AUC parity and preempt->resume
+    #: bit-exactness WITH the fused path on.  A checkpoint records its
+    #: ingest (the resume guard below refuses a silent fused/unfused mix
+    #: mid-model).
     fused_ingest: Any = "auto"
     pass_through: Dict[str, Any] = dataclasses.field(default_factory=dict)
 

@@ -1062,8 +1062,8 @@ def _hist_updates(grad, hess, mask):
     the narrow buffer; accumulation is always f32.  On other backends
     the products promote straight to f32 — XLA:CPU materializes the
     scatter's f32 updates operand regardless, and a bf16 intermediate
-    would only ADD a buffer (measured +2.3% bytes on the bench shape;
-    same backend-quirk class as the CPU donation guard in
+    would only ADD a buffer (measured: +2.3% bytes accessed; same
+    backend-quirk class as the CPU donation guard in
     models/dl/training.py)."""
     if jax.default_backend() == "tpu":
         count = (mask > 0).astype(grad.dtype)

@@ -4,9 +4,8 @@ cognitive/.../openai/OpenAI.scala:246)."""
 
 from .finetune import (finetune_lm, make_lm_train_step,
                        templated_log_corpus)
-from .generate import (cast_params, generate, generate_speculative,
-                       quantize_int8,
-                       sample_logits, spec_unpack)
+from .generate import (cast_params, generate, quantize_int8,
+                       sample_logits)
 from .model import (LLM_LOGICAL_RULES, CausalAttention, DecoderBlock,
                     LlamaConfig, LlamaModel, RMSNorm, apply_rope,
                     causal_lm_loss, init_cache, llama_from_pretrained,
@@ -42,12 +41,10 @@ __all__ = [
     "apply_rope", "causal_lm_loss",
     "cast_params", "dense_read_bytes", "engine_jit_cache_size",
     "finetune_lm", "generate",
-    "generate_speculative",
     "init_cache", "llama_from_pretrained", "make_lm_train_step",
     "paged_decode_attention", "paged_geometry", "paged_read_bytes",
     "program_lattice",
     "quantize_int8",
     "resolve_attention_backend", "rope_frequencies", "sample_logits",
-    "spec_unpack",
     "templated_log_corpus",
 ]

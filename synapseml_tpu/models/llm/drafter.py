@@ -14,11 +14,10 @@ again — and verification (:class:`~synapseml_tpu.models.llm.slots
 .SlotEngine`) keeps greedy output exact regardless, so a wrong draft
 costs only the verify positions it rode in, never correctness.
 
-Why HOST-side tables rather than the jitted windowed match in
-:func:`~synapseml_tpu.models.llm.generate._ngram_draft`: the jitted
-form must draft a FIXED k every step (static shapes), so a slot with no
-match burns k junk draft positions — the 0.091-acceptance failure mode
-of the old ``llama1b_spec`` bench leg.  A host table drafts a VARIABLE
+Why HOST-side tables rather than a windowed match inside the jitted
+step: a jitted match must draft a FIXED k every step (static shapes),
+so a slot with no match burns k junk draft positions that count as
+drafted and are accepted only by luck.  A host table drafts a VARIABLE
 span: nothing on a miss (the engine falls back to the plain one-token
 step), and on a hit only as many tokens as the matched continuation
 actually has.  Lookups are O(1) dict hits per step per slot; updates

@@ -40,8 +40,8 @@ def templated_log_corpus(rng: np.random.Generator, n: int, n_rec: int,
     """(n, n_rec·len(template)) int32 sequences of templated "log
     records": fixed template tokens with random field tokens in the -1
     slots — the canonical predictable-text corpus for demonstrating
-    speculative decoding's target regime (and the shared generator for
-    the bench and the tests, so both measure the same distribution)."""
+    speculative decoding's target regime (the tests and the example
+    draw from this one generator)."""
     tpl = _LOG_TEMPLATE if template is None else np.asarray(template)
     rec_len = len(tpl)
     out = np.zeros((n, n_rec * rec_len), np.int32)

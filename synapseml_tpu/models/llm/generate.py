@@ -16,14 +16,14 @@ Sampling: greedy (temperature=0), temperature, top-k, and nucleus
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional, Tuple
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .model import LlamaConfig, LlamaModel, init_cache
+from .model import LlamaModel, init_cache
 
 
 def sample_logits(logits: jnp.ndarray, key: jnp.ndarray,
@@ -164,246 +164,6 @@ def quantize_int8(variables: Any) -> Any:
 
     return {k: (walk(v) if isinstance(v, dict) else v)
             for k, v in variables.items()}
-
-
-def _ngram_draft(ctx: jnp.ndarray, cur_len: jnp.ndarray, draft_len: int,
-                 ngram: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Prompt-lookup drafting: find the latest earlier occurrence of the
-    last ``ngram`` tokens in the context and propose the tokens that
-    followed it.  No draft model — the context itself is the draft source
-    (strong on repetitive/structured text, harmless elsewhere because
-    verification keeps greedy output exact).
-
-    → ``(draft (B, draft_len) int32, vlen (B,) int32)`` where ``vlen``
-    is how many draft positions came from a REAL known continuation —
-    a row with no match (or a match whose continuation is shorter than
-    ``draft_len``) pads with repeats of the last token, which can only
-    be accepted by luck; counting those pads as "drafted" is the
-    accounting bug that reported the old llama1b leg at 0.091
-    acceptance (most of its "drafts" were never predictions at all).
-    Acceptance telemetry divides by ``vlen``, not ``draft_len``."""
-    B, L = ctx.shape
-    iota_l = jnp.arange(L)[None, :]
-    # gathers (take_along_axis) are the TPU pathology — every dynamic
-    # read here is a one-hot contraction instead (measured: the gather
-    # formulation cost several ms/step of the speculative loop's glue)
-    gpos = jnp.maximum(cur_len[:, None] - ngram + jnp.arange(ngram), 0)
-    tail = jnp.einsum("bjl,bl->bj",
-                      (gpos[:, :, None] == iota_l[:, None, :])
-                      .astype(jnp.int32), ctx)          # (B, n)
-    # windows[b, p, j] = ctx[b, p + j] for p in [0, L - ngram]
-    windows = jnp.stack([ctx[:, j:L - ngram + 1 + j] for j in range(ngram)],
-                        axis=-1)                       # (B, L-n+1, n)
-    match = jnp.all(windows == tail[:, None, :], axis=-1)
-    p_idx = jnp.arange(L - ngram + 1)[None, :]
-    # the match must END strictly before the tail and have at least one
-    # known continuation token
-    valid = match & (p_idx + ngram < cur_len[:, None])
-    has = jnp.any(valid, axis=1)
-    p_best = jnp.argmax(jnp.where(valid, p_idx, -1), axis=1)   # latest
-    src = p_best[:, None] + ngram + jnp.arange(draft_len)      # (B, K)
-    # clip unknown continuation positions to the last known token
-    src = jnp.minimum(src, cur_len[:, None] - 1)
-    oh = (src[:, :, None] == iota_l[:, None, :]).astype(jnp.int32)
-    draft = jnp.einsum("bkl,bl->bk", oh, ctx)
-    last = jnp.sum(jnp.where(iota_l == cur_len[:, None] - 1, ctx, 0),
-                   axis=1, keepdims=True)
-    vlen = jnp.where(
-        has,
-        jnp.clip(cur_len - (p_best + ngram), 0, draft_len),
-        0).astype(jnp.int32)
-    return jnp.where(has[:, None], draft,
-                     jnp.broadcast_to(last, draft.shape)
-                     ).astype(jnp.int32), vlen
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "model", "max_new_tokens", "draft_len", "ngram", "eos_id", "pad_id"))
-def _generate_spec_jit(model: LlamaModel, variables: Any,
-                       prompt_ids: jnp.ndarray, max_new_tokens: int,
-                       draft_len: int, ngram: int,
-                       eos_id: Optional[int], pad_id: int):
-    cfg = model.cfg
-    B, P = prompt_ids.shape
-    K = draft_len
-    L = P + max_new_tokens + K + 2        # ctx/cache capacity with slack
-    cache = init_cache(cfg, B, L)
-
-    ctx = jnp.full((B, L), pad_id, jnp.int32).at[:, :P].set(prompt_ids)
-
-    # prefill the prompt minus its last token (the last token is the first
-    # verify block's "input 0" so its K/V lands there)
-    positions = jnp.broadcast_to(jnp.arange(P - 1)[None, :], (B, P - 1))
-    _, cache = model.apply(variables, prompt_ids[:, :-1],
-                           positions=positions, cache=cache, cache_index=0)
-
-    def cond(s):
-        return (~jnp.all(s[2])) & (s[4] < max_new_tokens)
-
-    def body(s):
-        (ctx, cur_len, done, cache, steps, acc, row_steps, drafted,
-         acc_valid) = s
-        draft, vlen = _ngram_draft(ctx, cur_len, K, ngram)      # (B, K)
-        last = jnp.sum(jnp.where(jnp.arange(L)[None, :]
-                                 == cur_len[:, None] - 1, ctx, 0),
-                       axis=1, keepdims=True)
-        inputs = jnp.concatenate([last, draft], axis=1)         # (B, K+1)
-        pos = (cur_len - 1)[:, None] + jnp.arange(K + 1)[None, :]
-        logits, new_cache = model.apply(variables, inputs, positions=pos,
-                                        cache=cache,
-                                        cache_index=cur_len - 1)
-        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # (B, K+1)
-        match = draft == g[:, :K]
-        a = jnp.where(jnp.all(match, axis=1), K,
-                      jnp.argmin(match.astype(jnp.int32), axis=1))  # (B,)
-        n_new = a + 1                            # tokens g[:, 0..a]
-        if eos_id is not None:
-            is_eos = g == eos_id
-            eos_pos = jnp.where(jnp.any(is_eos, axis=1),
-                                jnp.argmax(is_eos, axis=1), K + 1)
-            n_new = jnp.minimum(n_new, eos_pos + 1)
-        n_new = jnp.where(done, 0, n_new)
-        # scatter the accepted tokens g[:, i], i < n_new, at cur_len + i
-        tpos = cur_len[:, None] + jnp.arange(K + 1)[None, :]    # (B, K+1)
-        take = jnp.arange(K + 1)[None, :] < n_new[:, None]
-        oh = (tpos[:, :, None] == jnp.arange(L)[None, None, :]) \
-            & take[:, :, None]                                  # (B,K+1,L)
-        ctx = jnp.where(jnp.any(oh, axis=1), jnp.einsum(
-            "bsl,bs->bl", oh.astype(jnp.int32), g), ctx)
-        if eos_id is not None:
-            done = done | jnp.any((g == eos_id) & take, axis=1)
-        acc = acc + n_new
-        row_steps = row_steps + (n_new > 0).astype(jnp.int32)
-        # honest acceptance accounting: only REAL draft positions
-        # (known continuations, see _ngram_draft's vlen) count as
-        # drafted, and an accepted prefix counts only up to vlen —
-        # lucky matches on pad repeats are free tokens, not draft
-        # skill.  n_new > 0 <=> the row entered this step live (a live
-        # row always commits >= 1 token; a done row is zeroed above)
-        live = (n_new > 0).astype(jnp.int32)
-        drafted = drafted + vlen * live
-        acc_valid = acc_valid + jnp.minimum(a, vlen) * live
-        cur_len = cur_len + n_new
-        # rows that reached their budget are done: keeping them in the
-        # loop would burn full-model forwards and inflate the stats with
-        # tokens the cropped output never shows
-        done = done | (cur_len >= P + max_new_tokens)
-        return (ctx, cur_len, done, new_cache, steps + 1, acc, row_steps,
-                drafted, acc_valid)
-
-    done0 = jnp.zeros(B, bool)
-    state = (ctx, jnp.full((B,), P, jnp.int32), done0, cache,
-             jnp.zeros((), jnp.int32), jnp.zeros((B,), jnp.int32),
-             jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-             jnp.zeros((B,), jnp.int32))
-    (ctx, cur_len, done, cache, steps, acc, row_steps, drafted,
-     acc_valid) = lax.while_loop(cond, body, state)
-    out = ctx[:, P:P + max_new_tokens]
-    # pad everything past each sequence's end (eos freeze)
-    keep = jnp.arange(max_new_tokens)[None, :] < (cur_len - P)[:, None]
-    out = jnp.where(keep, out, pad_id)
-    # pack tokens + stats into ONE array: one blocking host readback per
-    # call instead of one per field
-    packed = jnp.concatenate(
-        [out, acc[:, None], row_steps[:, None],
-         jnp.broadcast_to(steps, (B,))[:, None],
-         drafted[:, None], acc_valid[:, None]], axis=1)
-    return packed
-
-
-def spec_unpack(packed, max_new_tokens: int, draft_len: int = 0):
-    """Host-side unpack of a ``block=False`` speculative result →
-    (tokens (B, max_new_tokens), stats dict) — same stats as the
-    blocking path.  Publishes the acceptance telemetry (see
-    :func:`_record_spec_stats`), so pipelined serving drains report the
-    same metrics as blocking calls.  ``draft_len`` is unused (kept for
-    call-site compatibility): the acceptance denominator is the REAL
-    drafted count packed by the device loop, not the static k.
-
-    ``acceptance_rate`` is accepted-over-DRAFTED: only draft positions
-    backed by a real known continuation count (``_ngram_draft``'s
-    ``vlen``) — the old definition divided committed tokens by the full
-    static ``draft_len`` every step, so no-match steps (which draft
-    nothing real) crushed the rate toward zero (0.091 on the llama1b
-    leg) while saying nothing about draft quality."""
-    packed = np.asarray(packed)
-    out = packed[:, :max_new_tokens]
-    acc = packed[:, max_new_tokens].astype(np.float64)
-    row_steps = np.maximum(packed[:, max_new_tokens + 1].astype(np.float64),
-                           1.0)
-    drafted = packed[:, max_new_tokens + 3].astype(np.float64)
-    acc_valid = packed[:, max_new_tokens + 4].astype(np.float64)
-    tps = float(np.mean(acc / row_steps))
-    stats = {"steps": int(packed[0, max_new_tokens + 2]),
-             "accepted": int(acc.sum()),
-             "drafted": int(drafted.sum()),
-             "tokens_per_step": tps,
-             "acceptance_rate": float(acc_valid.sum())
-             / max(float(drafted.sum()), 1.0)}
-    _record_spec_stats(stats)
-    return out, stats
-
-
-def _record_spec_stats(stats: dict) -> None:
-    """Export speculative-decode acceptance as process metrics — the
-    number ROADMAP item 3 tracks lived only inside bench.py before;
-    with it on /metrics a serving fleet can watch draft quality decay
-    live (e.g. after a model or tokenizer swap)."""
-    from ...telemetry import get_registry
-    reg = get_registry()
-    reg.counter("llm_spec_accepted_tokens_total",
-                "draft tokens accepted by speculative verification").inc(
-        stats["accepted"])
-    reg.counter("llm_spec_verify_steps_total",
-                "speculative verify forwards executed").inc(stats["steps"])
-    reg.gauge("llm_spec_tokens_per_step",
-              "accepted tokens per verify step (last call)").set(
-        stats["tokens_per_step"])
-    reg.gauge("llm_spec_acceptance_rate",
-              "fraction of drafted tokens accepted (last call)").set(
-        stats["acceptance_rate"])
-
-
-def generate_speculative(model: LlamaModel, variables: Any, prompt_ids,
-                         max_new_tokens: int = 32, draft_len: int = 7,
-                         ngram: int = 2, eos_id: Optional[int] = None,
-                         pad_id: int = 0, block: bool = True):
-    """Greedy decode with self-speculative (prompt-lookup) drafting.
-
-    Each loop step verifies ``draft_len`` n-gram-drafted tokens in ONE
-    forward of length draft_len+1.  At small batch the per-token matmuls
-    use only B of the MXU's 128 rows, so a (B, K+1)-token verify costs the
-    same as a single-token step — every accepted draft token is a free
-    extra token.  Output is EXACTLY greedy decoding's (verification
-    accepts a draft token only when it equals the model's argmax), so this
-    is a pure serving-throughput lever, not an approximation.
-
-    Returns (tokens (B, max_new_tokens) int32, stats dict with
-    ``steps``/``accepted``/``tokens_per_step``).
-
-    ``block=False`` instead returns the PACKED on-device
-    (B, max_new_tokens + 5) array without the host readback — serving
-    loops dispatch the next request while this one runs and recover
-    (tokens, stats) later with :func:`spec_unpack`; the blocking
-    readback is paid once per pipeline drain instead of once per call.
-    """
-    prompt_ids = jnp.asarray(prompt_ids, jnp.int32)
-    if prompt_ids.shape[1] < max(ngram, 2):
-        raise ValueError("prompt must be at least ngram tokens long")
-    if max_new_tokens < 1:
-        raise ValueError("max_new_tokens must be >= 1")
-    packed = _generate_spec_jit(
-        model, variables, prompt_ids, int(max_new_tokens), int(draft_len),
-        int(ngram), eos_id, int(pad_id))
-    if not block:
-        # serving loops dispatch the next request while this one runs and
-        # unpack later via :func:`spec_unpack` — the blocking readback
-        # is paid once per pipeline drain, not once per call
-        return packed
-    # per-ROW stat averages (inside spec_unpack): rows finish at
-    # different times, and a finished row must not dilute the rate of
-    # rows still decoding.  ONE readback, not one per field
-    return spec_unpack(packed, int(max_new_tokens), int(draft_len))
 
 
 def generate(model: LlamaModel, variables: Any, prompt_ids,

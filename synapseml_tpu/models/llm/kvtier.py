@@ -285,7 +285,7 @@ class _ArenaEntry:
 class HostKVArena:
     """Byte-budgeted host-RAM LRU of spilled K/V spans, radix-indexed
     by token ids (see module docstring).  Thread-safe: the decode loop
-    spills from its own thread while tests/benches probe from another.
+    spills from its own thread while tests probe from another.
 
     ``put`` accepts per-layer ``{"k", "v"}`` rows of shape
     ``(span, kv_heads, d_head)`` in the cache's native dtype and packs

@@ -42,8 +42,7 @@ pinned ulp-tolerant across spans and tiles (tests/test_llm_paged.py).
 Speed is measured where the hardware is; the byte ledger below
 (:func:`paged_read_bytes` / :func:`dense_read_bytes`) is the kernel's
 exact DMA accounting by construction — it feeds the
-``llm_decode_bytes_per_token`` gauge and bench.py's paired
-``llmserve_decode_roofline_before/after`` blocks.
+``llm_decode_bytes_per_token`` gauge.
 """
 
 from __future__ import annotations
@@ -209,7 +208,7 @@ def resolve_attention_backend(backend: str, *, max_len: int,
                               d_head: int, dtype: Any = jnp.bfloat16,
                               max_query_span: int = 1) -> str:
     """The one parser for ``attention_backend`` (SlotEngine /
-    LLMServer / bench) — returns the RESOLVED backend
+    LLMServer) — returns the RESOLVED backend
     (``'dense'`` | ``'paged'`` | ``'interpret'``) or fails fast with an
     actionable message (the ``resolve_collective_config`` validation
     idiom):
@@ -253,7 +252,7 @@ def resolve_attention_backend(backend: str, *, max_len: int,
 
 
 # ---------------------------------------------------------------------------
-# the byte ledger (exact DMA accounting, shared by telemetry and bench)
+# the byte ledger (exact DMA accounting, for telemetry)
 # ---------------------------------------------------------------------------
 
 def paged_live_tiles(spans, tile: int) -> int:

@@ -158,8 +158,8 @@ def _prefill_slot_jit(model: LlamaModel, variables: Any, cache: Any,
     new_cache = jax.tree.map(
         lambda c, r: lax.dynamic_update_slice_in_dim(c, r, slot, axis=0),
         cache, row)
-    # one-hot extraction: plen is traced, a dynamic gather would be the
-    # TPU pathology (see generate._ngram_draft)
+    # one-hot extraction: plen is traced, and a dynamic gather is slow on
+    # the TPU
     last = jnp.sum(jnp.where((jnp.arange(pb) == plen - 1)[:, None],
                              logits[0], 0.0), axis=0)
     return new_cache, last
@@ -191,9 +191,9 @@ def _decode_step_jit(model: LlamaModel, variables: Any, cache: Any,
     attention: each slot's K/V read covers only its live span instead of
     the full ``max_len`` row (see
     :mod:`~synapseml_tpu.models.llm.pallas_attn`).  ``paged_num_tiles``
-    is accepted and ignored (the engine no longer passes it: the kernel
-    walks live tiles itself, so one program serves every span; the
-    benchmark harness's naming test still does, PERF.md §7)."""
+    is accepted and ignored: the kernel walks live tiles itself, so one
+    program serves every span and the engine passes none (the benchmark
+    harness's naming test does, PERF.md §7)."""
     if prev_nxt is not None:
         tokens = jnp.where(feed_host, tokens, prev_nxt)
     positions = (lengths - 1)[:, None]
@@ -350,10 +350,10 @@ class _Flight:
 class SlotEngine:
     """Continuous-batching decode engine over a slotted KV cache.
 
-    Single-threaded by contract: one serving loop (or bench driver) owns
-    the engine and interleaves :meth:`admit` / :meth:`step` freely — a
-    sequence admitted mid-flight decodes next to longer-running
-    neighbors in the same jitted step.  Greedy output is token-exact
+    Single-threaded by contract: one serving loop owns the engine and
+    interleaves :meth:`admit` / :meth:`step` freely — a sequence
+    admitted mid-flight decodes next to longer-running neighbors in
+    the same jitted step.  Greedy output is token-exact
     with the dense-cache ``generate`` path.  The public state
     (``active``, ``lengths``, ``ctx``, ``kv_len``) is what the returned
     events have made true; a step already handed to the device is not
@@ -368,7 +368,7 @@ class SlotEngine:
                  pad_id: int = 0, min_prefix: int = 8,
                  min_bucket: Optional[int] = None, seed: int = 0,
                  name: str = "llm",
-                 attention_backend: str = "auto", step_profiler=None,
+                 attention_backend: str = "auto",
                  spec_draft_len: int = 0, spec_ngram: int = 3,
                  spec_adapt: bool = True, trace_sink=None,
                  warmup: str = "off", kv_arena=None):
@@ -424,10 +424,6 @@ class SlotEngine:
         if self._paged_geo is not None:
             self._paged_geo = self._consult_paged_tile(
                 spec_span, self._paged_geo)
-        #: optional telemetry.gangplane.StepProfiler — decode steps run
-        #: under step/mark and (capture_xla) the per-bucket step program
-        #: goes through capture_cost for the roofline gauges
-        self.step_profiler = step_profiler
         #: optional request-trace hook ``sink(slot, event, **attrs)`` —
         #: the serving loop installs one mapping slots to trace ids, and
         #: the engine reports per-slot step outcomes through it
@@ -446,8 +442,8 @@ class SlotEngine:
         self.min_prefix = max(1, int(min_prefix))
         self.name = name
         # speculative decoding: n-gram self-drafts verified in a
-        # multi-token step (spec_draft_len == 0 keeps the engine on the
-        # plain one-token step — the pre-spec behavior exactly)
+        # multi-token step (spec_draft_len == 0: every step is the plain
+        # one-token step)
         self.spec_draft_len = max(0, int(spec_draft_len))
         self.spec_adapt = bool(spec_adapt)
         if self.spec_draft_len and self.temperature > 0:
@@ -464,7 +460,7 @@ class SlotEngine:
         # O(log max_len) programs however ragged the traffic.  The grid
         # floor defaults to 8; an explicit min_bucket wins outright, and
         # the None sentinel consults the ``llm_bucket_grid`` tuning
-        # table (absent/mismatched table → 8, the HEAD-identical grid)
+        # table (absent/mismatched table → 8)
         if min_bucket is None:
             min_bucket = self._consult_min_bucket()
         buckets = []
@@ -593,13 +589,13 @@ class SlotEngine:
         self.prefix_tokens_reused = 0
         self.prefix_reuse_skipped = 0
         self.tokens_generated = 0
-        # the compile plane (ISSUE 15): 'sync' blocks construction until
-        # the full program lattice — every prefill bucket, decode span
-        # bucket, (S, span) verify pair, and the prefix copy — is
-        # AOT-compiled; 'background' warms on a daemon thread (serve
-        # readiness through compile_plane.is_warm / the LLMServer
-        # /readyz gate); 'off' keeps the pre-plane lazy-compile
-        # behavior exactly.  Programs are warmed through the REAL
+        # the compile plane: 'sync' blocks construction until the full
+        # program lattice — every prefill bucket, the decode step, every
+        # verify width, and the prefix copy — is AOT-compiled;
+        # 'background' warms on a daemon thread (serve readiness through
+        # compile_plane.is_warm / the LLMServer /readyz gate); 'off'
+        # compiles each program at its first call.  Programs are warmed
+        # through the REAL
         # jitted entry points against scratch state, so the first
         # serving hit is a dispatch-cache hit, not a compile.
         if warmup in (None, False):
@@ -616,15 +612,15 @@ class SlotEngine:
             self.compile_plane = CompilePlane(self, name=name)
             self.compile_plane.start(background=(warmup == "background"))
         #: cumulative decode-attention K/V bytes (the ledger feeding the
-        #: gauge above; bench reads it for the paired roofline block)
+        #: ``llm_decode_bytes_per_token`` gauge)
         self.decode_attn_bytes = 0
         #: the last accounted step's tile counts (paged backends), for
         #: the ``engine.step`` span
         self._step_tiles: Dict[str, int] = {}
-        #: speculative-decode accounting (bench's llmserve_spec_* /
-        #: llama1b_spec_* fields read these): steps_run counts EVERY
-        #: engine step (plain or verify), spec_* only drafted work;
-        #: steps_overlapped those of them dispatched a step ahead
+        #: step accounting: steps_run counts EVERY engine step (plain
+        #: or verify), spec_* only drafted work
+        #: (``tokens_per_step_estimate`` reads them); steps_overlapped
+        #: those of them dispatched a step ahead
         self.steps_run = 0
         self.steps_overlapped = 0
         self.spec_steps = 0
@@ -1280,13 +1276,12 @@ class SlotEngine:
         return flight.slots & self.active & (flight.epoch == self._epoch)
 
     def _dispatch(self, prev: Optional[_Flight]) -> Optional[_Flight]:
-        """Hand the device one one-token step (the pre-spec decode
-        path).  ``prev`` is the step before it where that one has not
-        been read: its live slots feed from its output on the device
-        at their next position, but for those that reach their budget
-        in it; every other active slot (admitted, resumed or restored
-        since) feeds the host's pending token.  None when no slot would
-        be active."""
+        """Hand the device one one-token step.  ``prev`` is the step
+        before it where that one has not been read: its live slots feed
+        from its output on the device at their next position, but for
+        those that reach their budget in it; every other active slot
+        (admitted, resumed or restored since) feeds the host's pending
+        token.  None when no slot would be active."""
         with step_span("engine.step.prepare"):
             # host arrays, their uploads and the dispatch (asynchronous:
             # what is timed there is the enqueue)
@@ -1304,20 +1299,6 @@ class SlotEngine:
                               self.pad_id).astype(np.int32)
             prev_nxt = self._no_prev if prev is None else prev.nxt
             program = _decode_program_key(self.attention_backend)
-            prof = self.step_profiler
-            if prof is not None:
-                if getattr(prof, "capture_xla", False):
-                    prof.capture_cost(
-                        f"llm_decode_step_{self.attention_backend}",
-                        _decode_step_jit, self.model, self.variables,
-                        self.cache, jnp.asarray(tokens),
-                        jnp.asarray(lengths.astype(np.int32)),
-                        jnp.asarray(active), self._key,
-                        self.temperature, self.top_k, self.top_p,
-                        prev_nxt=prev_nxt, feed_host=jnp.asarray(~carried),
-                        items=float(active.sum()), **kw)
-                if prev is None:    # else its step runs on from prev's read
-                    prof.step_begin()
             with step_span("engine.step.prepare.upload"):
                 step_in = (jnp.asarray(tokens),
                            jnp.asarray(lengths.astype(np.int32)),
@@ -1332,22 +1313,16 @@ class SlotEngine:
             return _Flight(nxt, active, self._epoch.copy(), lengths, program)
 
     def _plain_step(self) -> List[StepEvent]:
-        """The one-token step (the pre-spec decode path): make sure it
-        is dispatched, dispatch the one after it where the engine may
-        (no drafter waits for this step's tokens), then read its tokens
-        and make them the engine's state."""
+        """The one-token step: make sure it is dispatched, dispatch the
+        one after it where the engine may (no drafter waits for this
+        step's tokens), then read its tokens and make them the engine's
+        state."""
         overlapped = self._flight is not None   # only a step ahead waits there
         flight = self._flight or self._dispatch(None)
         self._flight = (self._dispatch(flight) if self._drafter is None
                         else None)
         with step_span("engine.step.wait"):
             nxt = np.asarray(flight.nxt)      # the step's one blocking call
-        prof = self.step_profiler
-        if prof is not None:
-            prof.mark("compute")      # np.asarray synchronized the step
-            prof.step_end()
-            if self._flight is not None:
-                prof.step_begin()     # the next one is already running
         with step_span("engine.step.commit"):
             self.last_program = flight.program
             if overlapped:
@@ -1447,17 +1422,6 @@ class SlotEngine:
                 d = d[:S - 1]
                 tokens[slot, 1:1 + len(d)] = d
                 klen[slot] = len(d)
-            prof = self.step_profiler
-            if prof is not None:
-                if getattr(prof, "capture_xla", False):
-                    prof.capture_cost(
-                        f"llm_verify_step_{self.attention_backend}_s{S}",
-                        _verify_step_jit, self.model, self.variables,
-                        self.cache, jnp.asarray(tokens),
-                        jnp.asarray(lengths.astype(np.int32)),
-                        jnp.asarray(self.active),
-                        items=float(self.active_count), **kw)
-                prof.step_begin()
             self.last_program = _verify_program_key(
                 self.attention_backend, S)
             with step_span("engine.step.prepare.upload"):
@@ -1470,9 +1434,6 @@ class SlotEngine:
                     self.model, self.variables, self.cache, *step_in, **kw)
         with step_span("engine.step.wait"):
             g = np.asarray(g)         # the step's one blocking call
-        if prof is not None:
-            prof.mark("compute")      # np.asarray synchronized the step
-            prof.step_end()
         with step_span("engine.step.commit"):
             return self._finish_step(
                 self._commit_verified(tokens, g, klen, lengths, S))

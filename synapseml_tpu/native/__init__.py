@@ -225,9 +225,8 @@ def read_colstore(path: str) -> np.ndarray:
         elif version == 2:
             # v2 stores bf16 bit patterns (io.colstore.write_matrix
             # dtype="bf16"); upcast exactly like ChunkedColumnSource
-            from ..io.colstore import bf16_bits_to_f32
-            data = bf16_bits_to_f32(
-                np.frombuffer(f.read(rows * cols * 2), np.uint16))
+            bits = np.frombuffer(f.read(rows * cols * 2), np.uint16)
+            data = (bits.astype(np.uint32) << 16).view(np.float32)
         else:
             raise IOError(f"{path}: unknown SMLC version {version}")
     return data.reshape(cols, rows).T

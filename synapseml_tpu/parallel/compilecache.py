@@ -2,7 +2,7 @@
 
 Where the cache lives is decided once, in ``synapseml_tpu/__init__.py``
 (``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``);
-gang workers and bench children inherit it through the environment.  This
+gang workers and other children inherit it through the environment.  This
 module only *reads* it (:func:`compilation_cache_dir`) and attributes what
 the compiler does (the serving-side lattice warmup lives in
 :mod:`synapseml_tpu.models.llm.warmup`; the DL/GBDT training steps are
@@ -52,8 +52,8 @@ _lock = threading.Lock()
 _listeners_installed = False
 #: thread-local compile attribution label (see :func:`compile_label`)
 _tls = threading.local()
-#: process-wide raw tallies, readable without the registry (the bench
-#: children and the gang cache-reuse pin read these)
+#: process-wide raw tallies, readable without the registry (the gang
+#: cache-reuse pin reads these)
 _counts = {"compiles": 0, "cache_hits": 0, "cache_misses": 0}
 
 

@@ -1,10 +1,10 @@
 """Compressed + sharded collectives: quantized allreduce with error
 feedback, behind the :mod:`~synapseml_tpu.parallel.collectives` dispatch.
 
-BENCH_r05 put the f32 gradient allreduce at the top of the BERT
-fine-tune StepProfiler decomposition and GBDT's per-iteration histogram
-psum is pure bandwidth — both move 4 bytes per value when far fewer
-carry the signal.  This module implements the two levers:
+The f32 gradient allreduce of a data-parallel fine-tune and GBDT's
+per-iteration histogram psum are pure bandwidth — both move 4 bytes per
+value when far fewer carry the signal.  This module implements the two
+levers:
 
 - **Quantized allreduce codecs** (EQuARX, arXiv:2506.17615): ``bf16``
   (cast, reduce in bf16, cast back — 2x wire) and ``int8`` (chunked
@@ -80,9 +80,8 @@ class CollectiveConfig:
     #: force the manual data-parallel shard_map step even with
     #: ``compression='none'`` — a measurement pin, not a perf knob: a
     #: compressed-vs-f32 pair where the f32 leg rides pjit would
-    #: conflate the codec with the execution-mode change, so the bench
-    #: pins BOTH legs to the manual mode (the bench_obs_overhead
-    #: same-dispatch-mode methodology)
+    #: conflate the codec with the execution-mode change, so such a
+    #: pair pins BOTH legs to the manual mode
     manual: bool = False
     #: reduction ROUTE (:mod:`~synapseml_tpu.parallel.planner`):
     #: 'auto' (default — per-payload planner choice; resolves 'flat'

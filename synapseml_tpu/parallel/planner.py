@@ -17,8 +17,8 @@ from payload bytes × world size × link class, behind the existing
 Honesty contract (the roofline spec-table pattern): the ``auto``
 decision table only routes away from ``flat`` when the topology is
 actually KNOWN — device mesh coords discovered from the backend, or an
-explicitly injected :class:`TopologySpec` (CPU-container tests and
-bench).  An unknown topology plans ``flat``, byte-identical to the
+explicitly injected :class:`TopologySpec` (CPU-container tests).  An
+unknown topology plans ``flat``, byte-identical to the
 pre-planner dispatch; nothing is fabricated.
 
 Plans bind at TRACE time (the planner runs while jit traces, like the
@@ -38,7 +38,7 @@ all: forced strategies, single rank, unknown topology),
 ``plan_decide``/``plan_invalidate`` flight events, the
 ``collective_wire_bytes_total{op,axis,codec,strategy}`` strategy label,
 and the StepProfiler collective segment split by strategy — every
-routing choice is attributable in /metrics, flight rings and bench.
+routing choice is attributable in /metrics and flight rings.
 """
 
 from __future__ import annotations
@@ -70,7 +70,7 @@ TREE_CUTOFF_BYTES = 256 << 10
 PLANNER_METRICS = frozenset({"collective_plans_total"})
 
 #: aggregate per-chip ICI bytes/s by device kind (public spec sheets) —
-#: carried on discovered specs for bench/telemetry context and link-class
+#: carried on discovered specs for telemetry context and link-class
 #: RANKING only (the decision table is structural); absent kinds stay
 #: None: unknown backend ⇒ claim nothing (telemetry.roofline pattern)
 CHIP_ICI_BW = {
@@ -89,7 +89,7 @@ class TopologySpec:
     like a jit static.  ``source='discovered'`` specs are built from the
     live :func:`~synapseml_tpu.parallel.topology.get_topology` snapshot;
     ``'injected'`` specs are explicit overrides (CPU-container tests,
-    bench synthetic topologies) and are always trusted.
+    synthetic topologies) and are always trusted.
     """
     n_hosts: int = 1
     devices_per_host: int = 1
@@ -431,8 +431,8 @@ def _record_routed(op: str, axis, x, plan: "ReductionPlan",
     plain calls/logical series plus the strategy-labeled wire series at
     the bytes the ROUTE really ships (:meth:`ReductionPlan.wire_nbytes`
     — codec='none' routes report wire == logical, hierarchical counts
-    its intra-host f32 legs), so the per-strategy wire histogram in
-    bench covers uncompressed routes too.  Telemetry must never break a
+    its intra-host f32 legs), so the per-strategy wire series covers
+    uncompressed routes too.  Telemetry must never break a
     trace."""
     try:
         from .collectives import _record

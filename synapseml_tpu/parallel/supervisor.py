@@ -26,8 +26,7 @@ Horovod-elastic / TPU-pod style (preemption is the common case):
   FRESH coordinator port.  A ``checkpoint_dir`` threads through to every
   worker (``SMLTPU_CKPT_DIR``), so trainers that checkpoint (GBDT/DL)
   resume from the last *complete* step — a retry costs seconds, not the
-  job.  ``last_recovery_s`` clocks kill-to-resumed-step wall time (the
-  ``bench_gang_recovery`` probe's number).
+  job.  ``last_recovery_s`` clocks kill-to-resumed-step wall time.
 
 - **Elastic resize** (this PR): a permanently lost rank no longer kills
   the job.  With ``min_ranks`` set, repeated failure of the same rank

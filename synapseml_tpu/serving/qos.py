@@ -167,7 +167,7 @@ class QosScheduler:
         self._last_total = 0
         self._budgets: Dict[str, Optional[_ClockedBudget]] = {}
         self._last_preempt = float("-inf")
-        #: total preemption verdicts issued (the bench reads this)
+        #: total preemption verdicts issued
         self.preemptions = 0
         #: total budget sheds by tenant (attribution beside the metric)
         self.budget_sheds: Dict[str, int] = {}
@@ -275,8 +275,8 @@ class QosScheduler:
             return self._committed.get(tenant, 0)
 
     def committed_share(self) -> Dict[str, float]:
-        """Each tenant's fraction of all committed tokens — the
-        weighted-fairness convergence surface the bench pins."""
+        """Each tenant's fraction of all committed tokens: under
+        saturation it converges to the tenants' weight shares."""
         with self._lock:
             total = sum(self._committed.values())
             if not total:

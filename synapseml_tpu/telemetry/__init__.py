@@ -1,5 +1,5 @@
 """Unified telemetry: metrics registry, span tracing, exposition, and
-atomic bench artifacts.
+atomic JSON artifacts.
 
 The observability spine of the TPU-native stack — the analogue (and
 superset) of the reference's ``SynapseMLLogging`` structured verb
@@ -13,16 +13,16 @@ telemetry plus ``LightGBMPerformance.scala`` phase measures:
 - :mod:`.exposition` — Prometheus text + JSON rendering; served by
   ``ServingServer`` at ``GET /metrics``.
 - :mod:`.artifact` — atomic, round-trip-verified JSON artifact writes
-  (``write_json``), used by ``bench.py`` so a truncated ``BENCH_*.json``
-  cannot recur.
+  (``write_json``): the supervisor's bundles, the flight recorder's
+  dumps and the tuning table are written through it, so a reader never
+  sees a truncated file.
 - :mod:`.flight` — the crash flight recorder: a bounded,
   allocation-stable ring of structured events (collectives, checkpoint
   publishes, backoffs, fault firings, heartbeats, rowguard verdicts),
   dumped SIGKILL-atomically for post-mortem bundles.
 - :mod:`.roofline` — the roofline auditor: XLA-captured bytes/flops +
-  top byte-moving HLOs for any jitted step, and the canonical paired
-  before/after roofline block every perf change lands with in
-  ``BENCH_latest.json`` (ROADMAP item 4's standing requirement).
+  top byte-moving HLOs for any jitted step, and the chip peak tables
+  behind the ``StepProfiler`` gauges.
 - :mod:`.gangplane` — the gang-wide observability plane: cross-rank
   metric/span export over the ``SMLMP_TM:`` wire, ``worker_*{rank=}``
   mirroring into the coordinator's ``/metrics``, multi-lane Chrome-trace
@@ -76,8 +76,6 @@ from .gangplane import (GangPlane, StepProfiler, TM_MARKER,
 from .registry import (DEFAULT_BUCKETS, SERVING_TOKEN_LATENCY_BUCKETS,
                        SERVING_TTFT_BUCKETS, Counter, Gauge, Histogram,
                        MetricsRegistry, bucket_quantile, get_registry)
-from .roofline import (ROOFLINE_BLOCK_KEYS, check_roofline_block,
-                       paired_roofline, roofline_block)
 from .slo import (SLO_METRICS, SLOZ_SCHEMA, SLOZ_SCHEMA_VERSION, SloStore,
                   SloWindow, WindowedCounter, WindowedHistogram, check_sloz,
                   get_slo_store, plane_tenant, tenant_plane_name)
@@ -102,8 +100,6 @@ __all__ = [
     "FlightRecorder", "get_flight",
     "GangPlane", "StepProfiler", "TM_MARKER", "check_postmortem",
     "parse_telemetry", "write_postmortem",
-    "ROOFLINE_BLOCK_KEYS", "check_roofline_block", "paired_roofline",
-    "roofline_block",
     "AUTOTUNE_METRICS", "Autotuner", "CollectiveCostModel", "TuneSpace",
     "fit_alpha_beta", "register_space", "registered_spaces",
     "resolve_entry_point",

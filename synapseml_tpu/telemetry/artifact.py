@@ -1,9 +1,9 @@
 """Atomic, schema-checked JSON artifact IO.
 
-Round 5's bench artifact shipped truncated (``BENCH_r05.json`` carried a
-cut-off stdout tail and ``"parsed": null``), losing the headline number.
-This module makes that class of loss structurally impossible for
-anything written through it:
+A record cut off mid-write loses what it was written for.  This module
+makes that class of loss structurally impossible for anything written
+through it (the supervisor's bundles, the flight recorder's dumps, the
+tuning table):
 
 - ``write_json`` serializes, **round-trip parses the serialized text**,
   writes to a temp file in the TARGET directory, ``fsync``\\ s, then

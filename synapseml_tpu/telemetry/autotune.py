@@ -108,8 +108,8 @@ class TuneSpace:
     this backend — the harness claims nothing.
 
     ``ctx`` parameterizes the geometry (a test tunes the exact tiny
-    geometry its engine will consult with; the bench uses the
-    representative defaults).
+    geometry its engine will consult with; the defaults are
+    representative ones).
     """
     name: str
     entry_point: str

@@ -66,7 +66,7 @@ class FlightRecorder:
     """Fixed-capacity ring of ``(seq, ts, kind, fields)`` events.
 
     Thread-safe; ``enabled=False`` turns :meth:`record` into a single
-    attribute read (the bench's paired off leg).  ``seq`` is a
+    attribute read.  ``seq`` is a
     monotonically increasing per-recorder counter, so consumers (the
     gang wire, the post-mortem gather) can express "events since" and
     compare the freshness of two tails of the same rank.

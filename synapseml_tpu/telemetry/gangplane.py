@@ -502,9 +502,9 @@ def observe_collective(seconds: float, nbytes: int = 0,
     """Collective-dispatch hook: attributes host-observed collective
     time to the open step's ``collective`` segment, split by the
     planner route that dispatched it (``strategy`` — 'flat' for the
-    direct dispatch), so bench pairs isolate routing from codec
-    effects.  Called by ``parallel.collectives``; free when no step is
-    open."""
+    direct dispatch), so a flat-vs-planned pair isolates routing from
+    codec effects.  Called by ``parallel.collectives``; free when no
+    step is open."""
     prof = getattr(_active, "profiler", None)
     if prof is not None:
         prof._note_collective(seconds, nbytes, strategy=strategy)
@@ -544,9 +544,8 @@ class StepProfiler:
     @staticmethod
     def measure(legs, *, blocks: int = 3, pairs: int = 6,
                 timer: Callable[[], float] = time.perf_counter):
-        """The bench's alternating min-of-blocks timing protocol as a
-        library call (it had grown three hand-rolled copies in bench.py;
-        the autotuner is its fourth caller).
+        """The alternating min-of-blocks timing protocol (the autotuner
+        times its candidates through it).
 
         Two shapes of ``legs``:
 
@@ -640,8 +639,8 @@ class StepProfiler:
         self.collective_bytes = 0
         #: hook-fed collective seconds by planner route ('flat' = the
         #: direct dispatch) — the strategy split of the collective
-        #: segment, so a flat-vs-planned bench pair attributes its
-        #: delta to routing rather than codec
+        #: segment, so a flat-vs-planned pair attributes its delta to
+        #: routing rather than codec
         self.collective_by_strategy: Dict[str, float] = {}
         self.costs: Dict[str, Optional[Dict[str, float]]] = {}
         #: per-device items (samples/rows) one step processes, by capture
@@ -758,11 +757,10 @@ class StepProfiler:
         the per-device sample (or row) count one step processes — when
         given, :meth:`summary` also exports the
         ``train_step_bytes_per_sample`` / ``train_step_mfu`` gauges so
-        byte regressions surface in live ``/metrics``, not just bench
-        runs.  Triggers an AOT compile, so call it at most once per
-        compiled fn and only when roofline numbers are wanted
-        (``capture_xla=True`` callers); any failure records None and
-        never propagates."""
+        byte regressions surface in live ``/metrics``.  Triggers an AOT
+        compile, so call it at most once per compiled fn and only when
+        roofline numbers are wanted (``capture_xla=True`` callers); any
+        failure records None and never propagates."""
         if key in self.costs:
             return self.costs[key]
         from . import roofline as _roofline
@@ -804,8 +802,8 @@ class StepProfiler:
                 "bytes_per_sample": (cost["bytes_accessed"] / items
                                      if items else None),
             }
-            # live-telemetry export (the bench-independent view of byte
-            # regressions); telemetry must never break the summary
+            # live-telemetry export (byte regressions on /metrics);
+            # telemetry must never break the summary
             try:
                 if items and cost["bytes_accessed"]:
                     self._g_bytes.set(cost["bytes_accessed"] / items,
@@ -827,7 +825,6 @@ class StepProfiler:
                 "roofline": roofline, "last_steps": tail[-16:]}
 
     def export(self, path: str) -> Dict[str, Any]:
-        """Atomically write :meth:`summary` (the reusable form of
-        bench.py's hand-rolled round-5 step decomposition)."""
+        """Atomically write :meth:`summary`."""
         return write_json(path, _sanitize(self.summary()),
                           schema=("model", "steps", "seconds"))

@@ -1,42 +1,22 @@
 """Roofline auditor: XLA-captured bytes/flops for any jitted step.
 
-ROADMAP item 4's standing requirement is that every perf change lands
-with a before/after roofline block in ``BENCH_latest.json``.  This module
-is the ONE implementation behind those blocks:
-
 - :func:`capture` — AOT-lower + compile a jitted callable and record
   XLA's own cost analysis (flops, bytes accessed) plus the top
   byte-moving HLOs estimated from the optimized module's result shapes
   (the "where do the bytes go" answer ``cost_analysis`` alone cannot
-  give).
-- :func:`roofline_block` — turn (bytes/sample, flops/sample, measured
-  ms) into the canonical paired-block schema: ``bytes_per_sample`` /
-  ``flops_per_sample`` / ``compute_ms`` / ``bandwidth_ms`` /
-  ``measured_ms`` / ``frac_of_bandwidth_roofline``, every field numeric
-  or null.  Compute/bandwidth bounds come from the per-device-kind spec
-  tables below; on a backend with no table entry (e.g. the CPU
-  container) they are null — byte reductions are still proven by the
-  XLA-captured bytes, but no bandwidth-roofline claim is fabricated
-  (the PR-6/PR-8 measurement-honesty pattern).
-- :func:`paired_roofline` — the ``{leg}_roofline_before`` /
-  ``{leg}_roofline_after`` dict bench.py merges into its record; the
-  tier-1 artifact schema check (tests/test_artifacts_json.py) holds any
-  record carrying one side of a pair to the full two-sided block.
-
-The chip spec tables live HERE (bench.py imports them) so the auditor,
-the StepProfiler gauges and the bench can never disagree on a peak.
+  give).  :func:`capture_compiled` does the same for an executable the
+  caller already holds.
+- the chip spec tables (``CHIP_PEAK_FLOPS``, ``CHIP_HBM_BW``) with
+  :func:`chip_peak_flops` / :func:`chip_hbm_bw`: peaks by device kind
+  for the ``StepProfiler`` gauges and the collective planner; a backend
+  with no table entry (e.g. the CPU container) gets None, so no roofline
+  share is made up where the bound is unknown.
 """
 
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, List, Optional, Sequence, Tuple
-
-#: the canonical paired-block field set — schema-checked in tier-1
-ROOFLINE_BLOCK_KEYS = (
-    "bytes_per_sample", "flops_per_sample", "compute_ms", "bandwidth_ms",
-    "measured_ms", "frac_of_bandwidth_roofline",
-)
+from typing import Any, Dict, List, Optional
 
 #: peak dense bf16 FLOPs/s by device kind (public spec sheets)
 CHIP_PEAK_FLOPS = {
@@ -143,15 +123,15 @@ def top_byte_hlos(hlo_text: str, k: int = 8) -> List[Dict[str, Any]]:
 
 
 # ---------------------------------------------------------------------------
-# capture + blocks
+# capture
 # ---------------------------------------------------------------------------
 
 def capture_compiled(compiled, top_k: int = 8) -> Optional[Dict[str, Any]]:
     """Cost entry of an ALREADY-compiled executable: ``{"flops",
     "bytes_accessed", "top_hlos"}`` or None.  The one cost_analysis
     parser — callers that keep their Compiled object to execute it
-    (bench legs) share it with :func:`capture` instead of re-deriving
-    the dict shape."""
+    share it with :func:`capture` instead of re-deriving the dict
+    shape."""
     try:
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):
@@ -180,85 +160,3 @@ def capture(fn, *args, top_k: int = 8, **kw) -> Optional[Dict[str, Any]]:
                                 top_k=top_k)
     except Exception:
         return None
-
-
-def roofline_block(bytes_per_sample: Optional[float],
-                   flops_per_sample: Optional[float],
-                   measured_ms: Optional[float],
-                   device=None,
-                   samples: float = 1.0) -> Dict[str, Optional[float]]:
-    """The canonical 6-key block for one leg/config.
-
-    ``measured_ms`` is the measured wall time of ``samples`` samples
-    (one step, usually); compute/bandwidth bounds are for the same
-    ``samples`` against the device's spec-sheet peaks — null on a
-    backend with no table entry, so no roofline fraction is invented
-    where the bound is unknown."""
-    peak = chip_peak_flops(device) if device is not None else None
-    bw = chip_hbm_bw(device) if device is not None else None
-    compute_ms = (samples * flops_per_sample / peak * 1e3
-                  if peak and flops_per_sample else None)
-    bandwidth_ms = (samples * bytes_per_sample / bw * 1e3
-                    if bw and bytes_per_sample else None)
-    frac = (bandwidth_ms / measured_ms
-            if bandwidth_ms and measured_ms else None)
-    return {
-        "bytes_per_sample": bytes_per_sample,
-        "flops_per_sample": flops_per_sample,
-        "compute_ms": compute_ms,
-        "bandwidth_ms": bandwidth_ms,
-        "measured_ms": measured_ms,
-        "frac_of_bandwidth_roofline": frac,
-    }
-
-
-def check_roofline_block(block: Any) -> None:
-    """Schema guard shared with tests/test_artifacts_json.py: a paired
-    roofline block is a dict carrying EXACTLY the canonical keys, each
-    numeric or null."""
-    if not isinstance(block, dict):
-        raise ValueError(f"roofline block must be a dict, got "
-                         f"{type(block).__name__}")
-    missing = [key for key in ROOFLINE_BLOCK_KEYS if key not in block]
-    if missing:
-        raise ValueError(f"roofline block missing keys {missing}")
-    bad = [key for key, v in block.items()
-           if v is not None and not isinstance(v, (int, float))]
-    if bad:
-        raise ValueError(f"roofline block non-numeric fields {bad}")
-
-
-def paired_roofline(leg: str, before: Dict[str, Optional[float]],
-                    after: Dict[str, Optional[float]]) -> Dict[str, Any]:
-    """``{leg}_roofline_before`` / ``{leg}_roofline_after`` pair, both
-    sides schema-checked before they can enter a bench record."""
-    check_roofline_block(before)
-    check_roofline_block(after)
-    return {f"{leg}_roofline_before": dict(before),
-            f"{leg}_roofline_after": dict(after)}
-
-
-def audit(key: str, fn, *args, samples: float = 1.0,
-          measured_ms: Optional[float] = None, device=None,
-          **kw) -> Optional[Dict[str, Any]]:
-    """One-call wrap of any jitted step: capture its compiled cost and
-    produce the per-sample roofline block plus the top byte movers.
-
-    → ``{"key", "bytes_per_sample", "flops_per_sample",
-    "arithmetic_intensity", "block", "top_hlos"}`` or None when the
-    backend exposes no cost analysis."""
-    cost = capture(fn, *args, **kw)
-    if cost is None or not cost.get("bytes_accessed"):
-        return None
-    bps = cost["bytes_accessed"] / max(samples, 1e-9)
-    fps = cost["flops"] / max(samples, 1e-9)
-    return {
-        "key": key,
-        "bytes_per_sample": bps,
-        "flops_per_sample": fps,
-        "arithmetic_intensity": (cost["flops"] / cost["bytes_accessed"]
-                                 if cost["bytes_accessed"] else None),
-        "block": roofline_block(bps, fps, measured_ms, device=device,
-                                samples=samples),
-        "top_hlos": cost.get("top_hlos", []),
-    }

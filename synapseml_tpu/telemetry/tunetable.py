@@ -401,8 +401,8 @@ def get_tuneplane() -> TunePlane:
 
 
 def set_tuneplane(plane: Optional[TunePlane]) -> Optional[TunePlane]:
-    """Swap the process-default plane (tests, the bench) → the previous
-    one.  ``None`` unpins and reverts to env resolution."""
+    """Swap the process-default plane (tests) → the previous one.
+    ``None`` unpins and reverts to env resolution."""
     global _plane, _plane_pinned
     with _plane_lock:
         prev = _plane

@@ -102,7 +102,7 @@ SLOW_CLASS_TESTS = {
 #: before the cap instead of burning the budget on the heavy GBDT
 #: modules mid-alphabet.  Unlisted modules default to mid-weight.
 MODULE_COST_S = {
-    "test_plot": 1, "test_artifacts_json": 1, "test_automl": 1,
+    "test_plot": 1, "test_automl": 1,
     "test_native": 1, "test_batchers": 1, "test_services": 1,
     "test_exploratory_iforest": 1, "test_parallel": 1, "test_codegen": 1,
     "test_recommendation": 1, "test_nn": 2, "test_cyber": 2,

@@ -173,14 +173,6 @@ class TestMeasureProtocol:
         with pytest.raises(TypeError):
             StepProfiler.measure((lambda: None,))
 
-    def test_bench_legs_ride_the_library_protocol(self):
-        """Satellite: bench.py's hand-rolled timing copies are gone —
-        the paired overhead legs, the codec comparison, and the
-        autotune leg all route through ``StepProfiler.measure``."""
-        src = open(os.path.join(REPO, "bench.py"), encoding="utf-8").read()
-        assert src.count("StepProfiler.measure(") >= 4
-        assert '"autotune"' in src.split("BENCH_LEGS")[1][:600]
-
 
 # ---------------------------------------------------------------------------
 # the tuning table — round-trip, honesty, atomicity

@@ -482,23 +482,3 @@ class TestByteLedger:
         assert g.value(engine="t-paged", backend="interpret") > 0
         assert g.value(engine="t-dense", backend="dense") \
             > g.value(engine="t-paged", backend="interpret")
-
-    def test_step_profiler_captures_decode_cost(self, tiny_model):
-        """The telemetry satellite: a capture_xla StepProfiler handed to
-        the engine records the decode step's XLA cost analysis under a
-        per-bucket key and times the step's compute segment."""
-        from synapseml_tpu.telemetry.gangplane import StepProfiler
-        cfg, model, variables = tiny_model
-        prof = StepProfiler("llm_decode_test", capture_xla=True)
-        eng = SlotEngine(model, variables, n_slots=2, max_len=64,
-                         attention_backend="dense", step_profiler=prof)
-        eng.admit(_prompts(cfg, 1, 6, seed=14)[0], 4)
-        eng.run_to_completion()
-        s = prof.summary()
-        assert s["steps"] >= 3
-        assert s["per_step_avg_seconds"]["compute"] > 0
-        keys = [k for k in s["roofline"] if k.startswith("llm_decode_step")]
-        assert keys, s["roofline"]
-        cost = s["roofline"][keys[0]]
-        assert cost and cost["bytes_accessed"] > 0
-        assert cost["bytes_per_sample"] and cost["bytes_per_sample"] > 0

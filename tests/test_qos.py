@@ -171,6 +171,28 @@ def test_weighted_interleave_within_one_class():
         ["b0", "b1", "b2", "b3"]
 
 
+def test_saturated_weighted_pair_converges_to_weight_shares():
+    """A 3:1 weight pair whose backlogs never empty: one slot frees a
+    round, the head of the round's order takes it and is charged its
+    committed tokens.  The committed split converges to 0.75/0.25 and
+    the weight-normalized Jain index to 1."""
+    q = QosScheduler(policies={"heavy": TenantPolicy(weight=3.0),
+                               "light": TenantPolicy(weight=1.0)},
+                     quantum_tokens=8.0, clock=FakeClock())
+    backlog = [_item(t, max_new=8) for t in ("heavy", "light")
+               for _ in range(3)]
+    for _ in range(400):
+        pick = q.admission_order(backlog)[0]
+        backlog.remove(pick)
+        q.charge(pick.tenant, pick.max_new)
+        backlog.append(_item(pick.tenant, max_new=8))
+    share = q.committed_share()
+    assert share["heavy"] == pytest.approx(0.75, abs=0.01)
+    assert share["light"] == pytest.approx(0.25, abs=0.01)
+    assert jain_fairness([share["heavy"] / 3.0, share["light"] / 1.0]) \
+        == pytest.approx(1.0, abs=1e-3)
+
+
 def test_flooding_tenant_cannot_sweep_a_round():
     q = QosScheduler(quantum_tokens=8.0, clock=FakeClock())
     flood = [_item("flood", max_new=8, tag=f"f{i}") for i in range(20)]

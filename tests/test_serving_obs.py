@@ -665,18 +665,3 @@ class TestAffinityCounters:
             r.route()
         for outcome in ("hit", "miss", "repin"):
             assert self._val(r, outcome) == 0.0
-
-
-# ---------------------------------------------------------------------------
-# the bench leg (slow: runs the paired measurement end to end)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_trace_overhead_bench_leg():
-    """The ISSUE acceptance bar: the paired bare-vs-traced serving leg
-    measures < 3% overhead and returns the full triple."""
-    import bench
-    pct, bare_ms, traced_ms = bench.bench_llm_trace_overhead()
-    assert isinstance(pct, float)
-    assert bare_ms > 0 and traced_ms > 0
-    assert pct < 3.0, f"trace overhead {pct:.2f}% >= 3%"
