@@ -187,6 +187,19 @@ class BoostingConfig:
         )
 
 
+def _round_ingest(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` rounded to bfloat16, as the fused ingest states gradients
+    enter a histogram.  ``reduce_precision`` first: under
+    ``xla_allow_excess_precision`` XLA drops a bare cast to bfloat16 and
+    back wherever the objective's chain fuses into the consumer, and
+    which consumers it fuses into moves with the grower's program (PR 32:
+    the trees of the boosting cell changed with the layout of the value
+    matrix until the rounding was made explicit).  The cast after it is
+    exact and keeps the materialized arrays at half the bytes."""
+    return lax.reduce_precision(
+        x, exponent_bits=8, mantissa_bits=7).astype(jnp.bfloat16)
+
+
 def _tuned_hist_chunk(num_features: int, total_bins: int,
                       n_slots: int) -> int:
     """Tuned rows-per-chunk for the Pallas histogram kernels, or 0.
@@ -741,8 +754,7 @@ def _make_step(p: GrowthParams, objective_fn, num_class: int,
                 # build (all waves of the tree) reads half the bytes;
                 # bin accumulation promotes back to f32, exact over the
                 # rounded values
-                grad = grad.astype(jnp.bfloat16)
-                hess = hess.astype(jnp.bfloat16)
+                grad, hess = _round_ingest(grad), _round_ingest(hess)
             tree, node_id = grower(bins_t, grad, hess, rv, feature_mask,
                                    upper_bounds, num_bins, learning_rate,
                                    p, axis, use_pallas,
@@ -760,8 +772,7 @@ def _make_step(p: GrowthParams, objective_fn, num_class: int,
                 grad, hess = softmax_grad_hess(scores, onehot, weights)
             g_hist, h_hist = grad, hess
             if fused_ingest:       # see the single-class branch above
-                g_hist = grad.astype(jnp.bfloat16)
-                h_hist = hess.astype(jnp.bfloat16)
+                g_hist, h_hist = _round_ingest(grad), _round_ingest(hess)
             new_scores = scores
             for k in range(num_class):
                 rv = bag_mask
@@ -1840,6 +1851,17 @@ def _train(fit_span, X, y, config, sample_weight, valid, feature_names, mesh,
     _sargs, _skw = _step_factory_args(config, K, mesh, featpar, use_pallas,
                                       objective_fn=objective_fn,
                                       num_features=_hist_F)
+    if uses_fused:
+        # what the depth-wise grower will build its histograms with (the
+        # grower decides by the same function at trace time): hist_ft,
+        # hist_feature_groups, hist_chunk, hist_grid_steps_per_pass,
+        # hist_two_level on the gbdt.fit span
+        from .trainer import depthwise_hist_plan
+        fit_span.set(**{f"hist_{k}": v for k, v in depthwise_hist_plan(
+            _hist_F, N // max(row_shards, 1), _sargs[0],
+            default_n_slots(config.num_leaves),
+            bundled=bundle_map_dev is not None,
+            use_pallas=bool(use_pallas)).items()})
     # lambdarank's objective closes over per-dataset arrays: a cache entry
     # would both never hit again and pin the arrays — bypass the cache
     make = (_make_step.__wrapped__ if config.objective == "lambdarank"

@@ -1,26 +1,39 @@
 """Pallas TPU histogram kernel — the GBDT hot op.
 
-Replaces the XLA scatter-add histogram (TPU scatters serialize; measured
-~8 s per 1M×28-row training step) with an MXU formulation.  Each grid step
-loads an 8-feature × CHUNK-row tile of the binned matrix and builds the
-features' one-hot bin matrices directly in transposed "tall" layout
-(FEAT_TILE·B, CHUNK) in VMEM scratch, then runs ONE matmul per step:
+Replaces the XLA scatter-add histogram (TPU scatters serialize) with an MXU
+formulation.  Each grid step loads a (features x CHUNK rows) tile of the
+binned matrix and builds the features' one-hot bin matrices directly in
+transposed "tall" layout (ft·B, CHUNK) in VMEM scratch, then runs ONE matmul
+per step:
 
-    hist_tile += OH(f·B+b, c) · vals(c, v)      # (2048, C) x (C, 8)
+    hist_tile += OH(f·B+b, c) · vals(c, v)      # (ft·B, C) x (C, S·8)
 
-The tall M dimension keeps the MXU rows busy (M=8-style layouts lower
-~10× slower on Mosaic).
+The tall M dimension keeps the MXU rows busy: the MXU's time is M x C / 128
+row pushes whatever the lane count, so S node slots ride the 128 lanes for
+the price of one.
 
-Round-4 formulation: the matmul runs **int8 × int8 → int32**.  The
-one-hot is exact in int8, and gradients/hessians are quantized to THREE
-balanced base-128 int8 limbs each (signed digits in [-64, 63], range
-±2^20 on a per-tree max-|value| scale), so the histogram accumulates
-EXACT integer sums of 21-bit-quantized values — quantization noise
-~max|g|·2^-21·sqrt(count) per bin, below the old bf16 hi/lo pair's error.
-Why: the kernel was measured VMEM-bandwidth-bound on the one-hot operand
-(bf16 @ B=256: 15.1 ms per 1M×28 level pass at ~70% MXU peak; int8 one-hot
-halves that traffic → 10.5 ms; B=64: 7.9 → 6.1 ms).  Lanes per slot:
-[g0 g1 g2 h0 h1 h2 count pad].
+The matmul runs **int8 × int8 → int32**.  The one-hot is exact in int8, and
+gradients/hessians are quantized to THREE balanced base-128 int8 limbs each
+(signed digits in [-64, 63], range ±2^20 on a per-tree max-|value| scale),
+so the histogram accumulates EXACT integer sums of 21-bit-quantized values
+(quantization noise ~max|g|·2^-21·sqrt(count) per bin, below the error of
+the bf16 hi/lo pair it replaced), and no order of rows, chunks or feature
+tiles can change a bit of it.  Lanes per slot: [g0 g1 g2 h0 h1 h2 count pad].
+
+What bounds it, read on a TPU v5e at 12,001,280 x 28, 256 bins (PERF.md §5,
+PR 32).  Mosaic lowers the product to the MXU's native int8 mode and a plain
+(2944, 2048) x (2048, 128) product runs at 362 Tops/s, 92% of the chip's
+int8 peak and 1.92x the same product in bf16, so the products of a
+2,048-row chunk of the two-level pass are 4.3 us.  The pass took 10.0 us a
+chunk: its one-hot build (`(iota == b).astype(int8)`: 58,000 of the chunk's
+80,000 vector operations), the 16-fold lane tile of the value block and four
+grid steps a chunk all ran in sequence with the products.  One step a chunk
+(:func:`fused_geometry`), the one-hot built four rows to a 32-bit word
+(:func:`_onehot_words`) and the value block tiled once a tree
+(:func:`prep_hist_vals_rows`) leave 4.4 us.  Timings this module quoted before
+(15.1 → 10.5 ms a 1M x 28 level pass at 256 bins for int8 against bf16,
+2.3 ms at 64 bins, 27 → 10.5 ms for the fused pass) were records of earlier
+rounds on another chip and JAX; they are gone.
 
 This is the TPU-native equivalent of LightGBM's C++ histogram construction
 (reference: the native code behind LGBM_BoosterUpdateOneIter,
@@ -37,12 +50,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: rows per grid chunk
-CHUNK = 1024
-#: features per grid step (Pallas sublane granularity for the bins block)
-FEAT_TILE = 8
 #: value channels: g limbs ×3, h limbs ×3, count, pad
 VALS = 8
+#: value channels per node slot in the batched kernels
+SLOT_LANES = 8
 
 #: largest magnitude representable in 3 balanced base-128 digits
 #: (63 + 63·128 + 63·16384)
@@ -75,14 +86,23 @@ def _reconstruct(out: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([g, h, o[..., 6]], axis=-1)
 
 
-def _tile_for(total_bins: int):
-    """(max features-per-step, rows-per-chunk) for the one-hot scratch.
+def coarse_bins(total_bins: int, shift: int) -> int:
+    """Histogram width of the coarse (``bin >> shift``) level, padded to a
+    sublane multiple so the (ft·Bc, chunk) one-hot scratch tiles cleanly."""
+    bc = -(-total_bins // (1 << shift))
+    return -(-bc // 8) * 8
 
-    The scratch is (ft·B, chunk) int8 and must fit VMEM (~16 MB/core)
-    alongside the resident (Fp·B, S·8) int32 accumulator.  Wider feature
-    tiles and chunks amortize the per-grid-step overhead — at B=64 the
-    (≤32, 2048) int8 geometry runs the 1M×28 level pass in ~2.3 ms vs
-    ~27 ms for the round-2 (8, 1024) bf16 geometry."""
+
+def _tile_for(total_bins: int):
+    """(max features-per-step, rows-per-chunk) for the one-hot scratch of a
+    histogram ``total_bins`` wide: at most 2,048 one-hot rows a step.
+
+    The scratch is (ft·B, chunk) one-hot bytes and must fit VMEM (~16
+    MB/core) alongside the resident (Fp·B, S·8) int32 accumulator.  Wider
+    feature tiles and chunks amortize the per-grid-step cost: a step of
+    224 one-hot rows is 0.6 us of products under a fixed cost of the same
+    order (four such steps a chunk against one of 896 rows: 1.2 us a
+    chunk; my chip run, PR 32)."""
     if total_bins <= 64:
         return 32, 2048
     if total_bins <= 128:
@@ -113,28 +133,40 @@ _VMEM_BUDGET = 13 * 1024 * 1024
 
 
 def fused_geometry(num_features: int, total_bins: int, n_slots: int,
-                   chunk_override: int = 0):
-    """(ft, chunk) for the fused route+hist kernel, or None if no geometry
-    fits VMEM.  Unlike the per-tile nodes kernel, the fused kernel's
-    accumulator is fully resident (routing is computed once per chunk, so
-    the grid runs chunk-major and every feature tile must stay hot) — its
-    footprint scales with F, and wide matrices must shrink the chunk or
-    fall back to the scatter path.
+                   chunk_override: int = 0, hist_shift: int = 0,
+                   refine_k: int = 0):
+    """(ft, chunk) for the fused route+hist pass, or None if no geometry
+    fits VMEM: the ONE home of the pass's VMEM arithmetic, keyed on the
+    tiles it really builds.  ``hist_shift`` > 0 (two-level) builds
+    ``Bh = coarse_bins(B, shift)``-row tiles, ``refine_k`` > 0 adds the
+    refined block (``K x B`` one-hot rows and their accumulator), so the
+    tile ladder is read at ``Bh``: at 28 features x 256 bins, shift 3,
+    that is ONE feature group (ft = 28, a step a chunk) where the
+    full-resolution pass takes four groups of 7.
+
+    Unlike the per-tile nodes kernel, the fused kernel's accumulator is
+    fully resident (routing is computed once per chunk, so the grid runs
+    chunk-major and every feature tile must stay hot): its footprint
+    scales with F, and wide matrices must shrink the chunk or fall back
+    to the scatter path.
 
     ``chunk_override`` (the tuned ``gbdt_hist_chunk`` winner) replaces
     the ladder's starting chunk; the SAME shrink-to-fit loop still
-    applies, so an override can never overcommit VMEM — it can only
+    applies, so an override can never overcommit VMEM: it can only
     start the search somewhere else."""
-    cap, chunk = _tile_for(total_bins)
+    Bh = coarse_bins(total_bins, hist_shift) if hist_shift else total_bins
+    cap, chunk = _tile_for(Bh)
     if chunk_override:
         chunk = int(chunk_override)
     ft = _feat_tile(num_features, cap)
+    Fp = -(-num_features // ft) * ft
     VN = n_slots * SLOT_LANES
     while chunk >= 1024:
-        Fp = -(-num_features // ft) * ft
-        need = (ft * total_bins * chunk * 1       # one-hot scratch (int8)
-                + Fp * total_bins * VN * 4        # resident accumulator (i32)
-                + 2 * chunk * VN * 1)             # vn scratch + vals (int8)
+        need = (ft * Bh * chunk                   # one-hot scratch (int8)
+                + Fp * Bh * VN * 4                # resident accumulator (i32)
+                + 2 * chunk * VN                  # vn scratch + vals (int8)
+                + refine_k * total_bins * chunk   # refined one-hot (int8)
+                + refine_k * total_bins * VN * 4)  # its accumulator (i32)
         if need <= _VMEM_BUDGET:
             return ft, chunk
         chunk //= 2
@@ -162,31 +194,34 @@ def hist_chunk_ok(num_features: int, total_bins: int, n_slots: int,
     return ft * total_bins * chunk <= _VMEM_BUDGET
 
 
-def _reshape_feat(bins_t: jnp.ndarray, ft: int):
-    """(F, N) → (G, ft, N) with minimal zero-padding of the feature axis.
+def feature_tiles(bins_t: jnp.ndarray, ft: int) -> jnp.ndarray:
+    """(F, N) → the kernels' (G, ft, N) tile layout, zero-padding the
+    feature axis to ``G * ft``.
 
-    NOT free on TPU: (G, ft, N) with ft < 8 pads each G-slice to 8
-    sublanes, so XLA materializes a ~224 MB copy at 1M×28.  Callers that
-    run many kernel passes per jit (the growers) must do this ONCE via
-    :func:`prepare_feature_tiles` OUTSIDE their wave loop — inside a
-    ``lax.cond`` branch XLA cannot hoist it, and it re-materializes
-    every wave (~2.7 ms/tree at B=256, measured by profile)."""
+    With ONE group (ft == F) it is a view.  Otherwise NOT free on TPU:
+    (G, ft, N) with ft < 8 pads each G-slice to 8 sublanes, so XLA
+    materializes a copy of the matrix (1.5 GB at 12M x 28, ft = 7).
+    Callers that run many kernel passes per jit (the growers) must do
+    this ONCE, OUTSIDE their wave loop: inside a ``lax.cond`` branch XLA
+    cannot hoist it, and it re-materializes every wave."""
     F, N = bins_t.shape
     G = -(-F // ft)
     if G * ft != F:
         bins_t = jnp.pad(bins_t, ((0, G * ft - F), (0, 0)))
-    return bins_t.reshape(G, ft, N), G
+    return bins_t.reshape(G, ft, N)
 
 
 def prepare_feature_tiles(bins_t: jnp.ndarray, total_bins: int,
                           num_features: int = None) -> jnp.ndarray:
-    """Pre-reshape the (F, N) binned matrix to the kernels' (G, ft, N)
-    tile layout — pass the result as ``bins_t`` to the kernel entry
-    points (they accept either layout, keyed on ndim)."""
+    """Pre-reshape the (F, N) binned matrix to the NODES kernel's
+    (G, ft, N) tile layout — pass the result as ``bins_t`` to
+    :func:`build_hist_nodes_pallas` (it accepts either layout, keyed on
+    ndim).  The fused pass picks its own tile: :func:`fused_geometry`
+    and :func:`feature_tiles`."""
     cap, _ = _tile_for(total_bins)
     ft = _feat_tile(num_features if num_features is not None
                     else bins_t.shape[0], cap)
-    return _reshape_feat(bins_t, ft)[0]
+    return feature_tiles(bins_t, ft)
 
 
 # (the former single-histogram "plain" kernel is gone: every pallas
@@ -219,19 +254,81 @@ def hist_pad_multiple() -> int:
 #     hist[f·B+b, j·8+v] += OH(f·B+b, c) · (slot(c)==j) · vals(c, v)
 #
 # The (C, S·8) per-node value matrix is built in-kernel from the row→slot
-# assignment (S masked copies of the 8-channel vals block — S·8·C VPU ops,
-# ~1/16 of the one-hot cost), so HBM traffic stays O(N) per pass instead of
-# O(N·S).  A depth level of up to S=16 nodes then costs ONE pass.
-
-#: value channels per node slot in the batched kernel
-SLOT_LANES = 8
+# assignment (one wide select of the S-fold lane-tiled value block:
+# _slot_values).  A depth level of up to S=16 nodes then costs ONE pass.
 
 
-def _make_hist_nodes_kernel(ft: int, shift: int = 0):
+def _onehot_words(b: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """One-hot of ``rows`` bins for a chunk's bin ids ``b`` (1, C) int32,
+    FOUR ROWS TO A 32-BIT WORD: (rows // 4, C) int32 whose byte ``j`` of
+    word-row ``i`` is ``b == 4 i + j``.  Stored to an int32 scratch and
+    read back through ``ref.bitcast(int8)`` it is the (rows, C) int8
+    one-hot the MXU takes, for ONE compare and one select a vreg of 4,096
+    one-hot elements.  ``(iota == b).astype(int8)`` lowers on this Mosaic
+    to four compares, four selects, two rounds of packs and unpacks and a
+    ``vnez`` for the same vreg: some 58,000 of the 80,000 vector
+    operations of a 2,048-row chunk of the two-level pass at 28 x 256
+    (PERF.md §5).  An id outside ``[0, rows)`` matches no row, as
+    before."""
+    iota = lax.broadcasted_iota(jnp.int32, (rows // 4, b.shape[1]), 0)
+    word = jnp.left_shift(jnp.int32(1), (b & 3) << 3)
+    return jnp.where(iota == (b >> 2), word, 0)
+
+
+def _store_onehot(oh_ref, k: int, b: jnp.ndarray, rows: int) -> None:
+    """Feature ``k``'s one-hot into the word scratch (``rows // 4``
+    word-rows a feature)."""
+    oh_ref[k * rows // 4:(k + 1) * rows // 4, :] = _onehot_words(
+        b[None, :], rows)
+
+
+def _slot_values(vals_ref, slot: jnp.ndarray, S: int) -> jnp.ndarray:
+    """The slot-masked value matrix: row ``c``'s 8 channels in the 8
+    positions of ``slot[c]``, zero elsewhere (``slot`` -1: nowhere), in ONE
+    wide compare against each position's slot index.
+
+    ``vals_ref`` is either :func:`prep_hist_vals`'s (C, 8) limb block,
+    lane-tiled S-fold here → (C, S·8); or the block of
+    :func:`prep_hist_vals_rows`'s matrix (32, C), channels on the ROWS of
+    one whole int8 tile, repeated here by whole vregs → (S·8, C), which
+    the product contracts over its last dimension.  Tiling along the
+    lanes is 16 lane rotations and as many unpacks and packs a vreg
+    (1.3-2.2 us of the fused pass's 2,048-row chunk at 16 slots, PERF.md
+    §5), and the row form also needs no relayout of ``slot``: growers
+    that make several passes a tree use it.  Select, not multiply:
+    ``arith.muli`` on i8 vectors fails to legalize in Mosaic."""
+    VN, C = S * SLOT_LANES, slot.shape[0]
+    if vals_ref.shape[0] != C:                       # channels on the rows
+        pos_j = lax.broadcasted_iota(jnp.int32, (VN, C), 0) // SLOT_LANES
+        reps = -(-VN // vals_ref.shape[0])
+        tiled = jnp.concatenate([vals_ref[...]] * reps, axis=0)[:VN, :]
+        sid = slot[None, :]
+    else:
+        pos_j = lax.broadcasted_iota(jnp.int32, (C, VN), 1) // SLOT_LANES
+        tiled = jnp.concatenate([vals_ref[...]] * S, axis=1)
+        sid = slot[:, None]
+    return jnp.where(sid == pos_j, tiled, jnp.zeros_like(tiled))
+
+
+def _vals_layout(vals: jnp.ndarray, N: int, chunk: int):
+    """Which of its two layouts the value matrix has → (block shape, block
+    index of chunk ``c``, dimension numbers of one-hot x values, channels
+    on the rows?): (N, 8) limbs, or channel rows (8·k, N)."""
+    if vals.shape == (N, VALS):
+        return ((chunk, VALS), lambda c: (c, 0), (((1,), (0,)), ((), ())),
+                False)
+    assert vals.shape[1] == N and vals.shape[0] % VALS == 0, (
+        f"vals {vals.shape}: ({N}, {VALS}) limbs (prep_hist_vals) or "
+        f"(8·k, {N}) channel rows (prep_hist_vals_rows)")
+    return ((vals.shape[0], chunk), lambda c: (0, c),
+            (((1,), (1,)), ((), ())), True)
+
+
+def _make_hist_nodes_kernel(ft: int, shift: int, dims):
     def kernel(bins_ref, slot_ref, vals_ref, out_ref, oh_ref):
         """Grid (G, N//chunk) — c fastest.  bins block (1, ft, C) int32;
         slot block (1, C) int32 (row's node slot, -1 = no slot); vals block
-        (C, 8) int8 limbs (the S-fold lane tile happens in-kernel); out
+        (C, 8) int8 limbs or channel rows (32, C) (:func:`_slot_values`); out
         block (1, ft·B, S·8) int32 revisited
         across the chunk dim — per-TILE residency keeps VMEM use
         F-independent (a fully resident accumulator scales with F and
@@ -242,73 +339,85 @@ def _make_hist_nodes_kernel(ft: int, shift: int = 0):
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        C = bins_ref.shape[2]
-        B = oh_ref.shape[0] // ft
+        B = oh_ref.shape[0] * 4 // ft
         S = out_ref.shape[2] // SLOT_LANES
-        iota_b = lax.broadcasted_iota(jnp.int32, (B, C), 0)
         for k in range(ft):
             b = bins_ref[0, k, :]
             if shift:
                 # two-level mode: coarse (bin >> shift) histograms
                 b = b >> shift
-            oh_ref[k * B:(k + 1) * B, :] = (iota_b == b[None, :]).astype(
-                jnp.int8)
-        # slot-masked value matrix in ONE wide compare against the lane's
-        # slot index — the round-2 loop of S narrow 8-lane writes cost more
-        # than the matmul it fed.  The S-fold lane tile happens HERE in
-        # VMEM: a host-side jnp.tile costs a 256 MB layout copy per tree
-        # plus S× the vals DMA traffic
-        sid = slot_ref[0, :]
-        lane_j = lax.broadcasted_iota(
-            jnp.int32, (C, S * SLOT_LANES), 1) // SLOT_LANES
-        tiled = jnp.concatenate([vals_ref[...]] * S, axis=1)
-        # int8 elementwise multiply fails to legalize in Mosaic
-        # (arith.muli on i8 vectors) — mask via select instead
-        vn = jnp.where(sid[:, None] == lane_j, tiled,
-                       jnp.zeros_like(tiled))
-        contrib = lax.dot_general(oh_ref[...], vn,
-                                  (((1,), (0,)), ((), ())),
+            _store_onehot(oh_ref, k, b, B)
+        vn = _slot_values(vals_ref, slot_ref[0, :], S)
+        contrib = lax.dot_general(oh_ref.bitcast(jnp.int8)[...], vn, dims,
                                   preferred_element_type=jnp.int32)
         out_ref[...] += contrib[None]
     return kernel
 
 
-def prep_hist_vals(grad: jnp.ndarray, hess: jnp.ndarray,
-                   mask: jnp.ndarray):
-    """Per-row value channels → ((N, 8) int8 limb matrix, (2,) f32 scales).
-
-    g/h quantize to 3 balanced base-128 int8 digits each on a per-call
-    max-|value| scale (range ±2^20), plus an exact 0/1 count lane.  Hoisted
-    out of the per-level loop: depends only on the iteration's
-    grad/hess/mask."""
+def _limb_channels(grad, hess, mask):
+    """The 8 value channels of a row as (N,) int32 vectors [g0 g1 g2 h0 h1
+    h2 count pad] and the (2,) f32 scales: g/h quantize to 3 balanced
+    base-128 digits each on a per-call max-|value| scale (range ±2^20),
+    plus an exact 0/1 count."""
     g = grad * mask
     h = hess * mask
     s_g = jnp.maximum(jnp.max(jnp.abs(g)), 1e-30) / _Q_MAX
     s_h = jnp.maximum(jnp.max(jnp.abs(h)), 1e-30) / _Q_MAX
-    g0, g1, g2 = _quant(g, s_g)
-    h0, h1, h2 = _quant(h, s_h)
     count = (mask > 0).astype(jnp.int32)
-    z = jnp.zeros_like(count)
-    vals = jnp.stack([g0, g1, g2, h0, h1, h2, count, z],
-                     axis=-1).astype(jnp.int8)
-    return vals, jnp.stack([s_g, s_h])
+    return ([*_quant(g, s_g), *_quant(h, s_h), count,
+             jnp.zeros_like(count)], jnp.stack([s_g, s_h]))
+
+
+def prep_hist_vals(grad: jnp.ndarray, hess: jnp.ndarray,
+                   mask: jnp.ndarray):
+    """Per-row value channels → ((N, 8) int8 limb matrix, (2,) f32 scales).
+    Hoisted out of the per-level loop: depends only on the iteration's
+    grad/hess/mask."""
+    chans, scales = _limb_channels(grad, hess, mask)
+    return jnp.stack(chans, axis=-1).astype(jnp.int8), scales
+
+
+#: rows of prep_hist_vals_rows: the 8 channels four times, one int8 tile
+VALS_ROWS = 32
+
+
+def prep_hist_vals_rows(grad: jnp.ndarray, hess: jnp.ndarray,
+                        mask: jnp.ndarray):
+    """:func:`prep_hist_vals` with the channels on the ROWS, four times →
+    ((32, N) int8, scales): one whole int8 tile of rows, which the
+    node-batched kernels repeat by whole vregs to their S·8 positions and
+    mask by slot (:func:`_slot_values`).  For growers that make several
+    passes a tree.  Rows of the data stay on the lanes, as grad and hess
+    have them, so XLA writes it in one elementwise pass, where the (N, 8)
+    matrix is a transpose that pads 8 lanes to 128 (1.5 GB at 12M rows).
+
+    Row r carries channel r % 8 by a select chain: a stack of (1, N)
+    int8 rows is a concatenate of padded operands, three times slower on
+    the chip (PERF.md §6, PR 32)."""
+    chans, scales = _limb_channels(grad, hess, mask)
+    ch = lax.broadcasted_iota(jnp.int32, (VALS_ROWS, grad.shape[0]),
+                              0) % VALS
+    vals = jnp.zeros_like(ch)                          # channel 7: pad
+    for i, c in enumerate(chans[:-1]):
+        vals = jnp.where(ch == i, c[None, :], vals)
+    return vals.astype(jnp.int8), scales
 
 
 def _bins_tiles(bins_t: jnp.ndarray, total_bins: int) -> tuple:
-    """Normalize the bins input: (F, N) reshapes here (ONE materialized
-    copy — hoist with :func:`prepare_feature_tiles` when calling from a
-    loop); (G, ft, N) passes through.  F is always G·ft: _feat_tile
-    minimizes padding first and ft=1 pads nothing, so the chosen tile
-    always divides the feature count.  → (bins_r, F, G, ft, N)."""
-    cap, _ = _tile_for(total_bins)
+    """Normalize the nodes kernel's bins input: (F, N) reshapes here (ONE
+    materialized copy — hoist with :func:`prepare_feature_tiles` when
+    calling from a loop); (G, ft, N) passes through.  F is always G·ft:
+    _feat_tile minimizes padding first and ft=1 pads nothing, so the
+    chosen tile always divides the feature count.
+    → (bins_r, F, G, ft, N)."""
     if bins_t.ndim == 3:
         G, ft, N = bins_t.shape
         return bins_t, G * ft, G, ft, N
     F, N = bins_t.shape
-    ft = _feat_tile(F, cap)
-    bins_r, G = _reshape_feat(bins_t, ft)
-    assert G * ft == F, (G, ft, F)
-    return bins_r, F, G, ft, N
+    ft = _feat_tile(F, _tile_for(total_bins)[0])
+    bins_r = feature_tiles(bins_t, ft)
+    assert bins_r.shape[0] * ft == F, (bins_r.shape, ft, F)
+    return bins_r, F, bins_r.shape[0], ft, N
 
 
 @functools.partial(jax.jit,
@@ -316,7 +425,7 @@ def _bins_tiles(bins_t: jnp.ndarray, total_bins: int) -> tuple:
                                     "interpret", "hist_chunk"))
 def build_hist_nodes_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
                             slot: jnp.ndarray,     # (N,) int32 in [-1, n_slots)
-                            vals: jnp.ndarray,     # (N, 8) int8 limbs
+                            vals: jnp.ndarray,     # (N, 8) | (32, N) int8
                             scales: jnp.ndarray,   # (2,) f32 from prep_hist_vals
                             n_slots: int,
                             total_bins: int,
@@ -345,18 +454,19 @@ def build_hist_nodes_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
             "hist_chunk_ok()")
     assert N % chunk == 0, f"N={N} must be a multiple of {chunk}"
     VN = n_slots * SLOT_LANES
+    vblock, vix, dims, _ = _vals_layout(vals, N, chunk)
 
     out = pl.pallas_call(
-        _make_hist_nodes_kernel(ft, hist_shift),
+        _make_hist_nodes_kernel(ft, hist_shift, dims),
         grid=(G, N // chunk),
         in_specs=[
             pl.BlockSpec((1, ft, chunk), lambda f, c: (f, 0, c)),
             pl.BlockSpec((1, chunk), lambda f, c: (0, c)),
-            pl.BlockSpec((chunk, VALS), lambda f, c: (c, 0)),
+            pl.BlockSpec(vblock, lambda f, c: vix(c)),
         ],
         out_specs=pl.BlockSpec((1, ft * Bh, VN), lambda f, c: (f, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((G, ft * Bh, VN), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((ft * Bh, chunk), jnp.int8)],
+        scratch_shapes=[pltpu.VMEM((ft * Bh // 4, chunk), jnp.int32)],
         interpret=interpret,
     )(bins_r, slot[None, :], vals)
 
@@ -375,51 +485,19 @@ def build_hist_nodes_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
 # the left-child histograms.  As separate kernels each scans the matrix
 # once; fused, the grid runs chunk-major (f innermost) so each chunk's
 # routing is computed ONCE at f==0 and the node-masked value matrix stays
-# in VMEM for the F/ft histogram steps that follow.  The histogram
+# in VMEM for the F/ft histogram steps that follow (one step where one
+# feature group holds the tile: fused_geometry).  The histogram
 # accumulator is a single constant-index output block (F/ft, ft·B, S·8)
 # resident in VMEM for the whole launch.
 #
-# Round-3 surgery (each measured on v5e @ 1M×28): the split features'
-# bin rows arrive PRE-GATHERED as a (S, N) matrix (jnp.take on the feature
-# axis — a contiguous row copy) so the kernel indexes them statically —
-# the former in-kernel ``pl.dslice(feat_ref[j], 1)`` dynamic sublane read
-# cost more than the histogram matmul it fed; the slot-masked value matrix
-# is one wide lane-iota compare instead of S narrow 8-lane writes; and the
-# (ft, chunk) geometry widens with small B (``_tile_for``).  Together:
-# 27 ms → 10.5 ms per level pass at max_bin=63.
+# The split features' bin rows arrive PRE-GATHERED as a (S, N) matrix
+# (jnp.take on the feature axis, a contiguous row copy) so the kernel
+# indexes them statically: an in-kernel ``pl.dslice(feat_ref[j], 1)``
+# dynamic sublane read cost more than the histogram matmul it fed (an
+# earlier round's finding at 1M x 28).
 
 
-def coarse_bins(total_bins: int, shift: int) -> int:
-    """Histogram width of the coarse (``bin >> shift``) level, padded to a
-    sublane multiple so the (ft·Bc, chunk) one-hot scratch tiles cleanly."""
-    bc = -(-total_bins // (1 << shift))
-    return -(-bc // 8) * 8
-
-
-def fused_refine_fits(num_features: int, total_bins: int, n_slots: int,
-                      shift: int, refine_k: int) -> bool:
-    """Whether the two-level fused pass (coarse tiles + the K refined
-    features' FULL-resolution scratch/accumulator) fits VMEM at the base
-    geometry.  ``fused_geometry`` models only the plain kernel; the
-    refine buffers scale with ``refine_k * total_bins`` and an uncapped
-    ``refine_features`` config must fall back to full-resolution growth
-    instead of failing at Mosaic compile time."""
-    geo = fused_geometry(num_features, total_bins, n_slots)
-    if geo is None:
-        return False
-    ft, chunk = geo
-    Bh = coarse_bins(total_bins, shift)
-    VN = n_slots * SLOT_LANES
-    Fp = -(-num_features // ft) * ft
-    need = (ft * Bh * chunk                     # coarse one-hot (int8)
-            + Fp * Bh * VN * 4                  # coarse accumulator (i32)
-            + 2 * chunk * VN                    # vn scratch + vals (int8)
-            + refine_k * total_bins * chunk     # fine one-hot (int8)
-            + refine_k * total_bins * VN * 4)   # fine accumulator (i32)
-    return need <= _VMEM_BUDGET
-
-
-def _make_fused_kernel(ft: int, shift: int = 0, refine: bool = False):
+def _make_fused_kernel(ft: int, shift: int, refine: bool, dims):
     """``refine=True`` (two-level mode) adds a second histogram output:
     full-resolution histograms of K pre-gathered refined-feature rows
     (``selk``), built at f==0 from the SAME slot-masked value matrix the
@@ -430,8 +508,8 @@ def _make_fused_kernel(ft: int, shift: int = 0, refine: bool = False):
                *refs):
         """Grid (N//chunk, G) — f fastest.  sel block (S, C) int32 (the
         split columns' bin rows), bins block (1, ft, C) (histogram tile),
-        nid (1, C), vals (C, 8) int8 limbs (lane-tiled in-kernel);
-        outputs: newid (1, C) and
+        nid (1, C), vals (C, 8) int8 limbs or channel rows (32, C)
+        (:func:`_slot_values`); outputs: newid (1, C) and
         the resident histogram accumulator (G, ft·B, S·8) int32.
 
         The routing condition is the UNIVERSAL form
@@ -456,9 +534,8 @@ def _make_fused_kernel(ft: int, shift: int = 0, refine: bool = False):
             if refine:
                 outf_ref[...] = jnp.zeros_like(outf_ref)
 
-        C = bins_ref.shape[2]
-        B = oh_ref.shape[0] // ft
-        S = vn_ref.shape[1] // SLOT_LANES
+        B = oh_ref.shape[0] * 4 // ft
+        S = out_ref.shape[2] // SLOT_LANES
 
         @pl.when(f == 0)
         def _route():
@@ -478,46 +555,111 @@ def _make_fused_kernel(ft: int, shift: int = 0, refine: bool = False):
                                 jnp.where(gl, lid_ref[j], rid_ref[j]), new)
                 bslot = jnp.where(inleaf & gl, j, bslot)
             newid_ref[0, :] = new
-            lane_j = lax.broadcasted_iota(
-                jnp.int32, (C, S * SLOT_LANES), 1) // SLOT_LANES
-            # the S-fold lane tile happens here in VMEM (a host-side
-            # jnp.tile costs a 256 MB layout copy per tree); select, not
-            # multiply: arith.muli on i8 vectors fails to legalize
-            tiled = jnp.concatenate([vals_ref[...]] * S, axis=1)
-            vn_ref[...] = jnp.where(bslot[:, None] == lane_j, tiled,
-                                    jnp.zeros_like(tiled))
+            vn_ref[...] = _slot_values(vals_ref, bslot, S)
             if refine:
                 # fine-K histograms off the SAME slot-masked values: the
                 # separate refine pass re-read bins, re-derived slots and
                 # re-built vn — here it costs one extra one-hot + matmul
                 K = selk_ref.shape[0]
-                Bf = ohf_ref.shape[0] // K
-                iota_f = lax.broadcasted_iota(jnp.int32, (Bf, C), 0)
+                Bf = ohf_ref.shape[0] * 4 // K
                 for k in range(K):
-                    bk = selk_ref[k, :]
-                    ohf_ref[k * Bf:(k + 1) * Bf, :] = (
-                        iota_f == bk[None, :]).astype(jnp.int8)
+                    _store_onehot(ohf_ref, k, selk_ref[k, :], Bf)
                 fcontrib = lax.dot_general(
-                    ohf_ref[...], vn_ref[...], (((1,), (0,)), ((), ())),
+                    ohf_ref.bitcast(jnp.int8)[...], vn_ref[...], dims,
                     preferred_element_type=jnp.int32)
                 outf_ref[...] += fcontrib[None]
 
-        iota_b = lax.broadcasted_iota(jnp.int32, (B, C), 0)
         for k in range(ft):
             b = bins_ref[0, k, :]
             if shift:
                 # two-level mode: histogram at COARSE (bin >> shift)
-                # resolution while routing stays at fine resolution — the
-                # one-hot build (the measured VPU bottleneck of the 255-bin
-                # level pass) and the matmul both shrink by 2^shift
+                # resolution while routing stays at fine resolution: the
+                # one-hot build and the matmul both shrink by 2^shift
                 b = b >> shift
-            oh_ref[k * B:(k + 1) * B, :] = (iota_b == b[None, :]).astype(
-                jnp.int8)
-        contrib = lax.dot_general(oh_ref[...], vn_ref[...],
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
+            _store_onehot(oh_ref, k, b, B)
+        contrib = lax.dot_general(oh_ref.bitcast(jnp.int8)[...], vn_ref[...],
+                                  dims, preferred_element_type=jnp.int32)
         out_ref[f, :, :] += contrib
     return kernel
+
+
+def fused_tiles(bins_t, n_slots: int, total_bins: int, hist_shift: int = 0,
+                refine_k: int = 0, hist_chunk: int = 0):
+    """Normalize the fused pass's bins input → (bins_r (G, ft, N), chunk)
+    at the geometry :func:`fused_geometry` picks for the tiles this
+    pass builds.  (F, N) is laid out here (a view with one feature group,
+    else ONE copy: growers hoist it with :func:`feature_tiles`);
+    (G, ft, N) must already be that geometry's layout."""
+    F = bins_t.shape[0] * bins_t.shape[1] if bins_t.ndim == 3 \
+        else bins_t.shape[0]
+    geo = fused_geometry(F, total_bins, n_slots, chunk_override=hist_chunk,
+                         hist_shift=hist_shift, refine_k=refine_k)
+    assert geo is not None, (
+        f"fused kernel does not fit VMEM at F={F}, B={total_bins}, "
+        f"S={n_slots}, shift={hist_shift}, K={refine_k}; the caller must "
+        "gate on fused_geometry(...)")
+    ft, chunk = geo
+    if bins_t.ndim == 2:
+        bins_t = feature_tiles(bins_t, ft)
+    assert bins_t.shape[1] == ft and bins_t.shape[0] * ft == F, (
+        bins_t.shape, ft, F)
+    assert bins_t.shape[2] % chunk == 0, (
+        f"N={bins_t.shape[2]} must be a multiple of {chunk}")
+    return bins_t, chunk
+
+
+def _route_and_hist_int(bins_t, node_id, leaf, sel, t1, rlo, rhi, dflt,
+                        l_id, r_id, vals, n_slots, total_bins, hist_shift,
+                        sel_k, interpret, hist_chunk):
+    """The fused pass's raw outputs: (new_node_id (1, N) int32, coarse or
+    plain accumulator (G, ft·Bh, S·8) int32[, refined accumulator
+    (1, K·B, S·8) int32]).  Integer sums of int8 products: the order of
+    rows, chunks and feature tiles cannot change a bit of them."""
+    B = total_bins
+    Bh = coarse_bins(B, hist_shift) if hist_shift else B
+    refine = sel_k is not None
+    K = sel_k.shape[0] if refine else 0
+    bins_r, chunk = fused_tiles(bins_t, n_slots, B, hist_shift, K,
+                                hist_chunk)
+    G, ft, N = bins_r.shape
+    VN = n_slots * SLOT_LANES
+    vblock, vix, dims, rows = _vals_layout(vals, N, chunk)
+    in_specs = [
+        pl.BlockSpec((n_slots, chunk), lambda c, f, *_: (0, c)),
+        pl.BlockSpec((1, ft, chunk), lambda c, f, *_: (f, 0, c)),
+        pl.BlockSpec((1, chunk), lambda c, f, *_: (0, c)),
+        pl.BlockSpec(vblock, lambda c, f, *_: vix(c)),
+    ]
+    out_specs = [
+        pl.BlockSpec((1, chunk), lambda c, f, *_: (0, c)),
+        pl.BlockSpec((G, ft * Bh, VN), lambda c, f, *_: (0, 0, 0)),
+    ]
+    out_shape = [jax.ShapeDtypeStruct((1, N), jnp.int32),
+                 jax.ShapeDtypeStruct((G, ft * Bh, VN), jnp.int32)]
+    scratch = [pltpu.VMEM((ft * Bh // 4, chunk), jnp.int32),  # one-hot words
+               pltpu.VMEM((VN, chunk) if rows else (chunk, VN),
+                          jnp.int8)]                        # _slot_values
+    operands = [sel, bins_r, node_id[None, :], vals]
+    if refine:
+        in_specs.insert(0, pl.BlockSpec((K, chunk), lambda c, f, *_: (0, c)))
+        out_specs.append(pl.BlockSpec((1, K * B, VN),
+                                      lambda c, f, *_: (0, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((1, K * B, VN), jnp.int32))
+        scratch.append(pltpu.VMEM((K * B // 4, chunk), jnp.int32))
+        operands.insert(0, sel_k)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(N // chunk, G),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        _make_fused_kernel(ft, hist_shift, refine, dims),
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(leaf, t1, rlo, rhi, dflt, l_id, r_id, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("n_slots", "total_bins",
@@ -533,7 +675,7 @@ def route_and_hist_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
                           dflt: jnp.ndarray,     # (S,) int32 out-of-range dir
                           l_id: jnp.ndarray,     # (S,) int32 left child id
                           r_id: jnp.ndarray,     # (S,) int32 right child id
-                          vals: jnp.ndarray,     # (N, 8) int8 limbs
+                          vals: jnp.ndarray,     # (N, 8) | (32, N) int8
                           scales: jnp.ndarray,   # (2,) f32 from prep_hist_vals
                           n_slots: int,
                           total_bins: int,
@@ -557,6 +699,10 @@ def route_and_hist_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
     histograms in the same pass, off the same routing and slot-masked
     value matrix — one bins read and one vn build for both levels.
 
+    ``bins_t`` as (G, ft, N) must be :func:`feature_tiles` at the ``ft``
+    :func:`fused_geometry` returns for THESE arguments (the tile follows
+    what the pass builds: ``hist_shift``, ``sel_k``).
+
     ``hist_chunk`` is the tuned rows-per-chunk override (jit-static for
     the same dispatch-cache reason as in
     :func:`build_hist_nodes_pallas`); the fused fit loop still applies,
@@ -564,59 +710,16 @@ def route_and_hist_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
     VMEM."""
     B = total_bins
     Bh = coarse_bins(B, hist_shift) if hist_shift else B
-    refine = sel_k is not None
-    bins_r, F, G, ft, N = _bins_tiles(bins_t, B)
-    geo = fused_geometry(F, B, n_slots, chunk_override=hist_chunk)
-    assert geo is not None, (
-        f"fused kernel does not fit VMEM at F={F}, B={B}, S={n_slots}; "
-        "the caller must gate on fused_geometry(...)")
-    ft_geo, chunk = geo
-    assert ft_geo == ft, (ft_geo, ft)
-    assert N % chunk == 0, f"N={N} must be a multiple of {chunk}"
-    VN = n_slots * SLOT_LANES
-    in_specs = [
-        pl.BlockSpec((n_slots, chunk), lambda c, f, *_: (0, c)),
-        pl.BlockSpec((1, ft, chunk), lambda c, f, *_: (f, 0, c)),
-        pl.BlockSpec((1, chunk), lambda c, f, *_: (0, c)),
-        pl.BlockSpec((chunk, VALS), lambda c, f, *_: (c, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, chunk), lambda c, f, *_: (0, c)),
-        pl.BlockSpec((G, ft * Bh, VN), lambda c, f, *_: (0, 0, 0)),
-    ]
-    out_shape = [jax.ShapeDtypeStruct((1, N), jnp.int32),
-                 jax.ShapeDtypeStruct((G, ft * Bh, VN), jnp.int32)]
-    scratch = [pltpu.VMEM((ft * Bh, chunk), jnp.int8),
-               pltpu.VMEM((chunk, VN), jnp.int8)]
-    operands = [sel, bins_r, node_id[None, :], vals]
-    if refine:
-        K = sel_k.shape[0]
-        in_specs.insert(0, pl.BlockSpec((K, chunk), lambda c, f, *_: (0, c)))
-        out_specs.append(pl.BlockSpec((1, K * B, VN),
-                                      lambda c, f, *_: (0, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((1, K * B, VN), jnp.int32))
-        scratch.append(pltpu.VMEM((K * B, chunk), jnp.int8))
-        operands.insert(0, sel_k)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(N // chunk, G),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    res = pl.pallas_call(
-        _make_fused_kernel(ft, hist_shift, refine),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(leaf, t1, rlo, rhi, dflt, l_id, r_id, *operands)
-
+    res = _route_and_hist_int(bins_t, node_id, leaf, sel, t1, rlo, rhi, dflt,
+                              l_id, r_id, vals, n_slots, B, hist_shift,
+                              sel_k, interpret, hist_chunk)
     new_id, out = res[0], res[1]
-    out = out.reshape(G * ft, Bh, n_slots, SLOT_LANES)[:F]
+    # (G, ft·Bh, S·8) → (F, Bh, S, 8): F == G·ft, _feat_tile pads nothing
+    out = out.reshape(-1, Bh, n_slots, SLOT_LANES)
     out = jnp.moveaxis(out, 2, 0)                      # (S, F, Bh, 8)
     hists = _reconstruct(out, scales)
-    if not refine:
+    if sel_k is None:
         return new_id[0], hists
-    outf = res[2].reshape(K, B, n_slots, SLOT_LANES)
+    outf = res[2].reshape(sel_k.shape[0], B, n_slots, SLOT_LANES)
     fine = _reconstruct(jnp.moveaxis(outf, 2, 0), scales)
     return new_id[0], hists, fine

@@ -316,6 +316,46 @@ TWO_LEVEL_MIN_ROWS = 500_000
 TWO_LEVEL_SHIFT = 3
 
 
+def depthwise_hist_plan(num_features: int, n_rows: int, p: "GrowthParams",
+                        n_slots: int, bundled: bool,
+                        use_pallas: bool) -> dict:
+    """What :func:`grow_tree_depthwise` builds its histograms with, from
+    what it can see in its shapes: whether they are two-level, and on the
+    pallas path the fused pass's tile.  The grower decides by this, and
+    ``booster.train`` writes it on the ``gbdt.fit`` span.
+
+    Two-level is structurally excluded wherever an exactness-pinned path
+    needs full resolution (EFB bit-identity: ``bundled``; monotone refresh
+    re-picks), under 128 bins, and where the refined block would not fit
+    the fused pass's VMEM (an uncapped ``refine_features`` falls back to
+    full-resolution growth instead of failing at Mosaic compile time);
+    "auto" additionally wants big data, so small-data fits keep exact-255
+    semantics.
+
+    → ``two_level``; ``ft``, ``feature_groups``, ``chunk`` and
+    ``grid_steps_per_pass`` of a wave's pass (0 off the pallas path, or
+    where no tile fits VMEM and the caller must not take it).
+    ``num_features`` and ``n_rows`` are what ONE device's grower sees
+    (bundled columns, padded rows of its shard)."""
+    from .pallas_hist import fused_geometry
+    B, K, SH = p.total_bins, p.refine_k, TWO_LEVEL_SHIFT
+    mono = p.monotone_constraints is not None and any(p.monotone_constraints)
+    geo_tl = (fused_geometry(num_features, B, n_slots, p.hist_chunk,
+                             hist_shift=SH, refine_k=K)
+              if use_pallas else None)
+    tl = (K > 0 and p.two_level != "off" and not bundled and not mono
+          and B >= 128 and num_features > K
+          and (p.two_level == "on" or n_rows >= TWO_LEVEL_MIN_ROWS)
+          and (not use_pallas or geo_tl is not None))
+    geo = geo_tl if tl or not use_pallas else fused_geometry(
+        num_features, B, n_slots, p.hist_chunk)
+    ft, chunk = geo or (0, 0)
+    groups = -(-num_features // ft) if ft else 0
+    return dict(two_level=bool(tl), ft=ft, feature_groups=groups,
+                chunk=chunk,
+                grid_steps_per_pass=(n_rows // chunk) * groups if ft else 0)
+
+
 def _pool_coarse(hist, Bc: int, shift: int):
     """Fine (..., B, 3) f32 histograms → coarse (..., Bc, 3) by summing
     the ``1 << shift`` fine bins sharing each coarse index — the XLA-path
@@ -1183,7 +1223,7 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
     ``cconfig``: quantized wire for the per-wave histogram psum — see
     :func:`grow_tree`.
     """
-    from .pallas_hist import prep_hist_vals
+    from .pallas_hist import prep_hist_vals_rows
 
     F, N = bins_t.shape
     B = p.total_bins
@@ -1203,50 +1243,48 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
             x, axis_name, cconfig,
             op="gbdt_hist_psum" if cconfig is not None else "psum")
 
-    vals8, scales = (prep_hist_vals(grad, hess, row_valid) if use_pallas
-                     else (None, None))
-    flat_bins = None
-    bins_pl = bins_t
-    if not use_pallas:
-        flat_bins = bins_t + (jnp.arange(F, dtype=jnp.int32) * B)[:, None]
-    else:
-        # the (G, ft, N) tile reshape materializes a copy (ft < 8 pads
-        # sublanes): done ONCE per tree here — inside the wave loop's
-        # cond XLA re-materializes it every level (~2.7 ms/tree @B=256)
-        from .pallas_hist import prepare_feature_tiles
-        bins_pl = prepare_feature_tiles(bins_t, B, F)
-
-    def build(slot):
-        return ar(_build_hist_nodes(bins_pl, flat_bins, vals8, scales, grad,
-                                    hess, row_valid, slot, S, F, B,
-                                    use_pallas, hist_chunk=p.hist_chunk))
+    # the limbs with their channels on the rows: each of the tree's passes
+    # masks them by slot, and tiling (N, 8) limbs along the lanes inside
+    # the kernel cost a fifth of a pass
+    vals8, scales = (prep_hist_vals_rows(grad, hess, row_valid)
+                     if use_pallas else (None, None))
 
     F_search = num_bins.shape[0]           # ORIGINAL feature count
     mono_c = _mono_vec(p, F_search)
 
-    # two-level (coarse-then-refine) histograms: see the module comment
-    # above _pool_coarse.  Structural exclusions keep every exactness-
-    # pinned path (EFB bit-identity, monotone refresh re-picks) at full
-    # resolution; "auto" additionally requires big data so small-data
-    # tests keep exact-255 semantics
-    from .pallas_hist import coarse_bins, fused_refine_fits
-    tl = (p.refine_k > 0 and p.two_level != "off"
-          and bundle_map is None and mono_c is None
-          and B >= 128 and F > p.refine_k
-          and (p.two_level == "on" or N >= TWO_LEVEL_MIN_ROWS)
-          # the fused pass carries the K refined features' full-res
-          # scratch/accumulator in VMEM — an uncapped refine_features
-          # falls back to full-resolution growth instead of failing at
-          # Mosaic compile time
-          and (not use_pallas
-               or fused_refine_fits(F, B, S, TWO_LEVEL_SHIFT,
-                                    p.refine_k)))
+    # two-level (coarse-then-refine) histograms and, on the pallas path,
+    # the fused pass's tile: one decision, see depthwise_hist_plan
+    from .pallas_hist import coarse_bins
+    plan = depthwise_hist_plan(F, N, p, S, bundled=bundle_map is not None,
+                               use_pallas=bool(use_pallas))
+    tl = plan["two_level"]
     _tl_gauge("depthwise", tl)
     SH = TWO_LEVEL_SHIFT
     Bc = coarse_bins(B, SH)
     Bh = Bc if tl else B                   # stored-histogram width
     K = p.refine_k
     num_bins_c = -(-num_bins // (1 << SH))
+
+    flat_bins = None
+    bins_pl = bins_t
+    if not use_pallas:
+        flat_bins = bins_t + (jnp.arange(F, dtype=jnp.int32) * B)[:, None]
+    else:
+        # the fused pass's (G, ft, N) layout, ONCE per tree: a view when
+        # one feature group holds the tile (two-level at 28 x 256), else
+        # a copy that XLA would re-materialize every level inside the
+        # wave loop's cond
+        from .pallas_hist import feature_tiles
+        assert plan["ft"], (
+            f"fused kernel does not fit VMEM at F={F}, B={B}, S={S}; the "
+            "caller must gate on fused_geometry(...)")
+        bins_pl = feature_tiles(bins_t, plan["ft"])
+
+    def build(slot):
+        # the XLA path's histograms: on the pallas path the root and
+        # every wave ride the fused pass
+        return ar(_build_hist_nodes_xla(flat_bins, grad, hess, row_valid,
+                                        slot, S, F, B))
 
     def unb(hists, g, h, c):
         if bundle_map is None:
@@ -1279,11 +1317,10 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
     # this rides the FUSED kernel with a degenerate all-left split of leaf 0
     # (t1=B → every row left, child id 0 → node ids unchanged): the fused
     # kernel computes its slot mask once per chunk instead of once per
-    # (feature-tile, chunk) step, measured ~25% faster than the nodes
-    # kernel for the same histograms
+    # (feature-tile, chunk) step (an earlier round's reading, 1M x 28 on
+    # another chip: some 25% under the nodes kernel for the same histograms)
     if use_pallas:
-        from .pallas_hist import fused_geometry, route_and_hist_pallas
-    if use_pallas and fused_geometry(F, B, S) is not None:
+        from .pallas_hist import route_and_hist_pallas
         jv = jnp.full((S,), JUNK, jnp.int32)
         _, root_hists = route_and_hist_pallas(
             bins_pl, jnp.zeros(N, jnp.int32), jv.at[0].set(0),

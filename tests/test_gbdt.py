@@ -640,11 +640,15 @@ def test_depthwise_unbounded_budget_matches_lossguide_exactly():
             np.testing.assert_allclose(ref, b.predict_margin(X), atol=1e-5)
 
 
-def test_node_batched_hist_matches_scatter():
-    """Node-batched Pallas kernel (interpret) vs the XLA scatter fallback."""
+@pytest.mark.parametrize("rows", [False, True],
+                         ids=["limbs-N-by-8", "channel-rows-32-by-N"])
+def test_node_batched_hist_matches_scatter(rows):
+    """Node-batched Pallas kernel (interpret) vs the XLA scatter fallback,
+    with the (N, 8) limbs and with ``prep_hist_vals_rows``'s (32, N)
+    matrix, which 5 slots use 40 positions of."""
     import jax.numpy as jnp
     from synapseml_tpu.models.gbdt.pallas_hist import (
-        build_hist_nodes_pallas, prep_hist_vals)
+        build_hist_nodes_pallas, prep_hist_vals, prep_hist_vals_rows)
     from synapseml_tpu.models.gbdt.trainer import _build_hist_nodes_xla
 
     rng = np.random.default_rng(3)
@@ -654,8 +658,14 @@ def test_node_batched_hist_matches_scatter():
     hess = (np.abs(grad) + 0.1).astype(np.float32)
     mask = (rng.random(N) < 0.7).astype(np.float32) * 1.5
     slot = rng.integers(-1, S, N).astype(np.int32)
-    vals, scales = prep_hist_vals(jnp.asarray(grad), jnp.asarray(hess),
-                                  jnp.asarray(mask))
+    gh = (jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask))
+    vals, scales = prep_hist_vals(*gh)
+    assert vals.shape == (N, 8) and vals.dtype == jnp.int8
+    if rows:
+        limbs, (vals, scales) = vals, prep_hist_vals_rows(*gh)
+        assert vals.dtype == jnp.int8
+        np.testing.assert_array_equal(
+            np.asarray(vals), np.tile(np.asarray(limbs).T, (4, 1)))
     out_p = np.asarray(build_hist_nodes_pallas(
         jnp.asarray(bins_t), jnp.asarray(slot), vals, scales, S, B,
         interpret=True))

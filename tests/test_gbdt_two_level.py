@@ -184,13 +184,199 @@ def test_two_level_odd_bin_count():
     assert float(auc(yh, b.predict_margin(Xh))) > 0.75
 
 
+def _int_reference(bins, node_id, leaf, sel, t1, rlo, rhi, dflt, l_id, r_id,
+                   vals, S, Bh, shift, sel_k, B):
+    """Plain numpy: route every row, then scatter its int8 limbs into the
+    histograms of the slot it went LEFT in.  → (new_node_id, coarse-or-
+    plain (F, Bh, S, 8) int64, refined (K, B, S, 8) int64 or None)."""
+    new, slot = node_id.copy(), np.full(node_id.shape, -1)
+    for j in range(S):
+        inleaf = node_id == leaf[j]
+        x = sel[j]
+        gl = np.where((x > rlo[j]) & (x <= rhi[j]), x <= t1[j], dflt[j] != 0)
+        new = np.where(inleaf, np.where(gl, l_id[j], r_id[j]), new)
+        slot = np.where(inleaf & gl, j, slot)
+    live = slot >= 0
+    v = vals.astype(np.int64)[live]
+
+    def scatter(rows, width, sh):
+        acc = np.zeros((rows.shape[0], width, S, 8), np.int64)
+        for f in range(rows.shape[0]):
+            np.add.at(acc[f], (rows[f][live] >> sh, slot[live]), v)
+        return acc
+    return (new, scatter(bins, Bh, shift),
+            None if sel_k is None else scatter(sel_k, B, 0))
+
+
+@pytest.mark.parametrize("F,B,shift,K,S,live,N,hist_chunk,tiled", [
+    pytest.param(28, 256, 3, 8, 16, 1, 8192, 0, True, id="two-level-live1"),
+    pytest.param(28, 256, 3, 8, 16, 4, 8192, 0, True, id="two-level-live4"),
+    pytest.param(28, 256, 3, 8, 16, 8, 8192, 0, True, id="two-level-live8"),
+    pytest.param(28, 256, 3, 8, 16, 16, 8192, 0, True,
+                 id="two-level-live16"),
+    # the (N, 8) limbs, lane-tiled inside the kernel
+    pytest.param(28, 256, 3, 8, 16, 8, 8192, 0, False,
+                 id="two-level-live8-limbs-untiled"),
+    # rows that the ladder's 2,048-row chunk does not divide
+    pytest.param(28, 256, 3, 8, 16, 8, 3072, 1024, True,
+                 id="two-level-3072-rows-chunk1024"),
+    pytest.param(28, 256, 3, 0, 16, 1, 8192, 0, True, id="coarse-only-root"),
+    pytest.param(28, 256, 0, 0, 16, 8, 4096, 0, True, id="full-256"),
+    pytest.param(28, 64, 0, 0, 16, 8, 4096, 0, False, id="full-64"),
+    pytest.param(9, 200, 3, 4, 4, 3, 4096, 0, True, id="odd-bins-4-slots"),
+    pytest.param(9, 200, 0, 0, 4, 3, 4096, 0, False, id="odd-bins-full"),
+])
+def test_fused_pass_is_the_integer_reference_bit_for_bit(
+        F, B, shift, K, S, live, N, hist_chunk, tiled):
+    """The accumulators are int32 sums of int8 products, so no geometry
+    may change a bit of them: ``new_node_id`` and both accumulators equal
+    a numpy scatter of the limbs, at the cell's shapes (28 x 256, shift
+    3, 8 refined, 16 slots) for every count of live slots a tree has, at
+    full resolution, at a tuned chunk, and whether the limbs come as
+    ``prep_hist_vals_rows``'s channel rows or as the (N, 8) matrix."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+
+    rng = np.random.default_rng(F * 1000 + B + live)
+    bins = rng.integers(0, B - 1, (F, N)).astype(np.int32)
+    node_id = rng.integers(0, live + 1, N).astype(np.int32)  # one not split
+    JUNK = 61
+    leaf = np.where(np.arange(S) < live, np.arange(S), JUNK).astype(np.int32)
+    cols = rng.integers(0, F, S)
+    sel = bins[cols]
+    t1 = rng.integers(0, B, S).astype(np.int32)
+    # slot 0 routes by an EFB-style range with a default direction
+    rlo = np.full(S, -1, np.int32)
+    rhi = np.full(S, B, np.int32)
+    rlo[0], rhi[0] = 20, 180
+    dflt = (np.arange(S) % 2).astype(np.int32)
+    l_id = (100 + 2 * np.arange(S)).astype(np.int32)
+    r_id = l_id + 1
+    grad = rng.normal(size=N).astype(np.float32)
+    hess = (np.abs(grad) * 0.5 + 0.2).astype(np.float32)
+    mask = (rng.random(N) < 0.9).astype(np.float32)
+    gh = (jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask))
+    vals, _ = ph.prep_hist_vals(*gh)
+    if tiled:
+        limbs, (vals, _) = vals, ph.prep_hist_vals_rows(*gh)
+        np.testing.assert_array_equal(np.asarray(vals),
+                                      np.tile(np.asarray(limbs).T, (4, 1)))
+    sel_k = bins[rng.choice(F, K, replace=False)] if K else None
+    Bh = ph.coarse_bins(B, shift) if shift else B
+
+    run = jax.jit(functools.partial(
+        ph._route_and_hist_int, n_slots=S, total_bins=B, hist_shift=shift,
+        interpret=True, hist_chunk=hist_chunk))
+    res = run(jnp.asarray(bins), jnp.asarray(node_id), jnp.asarray(leaf),
+              jnp.asarray(sel), jnp.asarray(t1), jnp.asarray(rlo),
+              jnp.asarray(rhi), jnp.asarray(dflt), jnp.asarray(l_id),
+              jnp.asarray(r_id), vals,
+              sel_k=None if sel_k is None else jnp.asarray(sel_k))
+    want_id, want, want_f = _int_reference(
+        bins, node_id, leaf, sel, t1, rlo, rhi, dflt, l_id, r_id,
+        np.asarray(vals)[:8].T if tiled else np.asarray(vals), S, Bh, shift,
+        sel_k, B)
+    assert res[1].dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(res[0])[0], want_id)
+    np.testing.assert_array_equal(
+        np.asarray(res[1]).reshape(F, Bh, S, 8), want)
+    if K:
+        np.testing.assert_array_equal(
+            np.asarray(res[2]).reshape(K, B, S, 8), want_f)
+    assert want[..., 6].sum() > 0           # rows did reach a histogram
+
+
+@pytest.mark.parametrize("F,B,S,shift,K,chunk_override,want", [
+    # the cell: the tile a step builds is 28 x 32 coarse rows beside the
+    # 8 x 256 refined block, so ONE feature group, one step a chunk
+    pytest.param(28, 256, 16, 3, 8, 0, (28, 2048), id="cell-two-level"),
+    pytest.param(28, 256, 16, 3, 0, 0, (28, 2048), id="cell-root-coarse"),
+    # without two-level: what the ladder gave before this function knew
+    # of two-level (four groups of 7 at 256 bins, one group at 64)
+    pytest.param(28, 256, 16, 0, 0, 0, (7, 2048), id="full-256"),
+    pytest.param(28, 64, 16, 0, 0, 0, (28, 2048), id="full-64"),
+    pytest.param(28, 128, 16, 0, 0, 0, (14, 2048), id="full-128"),
+    pytest.param(50, 256, 16, 3, 8, 0, (25, 2048), id="two-groups-of-25"),
+    # a tuned chunk starts the search elsewhere and still shrinks to fit
+    pytest.param(28, 256, 16, 3, 8, 1024, (28, 1024), id="tuned-1024"),
+    pytest.param(28, 256, 16, 3, 8, 8192, (28, 2048), id="tuned-too-big"),
+    # VMEM cannot hold it: the callers fall back (scatter path, or
+    # full-resolution growth for an uncapped refine_features)
+    pytest.param(400, 256, 16, 0, 0, 0, None, id="wide-matrix"),
+    pytest.param(100, 256, 16, 3, 32, 0, None, id="uncapped-refine"),
+])
+def test_fused_geometry_follows_the_tiles_the_pass_builds(
+        F, B, S, shift, K, chunk_override, want):
+    from synapseml_tpu.models.gbdt.pallas_hist import fused_geometry
+    assert fused_geometry(F, B, S, chunk_override, hist_shift=shift,
+                          refine_k=K) == want
+
+
+def test_a_wide_matrix_is_refused_by_name_not_by_mosaic():
+    """Past the gate the pass itself says what does not fit (the
+    booster's gate, ``fused_geometry(...) is None``, takes the scatter
+    path before it)."""
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt.pallas_hist import fused_tiles
+    with pytest.raises(AssertionError, match="does not fit VMEM at F=400"):
+        fused_tiles(jnp.zeros((400, 2048), jnp.int32), 16, 256)
+
+
+def test_a_tuned_hist_chunk_still_goes_through_hist_chunk_ok():
+    """The ``gbdt_hist_chunk`` winner is admitted at the full-resolution
+    geometry of both entry points, as before; the two-level pass then
+    starts its own fit loop from it."""
+    from synapseml_tpu.models.gbdt.pallas_hist import (fused_geometry,
+                                                       hist_chunk_ok)
+    assert hist_chunk_ok(28, 256, 16, 1024)
+    assert hist_chunk_ok(28, 256, 16, 2048)
+    assert hist_chunk_ok(28, 256, 16, 4096)
+    assert not hist_chunk_ok(28, 256, 16, 8192)     # the plain pass shrinks
+    assert not hist_chunk_ok(28, 256, 16, 512)      # under the 1024 floor
+    assert not hist_chunk_ok(28, 256, 16, 3072)     # does not divide the pad
+    assert fused_geometry(28, 256, 16, 1024, hist_shift=3,
+                          refine_k=8) == (28, 1024)
+    assert fused_geometry(28, 256, 16, 4096, hist_shift=3,
+                          refine_k=8) == (28, 2048)  # 4,096 + refined block
+
+
+def test_the_fit_span_says_what_the_geometry_chose():
+    """``gbdt.fit`` carries the depth-wise grower's plan: on the CPU no
+    pallas tile (zeros), and whether the histograms are two-level; the
+    plan itself at the cell's shapes is one step a chunk."""
+    from synapseml_tpu import telemetry
+    from synapseml_tpu.models.gbdt.trainer import (GrowthParams,
+                                                   depthwise_hist_plan)
+    p = GrowthParams(num_leaves=31, total_bins=256, two_level="on",
+                     refine_k=8)
+    assert depthwise_hist_plan(28, 12_001_280, p, 16, bundled=False,
+                               use_pallas=True) == dict(
+        two_level=True, ft=28, feature_groups=1, chunk=2048,
+        grid_steps_per_pass=5860)
+    assert depthwise_hist_plan(28, 12_001_280, p, 16, bundled=True,
+                               use_pallas=True) == dict(
+        two_level=False, ft=7, feature_groups=4, chunk=2048,
+        grid_steps_per_pass=23440)
+    X, y = _data(n=4_000, F=12)
+    train(X, y, BoostingConfig(objective="binary", num_iterations=2,
+                               num_leaves=7, max_bin=255,
+                               two_level_hist="on"))
+    a = telemetry.get_tracer().spans("gbdt.fit")[-1].attrs
+    assert a["hist_two_level"] is True
+    assert (a["hist_ft"], a["hist_feature_groups"], a["hist_chunk"],
+            a["hist_grid_steps_per_pass"]) == (0, 0, 0, 0)
+
+
 def test_fused_refine_vmem_gate():
     """The fused coarse+refine pass models its OWN VMEM need: the bench
     shape fits, an uncapped refine_features does not (and the grower
     then falls back to full resolution instead of failing in Mosaic)."""
-    from synapseml_tpu.models.gbdt.pallas_hist import fused_refine_fits
-    assert fused_refine_fits(28, 256, 16, 3, 8)
-    assert not fused_refine_fits(100, 256, 16, 3, 32)
+    from synapseml_tpu.models.gbdt.pallas_hist import fused_geometry
+    assert fused_geometry(28, 256, 16, hist_shift=3, refine_k=8) is not None
+    assert fused_geometry(100, 256, 16, hist_shift=3, refine_k=32) is None
 
 
 def test_two_level_lossguide_interpret_matches_xla():

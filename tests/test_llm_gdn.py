@@ -20,7 +20,9 @@ Pinned here, at small sizes on the CPU:
   prefill from zero and count it;
 - the paged decode kernel at 30 K/V heads in a cache row padded to 32;
 - both kernels compiled for the v5e at the published geometry (no chip
-  needed: the TPU compiler is installed; nothing runs).
+  needed: the TPU compiler is installed; nothing runs); and, because such
+  compiles belong in ONE test file (its worker holds the TPU library), the
+  GBDT fused histogram pass at the boosting cell's shapes.
 
 Tolerances.  Program and reference both compute in float32 from the same
 bfloat16-rounded weights; they differ in summation order (XLA's CPU dot
@@ -710,3 +712,28 @@ def test_paged_kernel_compiles_for_the_v5e_with_no_copy_of_the_cache(
     assert f"bf16[{n},{max_len * row_heads},128]" in text     # flat rows ...
     assert not re.search(                                       # ... by bitcast
         rf"bf16\[{n},{max_len * row_heads},128\]\S* (copy|fusion)\(", text)
+
+
+@pytest.mark.parametrize("refine_k", [8, 0], ids=["wave", "root"])
+def test_fused_histogram_pass_compiles_for_the_v5e_with_no_copy_of_the_bins(
+        one_chip, refine_k):
+    """At the boosting cell's shapes (12,001,280 x 28, 256 bins, shift 3,
+    8 refined features, 16 slots) the tile `fused_geometry` picks fits the
+    chip's VMEM (the compiler refuses what does not), and with one feature
+    group the binned matrix reaches the kernel as a bitcast: the (4, 7, N)
+    layout of the full-resolution pass is a 1.5 GB copy a tree."""
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+    N, F, B, S = 12_001_280, 28, 256, 16
+    assert ph.fused_geometry(F, B, S, hist_shift=3,
+                             refine_k=refine_k) == (28, 2048)
+
+    def sd(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = ph.route_and_hist_pallas.lower(
+        sd((F, N)), sd((N,)), sd((S,)), sd((S, N)), *[sd((S,))] * 6,
+        sd((32, N), jnp.int8), sd((2,), jnp.float32), n_slots=S,
+        total_bins=B, hist_shift=3,
+        sel_k=sd((refine_k, N)) if refine_k else None).compile().as_text()
+    assert "tpu_custom_call" in text and "route_and_hist_pallas" in text
+    assert re.search(rf"s32\[1,{F},{N}\]\S* bitcast\(", text)
+    assert not re.search(rf"s32\[\d+,\d+,{N}\]\S* (copy|fusion)\(", text)
