@@ -14,6 +14,10 @@ capacity ``C = ceil(capacity_factor · k · N / E)``; overflow tokens fall
 through the residual connection (their combine weights are zeroed).  The
 load-balance auxiliary loss (Switch eq. 4) is sown into the ``losses``
 collection; DLTrainer adds every sown loss to the objective.
+
+This is the trainer's layer.  Serving uses
+:mod:`synapseml_tpu.models.llm.experts`, which has no capacity, drops no
+token and computes one chip's share of the experts exactly.
 """
 
 from __future__ import annotations

@@ -6,10 +6,11 @@ from .finetune import (finetune_lm, make_lm_train_step,
                        templated_log_corpus)
 from .generate import (cast_params, generate, quantize_int8,
                        sample_logits)
+from .experts import ExpertFFN, expert_ffn
 from .model import (LLM_LOGICAL_RULES, CausalAttention, DecoderBlock,
-                    LlamaConfig, LlamaModel, RMSNorm, apply_rope,
-                    causal_lm_loss, init_cache, llama_from_pretrained,
-                    rope_frequencies)
+                    LayerNorm, LlamaConfig, LlamaModel, RMSNorm,
+                    SlidingAttention, apply_rope, causal_lm_loss, init_cache,
+                    llama_from_pretrained, rope_frequencies)
 from .drafter import NgramDrafter
 from .kvtier import (KVTIER_METRICS, TRANSFER_MAGIC, ChecksumError,
                      HostKVArena, KVTransfer, RadixPrefixIndex,
@@ -30,7 +31,7 @@ __all__ = [
     "ChecksumError", "CompilePlane",
     "HostKVArena", "KVTIER_METRICS", "KVTransfer", "TRANSFER_MAGIC",
     "LLM_LOGICAL_RULES", "AdmitResult", "CausalAttention", "DecoderBlock",
-    "LLMTransformer",
+    "ExpertFFN", "LLMTransformer", "LayerNorm", "SlidingAttention",
     "LlamaConfig", "LlamaModel", "NgramDrafter", "PagedGeometry",
     "ProgramSpec",
     "RMSNorm", "RadixPrefixIndex", "SessionJournal", "SessionState",
@@ -40,6 +41,7 @@ __all__ = [
     "unpack_kv_transfer",
     "apply_rope", "causal_lm_loss",
     "cast_params", "dense_read_bytes", "engine_jit_cache_size",
+    "expert_ffn",
     "finetune_lm", "generate",
     "init_cache", "llama_from_pretrained", "make_lm_train_step",
     "paged_decode_attention", "paged_geometry", "paged_read_bytes",

@@ -8,7 +8,12 @@ kind is one mixer class that declares its own cache entry
 family; ``linear_attention`` layers (gated delta rule, a recurrent state in
 place of K/V rows), the OLMo block order, query/key normalisation and a
 decoder without rotary embeddings are rows of the same description
-(:meth:`LlamaConfig.from_hf`).
+(:meth:`LlamaConfig.from_hf`).  So are ``sliding_attention`` layers (the
+attention layer behind a window), a feed-forward kind beside the mixer kind
+(:data:`FFNS`: dense SwiGLU, or the expert layer of
+:mod:`~synapseml_tpu.models.llm.experts`), the parallel block, LayerNorm, a
+head width that is not ``d_model / num_heads``, and interleaved rotary
+embeddings on some layer kinds and none on others.
 
 The reference has no LLM training/serving of its own — its OpenAI stages
 call out to a remote service (reference: cognitive/.../openai/OpenAI.scala
@@ -83,6 +88,42 @@ class LlamaConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False
+    #: width of one attention head; None: ``d_model // num_heads``
+    head_dim: Optional[int] = None
+    #: "rms": RMSNorm; "layer": LayerNorm (mean subtracted, a learned scale,
+    #: no bias), both with ``rms_norm_eps``
+    norm: str = "rms"
+    #: "half": rotary pairs ``(i, i + d/2)`` (Llama, GPT-NeoX);
+    #: "interleaved": pairs ``(2i, 2i + 1)`` (GPT-J)
+    rope_style: str = "half"
+    #: layer kinds that get the rotary embedding; None: every attention
+    #: layer (where ``rope_theta`` is set at all)
+    rope_layers: Optional[Tuple[str, ...]] = None
+    #: keys a ``sliding_attention`` layer's query sees: key ``j`` is visible
+    #: to query ``i`` iff ``i - sliding_window < j <= i``
+    sliding_window: Optional[int] = None
+    #: the logits are multiplied by it
+    logit_scale: float = 1.0
+    #: feed-forward kind of every layer (a key of :data:`FFNS`): "dense"
+    #: (SwiGLU of width ``d_ff``) or "experts"
+    #: (:class:`~synapseml_tpu.models.llm.experts.ExpertFFN`)
+    ffn: str = "dense"
+    # expert layers: the router's width, experts a token selects, shared
+    # experts every token takes (averaged), the width of one expert (None:
+    # ``d_ff``), "sigmoid" or "softmax" selection scores, and whether the
+    # selected scores are normalised to sum to one
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    num_shared_experts: int = 0
+    expert_d_ff: Optional[int] = None
+    expert_selection: str = "softmax"
+    norm_topk_prob: bool = False
+    #: which routed experts THIS program holds: ``experts_held`` of them
+    #: from ``experts_first`` on (None: all ``num_experts``).  The router
+    #: keeps its width and every token its ``num_experts_per_tok``; the
+    #: pairs of absent experts are left out of the layer's result
+    experts_first: int = 0
+    experts_held: Optional[int] = None
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -95,12 +136,44 @@ class LlamaConfig:
             if unknown:
                 raise ValueError(f"unknown layer kinds {sorted(unknown)}; "
                                  f"the model has {sorted(MIXERS)}")
-        if self.norm_order not in ("pre", "post"):
+        if self.rope_layers is not None:
+            self.rope_layers = tuple(self.rope_layers)
+        if self.norm_order not in ("pre", "post", "parallel"):
             raise ValueError(f"norm_order={self.norm_order!r}")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"norm={self.norm!r}")
+        if self.rope_style not in ("half", "interleaved"):
+            raise ValueError(f"rope_style={self.rope_style!r}")
+        if self.ffn not in FFNS:
+            raise ValueError(f"ffn={self.ffn!r}; the model has {sorted(FFNS)}")
+        if "sliding_attention" in self.layer_kinds \
+                and not self.sliding_window:
+            raise ValueError("sliding_attention layers need sliding_window")
+        if self.ffn == "experts":
+            if self.expert_selection not in ("sigmoid", "softmax"):
+                raise ValueError(
+                    f"expert_selection={self.expert_selection!r}")
+            if not 1 <= self.num_experts_per_tok <= self.num_experts:
+                raise ValueError(
+                    f"num_experts_per_tok={self.num_experts_per_tok} of "
+                    f"num_experts={self.num_experts}")
+            if self.experts_first < 0 or self.experts_first \
+                    + self.experts_held_count > self.num_experts:
+                raise ValueError(
+                    f"experts {self.experts_first}..+{self.experts_held} "
+                    f"are not among the router's {self.num_experts}")
+            if self.weight_quant != "none":
+                raise ValueError("expert layers have no int8 path")
 
     @property
     def d_head(self) -> int:
-        return self.d_model // self.num_heads
+        return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def experts_held_count(self) -> int:
+        """Routed experts this program holds (all where none is named)."""
+        return self.num_experts if self.experts_held is None \
+            else int(self.experts_held)
 
     @property
     def kv_cache_heads(self) -> int:
@@ -120,7 +193,13 @@ class LlamaConfig:
 
     @property
     def num_attention_layers(self) -> int:
-        return self.layer_kinds.count("full_attention")
+        """Layers that keep K/V rows by position (full and window)."""
+        return self.layer_kinds.count("full_attention") \
+            + self.num_window_layers
+
+    @property
+    def num_window_layers(self) -> int:
+        return self.layer_kinds.count("sliding_attention")
 
     @property
     def num_recurrent_layers(self) -> int:
@@ -131,11 +210,19 @@ class LlamaConfig:
         """The description from a ``transformers`` ``config.json``'s keys
         (``kw`` overrides: ``max_len``, ``dtype``...).  ``model_type``
         ``olmo_hybrid`` brings the family's block order and query/key
-        norm, which its config does not spell out."""
+        norm, which its config does not spell out; ``cohere2_moe`` brings
+        LayerNorm, rotary embeddings on the window layers alone, and what
+        its keys say in the family's own words (``use_parallel_block``,
+        ``position_embedding_type`` ``rope_gptj``, ``use_qk_norm``)."""
         rope = hc.get("rope_parameters") or {}
         theta = rope["rope_theta"] if "rope_theta" in rope \
             else hc.get("rope_theta", 10_000.0)   # HF's default (Llama-1/2)
         olmo = hc.get("model_type") == "olmo_hybrid"
+        cohere = hc.get("model_type") == "cohere2_moe"
+        eps = hc.get("rms_norm_eps")
+        layer_norm = eps is None and hc.get("layer_norm_eps") is not None
+        if eps is None:
+            eps = hc.get("layer_norm_eps", 1e-5)
         args = dict(
             vocab_size=hc["vocab_size"], d_model=hc["hidden_size"],
             num_layers=hc["num_hidden_layers"],
@@ -145,10 +232,27 @@ class LlamaConfig:
             d_ff=hc["intermediate_size"],
             max_len=int(hc.get("max_position_embeddings", 8192)),
             rope_theta=None if theta is None else float(theta),
-            rms_norm_eps=float(hc.get("rms_norm_eps", 1e-5)),
+            rms_norm_eps=float(eps),
             tie_embeddings=bool(hc.get("tie_word_embeddings", False)),
             layer_types=hc.get("layer_types"),
-            norm_order="post" if olmo else "pre", qk_norm=olmo)
+            norm_order="post" if olmo else "parallel"
+            if hc.get("use_parallel_block") else "pre",
+            qk_norm=olmo or bool(hc.get("use_qk_norm", False)),
+            head_dim=hc.get("head_dim"),
+            norm="layer" if layer_norm else "rms",
+            rope_style="interleaved"
+            if hc.get("position_embedding_type") == "rope_gptj" else "half",
+            rope_layers=("sliding_attention",) if cohere else None,
+            sliding_window=hc.get("sliding_window"),
+            logit_scale=float(hc.get("logit_scale", 1.0)))
+        if hc.get("num_experts"):
+            args.update(
+                ffn="experts", num_experts=hc["num_experts"],
+                num_experts_per_tok=hc["num_experts_per_tok"],
+                num_shared_experts=hc.get("num_shared_experts", 0),
+                expert_d_ff=hc.get("moe_intermediate_size"),
+                expert_selection=hc.get("expert_selection_fn", "softmax"),
+                norm_topk_prob=bool(hc.get("norm_topk_prob", False)))
         if "linear_num_value_heads" in hc:
             if hc.get("linear_num_key_heads") != hc["linear_num_value_heads"]:
                 raise ValueError("linear layers with fewer key heads than "
@@ -199,18 +303,54 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(self.dtype)
 
 
+class LayerNorm(nn.Module):
+    """``(x - mean) / sqrt(var + eps) * scale``: no bias."""
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.with_partitioning(
+            nn.initializers.ones, ("embed",)), (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, -1, keepdims=True)
+        var = jnp.mean(jnp.square(x), -1, keepdims=True)
+        return (x * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
+
+
+def _norm(cfg: "LlamaConfig", name: str):
+    cls = LayerNorm if cfg.norm == "layer" else RMSNorm
+    return cls(cfg.rms_norm_eps, cfg.dtype, name=name)
+
+
 def rope_frequencies(d_head: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, d_head, 2, np.float32) / d_head))
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """x: (B, S, H, D); positions: (B, S) absolute token positions."""
+               theta: float, style: str = "half") -> jnp.ndarray:
+    """x: (B, S, H, D); positions: (B, S) absolute token positions.
+    ``style`` "half" rotates the pairs ``(i, i + D/2)``, "interleaved" the
+    pairs ``(2i, 2i + 1)``; pair ``i`` turns by ``theta^(-2i/D)`` a
+    position in both."""
     d = x.shape[-1]
     inv = jnp.asarray(rope_frequencies(d, theta))          # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * inv   # (B, S, D/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if style == "interleaved":
+        # out[2i] = x[2i] cos_i - x[2i+1] sin_i, out[2i+1] = x[2i+1] cos_i +
+        # x[2i] sin_i, with each element's partner brought beside it by a
+        # roll along the lanes: a reshape to (..., D/2, 2) would put 2 on
+        # the minor dimension, and XLA then relays q_proj's whole weight
+        # into that order every step
+        xf = x.astype(jnp.float32)
+        even = (jnp.arange(d) % 2 == 0)
+        partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                            jnp.roll(xf, 1, axis=-1))
+        out = xf * jnp.repeat(cos, 2, axis=-1) \
+            + partner * jnp.repeat(sin, 2, axis=-1)
+        return out.astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)
@@ -296,8 +436,94 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> List[Dict]:
             for kind in cfg.layer_kinds]
 
 
+#: a prefill's ``(heads, S, T)`` float32 scores above this many bytes are
+#: never built: attention then runs in blocks over keys
+#: (:func:`blocked_attention`).  The dense models the benchmark serves stay
+#: under it (32 heads x 2048 x 2048: 0.5 GiB); 128 heads over a 5,632-row
+#: cache pass it from a 512-token bucket on
+_DENSE_SCORE_BYTES = 1 << 30
+
+
+def _block_len(n: int, most: int = 512) -> int:
+    """The largest power of two up to ``most`` that divides ``n`` (else
+    ``n`` itself: one block)."""
+    b = most
+    while b > 1 and n % b:
+        b //= 2
+    return b if b >= 8 else n
+
+
+def blocked_attention(q, k, v, positions, window: Optional[int], dtype):
+    """Causal softmax attention without the ``(heads, S, T)`` scores.
+
+    ``q (B, S, H, D)`` at ``positions (B, S)`` over ``k``, ``v``
+    ``(B, T, KV, D)`` whose row ``j`` is key position ``j``: query ``i``
+    sees key ``j`` iff ``j <= i`` and, with a ``window``,
+    ``j > i - window``.  Blocks of queries in turn; for each, the key
+    blocks that hold a visible key (none past the causal edge, none before
+    the window) through an online softmax in float32, probabilities cast
+    to ``dtype`` before the product with ``v`` as the dense path casts
+    them.  -> ``(B, S, H * D)``."""
+    B, S, H, D = q.shape
+    T, KV = k.shape[1], k.shape[2]
+    G = H // KV
+    bq, bk = _block_len(S), _block_len(T)
+    neg = jnp.finfo(jnp.float32).min
+    scale = 1.0 / np.sqrt(D)
+    qb = jnp.moveaxis(q.reshape(B, S // bq, bq, KV, G, D), 1, 0)
+    pb = jnp.moveaxis(positions.reshape(B, S // bq, bq), 1, 0)
+
+    def q_block(args):
+        qi, pos = args                         # (B, bq, KV, G, D), (B, bq)
+        hi = jnp.max(pos) // bk + 1
+        lo = 0 if window is None else \
+            jnp.maximum(jnp.min(pos) - (window - 1), 0) // bk
+
+        def k_block(j, carry):
+            m, l, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=1)
+            s = jnp.einsum("bskgd,btkd->bkgst", qi, kj,
+                           preferred_element_type=jnp.float32) * scale
+            kpos = j * bk + jnp.arange(bk)
+            see = kpos[None, None, :] <= pos[:, :, None]        # (B, bq, bk)
+            if window is not None:
+                see &= kpos[None, None, :] > pos[:, :, None] - window
+            see = see[:, None, None]
+            m_new = jnp.maximum(m, jnp.max(jnp.where(see, s, neg), -1))
+            # a query with no visible key in this block adds nothing (its
+            # running max may still be ``neg``: exp(0) would count)
+            p = jnp.where(see, jnp.exp(s - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bkgst,btkd->bkgsd", p.astype(dtype), vj,
+                preferred_element_type=jnp.float32)
+            return m_new, l * alpha + jnp.sum(p, -1), acc
+
+        m0 = jnp.full((B, KV, G, bq), neg, jnp.float32)
+        _, l, acc = jax.lax.fori_loop(
+            lo, hi, k_block,
+            (m0, jnp.zeros_like(m0), jnp.zeros((B, KV, G, bq, D),
+                                               jnp.float32)))
+        # every query sees its own key, so l >= 1
+        out = (acc / l[..., None]).astype(dtype)               # (B,KV,G,bq,D)
+        return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, bq, H * D)
+
+    out = jax.lax.map(q_block, (qb, pb))                       # (S/bq, B, bq, HD)
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, H * D)
+
+
 class CausalAttention(nn.Module):
+    """Grouped-query softmax attention over K/V rows kept by position.
+    :class:`SlidingAttention` is the same layer behind a window."""
     cfg: LlamaConfig
+
+    #: the layer kind (``layer_types`` entry) this class serves
+    KIND = "full_attention"
+
+    @property
+    def window(self) -> Optional[int]:
+        return None
 
     @staticmethod
     def cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> Dict:
@@ -327,10 +553,12 @@ class CausalAttention(nn.Module):
             q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
-        if cfg.rope_theta is not None:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+        if cfg.rope_theta is not None and (
+                cfg.rope_layers is None or self.KIND in cfg.rope_layers):
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_style)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_style)
         v = v.reshape(B, S, KV, D)
+        window = self.window
 
         new_cache = None
         if cache is not None:
@@ -376,10 +604,14 @@ class CausalAttention(nn.Module):
             key_pos = jnp.arange(T)[None, :]                    # (1, T)
             qpos = positions[:, :, None]                        # (B, S, 1)
             causal = key_pos[:, None, :] <= qpos                # (B, S, T)
+            if window is not None:
+                causal &= key_pos[:, None, :] > qpos - window
         else:
             k_att, v_att = k, v
             T = S
             causal = jnp.tril(jnp.ones((S, S), bool))[None]     # (1, S, S)
+            if window is not None:
+                causal &= ~jnp.tril(jnp.ones((S, S), bool), -window)[None]
 
         if (attention_backend in ("paged", "interpret")
                 and cache is not None and jnp.ndim(cache_index) != 0):
@@ -410,8 +642,15 @@ class CausalAttention(nn.Module):
             spans = positions[:, -1].astype(jnp.int32) + 1
             out = paged_decode_attention(
                 q, k_all, v_all, spans, tile=tile, kv_heads=KV,
-                interpret=(attention_backend == "interpret")
-            ).reshape(B, S, H * D)
+                interpret=(attention_backend == "interpret"),
+                window=window).reshape(B, S, H * D)
+        elif H * S * T * 4 > _DENSE_SCORE_BYTES \
+                and (cache is None or jnp.ndim(cache_index) == 0):
+            # without a cache key j is row j of this pass, whatever
+            # ``positions`` says (as the dense mask below has it)
+            rows = positions if cache is not None else \
+                jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+            out = blocked_attention(q, k_att, v_att, rows, window, cfg.dtype)
         else:
             group = H // KV
             qg = q.reshape(B, S, KV, group, D)
@@ -566,15 +805,72 @@ class GatedDeltaNet(nn.Module):
         return out, new_cache
 
 
+class SlidingAttention(CausalAttention):
+    """:class:`CausalAttention` whose query ``i`` sees the keys
+    ``i - sliding_window < j <= i``.  Its cache entry is the full
+    attention layer's: rows of ``max_len`` positions, so that a slot stays
+    a prefix-reuse source at every length (a ring of ``sliding_window``
+    rows would have overwritten the prefix once its owner decoded past it;
+    ROADMAP R6).  The window acts in the masks, in the blocks prefill
+    visits and in the tiles the paged kernel walks."""
+
+    KIND = "sliding_attention"
+
+    @property
+    def window(self) -> Optional[int]:
+        return int(self.cfg.sliding_window)
+
+
 #: mixer kind (a ``layer_types`` entry) -> its class; each declares its
 #: own cache entry and takes the same call
 MIXERS = {"full_attention": CausalAttention,
+          "sliding_attention": SlidingAttention,
           "linear_attention": GatedDeltaNet}
 #: the name of a kind's parameters inside a block
-_MIXER_NAME = {"full_attention": "attn", "linear_attention": "gdn"}
+_MIXER_NAME = {"full_attention": "attn", "sliding_attention": "attn",
+               "linear_attention": "gdn"}
+
+
+def _swiglu(cfg: LlamaConfig, h, valid, backend):
+    """The dense feed-forward: ``down(silu(gate h) * up h)``, its three
+    matrices parameters of the block that calls it."""
+    del valid, backend
+    gate = _dense(cfg.d_ff, ("embed", "mlp"), "gate_proj", cfg.dtype,
+                  cfg.weight_quant)(h)
+    up = _dense(cfg.d_ff, ("embed", "mlp"), "up_proj", cfg.dtype,
+                cfg.weight_quant)(h)
+    return _dense(cfg.d_model, ("mlp", "embed"), "down_proj", cfg.dtype,
+                  cfg.weight_quant)(nn.silu(gate) * up)              # SwiGLU
+
+
+def _experts(cfg: LlamaConfig, h, valid, backend):
+    from .experts import ExpertFFN
+    return ExpertFFN(cfg, name="moe")(h, valid, backend)
+
+
+#: feed-forward kind (``LlamaConfig.ffn``) -> ``f(cfg, h, valid, backend)``,
+#: called inside the block's scope (its modules are the block's): ``h (B, S,
+#: d)``, ``valid (B, S)`` the tokens that are real (an expert layer routes
+#: the others nowhere), ``backend`` the engine's ``attention_backend``
+FFNS = {"dense": _swiglu, "experts": _experts}
+
+
+def _valid_tokens(B: int, S: int, slot_mask, valid_len):
+    """(B, S) bool: not a bucket's padding, not an inactive slot's row."""
+    valid = jnp.ones((B, S), bool)
+    if valid_len is not None:
+        n = jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32), (B,))
+        valid &= jnp.arange(S)[None, :] < n[:, None]
+    if slot_mask is not None:
+        valid &= slot_mask[:, None]
+    return valid
 
 
 class DecoderBlock(nn.Module):
+    """One layer: a mixer (:data:`MIXERS`) and a feed-forward (:data:`FFNS`)
+    around the residual, in ``cfg.norm_order``: "pre" ``x + f(norm(x))``
+    twice, "post" ``x + norm(f(x))`` twice, "parallel" one norm and
+    ``x + mixer(h) + ffn(h)``."""
     cfg: LlamaConfig
     kind: str = "full_attention"
 
@@ -584,21 +880,23 @@ class DecoderBlock(nn.Module):
                  paged_tile: Optional[int] = None,
                  valid_len: Optional[jnp.ndarray] = None):
         cfg = self.cfg
+        mixer = MIXERS[self.kind](cfg, name=_MIXER_NAME[self.kind])
+        ffn = FFNS[cfg.ffn]
+        valid = None if cfg.ffn == "dense" else _valid_tokens(
+            x.shape[0], x.shape[1], slot_mask, valid_len)
+        ln_attn = _norm(cfg, "ln_attn")
+        if cfg.norm_order == "parallel":
+            h = ln_attn(x)
+            a, new_cache = mixer(h, positions, cache, cache_index, slot_mask,
+                                 attention_backend, paged_tile, valid_len)
+            return x + a + ffn(cfg, h, valid, attention_backend), new_cache
         pre = cfg.norm_order == "pre"
-        ln_attn = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_attn")
-        ln_mlp = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_mlp")
-        a, new_cache = MIXERS[self.kind](cfg, name=_MIXER_NAME[self.kind])(
+        ln_mlp = _norm(cfg, "ln_mlp")
+        a, new_cache = mixer(
             ln_attn(x) if pre else x, positions, cache, cache_index,
             slot_mask, attention_backend, paged_tile, valid_len)
         x = x + (a if pre else ln_attn(a))
-        h = ln_mlp(x) if pre else x
-        gate = _dense(cfg.d_ff, ("embed", "mlp"), "gate_proj", cfg.dtype,
-                      cfg.weight_quant)(h)
-        up = _dense(cfg.d_ff, ("embed", "mlp"), "up_proj", cfg.dtype,
-                    cfg.weight_quant)(h)
-        h = nn.silu(gate) * up                                  # SwiGLU
-        h = _dense(cfg.d_model, ("mlp", "embed"), "down_proj", cfg.dtype,
-                   cfg.weight_quant)(h)
+        h = ffn(cfg, ln_mlp(x) if pre else x, valid, attention_backend)
         return x + (h if pre else ln_mlp(h)), new_cache
 
 
@@ -613,10 +911,15 @@ class LlamaModel(nn.Module):
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
                  paged_tile: Optional[int] = None,
-                 valid_len: Optional[jnp.ndarray] = None):
+                 valid_len: Optional[jnp.ndarray] = None,
+                 logits_at: Optional[jnp.ndarray] = None):
         """``valid_len`` (scalar or ``(B,)``): how many of the ``S`` tokens
         are real, the rest a bucket's padding; a recurrent layer must not
-        take a padded token into its state (attention layers ignore it)."""
+        take a padded token into its state (attention layers ignore it).
+        ``logits_at`` (scalar or ``(B,)``): the one position whose logits
+        the caller reads; the head then runs over that row alone and the
+        logits are ``(B, 1, vocab)`` (a prefill reads its last real
+        token's: ``S x vocab`` float32 is 5.9 GB at 5,632 x 262,144)."""
         cfg = self.cfg
         B, S = input_ids.shape
         if positions is None:
@@ -638,16 +941,30 @@ class LlamaModel(nn.Module):
                 x, positions, layer_cache, cache_index, slot_mask,
                 attention_backend, paged_tile, valid_len)
             new_caches.append(nc)
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="ln_final")(x)
+        if logits_at is not None:
+            # one-hot extraction: the position is traced, and a dynamic
+            # gather is slow on the TPU
+            at = jnp.broadcast_to(jnp.asarray(logits_at), (B,))
+            x = jnp.sum(jnp.where(
+                (jnp.arange(S)[None, :] == at[:, None])[..., None], x, 0),
+                axis=1, keepdims=True).astype(x.dtype)
+        x = _norm(cfg, "ln_final")(x)
         if cfg.tie_embeddings:
             if isinstance(embed, QuantEmbed):
                 logits = embed.attend(x)      # f32 accumulation inside
             else:
-                logits = embed.attend(x.astype(jnp.float32))
+                # ``nn.Embed.attend`` would round the logits to the
+                # model's dtype; operands in it, float32 out
+                logits = jax.lax.dot_general(
+                    x, embed.embedding.astype(x.dtype),
+                    (((x.ndim - 1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
         else:
             logits = _dense(cfg.vocab_size, ("embed", "vocab"), "lm_head",
                             jnp.float32, cfg.weight_quant)(x)
         logits = logits.astype(jnp.float32)
+        if cfg.logit_scale != 1.0:
+            logits = logits * cfg.logit_scale
         if cache is not None:
             return logits, new_caches
         return logits

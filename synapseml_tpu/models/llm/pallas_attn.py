@@ -255,17 +255,28 @@ def resolve_attention_backend(backend: str, *, max_len: int,
 # the byte ledger (exact DMA accounting, for telemetry)
 # ---------------------------------------------------------------------------
 
-def paged_live_tiles(spans, tile: int) -> int:
+def paged_live_tiles(spans, tile: int, window: Optional[int] = None,
+                     query_span: int = 1) -> int:
     """Tiles ONE layer's kernel call fetches, and loop trips it makes,
-    for ``spans``: ``ceil(span / tile)`` a slot, at least one."""
-    return int(np.ceil(np.maximum(np.asarray(spans, np.float64), 1.0)
-                       / tile).sum())
+    for ``spans``: ``ceil(span / tile)`` a slot, at least one; behind a
+    ``window`` less the ``floor((span - (query_span - 1) - window) /
+    tile)`` tiles that lie wholly before the first query's window."""
+    spans = np.maximum(np.asarray(spans, np.int64), 1)
+    tiles = -(-spans // tile)
+    if window is not None:
+        tiles = tiles - np.maximum(spans - (query_span - 1) - window, 0) \
+            // tile
+    return int(tiles.sum())
 
 
 def paged_read_bytes(spans, tile: int, num_kv_heads: int, d_head: int,
-                     itemsize: int, num_layers: int = 1) -> int:
+                     itemsize: int, num_layers: int = 1,
+                     window: Optional[int] = None,
+                     query_span: int = 1) -> int:
     """K/V bytes ONE paged decode step DMAs for ``spans``: each slot
-    reads ``ceil(span / tile)`` tiles of K and of V per layer — the
+    reads ``ceil(span / tile)`` tiles of K and of V per layer (behind a
+    ``window``, the tiles from the window's first on:
+    :func:`paged_live_tiles`) — the
     kernel starts one copy of K and one of V a live tile and none
     else, so this is exact by construction, not an estimate
     (``tests/test_llm_paged.py`` counts the copies).
@@ -276,7 +287,8 @@ def paged_read_bytes(spans, tile: int, num_kv_heads: int, d_head: int,
     kernel's wrapper pads it).  ``spans`` must cover EVERY slot in the
     launch, not just the active ones: an inactive slot (span 1) still
     fetches its first tile."""
-    return int(num_layers * 2 * paged_live_tiles(spans, tile) * tile
+    return int(num_layers * 2
+               * paged_live_tiles(spans, tile, window, query_span) * tile
                * num_kv_heads * _pad(d_head, 128) * itemsize)
 
 
@@ -295,10 +307,14 @@ def dense_read_bytes(n_slots: int, max_len: int, num_kv_heads: int,
 # ---------------------------------------------------------------------------
 
 def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
-                        tile: int, total_tiles: int, d_head: int, chunk: int):
+                        tile: int, total_tiles: int, d_head: int, chunk: int,
+                        window: Optional[int] = None):
     """``heads`` query heads in groups of ``group`` over the first K/V
     heads of a cache row of ``row_heads``; ``d_head`` the model's (the
-    lane width the kernel sees may be padded past it)."""
+    lane width the kernel sees may be padded past it).  ``window``: a
+    query at position ``p`` sees the keys ``p - window < j <= p``, and a
+    slot's walk starts at the tile that holds the first query's first
+    visible key."""
     neg = float(np.finfo(np.float32).min)
     hp = _pad(heads, 8)               # rows a query position takes
     q_rows = s_len * hp
@@ -321,10 +337,18 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
         span = spans_ref[s]
 
         def live_tiles(slot):
-            # at least the first (an idle slot's span is 1), never past
-            # the cache row
+            # the tile after the last live one: at least the first (an
+            # idle slot's span is 1), never past the cache row
             return jnp.clip(lax.div(spans_ref[slot] + (tile - 1), tile),
                             1, total_tiles)
+
+        def first_tile(slot):
+            # the tile of the first key the first query sees: key
+            # span - (S - 1) - window
+            if window is None:
+                return 0
+            return lax.div(jnp.maximum(
+                spans_ref[slot] - (s_len - 1) - window, 0), tile)
 
         def copies(slot, t, buf):
             src = pl.ds(t * rows, rows)
@@ -346,12 +370,14 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
             last = t + 1 >= live_tiles(jnp.minimum(slot, n_slots - 1))
             cur_ref[1] = issued + 1
             cur_ref[2] = jnp.where(last, slot + 1, slot)
-            cur_ref[3] = jnp.where(last, 0, t + 1)
+            cur_ref[3] = jnp.where(
+                last, first_tile(jnp.minimum(slot + 1, n_slots - 1)), t + 1)
 
         @pl.when(s == 0)
         def _first():
-            for i in range(4):
+            for i in range(3):
                 cur_ref[i] = 0
+            cur_ref[3] = first_tile(0)
             for _ in range(_RING - 1):
                 issue()
 
@@ -373,6 +399,7 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
         r = lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0)
         kv_of_row = jnp.where(r % hp < heads, (r % hp) // group, 0)
         limit = span - (s_len - 1) + r // hp                 # (S*hp, 1)
+        floor = None if window is None else limit - window   # first key seen
         c = lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
         own = c % row_heads == kv_of_row                     # (S*hp, C)
         key_of_col = c // row_heads                          # (1, C)
@@ -389,8 +416,10 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 kpos = t * tile + c0 // row_heads + key_of_col
-                logits = jnp.where(jnp.logical_and(own, kpos < limit),
-                                   logits, neg)              # (S*hp, C)
+                seen = jnp.logical_and(own, kpos < limit)
+                if floor is not None:
+                    seen = jnp.logical_and(seen, kpos >= floor)
+                logits = jnp.where(seen, logits, neg)        # (S*hp, C)
                 m_prev = m_ref[:, 0:1]
                 m_new = jnp.maximum(
                     m_prev, jnp.max(logits, -1, keepdims=True))
@@ -407,7 +436,7 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
             cur_ref[0] = cur_ref[0] + 1
             return carry
 
-        lax.fori_loop(0, live_tiles(s), tile_body, 0)
+        lax.fori_loop(first_tile(s), live_tiles(s), tile_body, 0)
         # every live query attends >= 1 unmasked key whose probability
         # at the running max is exp(0) = 1, so l >= 1; the floor only
         # guards the impossible all-masked row
@@ -419,7 +448,8 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "num_tiles",
-                                             "interpret", "kv_heads"))
+                                             "interpret", "kv_heads",
+                                             "window"))
 def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
                            k: jnp.ndarray,      # (B, max_len, KV, D)
                            v: jnp.ndarray,      # (B, max_len, KV, D)
@@ -427,7 +457,8 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
                            tile: int,
                            num_tiles: Optional[int] = None,
                            interpret: bool = False,
-                           kv_heads: Optional[int] = None) -> jnp.ndarray:
+                           kv_heads: Optional[int] = None,
+                           window: Optional[int] = None) -> jnp.ndarray:
     """One decode step's attention for every slot, reading only each
     slot's live K/V span: → same shape as ``q``, in ``q.dtype``.
 
@@ -443,7 +474,11 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
     ``k``/``v`` that are real, the first ones, where a cache row is
     padded past them (``LlamaConfig.kv_cache_heads``); the padding is
     fetched with its tile and weighs nothing (it must be finite: the
-    cache's zeros).  ``num_tiles`` is accepted and ignored: the kernel
+    cache's zeros).  ``window`` (None: every earlier key): a query at
+    position ``p`` attends the keys ``p - window < j <= p``; the walk then
+    starts at tile ``floor((span - (S - 1) - window) / tile)``, masks that
+    tile's keys before the window, and neither fetches nor counts a tile
+    before it.  ``num_tiles`` is accepted and ignored: the kernel
     walks each slot's live tiles itself, there is no span bucket (the
     benchmark harness's naming test still passes it; PERF.md §7).
 
@@ -489,7 +524,7 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
     # tiles, so the flat-row view is the same bytes: a bitcast
     out = pl.pallas_call(
         _make_decode_kernel(S, H, H // heads, row_heads, tile, T // tile,
-                            d_head, chunk),
+                            d_head, chunk, window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
         # the ring's cursor runs from one slot into the next: in order

@@ -15,7 +15,13 @@ kind declares (``model.MIXERS[kind].cache_entry``):
 - a *full-attention* layer keeps rows of keys and values by token
   position, ``(n_slots, max_len, kv_heads, d_head)``.  A position can be
   sliced, copied, overwritten and rolled back, and everything below that
-  reuses or moves a slot's history works on such rows;
+  reuses or moves a slot's history works on such rows.  A
+  *sliding-attention* layer keeps the same rows (all ``max_len`` of them:
+  a ring of ``sliding_window`` rows could not be a prefix-reuse source
+  once its owner had decoded past the prefix), so prefix reuse, the host
+  arena, preemption, disaggregated prefill and speculative verify work
+  on it unchanged; its window acts in the masks and in the tiles the
+  paged kernel walks, and the byte ledger counts ``min(span, window)``;
 - a *linear-attention* layer keeps a recurrent state and a convolution
   window of a fixed size whatever the length
   (``{"state": (n_slots, heads/pack, d_k, pack*d_v) f32, "conv":
@@ -95,6 +101,15 @@ Mechanics:
   needs the host's tokens BEFORE a step, so a speculative engine
   dispatches and reads each step in one call.
 
+**Expert layers** (``cfg.ffn == "experts"``,
+:mod:`~synapseml_tpu.models.llm.experts`): the program holds some of a
+layer's routed experts and computes their part of the result; a token that
+is a bucket's padding or an inactive slot's row routes nowhere.  Each
+program returns two counts with its tokens or logits (pairs computed here,
+held experts touched), which become attributes of ``engine.step`` and
+``engine.admit`` and the counters ``llm_expert_pairs_total`` and
+``llm_experts_touched_total``.
+
 Junk-write safety: padded prefill rows and pre-copy leftovers only ever
 land at positions strictly beyond a slot's current length; decode writes
 position ``q`` BEFORE attending ``<= q``, so every attendable key was
@@ -124,11 +139,25 @@ from ...telemetry.flight import record as _flight_record
 from .drafter import NgramDrafter
 from .kvtier import ChecksumError, RadixPrefixIndex, kvtier_metrics
 from .generate import sample_logits
+from .experts import stats_totals
 from .model import LlamaModel, init_cache
 from .pallas_attn import (dense_read_bytes, paged_geometry,
                           paged_live_tiles, paged_read_bytes,
                           resolve_attention_backend)
 from .pallas_gdn import resolve_recurrent_backend, slot_state_bytes
+
+
+def _apply(model: LlamaModel, variables: Any, *args, **kw):
+    """``model.apply`` -> ``(logits, cache, expert counts)``: for a model
+    with expert layers int32 ``[expert_pairs_held, experts_touched]`` of
+    the pass (:func:`~synapseml_tpu.models.llm.experts.stats_totals`),
+    else None.  The counts leave a program inside the array its tokens or
+    logits leave in, so reading them is no transfer of its own."""
+    if model.cfg.ffn != "experts":
+        return (*model.apply(variables, *args, **kw), None)
+    (logits, cache), state = model.apply(variables, *args, mutable=["stats"],
+                                         **kw)
+    return logits, cache, stats_totals(state["stats"])
 
 
 @functools.partial(jax.jit, static_argnames=("model", "attention_backend"),
@@ -140,7 +169,8 @@ def _prefill_slot_jit(model: LlamaModel, variables: Any, cache: Any,
     """Prefill ``plen`` real tokens (``tokens`` is padded to a static
     bucket length) into row ``slot`` starting at position ``start``.
     Returns ``(new_cache, last_logits (V,) f32)`` where ``last_logits``
-    is the row for the prompt's true last token.
+    is the row for the prompt's true last token; a model with expert
+    layers appends its two counts (:func:`_apply`) as float32.
 
     Padding rows of K/V are junk that is overwritten before it is read;
     a recurrent layer takes ``valid_len=plen`` and leaves its state as
@@ -151,17 +181,17 @@ def _prefill_slot_jit(model: LlamaModel, variables: Any, cache: Any,
     row = jax.tree.map(
         lambda c: lax.dynamic_slice_in_dim(c, slot, 1, axis=0), cache)
     positions = (start + jnp.arange(pb))[None, :]
-    logits, row = model.apply(variables, tokens[None, :],
-                              positions=positions, cache=row,
-                              cache_index=start, valid_len=plen,
-                              attention_backend=attention_backend)
+    logits, row, counts = _apply(model, variables, tokens[None, :],
+                                 positions=positions, cache=row,
+                                 cache_index=start, valid_len=plen,
+                                 logits_at=plen - 1,
+                                 attention_backend=attention_backend)
     new_cache = jax.tree.map(
         lambda c, r: lax.dynamic_update_slice_in_dim(c, r, slot, axis=0),
         cache, row)
-    # one-hot extraction: plen is traced, and a dynamic gather is slow on
-    # the TPU
-    last = jnp.sum(jnp.where((jnp.arange(pb) == plen - 1)[:, None],
-                             logits[0], 0.0), axis=0)
+    last = logits[0, 0]
+    if counts is not None:
+        last = jnp.concatenate([last, counts.astype(jnp.float32)])
     return new_cache, last
 
 
@@ -195,15 +225,17 @@ def _decode_step_jit(model: LlamaModel, variables: Any, cache: Any,
     program serves every span and the engine passes none (the benchmark
     harness's naming test does, PERF.md §7)."""
     if prev_nxt is not None:
-        tokens = jnp.where(feed_host, tokens, prev_nxt)
+        tokens = jnp.where(feed_host, tokens, prev_nxt[:tokens.shape[0]])
     positions = (lengths - 1)[:, None]
-    logits, cache = model.apply(variables, tokens[:, None],
-                                positions=positions, cache=cache,
-                                cache_index=lengths - 1, slot_mask=active,
-                                attention_backend=attention_backend,
-                                paged_tile=paged_tile)
+    logits, cache, counts = _apply(model, variables, tokens[:, None],
+                                   positions=positions, cache=cache,
+                                   cache_index=lengths - 1, slot_mask=active,
+                                   attention_backend=attention_backend,
+                                   paged_tile=paged_tile)
     key, sub = jax.random.split(key)
     nxt = sample_logits(logits[:, 0], sub, temperature, top_k, top_p)
+    if counts is not None:
+        nxt = jnp.concatenate([nxt, counts.astype(nxt.dtype)])
     return cache, nxt, key
 
 
@@ -231,14 +263,19 @@ def _verify_step_jit(model: LlamaModel, variables: Any, cache: Any,
     before it is ever attendable).  Greedy only: acceptance compares
     argmax, which is exactly the temperature-0 sampling rule.
     ``paged_num_tiles``: accepted and ignored, as in
-    :func:`_decode_step_jit`."""
+    :func:`_decode_step_jit`.  A model with expert layers appends one row
+    whose first two entries are its counts (:func:`_apply`; they count the
+    drafted tokens too, rejected or not: each was routed)."""
     positions = (lengths - 1)[:, None] + jnp.arange(tokens.shape[1])[None, :]
-    logits, cache = model.apply(variables, tokens, positions=positions,
-                                cache=cache, cache_index=lengths - 1,
-                                slot_mask=active,
-                                attention_backend=attention_backend,
-                                paged_tile=paged_tile)
-    return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    logits, cache, counts = _apply(model, variables, tokens,
+                                   positions=positions, cache=cache,
+                                   cache_index=lengths - 1, slot_mask=active,
+                                   attention_backend=attention_backend,
+                                   paged_tile=paged_tile)
+    g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if counts is not None:
+        g = jnp.concatenate([g, jnp.pad(counts, (0, g.shape[1] - 2))[None]])
+    return cache, g
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -394,6 +431,9 @@ class SlotEngine:
         #: layers whose state cannot be sliced by token position (module
         #: docstring, "Two kinds of state")
         self.recurrent = self.cfg.num_recurrent_layers > 0
+        #: the model has expert layers: its programs return two counts
+        #: with their tokens (:func:`_apply`)
+        self.experts = self.cfg.ffn == "experts"
         if self.recurrent:
             if spec_draft_len:
                 raise ValueError(
@@ -484,7 +524,8 @@ class SlotEngine:
         #: slot's admissions and resumes
         self._flight: Optional[_Flight] = None
         self._epoch = np.zeros(n, np.int64)
-        self._no_prev = jnp.zeros(n, jnp.int32)   # ``prev_nxt`` of a first step
+        # ``prev_nxt`` of a first step (an expert model's carries two counts)
+        self._no_prev = jnp.zeros(n + (2 if self.experts else 0), jnp.int32)
         # radix prefix indices over slot contexts, ONE PER TENANT:
         # longest_prefix is exact by construction (tokens, not hashes),
         # so reuse finds the TRUE longest match with no candidate probe
@@ -560,6 +601,26 @@ class SlotEngine:
             "engine holds beside its K/V cache (every slot, every "
             "linear-attention layer)", ("engine",)
         ).set(self.n_slots * self.slot_state_bytes, engine=name)
+        self._m_expert_pairs = reg.counter(
+            "llm_expert_pairs_total",
+            "(token, expert) pairs whose expert this program holds, computed "
+            "in decode steps and prefills (summed over layers)", ("engine",))
+        self._m_experts_touched = reg.counter(
+            "llm_experts_touched_total",
+            "held experts with at least one pair, summed over layers and "
+            "over decode steps and prefills: the expert weights read",
+            ("engine",))
+        if self.experts:
+            cfg = self.cfg
+            reg.gauge(
+                "llm_expert_weight_bytes_held",
+                "device bytes of the routed experts this program holds "
+                "(every expert layer)", ("engine",)
+            ).set(cfg.num_layers * cfg.experts_held_count * 3 * cfg.d_model
+                  * (cfg.expert_d_ff or cfg.d_ff)
+                  * np.dtype(cfg.dtype).itemsize, engine=name)
+        #: the last read program's expert counts, for its span
+        self._step_experts: Dict[str, int] = {}
         self._m_overlap = reg.counter(
             "llm_steps_overlapped_total",
             "decode steps dispatched before the previous step's tokens "
@@ -867,6 +928,9 @@ class SlotEngine:
             if sp.live:
                 sp.set(bucket=res.bucket, prompt_tokens=len(prompt),
                        reused_tokens=res.reused_tokens, path=res.path)
+                if self.experts:
+                    sp.set(expert_pairs_held=self._step_experts[
+                        "expert_pairs_held"])
             return res
 
     def _admit_into(self, slot: int, prompt: np.ndarray, max_new: int,
@@ -923,7 +987,7 @@ class SlotEngine:
                     self.model, self.variables, self.cache,
                     jnp.asarray(padded), len(tail), slot, lcp,
                     attention_backend=self.attention_backend)
-            logits = np.asarray(last, np.float32)
+            logits = self._count_experts(np.asarray(last, np.float32))
         with step_span("engine.admit.commit"):
             tok = self._sample_host(logits)
             plen = len(prompt)
@@ -960,6 +1024,19 @@ class SlotEngine:
                 slot, tok, finished, lcp, logits, bucket=pb, reason=reason,
                 path="restore" if restored else "reuse" if lcp
                 else "cold_recurrent" if skipped else "cold")
+
+    def _count_experts(self, out: np.ndarray) -> np.ndarray:
+        """Split what a program of a model with expert layers returned:
+        its last two entries are the pass's counts (:func:`_apply`).
+        -> the tokens or logits alone."""
+        if not self.experts:
+            return out
+        pairs, touched = int(out[-2]), int(out[-1])
+        self._step_experts = {"expert_pairs_held": pairs,
+                              "experts_touched": touched}
+        self._m_expert_pairs.inc(pairs, engine=self.name)
+        self._m_experts_touched.inc(touched, engine=self.name)
+        return out[:-2]
 
     # -- stepping ----------------------------------------------------------
     def _finish_reason(self, slot: int,
@@ -1180,23 +1257,31 @@ class SlotEngine:
         return {"attention_backend": self.attention_backend,
                 "paged_tile": None if geo is None else geo.tile}, lengths
 
-    def _account_decode_bytes(self, spans: np.ndarray, served: int) -> None:
+    def _account_decode_bytes(self, spans: np.ndarray, served: int,
+                              query_span: int = 1) -> None:
         """Per-step decode-attention K/V read accounting → the
         ``llm_decode_bytes_per_token`` gauge (exact for the paged kernel:
         it fetches a slot's live tiles and nothing else — ``spans``
         covers ALL slots, inactive ones at span 1, because every slot
         fetches at least its first tile; the full-capacity model for
-        dense) and the step's tile counts for the ``engine.step`` span."""
+        dense) and the step's tile counts for the ``engine.step`` span.
+        ``query_span``: the S of a verify step, whose ``spans`` include its
+        S written positions."""
         itemsize = np.dtype(self.cfg.dtype).itemsize
         layers = self.cfg.num_attention_layers
         if self._paged_geo is not None:
             tile = self._paged_geo.tile
-            nbytes = paged_read_bytes(
+            # (layers, window) of each attention kind: a window layer's
+            # walk starts at its window's first tile
+            kinds = [(layers - self.cfg.num_window_layers, None),
+                     (self.cfg.num_window_layers, self.cfg.sliding_window)]
+            nbytes = sum(paged_read_bytes(
                 spans, tile, self.cfg.kv_cache_heads, self.cfg.d_head,
-                itemsize, layers)
+                itemsize, n, window, query_span) for n, window in kinds if n)
             # the kernel makes one loop trip a tile it fetches: walked
             # over live is 1.0 while no dead tile is walked
-            live = layers * paged_live_tiles(spans, tile)
+            live = sum(n * paged_live_tiles(spans, tile, window, query_span)
+                       for n, window in kinds if n)
             self._step_tiles = {"paged_tiles_live": live,
                                 "paged_tiles_walked": live}
         else:
@@ -1240,6 +1325,10 @@ class SlotEngine:
                        kv_span_sum=int(self.lengths[act].sum()),
                        state_bytes=2 * int(act.sum()) * self.slot_state_bytes,
                        overlapped=flight is not None)
+                if self.cfg.num_window_layers:
+                    # what a window layer has to read of those spans
+                    sp.set(kv_window_span_sum=int(np.minimum(
+                        self.lengths[act], self.cfg.sliding_window).sum()))
             events = None
             if self._drafter is not None:
                 with step_span("engine.step.draft"):
@@ -1251,7 +1340,7 @@ class SlotEngine:
                 events = self._plain_step()
             if sp.live:
                 sp.set(tokens=len(events), program=self.last_program,
-                       **self._step_tiles)
+                       **self._step_tiles, **self._step_experts)
             return events
 
     def _finish_step(self, events: List[StepEvent]) -> List[StepEvent]:
@@ -1322,7 +1411,8 @@ class SlotEngine:
         self._flight = (self._dispatch(flight) if self._drafter is None
                         else None)
         with step_span("engine.step.wait"):
-            nxt = np.asarray(flight.nxt)      # the step's one blocking call
+            # the step's one blocking call
+            nxt = self._count_experts(np.asarray(flight.nxt))
         with step_span("engine.step.commit"):
             self.last_program = flight.program
             if overlapped:
@@ -1434,6 +1524,9 @@ class SlotEngine:
                     self.model, self.variables, self.cache, *step_in, **kw)
         with step_span("engine.step.wait"):
             g = np.asarray(g)         # the step's one blocking call
+            if self.experts:
+                self._count_experts(g[-1, :2])
+                g = g[:-1]
         with step_span("engine.step.commit"):
             return self._finish_step(
                 self._commit_verified(tokens, g, klen, lengths, S))
@@ -1492,7 +1585,7 @@ class SlotEngine:
                 events.append(StepEvent(slot, int(tok),
                                         finished and last,
                                         reason if last else None))
-        self._account_decode_bytes(lengths + (S - 1), max(1, served))
+        self._account_decode_bytes(lengths + (S - 1), max(1, served), S)
         return events
 
     def _adapt_slot(self, slot: int, acceptance: float) -> None:

@@ -74,6 +74,9 @@ REGISTERED_ENTRY_POINTS = {
     # prefill programs as the paged kernel is inside decode
     "synapseml_tpu.models.llm.pallas_gdn": frozenset({
         "gated_delta_decode", "gated_delta_prefill"}),
+    # the grouped product of expert layers: inside every program of a
+    # model that has them
+    "synapseml_tpu.models.llm.experts": frozenset({"expert_ffn"}),
     # non-LLM tunable entry points: not part of the serving lattice, but
     # the autotune source-scan lint requires every registered search
     # space to time a program listed here — the registry doubles as the
@@ -160,7 +163,7 @@ def program_lattice(engine) -> List[ProgramSpec]:
         cache, nxt, _ = _decode_step_jit(
             model, variables, cache, tokens, lengths, active,
             jax.random.PRNGKey(0), engine.temperature, engine.top_k,
-            engine.top_p, prev_nxt=jnp.zeros(n, jnp.int32),
+            engine.top_p, prev_nxt=jnp.zeros_like(engine._no_prev),
             feed_host=jnp.asarray(np.ones(n, bool)), **step_kwargs)
         jax.block_until_ready(nxt)
         return cache
@@ -337,14 +340,23 @@ class CompilePlane:
         t0 = time.monotonic()
         cfg = self.engine.cfg
         warm_span = span("llm.warmup", engine=self.name).start()
+        # inline, the engine is under construction and has admitted
+        # nothing: the lattice threads the engine's OWN cache, which gets
+        # fresh zeros afterwards (a second tree beside the weights, the
+        # cache and a prefill's temporaries did not fit the chip at the
+        # benchmark's largest model: 13.56 + 2.21 + 2.36 GB)
+        own = reraise and not self.engine.active.any()
         try:
-            # scratch state shaped exactly like the engine's cache: the
-            # jitted programs donate their cache argument, so one
-            # scratch tree threads through the whole lattice and dies
-            # with this frame (transiently 2x cache memory — warmup
-            # runs before admission fills the real one)
-            cache = init_cache(cfg, self.engine.n_slots,
-                               self.engine.max_len)
+            # state shaped exactly like the engine's cache: the jitted
+            # programs donate their cache argument, so one tree threads
+            # through the whole lattice.  A background warmup runs beside
+            # a serving engine and takes a scratch tree that dies with
+            # this frame (transiently 2x cache memory)
+            if own:
+                cache, self.engine.cache = self.engine.cache, None
+            else:
+                cache = init_cache(cfg, self.engine.n_slots,
+                                   self.engine.max_len)
             while True:
                 spec = self._pop_next()
                 if spec is None:
@@ -369,6 +381,10 @@ class CompilePlane:
                 raise
             return
         finally:
+            if own:
+                cache = None        # the junk the programs wrote: freed
+                self.engine.cache = init_cache(cfg, self.engine.n_slots,
+                                               self.engine.max_len)
             warm_span.set(programs=len(self._warmed))
             warm_span.close()
         self.warmup_seconds = time.monotonic() - t0

@@ -714,6 +714,53 @@ def test_paged_kernel_compiles_for_the_v5e_with_no_copy_of_the_cache(
         rf"bf16\[{n},{max_len * row_heads},128\]\S* (copy|fusion)\(", text)
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_paged_kernel_compiles_for_the_v5e_at_128_heads_over_8(one_chip,
+                                                              window):
+    """Command A+'s geometry: 24 slots of 5,632 positions, 128 query heads
+    in groups of 16 over a row of 8 K/V heads, with and without the window's
+    walk; the cache still reaches the kernel as a bitcast."""
+    n, max_len, heads, kv = 24, 5632, 128, 8
+    geo = paged_geometry(max_len, heads, kv, 128, jnp.bfloat16)
+    assert geo is not None and geo.tile == 128
+
+    def sd(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    cache = sd((n, max_len, kv, 128))
+    compiled = paged_decode_attention.lower(
+        sd((n, heads, 128)), cache, cache, sd((n,), jnp.int32),
+        tile=geo.tile, kv_heads=kv, window=window).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert not re.search(
+        rf"bf16\[{n},{max_len * kv},128\]\S* (copy|fusion)\(", text)
+
+
+@pytest.mark.parametrize("pairs,tm", [(192, 16), (8192, 256)],
+                         ids=["decode", "prefill-chunk"])
+def test_expert_ffn_compiles_for_the_v5e_at_the_published_widths(
+        one_chip, pairs, tm):
+    """The expert layer's grouped product at 16 experts of 4,096 x 4,096:
+    a decode step's 192 pairs in tiles of 16 rows, a prefill chunk's 8,192
+    in tiles of 256; both its forms (gate and up in one, then down), the
+    weights left where they are (no copy of 0.5 GB)."""
+    from synapseml_tpu.models.llm import experts as X
+    assert X._row_tile(pairs, 16) == tm
+    tiles = -(-pairs // tm) + 16
+
+    def sd(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    x, w = sd((tiles * tm, 4096)), sd((16, 4096, 4096))
+    te, na = sd((tiles,), jnp.int32), sd((1,), jnp.int32)
+    for args, kw in (((x, te, na, w, w), {}),
+                     ((x, te, na, w), {"out_dtype": jnp.float32})):
+        compiled = X.expert_ffn.lower(*args, tm=tm, **kw).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and "expert_ffn" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("refine_k", [8, 0], ids=["wave", "root"])
 def test_fused_histogram_pass_compiles_for_the_v5e_with_no_copy_of_the_bins(
         one_chip, refine_k):

@@ -211,6 +211,42 @@ class TestTokenExactness:
                     "from dense greedy")
 
 
+class TestSyncWarmupMemory:
+    def test_sync_warmup_never_holds_two_cache_trees(self, tiny_model,
+                                                     monkeypatch):
+        """Inline, the lattice threads the engine's own cache and the
+        engine gets fresh zeros afterwards: the weights, TWO cache trees
+        and a prefill's temporaries do not fit the chip at the
+        benchmark's largest model.  A background warmup runs beside a
+        serving engine and keeps its scratch tree."""
+        cfg, model, variables = tiny_model
+        real_init, real_warm = warmup_mod.init_cache, \
+            warmup_mod.CompilePlane._warm_all
+        planes, engine_had_none = [], []
+
+        def warm_all(plane, **kw):
+            planes.append(plane)
+            return real_warm(plane, **kw)
+
+        def init_cache(*a):
+            # what the plane allocates, and what the engine held then
+            engine_had_none.append(planes[-1].engine.cache is None)
+            return real_init(*a)
+        monkeypatch.setattr(warmup_mod.CompilePlane, "_warm_all", warm_all)
+        monkeypatch.setattr(warmup_mod, "init_cache", init_cache)
+        eng = SlotEngine(model, variables, n_slots=2, max_len=64,
+                         warmup="sync", name="own-cache")
+        assert eng.compile_plane.status == "warm"
+        assert engine_had_none == [True]
+        assert all(not np.asarray(leaf).any()
+                   for leaf in jax.tree.leaves(eng.cache))
+        ids = _prompts(cfg, 1, 9, seed=2)
+        slot = eng.admit(ids[0], 6).slot
+        assert np.array_equal(
+            eng.run_to_completion()[slot],
+            generate(model, variables, ids, max_new_tokens=6)[0])
+
+
 class TestReadinessGating:
     def test_readyz_gates_until_warm_and_requests_are_held(self,
                                                            tiny_model):
