@@ -7,7 +7,9 @@ layer is told which ones it holds (``LlamaConfig.experts_first``,
 - the router runs over ALL ``num_experts`` in float32 and every token
   selects its ``num_experts_per_tok`` (sigmoid or softmax scores; with
   ``norm_topk_prob`` the selected scores are normalised over all the
-  selected, held here or not);
+  selected, held here or not; with ``expert_selection_bias`` the choice is
+  by ``score + bias``, a learned float32 bias an expert, and the weights are
+  the scores without it);
 - the (token, expert) pairs whose expert is held are grouped by expert and
   go through one grouped product, :func:`expert_ffn`, that reads an
   expert's weights only if it has a pair.  No capacity: a group is as long
@@ -254,7 +256,14 @@ class ExpertFFN(nn.Module):
                     precision=lax.Precision.HIGHEST).astype(jnp.float32)
         score = jax.nn.sigmoid(r) if cfg.expert_selection == "sigmoid" \
             else jax.nn.softmax(r, axis=-1)
-        top, idx = lax.top_k(score, k)                            # (T, k)
+        if cfg.expert_selection_bias:
+            # selected by score + bias, weighed by the score alone
+            bias = self.param("router_bias", nn.initializers.zeros_init(),
+                              (E,), jnp.float32)
+            _, idx = lax.top_k(score + bias, k)
+            top = jnp.take_along_axis(score, idx, axis=-1)
+        else:
+            top, idx = lax.top_k(score, k)                        # (T, k)
         weight = top / jnp.sum(top, -1, keepdims=True) \
             if cfg.norm_topk_prob else top
         held = (idx >= first) & (idx < first + H) & valid.reshape(T, 1)

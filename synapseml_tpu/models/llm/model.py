@@ -9,11 +9,15 @@ family; ``linear_attention`` layers (gated delta rule, a recurrent state in
 place of K/V rows), the OLMo block order, query/key normalisation and a
 decoder without rotary embeddings are rows of the same description
 (:meth:`LlamaConfig.from_hf`).  So are ``sliding_attention`` layers (the
-attention layer behind a window), a feed-forward kind beside the mixer kind
-(:data:`FFNS`: dense SwiGLU, or the expert layer of
-:mod:`~synapseml_tpu.models.llm.experts`), the parallel block, LayerNorm, a
-head width that is not ``d_model / num_heads``, and interleaved rotary
-embeddings on some layer kinds and none on others.
+attention layer behind a window, its cache entry a ring where ``max_len``
+holds two rings: :class:`SlidingAttention`), a feed-forward kind beside the
+mixer kind, for every layer or layer by layer (:data:`FFNS`: dense SwiGLU, or
+the expert layer of :mod:`~synapseml_tpu.models.llm.experts`), the parallel
+block, LayerNorm, a head width that is not ``d_model / num_heads``,
+interleaved rotary embeddings on some layer kinds and none on others, and
+what ONE layer kind's attention overrides (:class:`AttentionKind`: its K/V
+heads, a key wider than the value, rotary embeddings on part of a head with
+the kind's own theta, a sink logit a head, a scale on the values).
 
 The reference has no LLM training/serving of its own — its OpenAI stages
 call out to a remote service (reference: cognitive/.../openai/OpenAI.scala
@@ -49,6 +53,43 @@ LLM_LOGICAL_RULES = (
     ("vocab", "model"),
     ("seq", None),
 )
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """What the attention of ONE layer kind (a ``layer_types`` entry) differs
+    in from the description's defaults; ``None``: the default
+    (``LlamaConfig.attention`` fills them in).  A kind that has such an entry
+    keeps its K/V rows packed (:func:`kv_pack`)."""
+    #: K/V heads (default ``num_kv_heads``)
+    num_kv_heads: Optional[int] = None
+    #: width of a query and a key head (default ``d_head``)
+    head_dim: Optional[int] = None
+    #: width of a value head (default: the key's)
+    v_head_dim: Optional[int] = None
+    #: the FIRST dims of a query and key head that take the rotary
+    #: embedding, the rest pass untouched (default: all of them)
+    rotary_dim: Optional[int] = None
+    #: default ``rope_theta`` (under ``rope_layers``)
+    rope_theta: Optional[float] = None
+    #: a learned logit a query head: one more column in the softmax that
+    #: takes mass and contributes no value, whatever the mask
+    sink: bool = False
+    #: the values are multiplied by it before the probabilities meet them
+    value_scale: float = 1.0
+
+
+#: rows of one block of a window layer's ring (:meth:`SlidingAttention
+#: .cache_rows`): the paged kernel's tile on a ring is at most this
+RING_BLOCK = 128
+
+
+def kv_pack(k_dim: int, kv_heads: int) -> int:
+    """K/V heads that lie side by side in one packed cache row: as many as
+    make the key row whole lane tiles (192 wide: 2, a row of 384 lanes and
+    no padding), where the kind's heads divide into such groups; else 1."""
+    f = 128 // np.gcd(int(k_dim), 128)
+    return int(f) if f <= 2 and kv_heads % f == 0 else 1
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -106,7 +147,8 @@ class LlamaConfig:
     logit_scale: float = 1.0
     #: feed-forward kind of every layer (a key of :data:`FFNS`): "dense"
     #: (SwiGLU of width ``d_ff``) or "experts"
-    #: (:class:`~synapseml_tpu.models.llm.experts.ExpertFFN`)
+    #: (:class:`~synapseml_tpu.models.llm.experts.ExpertFFN`, of width
+    #: ``expert_d_ff``); ``ffn_types`` says it layer by layer
     ffn: str = "dense"
     # expert layers: the router's width, experts a token selects, shared
     # experts every token takes (averaged), the width of one expert (None:
@@ -124,8 +166,36 @@ class LlamaConfig:
     #: pairs of absent experts are left out of the layer's result
     experts_first: int = 0
     experts_held: Optional[int] = None
+    #: what a layer kind's attention overrides: ``{kind: AttentionKind}``
+    #: (or the dict of its fields); kept as a sorted tuple of pairs
+    attention_kinds: Optional[Any] = None
+    #: feed-forward kind of EACH layer (keys of :data:`FFNS`); None: ``ffn``
+    #: for every layer
+    ffn_types: Optional[Tuple[str, ...]] = None
+    #: the router selects by ``score + bias`` (a learned float32 bias an
+    #: expert) and weighs by ``score`` alone
+    expert_selection_bias: bool = False
 
     def __post_init__(self):
+        if self.attention_kinds is not None:
+            pairs = dict(self.attention_kinds)
+            unknown = set(pairs) - {"full_attention", "sliding_attention"}
+            if unknown:
+                raise ValueError(f"attention_kinds names {sorted(unknown)}: "
+                                 "not attention layer kinds")
+            self.attention_kinds = tuple(sorted(
+                (k, v if isinstance(v, AttentionKind) else AttentionKind(**v))
+                for k, v in pairs.items()))
+        if self.ffn_types is not None:
+            self.ffn_types = tuple(self.ffn_types)
+            if len(self.ffn_types) != self.num_layers:
+                raise ValueError(
+                    f"ffn_types names {len(self.ffn_types)} layers, "
+                    f"num_layers is {self.num_layers}")
+            unknown = set(self.ffn_types) - set(FFNS)
+            if unknown:
+                raise ValueError(f"ffn_types={sorted(unknown)}; the model "
+                                 f"has {sorted(FFNS)}")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)      # hashable
             if len(self.layer_types) != self.num_layers:
@@ -149,7 +219,17 @@ class LlamaConfig:
         if "sliding_attention" in self.layer_kinds \
                 and not self.sliding_window:
             raise ValueError("sliding_attention layers need sliding_window")
-        if self.ffn == "experts":
+        for kind in self.attention_layer_kinds:
+            a = self.attention(kind)
+            if self.num_heads % a.num_kv_heads:
+                raise ValueError(
+                    f"{kind}: {self.num_heads} query heads do not divide "
+                    f"into {a.num_kv_heads} K/V heads")
+            if a.rotary_dim % 2 or a.rotary_dim > a.head_dim:
+                raise ValueError(
+                    f"{kind}: rotary_dim={a.rotary_dim} of a head of "
+                    f"{a.head_dim}")
+        if self.has_experts:
             if self.expert_selection not in ("sigmoid", "softmax"):
                 raise ValueError(
                     f"expert_selection={self.expert_selection!r}")
@@ -174,6 +254,43 @@ class LlamaConfig:
         """Routed experts this program holds (all where none is named)."""
         return self.num_experts if self.experts_held is None \
             else int(self.experts_held)
+
+    @property
+    def ffn_kinds(self) -> Tuple[str, ...]:
+        return self.ffn_types or (self.ffn,) * self.num_layers
+
+    @property
+    def has_experts(self) -> bool:
+        return "experts" in self.ffn_kinds
+
+    @property
+    def num_expert_layers(self) -> int:
+        return self.ffn_kinds.count("experts")
+
+    def packed(self, kind: str) -> bool:
+        """Whether ``kind`` has an entry in ``attention_kinds`` (and keeps
+        its K/V rows packed)."""
+        return kind in dict(self.attention_kinds or ())
+
+    def attention(self, kind: str) -> AttentionKind:
+        """The attention of layer kind ``kind`` with every default filled
+        in (``rope_theta`` None: the kind takes no rotary embedding)."""
+        o = dict(self.attention_kinds or ()).get(kind) or AttentionKind()
+        d = o.head_dim or self.d_head
+        roped = self.rope_theta is not None and (
+            self.rope_layers is None or kind in self.rope_layers)
+        theta = o.rope_theta if o.rope_theta is not None else \
+            self.rope_theta if roped else None
+        return AttentionKind(
+            num_kv_heads=o.num_kv_heads or self.num_kv_heads, head_dim=d,
+            v_head_dim=o.v_head_dim or d, rotary_dim=o.rotary_dim or d,
+            rope_theta=theta, sink=o.sink, value_scale=o.value_scale)
+
+    @property
+    def attention_layer_kinds(self) -> Tuple[str, ...]:
+        """The attention kinds the model has, each once, in layer order."""
+        return tuple(dict.fromkeys(
+            k for k in self.layer_kinds if k != "linear_attention"))
 
     @property
     def kv_cache_heads(self) -> int:
@@ -213,7 +330,14 @@ class LlamaConfig:
         norm, which its config does not spell out; ``cohere2_moe`` brings
         LayerNorm, rotary embeddings on the window layers alone, and what
         its keys say in the family's own words (``use_parallel_block``,
-        ``position_embedding_type`` ``rope_gptj``, ``use_qk_norm``)."""
+        ``position_embedding_type`` ``rope_gptj``, ``use_qk_norm``);
+        ``mimo_v2`` brings attention by layer kind (``hybrid_layer_pattern``
+        1: ``sliding_attention`` with the ``swa_*`` keys, 0:
+        ``full_attention``), ``v_head_dim``, ``partial_rotary_factor``,
+        ``attention_value_scale``, the sink logits, a feed-forward kind by
+        layer (``moe_layer_freq``) and the ``noaux_tc`` selection bias."""
+        if hc.get("model_type") == "mimo_v2":
+            hc, kw = _mimo_v2_keys(hc), {**_mimo_v2_args(hc), **kw}
         rope = hc.get("rope_parameters") or {}
         theta = rope["rope_theta"] if "rope_theta" in rope \
             else hc.get("rope_theta", 10_000.0)   # HF's default (Llama-1/2)
@@ -222,7 +346,7 @@ class LlamaConfig:
         eps = hc.get("rms_norm_eps")
         layer_norm = eps is None and hc.get("layer_norm_eps") is not None
         if eps is None:
-            eps = hc.get("layer_norm_eps", 1e-5)
+            eps = hc.get("layer_norm_eps", hc.get("layernorm_epsilon", 1e-5))
         args = dict(
             vocab_size=hc["vocab_size"], d_model=hc["hidden_size"],
             num_layers=hc["num_hidden_layers"],
@@ -264,6 +388,15 @@ class LlamaConfig:
                 linear_conv_kernel_dim=hc.get("linear_conv_kernel_dim", 4),
                 linear_allow_neg_eigval=bool(
                     hc.get("linear_allow_neg_eigval", False)))
+        n = hc["num_hidden_layers"]
+        dense = set(range(int(hc.get("first_k_dense_replace") or 0))) \
+            | set(hc.get("mlp_only_layers") or ())
+        freq = hc.get("moe_layer_freq")
+        if isinstance(freq, (list, tuple)):
+            dense |= {i for i, on in enumerate(freq) if not on}
+        if args.get("ffn") == "experts" and dense:
+            args["ffn_types"] = tuple(
+                "dense" if i in dense else "experts" for i in range(n))
         args.update(kw)
         return LlamaConfig(**args)
 
@@ -288,6 +421,48 @@ class LlamaConfig:
         kw.setdefault("d_ff", 256)
         kw.setdefault("max_len", 256)
         return LlamaConfig(**kw)
+
+
+def _mimo_v2_keys(hc: Dict[str, Any]) -> Dict[str, Any]:
+    """A ``mimo_v2`` config's keys under the names :meth:`LlamaConfig.from_hf`
+    reads from every family."""
+    for key, plain in (("routed_scaling_factor", (None, 1, 1.0)),
+                       ("n_group", (None, 1)), ("topk_group", (None, 1)),
+                       ("n_shared_experts", (None, 0)),
+                       ("hybrid_block_size", (None,))):
+        if hc.get(key) not in plain:
+            raise ValueError(f"mimo_v2 {key}={hc[key]!r} is not supported")
+    if hc.get("swa_num_attention_heads",
+              hc["num_attention_heads"]) != hc["num_attention_heads"]:
+        raise ValueError("window layers with another number of query heads "
+                         "than full layers are not supported")
+    return dict(
+        hc, layer_types=["sliding_attention" if w else "full_attention"
+                         for w in hc["hybrid_layer_pattern"]],
+        num_experts=hc.get("n_routed_experts"), num_shared_experts=0,
+        expert_selection_fn=hc.get("scoring_func", "softmax"))
+
+
+def _mimo_v2_args(hc: Dict[str, Any]) -> Dict[str, Any]:
+    """What a ``mimo_v2`` config says of attention by layer kind, and of the
+    router, as :class:`LlamaConfig` arguments."""
+    factor = float(hc.get("partial_rotary_factor", 1.0))
+    scale = float(hc.get("attention_value_scale") or 1.0)
+
+    def kind(pre, sink_key):
+        d = hc.get(pre + "head_dim", hc["head_dim"])
+        return AttentionKind(
+            num_kv_heads=hc.get(pre + "num_key_value_heads",
+                                hc["num_key_value_heads"]),
+            head_dim=d, v_head_dim=hc.get(pre + "v_head_dim", d),
+            rotary_dim=int(d * factor),
+            rope_theta=float(hc.get(pre + "rope_theta", hc["rope_theta"])),
+            sink=bool(hc.get(sink_key, False)), value_scale=scale)
+    return dict(
+        attention_kinds={
+            "full_attention": kind("", "add_full_attention_sink_bias"),
+            "sliding_attention": kind("swa_", "add_swa_attention_sink_bias")},
+        expert_selection_bias=hc.get("topk_method") == "noaux_tc")
 
 
 class RMSNorm(nn.Module):
@@ -328,11 +503,16 @@ def rope_frequencies(d_head: int, theta: float) -> np.ndarray:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float, style: str = "half") -> jnp.ndarray:
+               theta: float, style: str = "half",
+               rotary_dim: Optional[int] = None) -> jnp.ndarray:
     """x: (B, S, H, D); positions: (B, S) absolute token positions.
     ``style`` "half" rotates the pairs ``(i, i + D/2)``, "interleaved" the
     pairs ``(2i, 2i + 1)``; pair ``i`` turns by ``theta^(-2i/D)`` a
-    position in both."""
+    position in both.  ``rotary_dim`` under ``D``: the first ``rotary_dim``
+    dims are rotated as a head of that width, the rest pass untouched."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = apply_rope(x[..., :rotary_dim], positions, theta, style)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     inv = jnp.asarray(rope_frequencies(d, theta))          # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * inv   # (B, S, D/2)
@@ -453,19 +633,23 @@ def _block_len(n: int, most: int = 512) -> int:
     return b if b >= 8 else n
 
 
-def blocked_attention(q, k, v, positions, window: Optional[int], dtype):
+def blocked_attention(q, k, v, positions, window: Optional[int], dtype,
+                      sink=None, key_offset=None):
     """Causal softmax attention without the ``(heads, S, T)`` scores.
 
-    ``q (B, S, H, D)`` at ``positions (B, S)`` over ``k``, ``v``
-    ``(B, T, KV, D)`` whose row ``j`` is key position ``j``: query ``i``
-    sees key ``j`` iff ``j <= i`` and, with a ``window``,
+    ``q (B, S, H, D)`` at ``positions (B, S)`` over ``k (B, T, KV, D)``,
+    ``v (B, T, KV, Dv)`` whose row ``j`` is key position ``j`` (``key_offset
+    + j`` where one is given; a row at a negative position is no key): query
+    ``i`` sees key ``j`` iff ``j <= i`` and, with a ``window``,
     ``j > i - window``.  Blocks of queries in turn; for each, the key
     blocks that hold a visible key (none past the causal edge, none before
     the window) through an online softmax in float32, probabilities cast
     to ``dtype`` before the product with ``v`` as the dense path casts
-    them.  -> ``(B, S, H * D)``."""
+    them.  ``sink (H,)``: a logit a query head that every query sees beside
+    its keys and that carries no value (the online softmax starts from it).
+    -> ``(B, S, H * Dv)``."""
     B, S, H, D = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    T, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     bq, bk = _block_len(S), _block_len(T)
     neg = jnp.finfo(jnp.float32).min
@@ -475,9 +659,11 @@ def blocked_attention(q, k, v, positions, window: Optional[int], dtype):
 
     def q_block(args):
         qi, pos = args                         # (B, bq, KV, G, D), (B, bq)
-        hi = jnp.max(pos) // bk + 1
+        def row(p):                            # the row of position p
+            return p if key_offset is None else p - key_offset
+        hi = row(jnp.max(pos)) // bk + 1
         lo = 0 if window is None else \
-            jnp.maximum(jnp.min(pos) - (window - 1), 0) // bk
+            jnp.maximum(row(jnp.min(pos)) - (window - 1), 0) // bk
 
         def k_block(j, carry):
             m, l, acc = carry
@@ -486,9 +672,13 @@ def blocked_attention(q, k, v, positions, window: Optional[int], dtype):
             s = jnp.einsum("bskgd,btkd->bkgst", qi, kj,
                            preferred_element_type=jnp.float32) * scale
             kpos = j * bk + jnp.arange(bk)
+            if key_offset is not None:
+                kpos = kpos + key_offset
             see = kpos[None, None, :] <= pos[:, :, None]        # (B, bq, bk)
             if window is not None:
                 see &= kpos[None, None, :] > pos[:, :, None] - window
+            if key_offset is not None:
+                see &= kpos[None, None, :] >= 0
             see = see[:, None, None]
             m_new = jnp.maximum(m, jnp.max(jnp.where(see, s, neg), -1))
             # a query with no visible key in this block adds nothing (its
@@ -500,22 +690,55 @@ def blocked_attention(q, k, v, positions, window: Optional[int], dtype):
                 preferred_element_type=jnp.float32)
             return m_new, l * alpha + jnp.sum(p, -1), acc
 
-        m0 = jnp.full((B, KV, G, bq), neg, jnp.float32)
+        if sink is None:
+            m0 = jnp.full((B, KV, G, bq), neg, jnp.float32)
+            l0 = jnp.zeros_like(m0)
+        else:                                  # the sink's own term: exp(0)
+            m0 = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+                1, KV, G, 1), (B, KV, G, bq))
+            l0 = jnp.ones_like(m0)
         _, l, acc = jax.lax.fori_loop(
             lo, hi, k_block,
-            (m0, jnp.zeros_like(m0), jnp.zeros((B, KV, G, bq, D),
-                                               jnp.float32)))
+            (m0, l0, jnp.zeros((B, KV, G, bq, Dv), jnp.float32)))
         # every query sees its own key, so l >= 1
-        out = (acc / l[..., None]).astype(dtype)               # (B,KV,G,bq,D)
-        return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, bq, H * D)
+        out = (acc / l[..., None]).astype(dtype)               # (B,KV,G,bq,Dv)
+        return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, bq, H * Dv)
 
-    out = jax.lax.map(q_block, (qb, pb))                       # (S/bq, B, bq, HD)
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, H * D)
+    out = jax.lax.map(q_block, (qb, pb))                       # (S/bq, B, bq, HDv)
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, H * Dv)
+
+
+def _ring_pass(ring, new, start, n_valid):
+    """One pass at a scalar offset over a ring of ``R`` rows (position
+    ``p`` lies in row ``p mod R``).  ``ring (B, R, ...)``; ``new (B, S,
+    ...)`` the rows of positions ``start .. start + S - 1`` of which the
+    first ``n_valid`` are real.  -> ``(the ring after the pass, the rows of
+    positions start - R .. start - 1 in that order)``.  Only real rows are
+    written, the last ``R`` of them: a ring has no room beyond a slot's
+    length where a bucket's padding could land unread."""
+    B, R, S = ring.shape[0], ring.shape[1], new.shape[1]
+    r = jnp.arange(R)
+    ctx = jnp.take(ring, (start + r) % R, axis=1)
+    n = S if n_valid is None else n_valid
+    last = jnp.broadcast_to(jnp.asarray(start + n - 1, jnp.int32), (B,))
+    pos = last[:, None] - (last[:, None] - r[None, :]) % R     # (B, R)
+    src = jnp.clip(pos - start, 0, S - 1)
+    tail = (1,) * (ring.ndim - 2)
+    rows = jnp.take_along_axis(new, src.reshape((B, R) + tail), axis=1)
+    return jnp.where((pos >= start).reshape((B, R) + tail), rows, ring), ctx
 
 
 class CausalAttention(nn.Module):
     """Grouped-query softmax attention over K/V rows kept by position.
-    :class:`SlidingAttention` is the same layer behind a window."""
+    :class:`SlidingAttention` is the same layer behind a window.
+
+    What the layer's kind overrides (``LlamaConfig.attention``): K/V heads,
+    the key's and the value's width, rotary dims and theta, a sink logit a
+    head, a scale on the values.  A kind with an ``attention_kinds`` entry
+    keeps its cache PACKED: flat rows ``(slots, rows * KV / f, f * width)``,
+    ``f`` heads side by side (:func:`kv_pack`), the layout the paged kernel
+    reads, so that a 192-wide key costs no lane of padding and the step
+    relays nothing; every other kind keeps ``(slots, rows, KV, d_head)``."""
     cfg: LlamaConfig
 
     #: the layer kind (``layer_types`` entry) this class serves
@@ -525,52 +748,105 @@ class CausalAttention(nn.Module):
     def window(self) -> Optional[int]:
         return None
 
-    @staticmethod
-    def cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> Dict:
-        shape = (batch, max_len, cfg.kv_cache_heads, cfg.d_head)
-        return {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype)}
+    @classmethod
+    def cache_rows(cls, cfg: LlamaConfig, max_len: int) -> int:
+        """Rows a slot keeps in this kind's cache entry."""
+        return max_len
+
+    @classmethod
+    def cache_entry(cls, cfg: LlamaConfig, batch: int, max_len: int) -> Dict:
+        rows = cls.cache_rows(cfg, max_len)
+        if not cfg.packed(cls.KIND):
+            shape = (batch, rows, cfg.kv_cache_heads, cfg.d_head)
+            return {"k": jnp.zeros(shape, cfg.dtype),
+                    "v": jnp.zeros(shape, cfg.dtype)}
+        a = cfg.attention(cls.KIND)
+        f = kv_pack(a.head_dim, a.num_kv_heads)
+        n = rows * (a.num_kv_heads // f)
+        return {"k": jnp.zeros((batch, n, f * a.head_dim), cfg.dtype),
+                "v": jnp.zeros((batch, n, f * a.v_head_dim), cfg.dtype)}
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict],
                  cache_index: Optional[jnp.ndarray],
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
-                 paged_tile: Optional[int] = None,
+                 paged_tile: Optional[Any] = None,
                  valid_len: Optional[jnp.ndarray] = None):
-        # ``valid_len`` is the recurrent mixer's: a padded row's K/V lands
-        # beyond the slot's length and is overwritten before it is read
+        # ``valid_len`` is the recurrent mixer's and the ring's: by position
+        # a padded row's K/V lands beyond the slot's length and is
+        # overwritten before it is read
         cfg = self.cfg
+        a = cfg.attention(self.KIND)
+        packed = cfg.packed(self.KIND)
         B, S, _ = x.shape
-        H, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.d_head
+        H, KV, D, Dv = (cfg.num_heads, a.num_kv_heads, a.head_dim,
+                        a.v_head_dim)
         q = _dense(H * D, ("embed", "heads"), "q_proj", cfg.dtype,
                    cfg.weight_quant)(x)
         k = _dense(KV * D, ("embed", "kv"), "k_proj", cfg.dtype,
                    cfg.weight_quant)(x)
-        v = _dense(KV * D, ("embed", "kv"), "v_proj", cfg.dtype,
+        v = _dense(KV * Dv, ("embed", "kv"), "v_proj", cfg.dtype,
                    cfg.weight_quant)(x)
         if cfg.qk_norm:
             q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
-        if cfg.rope_theta is not None and (
-                cfg.rope_layers is None or self.KIND in cfg.rope_layers):
-            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_style)
-            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_style)
-        v = v.reshape(B, S, KV, D)
+        if a.rope_theta is not None:
+            q = apply_rope(q, positions, a.rope_theta, cfg.rope_style,
+                           a.rotary_dim)
+            k = apply_rope(k, positions, a.rope_theta, cfg.rope_style,
+                           a.rotary_dim)
+        v = v.reshape(B, S, KV, Dv)
+        if a.value_scale != 1.0:
+            v = v * jnp.asarray(a.value_scale, v.dtype)
+        sink = self.param("sink", nn.initializers.zeros_init(), (H,),
+                          jnp.float32) if a.sink else None
         window = self.window
+        f = kv_pack(D, KV) if packed else 1
+        rpp = KV // f                 # flat rows a position takes, packed
 
         new_cache = None
+        ring = False
+        key_offset = None             # position of row 0 where it is not 0
         if cache is not None:
-            if cfg.kv_cache_heads != KV:          # the row's padding heads
+            T = cache["k"].shape[1] // rpp if packed else cache["k"].shape[1]
+            # a window layer whose entry has exactly the ring's rows is a
+            # ring (by position such an entry would hold the same rows: no
+            # position reaches its end)
+            ring = window is not None and T == ring_rows(window)
+            if not packed and cfg.kv_cache_heads != KV:   # the row's padding
                 pad = ((0, 0), (0, 0), (0, cfg.kv_cache_heads - KV), (0, 0))
                 k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-            if jnp.ndim(cache_index) == 0:
+            # this pass's rows as the entry lays them
+            k_new, v_new = k, v
+            if packed:
+                k_new = k.reshape(B, S * rpp, f * D)
+                v_new = v.reshape(B, S * rpp, f * Dv)
+            if jnp.ndim(cache_index) == 0 and ring:
+                def through(entry, new):
+                    if not packed:
+                        return _ring_pass(entry, new, cache_index, valid_len)
+                    out, ctx = _ring_pass(
+                        entry.reshape(B, T, rpp, -1),
+                        new.reshape(B, S, rpp, -1), cache_index, valid_len)
+                    return out.reshape(entry.shape), ctx
+                k_all, k_ctx = through(cache["k"], k_new)
+                v_all, v_ctx = through(cache["v"], v_new)
+                # attend over [the ring's rows before this pass | this pass]
+                k_att = jnp.concatenate(
+                    [k_ctx.reshape(B, T, -1, D)[:, :, :KV], k[:, :, :KV]], 1)
+                v_att = jnp.concatenate(
+                    [v_ctx.reshape(B, T, -1, Dv)[:, :, :KV], v[:, :, :KV]], 1)
+                key_offset = cache_index - T
+                key_pos = (key_offset + jnp.arange(T + S))[None, :]
+                T = T + S
+            elif jnp.ndim(cache_index) == 0:
                 # write this step's K/V at cache_index, attend over prefix
-                k_all = jax.lax.dynamic_update_slice(
-                    cache["k"], k, (0, cache_index, 0, 0))
-                v_all = jax.lax.dynamic_update_slice(
-                    cache["v"], v, (0, cache_index, 0, 0))
+                at = (0, cache_index * rpp, 0) if packed else \
+                    (0, cache_index, 0, 0)
+                k_all = jax.lax.dynamic_update_slice(cache["k"], k_new, at)
+                v_all = jax.lax.dynamic_update_slice(cache["v"], v_new, at)
             else:
                 # PER-SEQUENCE write offsets (B,) — speculative decoding
                 # accepts a different number of tokens per sequence and
@@ -583,8 +859,13 @@ class CausalAttention(nn.Module):
                 # step, which made decode cost scale with slots x
                 # max_len instead of with the tokens actually written
                 wpos = cache_index[:, None] + jnp.arange(S)[None, :]
+                if ring:
+                    wpos = wpos % T
+                if packed:
+                    wpos = (wpos[:, :, None] * rpp
+                            + jnp.arange(rpp)).reshape(B, S * rpp)
                 bidx = jnp.arange(B)[:, None]
-                k_w, v_w = k, v
+                k_w, v_w = k_new, v_new
                 if slot_mask is not None:
                     # ACTIVE-SLOT gate (continuous-batching serving): a
                     # row whose slot is inactive must not write — a
@@ -593,19 +874,30 @@ class CausalAttention(nn.Module):
                     # it.  Masking the PAYLOAD (write back the old
                     # values, gathered (B, S) rows only) keeps the
                     # scatter shape — and its in-place update — intact.
-                    m = slot_mask.reshape(B, 1, 1, 1)
-                    k_w = jnp.where(m, k, cache["k"][bidx, wpos])
-                    v_w = jnp.where(m, v, cache["v"][bidx, wpos])
+                    m = slot_mask.reshape((B,) + (1,) * (k_new.ndim - 1))
+                    k_w = jnp.where(m, k_new, cache["k"][bidx, wpos])
+                    v_w = jnp.where(m, v_new, cache["v"][bidx, wpos])
                 k_all = cache["k"].at[bidx, wpos].set(k_w)
                 v_all = cache["v"].at[bidx, wpos].set(v_w)
             new_cache = {"k": k_all, "v": v_all}
-            k_att, v_att = k_all[:, :, :KV], v_all[:, :, :KV]
-            T = k_all.shape[1]
-            key_pos = jnp.arange(T)[None, :]                    # (1, T)
+            if key_offset is None:
+                if packed:
+                    k_att = k_all.reshape(B, T, KV, D)
+                    v_att = v_all.reshape(B, T, KV, Dv)
+                else:
+                    k_att, v_att = k_all[:, :, :KV], v_all[:, :, :KV]
+                key_pos = jnp.arange(T)[None, :]                # (1, T)
+                if ring:
+                    # per-slot positions: row r holds the newest position
+                    # congruent to r that the slot has written
+                    last = positions[:, -1:]
+                    key_pos = last - (last - key_pos) % T       # (B, T)
             qpos = positions[:, :, None]                        # (B, S, 1)
             causal = key_pos[:, None, :] <= qpos                # (B, S, T)
             if window is not None:
                 causal &= key_pos[:, None, :] > qpos - window
+            if ring:
+                causal &= key_pos[:, None, :] >= 0
         else:
             k_att, v_att = k, v
             T = S
@@ -623,13 +915,21 @@ class CausalAttention(nn.Module):
             # queries amortize one span read; prefill and training
             # stay dense, where the full-row read is the work).
             # ``paged_tile`` is the engine-resolved geometry (the byte
-            # ledger prices the same tile by construction); absent it,
-            # re-derive — the direct-apply ergonomic path.
+            # ledger prices the same tile by construction; one tile, or a
+            # tuple of (kind, tile) where the kinds' geometries differ);
+            # absent it, re-derive — the direct-apply ergonomic path.
             from .pallas_attn import paged_decode_attention, \
                 paged_geometry
-            tile = paged_tile
+            tile = paged_tile if paged_tile is None \
+                or isinstance(paged_tile, int) \
+                else dict(paged_tile)[self.KIND]
             if tile is None:
-                geo = paged_geometry(T, H, KV, D, cfg.dtype)
+                kind = {}
+                if packed:
+                    kind.update(pack=f, d_value=Dv)
+                if ring:
+                    kind.update(most=RING_BLOCK)
+                geo = paged_geometry(T, H, KV, D, cfg.dtype, **kind)
                 if geo is None:
                     raise ValueError(
                         f"attention_backend={attention_backend!r}: no "
@@ -637,20 +937,33 @@ class CausalAttention(nn.Module):
                         f"kv_heads={KV}, d_head={D} — resolve the "
                         "backend via resolve_attention_backend first")
                 tile = geo.tile
+            extra = {}
+            if packed:
+                extra.update(pack=f)
+            if ring:
+                extra.update(ring=True)
+            if sink is not None:
+                extra.update(sink=sink)
             # the LAST query's key count; earlier queries mask one key
             # fewer each inside the kernel (the in-span causal mask)
             spans = positions[:, -1].astype(jnp.int32) + 1
             out = paged_decode_attention(
                 q, k_all, v_all, spans, tile=tile, kv_heads=KV,
                 interpret=(attention_backend == "interpret"),
-                window=window).reshape(B, S, H * D)
+                window=window, **extra).reshape(B, S, H * Dv)
         elif H * S * T * 4 > _DENSE_SCORE_BYTES \
                 and (cache is None or jnp.ndim(cache_index) == 0):
             # without a cache key j is row j of this pass, whatever
             # ``positions`` says (as the dense mask below has it)
             rows = positions if cache is not None else \
                 jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-            out = blocked_attention(q, k_att, v_att, rows, window, cfg.dtype)
+            extra = {}
+            if sink is not None:
+                extra.update(sink=sink)
+            if key_offset is not None:
+                extra.update(key_offset=key_offset)
+            out = blocked_attention(q, k_att, v_att, rows, window, cfg.dtype,
+                                    **extra)
         else:
             group = H // KV
             qg = q.reshape(B, S, KV, group, D)
@@ -660,9 +973,16 @@ class CausalAttention(nn.Module):
             mask = jnp.broadcast_to(causal[:, None, None, :, :],
                                     logits.shape)
             logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+            if sink is not None:
+                # one more column: it takes mass and carries no value
+                col = jnp.broadcast_to(sink.reshape(1, KV, group, 1, 1),
+                                       (B, KV, group, S, 1))
+                logits = jnp.concatenate([logits, col], axis=-1)
             probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
+            if sink is not None:
+                probs = probs[..., :-1]
             out = jnp.einsum("bkgst,btkd->bskgd", probs, v_att)
-            out = out.reshape(B, S, H * D)
+            out = out.reshape(B, S, H * Dv)
         out = _dense(cfg.d_model, ("heads", "embed"), "o_proj",
                      cfg.dtype, cfg.weight_quant)(out)
         return out, new_cache
@@ -805,20 +1125,45 @@ class GatedDeltaNet(nn.Module):
         return out, new_cache
 
 
+def ring_rows(window: int) -> int:
+    """Rows of a window layer's ring: the window rounded up to whole blocks
+    of ``RING_BLOCK``, so that the window's keys are whole tiles of the paged
+    kernel, and one block more, so that the (at most ``window / tile + 1``)
+    position tiles a step walks, the newest of them part-written, are
+    distinct tiles of the ring.  128 + 128 = 256 behind a window of 128."""
+    return -(-int(window) // RING_BLOCK) * RING_BLOCK + RING_BLOCK
+
+
 class SlidingAttention(CausalAttention):
     """:class:`CausalAttention` whose query ``i`` sees the keys
-    ``i - sliding_window < j <= i``.  Its cache entry is the full
-    attention layer's: rows of ``max_len`` positions, so that a slot stays
-    a prefix-reuse source at every length (a ring of ``sliding_window``
-    rows would have overwritten the prefix once its owner decoded past it;
-    ROADMAP R6).  The window acts in the masks, in the blocks prefill
-    visits and in the tiles the paged kernel walks."""
+    ``i - sliding_window < j <= i``.
+
+    Its cache entry is a RING of :func:`ring_rows` rows a slot whatever
+    ``max_len``, position ``p`` in row ``p mod rows``, wherever ``max_len``
+    holds two rings or more; under that it keeps ``max_len`` rows by
+    position like a full layer (:meth:`cache_rows`: a rule on sizes).  By
+    position a retired slot stays a prefix-reuse source at every length and
+    costs rows no query reads again: the ring saves more than half of them
+    from two rings on, and where ``max_len`` is 1.3 windows (a preamble as
+    long as the window before a short tail) it would save a fifth and
+    overwrite the preamble every request shares.  On a ring a decode step
+    writes at ``position mod rows`` and the paged kernel walks the window's
+    position tiles through the ring; a prefill writes only its real rows,
+    the last ``rows`` of them, and attends over the ring's rows before it
+    and its own (:func:`_ring_pass`); what the engine's by-position paths do
+    on a ring is ``slots.py``'s to say.  The window acts in the masks, in
+    the blocks prefill visits and in the tiles the paged kernel walks."""
 
     KIND = "sliding_attention"
 
     @property
     def window(self) -> Optional[int]:
         return int(self.cfg.sliding_window)
+
+    @classmethod
+    def cache_rows(cls, cfg: LlamaConfig, max_len: int) -> int:
+        ring = ring_rows(cfg.sliding_window)
+        return ring if max_len >= 2 * ring else max_len
 
 
 #: mixer kind (a ``layer_types`` entry) -> its class; each declares its
@@ -848,7 +1193,8 @@ def _experts(cfg: LlamaConfig, h, valid, backend):
     return ExpertFFN(cfg, name="moe")(h, valid, backend)
 
 
-#: feed-forward kind (``LlamaConfig.ffn``) -> ``f(cfg, h, valid, backend)``,
+#: feed-forward kind (``LlamaConfig.ffn``, or a layer's entry of
+#: ``ffn_types``) -> ``f(cfg, h, valid, backend)``,
 #: called inside the block's scope (its modules are the block's): ``h (B, S,
 #: d)``, ``valid (B, S)`` the tokens that are real (an expert layer routes
 #: the others nowhere), ``backend`` the engine's ``attention_backend``
@@ -873,16 +1219,19 @@ class DecoderBlock(nn.Module):
     ``x + mixer(h) + ffn(h)``."""
     cfg: LlamaConfig
     kind: str = "full_attention"
+    #: the layer's feed-forward kind (None: ``cfg.ffn``)
+    ffn: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions, cache, cache_index, slot_mask=None,
                  attention_backend: str = "dense",
-                 paged_tile: Optional[int] = None,
+                 paged_tile: Optional[Any] = None,
                  valid_len: Optional[jnp.ndarray] = None):
         cfg = self.cfg
         mixer = MIXERS[self.kind](cfg, name=_MIXER_NAME[self.kind])
-        ffn = FFNS[cfg.ffn]
-        valid = None if cfg.ffn == "dense" else _valid_tokens(
+        ffn_kind = self.ffn or cfg.ffn
+        ffn = FFNS[ffn_kind]
+        valid = None if ffn_kind == "dense" else _valid_tokens(
             x.shape[0], x.shape[1], slot_mask, valid_len)
         ln_attn = _norm(cfg, "ln_attn")
         if cfg.norm_order == "parallel":
@@ -910,7 +1259,7 @@ class LlamaModel(nn.Module):
                  cache_index=None, deterministic: bool = True,
                  slot_mask: Optional[jnp.ndarray] = None,
                  attention_backend: str = "dense",
-                 paged_tile: Optional[int] = None,
+                 paged_tile: Optional[Any] = None,
                  valid_len: Optional[jnp.ndarray] = None,
                  logits_at: Optional[jnp.ndarray] = None):
         """``valid_len`` (scalar or ``(B,)``): how many of the ``S`` tokens
@@ -935,9 +1284,9 @@ class LlamaModel(nn.Module):
                              name="tok_embed")
         x = embed(input_ids)
         new_caches = []
-        for i, kind in enumerate(cfg.layer_kinds):
+        for i, (kind, ffn) in enumerate(zip(cfg.layer_kinds, cfg.ffn_kinds)):
             layer_cache = cache[i] if cache is not None else None
-            x, nc = DecoderBlock(cfg, kind, name=f"layer_{i}")(
+            x, nc = DecoderBlock(cfg, kind, ffn, name=f"layer_{i}")(
                 x, positions, layer_cache, cache_index, slot_mask,
                 attention_backend, paged_tile, valid_len)
             new_caches.append(nc)

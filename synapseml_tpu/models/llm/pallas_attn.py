@@ -133,7 +133,9 @@ def _chunk_rows(tile: int, row_heads: int, q_rows: int) -> int:
 def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
                    d_head: int, dtype: Any = jnp.bfloat16,
                    max_query_span: int = 1,
-                   tile: Optional[int] = None) -> Optional[PagedGeometry]:
+                   tile: Optional[int] = None, *,
+                   d_value: Optional[int] = None, pack: int = 1,
+                   most: Optional[int] = None) -> Optional[PagedGeometry]:
     """The VMEM gate: pick the key-tile length for a
     ``(max_len, num_kv_heads, d_head)`` cache row, or None when no
     geometry fits (the 'auto' backend then stays dense — the
@@ -162,28 +164,39 @@ def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
     override path.  It passes through the SAME divisibility/VMEM gate:
     a tuning-table winner that stopped fitting (config drift since it
     was measured) resolves to None, and the caller keeps the default
-    geometry — tables can suggest, only the gate admits."""
+    geometry — tables can suggest, only the gate admits.
+
+    One geometry a layer KIND: ``max_len`` is the rows the kind's cache
+    entry holds (a ring's, for a window layer on one), ``d_value`` the
+    value's width where it is not the key's, ``pack`` the K/V heads a
+    packed row holds side by side (``model.kv_pack``: the lanes are then
+    ``pack`` widths, the flat rows ``num_kv_heads / pack`` a position), and
+    ``most`` the largest tile allowed (a ring's block: the position tiles a
+    step walks must be distinct tiles of the ring)."""
     itemsize = np.dtype(dtype).itemsize
     sub = _sublane(dtype)
     s = max(1, int(max_query_span))
-    d_pad = _pad(d_head, 128)
-    row_heads = cache_row_heads(num_kv_heads, dtype)
+    d_pad = _pad(pack * d_head, 128)
+    v_pad = d_pad if d_value is None else _pad(pack * d_value, 128)
+    row_heads = cache_row_heads(num_kv_heads, dtype) if pack == 1 \
+        else num_kv_heads // pack
     q_rows = s * _pad(num_heads, 8)
 
     def need(cand):
         chunk = _chunk_rows(cand, row_heads, q_rows)
-        return (2 * _RING * _pad(cand * row_heads, sub) * d_pad
+        return (_RING * _pad(cand * row_heads, sub) * (d_pad + v_pad)
                 * itemsize                                       # K+V ring
-                + 2 * 2 * s * _pad(num_heads, sub) * d_pad
+                + 2 * s * _pad(num_heads, sub) * (d_pad + v_pad)
                 * itemsize                                       # q+out x2 buf
                 + _pad(q_rows, sub) * d_pad * itemsize           # query rows
-                + q_rows * d_pad * 4                             # f32 acc
+                + q_rows * v_pad * 4                             # f32 acc
                 + 2 * q_rows * 128 * 4                           # m + l
                 + q_rows * _pad(chunk, 128) * (4 + 4 + itemsize))  # logits, p
 
     fits = [c for c in (_TILE_CANDIDATES if tile is None else (int(tile),))
             if c > 0 and c % sub == 0 and max_len % c == 0
-            and c <= max_len // 2 and need(c) <= _VMEM_BUDGET]
+            and c <= max_len // 2 and (most is None or c <= most)
+            and need(c) <= _VMEM_BUDGET]
     if not fits:
         return None
     small = [c for c in fits
@@ -206,7 +219,7 @@ def paged_geometry_key(max_len: int, num_kv_heads: int, d_head: int,
 def resolve_attention_backend(backend: str, *, max_len: int,
                               num_heads: int, num_kv_heads: int,
                               d_head: int, dtype: Any = jnp.bfloat16,
-                              max_query_span: int = 1) -> str:
+                              max_query_span: int = 1, **kind) -> str:
     """The one parser for ``attention_backend`` (SlotEngine /
     LLMServer) — returns the RESOLVED backend
     (``'dense'`` | ``'paged'`` | ``'interpret'``) or fails fast with an
@@ -220,7 +233,11 @@ def resolve_attention_backend(backend: str, *, max_len: int,
       cannot compile for this backend) and when no geometry fits;
     - ``'interpret'`` — the kernel through the Pallas interpreter on
       any backend (the CPU correctness mode; orders of magnitude slower
-      than dense — tests and parity audits only)."""
+      than dense — tests and parity audits only).
+
+    ``kind``: :func:`paged_geometry`'s ``d_value``, ``pack`` and ``most``
+    for the layer kind asked about; a model of several kinds asks for each
+    and is paged where all are."""
     if backend not in ATTENTION_BACKENDS:
         raise ValueError(
             f"attention_backend={backend!r}: must be one of "
@@ -228,7 +245,7 @@ def resolve_attention_backend(backend: str, *, max_len: int,
     if backend == "dense":
         return "dense"
     geo = paged_geometry(max_len, num_heads, num_kv_heads, d_head, dtype,
-                         max_query_span=max_query_span)
+                         max_query_span=max_query_span, **kind)
     on_tpu = jax.default_backend() == "tpu"
     if backend == "auto":
         return "paged" if (on_tpu and geo is not None) else "dense"
@@ -272,7 +289,8 @@ def paged_live_tiles(spans, tile: int, window: Optional[int] = None,
 def paged_read_bytes(spans, tile: int, num_kv_heads: int, d_head: int,
                      itemsize: int, num_layers: int = 1,
                      window: Optional[int] = None,
-                     query_span: int = 1) -> int:
+                     query_span: int = 1, d_value: Optional[int] = None,
+                     pack: int = 1) -> int:
     """K/V bytes ONE paged decode step DMAs for ``spans``: each slot
     reads ``ceil(span / tile)`` tiles of K and of V per layer (behind a
     ``window``, the tiles from the window's first on:
@@ -286,10 +304,15 @@ def paged_read_bytes(spans, tile: int, num_kv_heads: int, d_head: int,
     its tile), and a head narrower than 128 lanes is fetched at 128 (the
     kernel's wrapper pads it).  ``spans`` must cover EVERY slot in the
     launch, not just the active ones: an inactive slot (span 1) still
-    fetches its first tile."""
-    return int(num_layers * 2
+    fetches its first tile.  ``d_value``: the value's width where it is
+    not the key's; ``pack``: the heads a packed row holds side by side (its
+    lanes are ``pack`` widths padded to 128: 2 x 192 is 384, no padding).
+    A ring changes nothing here: the tiles walked are the same."""
+    k_lanes = _pad(pack * d_head, 128)
+    v_lanes = k_lanes if d_value is None else _pad(pack * d_value, 128)
+    return int(num_layers
                * paged_live_tiles(spans, tile, window, query_span) * tile
-               * num_kv_heads * _pad(d_head, 128) * itemsize)
+               * (num_kv_heads // pack) * (k_lanes + v_lanes) * itemsize)
 
 
 def dense_read_bytes(n_slots: int, max_len: int, num_kv_heads: int,
@@ -308,21 +331,34 @@ def dense_read_bytes(n_slots: int, max_len: int, num_kv_heads: int,
 
 def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
                         tile: int, total_tiles: int, d_head: int, chunk: int,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None, pack: int = 1,
+                        sink: bool = False, ring: bool = False):
     """``heads`` query heads in groups of ``group`` over the first K/V
     heads of a cache row of ``row_heads``; ``d_head`` the model's (the
     lane width the kernel sees may be padded past it).  ``window``: a
     query at position ``p`` sees the keys ``p - window < j <= p``, and a
     slot's walk starts at the tile that holds the first query's first
-    visible key."""
+    visible key.
+
+    ``pack`` K/V heads side by side in one flat row (``row_heads`` is then
+    the flat rows a position takes, K/V heads over ``pack``): flat row ``c``
+    of a chunk holds heads ``pack * (c % row_heads) ..`` of key ``c //
+    row_heads``, the query rows come with their head's lanes filled and the
+    other heads' lanes zero, so the one contraction is still each query's
+    dot with its own head, and the accumulator's lanes are ``pack`` values
+    wide, of which the wrapper keeps the query's own.  ``sink``: one more
+    input ``(S*hp, 128)`` float32, the sink logit of each query row; the
+    online softmax starts from it (running max the logit, normaliser
+    ``exp(0)``, no value) in place of (lowest, 0).  ``ring``: the cache
+    row is a ring of ``total_tiles`` tiles and position tile ``t`` lies in
+    tile ``t mod total_tiles`` of it; the walk is by position as ever."""
     neg = float(np.finfo(np.float32).min)
     hp = _pad(heads, 8)               # rows a query position takes
     q_rows = s_len * hp
     rows = tile * row_heads           # flat K/V rows of a tile
     scale = 1.0 / np.sqrt(d_head)
 
-    def kernel(spans_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
-               cur_ref, qs_ref, acc_ref, m_ref, l_ref):
+    def kernel(spans_ref, q_ref, k_hbm, v_hbm, *refs):
         """Grid ``(n_slots,)``.  q/out blocks ``(1, S, H, D)`` (S == 1
         is the plain decode step; S > 1 the speculative-verify span,
         whose S query positions amortize ONE read of the span); K and V
@@ -332,15 +368,21 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
         next DMA fetches.  Scratch rows: query position j's head h at
         ``j * hp + h`` (``hp`` is H padded to 8; a padding row is a
         zero query of K/V head 0, finite and never written out)."""
+        sink_ref = refs[0] if sink else None
+        (o_ref, kbuf, vbuf, sem, cur_ref, qs_ref, acc_ref, m_ref,
+         l_ref) = refs[1:] if sink else refs
         s = pl.program_id(0)
         n_slots = pl.num_programs(0)
         span = spans_ref[s]
 
         def live_tiles(slot):
             # the tile after the last live one: at least the first (an
-            # idle slot's span is 1), never past the cache row
-            return jnp.clip(lax.div(spans_ref[slot] + (tile - 1), tile),
-                            1, total_tiles)
+            # idle slot's span is 1), never past the cache row (a ring
+            # has no end: its tiles are counted by position)
+            last = lax.div(spans_ref[slot] + (tile - 1), tile)
+            if ring:
+                return jnp.maximum(last, 1)
+            return jnp.clip(last, 1, total_tiles)
 
         def first_tile(slot):
             # the tile of the first key the first query sees: key
@@ -351,7 +393,8 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
                 spans_ref[slot] - (s_len - 1) - window, 0), tile)
 
         def copies(slot, t, buf):
-            src = pl.ds(t * rows, rows)
+            src = pl.ds((lax.rem(t, total_tiles) if ring else t) * rows,
+                        rows)
             return (pltpu.make_async_copy(k_hbm.at[slot, src], kbuf.at[buf],
                                           sem.at[0, buf]),
                     pltpu.make_async_copy(v_hbm.at[slot, src], vbuf.at[buf],
@@ -382,8 +425,12 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
                 issue()
 
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, neg)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink:
+            m_ref[...] = sink_ref[...]
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, neg)
+            l_ref[...] = jnp.zeros_like(l_ref)
         if hp != heads:
             qs_ref[...] = jnp.zeros_like(qs_ref)
         for j in range(s_len):
@@ -398,6 +445,8 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
         # a query attends the rows of its own K/V head alone
         r = lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0)
         kv_of_row = jnp.where(r % hp < heads, (r % hp) // group, 0)
+        if pack != 1:
+            kv_of_row = kv_of_row // pack       # the flat row of its head
         limit = span - (s_len - 1) + r // hp                 # (S*hp, 1)
         floor = None if window is None else limit - window   # first key seen
         c = lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
@@ -449,7 +498,7 @@ def _make_decode_kernel(s_len: int, heads: int, group: int, row_heads: int,
 
 @functools.partial(jax.jit, static_argnames=("tile", "num_tiles",
                                              "interpret", "kv_heads",
-                                             "window"))
+                                             "window", "pack", "ring"))
 def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
                            k: jnp.ndarray,      # (B, max_len, KV, D)
                            v: jnp.ndarray,      # (B, max_len, KV, D)
@@ -458,7 +507,10 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
                            num_tiles: Optional[int] = None,
                            interpret: bool = False,
                            kv_heads: Optional[int] = None,
-                           window: Optional[int] = None) -> jnp.ndarray:
+                           window: Optional[int] = None,
+                           pack: int = 1, ring: bool = False,
+                           sink: Optional[jnp.ndarray] = None
+                           ) -> jnp.ndarray:
     """One decode step's attention for every slot, reading only each
     slot's live K/V span: → same shape as ``q``, in ``q.dtype``.
 
@@ -484,54 +536,99 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
 
     A head width that is no multiple of 128 lanes is padded to one here:
     at such a width XLA already relays the whole cache for the kernel
-    every step (PERF.md §6, PR 22), and the padding rides that copy."""
+    every step (PERF.md §6, PR 22), and the padding rides that copy.
+
+    PACKED rows (3-D ``k (B, rows * KV / pack, pack * D)`` and ``v (B,
+    rows * KV / pack, pack * Dv)``, ``kv_heads`` required): the layout a
+    kind with an ``attention_kinds`` entry keeps its cache in; the value's
+    width may differ from the key's and the result is ``(B, S, H, Dv)``.
+    ``ring``: the ``rows`` are a ring, position ``p`` in row ``p mod rows``
+    (a window layer's: the walk touches ``window / tile + 1`` position tiles
+    at most, which the ring's rule on sizes keeps distinct), and ``spans``
+    may pass ``rows``.  ``sink (H,)`` float32: a logit a query head that
+    every query sees beside its keys and that carries no value."""
     del num_tiles
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     B, S, H, d_head = q.shape
-    T, row_heads = k.shape[1], k.shape[2]
-    heads = kv_heads or row_heads
-    assert H % heads == 0 and heads <= row_heads, (H, heads, row_heads)
-    D = _pad(d_head, 128)
-    if D != d_head:
-        lanes = ((0, 0),) * 3 + ((0, D - d_head),)
-        q, k, v = (jnp.pad(a, lanes) for a in (q, k, v))
-    q_rows = S * _pad(H, 8)
+    packed = k.ndim == 3
+    if packed:
+        heads = int(kv_heads)
+        assert H % heads == 0 and heads % pack == 0, (H, heads, pack)
+        row_heads = heads // pack
+        T = k.shape[1] // row_heads
+        d_value = v.shape[-1] // pack
+        # a query beside zeros: head h's lanes are those of its K/V head's
+        # place in the packed row
+        place = (jnp.arange(H) // (H // heads)) % pack              # (H,)
+        own = place[:, None] == jnp.arange(pack)[None, :]           # (H, pack)
+        q = jnp.where(own[:, :, None], q[..., None, :], 0).reshape(
+            B, S, H, pack * d_head)
+        D, Dv = _pad(pack * d_head, 128), _pad(pack * d_value, 128)
+        if D != q.shape[-1]:
+            q = jnp.pad(q, ((0, 0),) * 3 + ((0, D - q.shape[-1]),))
+            k = jnp.pad(k, ((0, 0),) * 2 + ((0, D - k.shape[-1]),))
+        if Dv != v.shape[-1]:
+            v = jnp.pad(v, ((0, 0),) * 2 + ((0, Dv - v.shape[-1]),))
+    else:
+        T, row_heads = k.shape[1], k.shape[2]
+        heads = kv_heads or row_heads
+        assert H % heads == 0 and heads <= row_heads, (H, heads, row_heads)
+        D = Dv = _pad(d_head, 128)
+        if D != d_head:
+            lanes = ((0, 0),) * 3 + ((0, D - d_head),)
+            q, k, v = (jnp.pad(a, lanes) for a in (q, k, v))
+    hp = _pad(H, 8)
+    q_rows = S * hp
     rows = tile * row_heads
     chunk = _chunk_rows(tile, row_heads, q_rows)
+    in_specs = [
+        pl.BlockSpec((1, S, H, D), lambda s, *_: (s, 0, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    operands = [q] + ([k, v] if packed else [
+        # a position's (row_heads, D) cache row is whole (8, 128) memory
+        # tiles, so the flat-row view is the same bytes: a bitcast
+        k.reshape(B, T * row_heads, D), v.reshape(B, T * row_heads, D)])
+    if sink is not None:
+        # each query row's sink logit, on every lane
+        per_row = jnp.pad(sink.astype(jnp.float32), (0, hp - H))
+        operands.append(jnp.broadcast_to(
+            jnp.tile(per_row, S)[:, None], (q_rows, 128)))
+        in_specs.append(pl.BlockSpec((q_rows, 128), lambda s, *_: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, S, H, D), lambda s, *_: (s, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, S, H, D), lambda s, *_: (s, 0, 0, 0)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, S, H, Dv), lambda s, *_: (s, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((_RING, rows, D), k.dtype),   # K ring
-            pltpu.VMEM((_RING, rows, D), v.dtype),   # V ring
+            pltpu.VMEM((_RING, rows, Dv), v.dtype),  # V ring
             pltpu.SemaphoreType.DMA((2, _RING)),
             pltpu.SMEM((4,), jnp.int32),             # the DMA cursor
             pltpu.VMEM((q_rows, D), q.dtype),        # query rows
-            pltpu.VMEM((q_rows, D), jnp.float32),    # online-softmax acc
+            pltpu.VMEM((q_rows, Dv), jnp.float32),   # online-softmax acc
             pltpu.VMEM((q_rows, 128), jnp.float32),  # running max (lane 0)
             pltpu.VMEM((q_rows, 128), jnp.float32),  # normalizer (lane 0)
         ],
     )
-    # a position's (row_heads, D) cache row is whole (8, 128) memory
-    # tiles, so the flat-row view is the same bytes: a bitcast
     out = pl.pallas_call(
         _make_decode_kernel(S, H, H // heads, row_heads, tile, T // tile,
-                            d_head, chunk, window),
+                            d_head, chunk, window, pack, sink is not None,
+                            ring),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, S, H, Dv), q.dtype),
         # the ring's cursor runs from one slot into the next: in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(spans.astype(jnp.int32), q, k.reshape(B, T * row_heads, D),
-      v.reshape(B, T * row_heads, D))
-    out = out[..., :d_head]
+    )(spans.astype(jnp.int32), *operands)
+    if packed:
+        out = out[..., :pack * d_value].reshape(B, S, H, pack, d_value)
+        out = jnp.take_along_axis(
+            out, place[None, None, :, None, None], axis=3)[:, :, :, 0]
+    else:
+        out = out[..., :d_head]
     return out[:, 0] if squeeze else out

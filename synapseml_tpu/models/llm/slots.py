@@ -15,13 +15,18 @@ kind declares (``model.MIXERS[kind].cache_entry``):
 - a *full-attention* layer keeps rows of keys and values by token
   position, ``(n_slots, max_len, kv_heads, d_head)``.  A position can be
   sliced, copied, overwritten and rolled back, and everything below that
-  reuses or moves a slot's history works on such rows.  A
-  *sliding-attention* layer keeps the same rows (all ``max_len`` of them:
-  a ring of ``sliding_window`` rows could not be a prefix-reuse source
-  once its owner had decoded past the prefix), so prefix reuse, the host
-  arena, preemption, disaggregated prefill and speculative verify work
-  on it unchanged; its window acts in the masks and in the tiles the
-  paged kernel walks, and the byte ledger counts ``min(span, window)``;
+  reuses or moves a slot's history works on such rows.  A layer kind that
+  ``LlamaConfig.attention_kinds`` describes (its own K/V heads, a key wider
+  than the value) keeps the same rows PACKED, ``(n_slots, rows * kv_heads /
+  f, f * width)`` with ``f`` heads side by side (``model.kv_pack``): the
+  layout the paged kernel reads, at no lane of padding;
+- a *sliding-attention* layer keeps such rows too where ``max_len`` is
+  under two rings (``SlidingAttention.cache_rows``: a rule on sizes), so
+  that a slot stays a prefix-reuse source at every length; its window acts
+  in the masks and in the tiles the paged kernel walks, and the byte ledger
+  counts ``min(span, window)``.  From two rings on it keeps a RING of
+  ``model.ring_rows(window)`` rows a slot, position ``p`` in row ``p mod
+  rows``, whatever ``max_len`` (``engine.ring``; **On a ring** below);
 - a *linear-attention* layer keeps a recurrent state and a convolution
   window of a fixed size whatever the length
   (``{"state": (n_slots, heads/pack, d_k, pack*d_v) f32, "conv":
@@ -101,7 +106,29 @@ Mechanics:
   needs the host's tokens BEFORE a step, so a speculative engine
   dispatches and reads each step in one call.
 
-**Expert layers** (``cfg.ffn == "experts"``,
+**On a ring.**  A window layer's ring holds the last ``rows`` positions
+its slot wrote and nothing older, and it has no room past a slot's length
+where junk could land unread.  So every path that assumed rows by position
+says what it does there, as the recurrent state's paths do: a decode step
+writes at ``position mod rows`` and the paged kernel walks the window's
+position tiles through the ring; a prefill writes only its real rows (no
+bucket's padding), the last ``rows`` of them, and attends over the ring's
+rows before its start and its own (``model._ring_pass``), so a tail after
+a reused prefix reads the window's rows before it from the ring; a prefix
+reuse, in place or by ``_copy_prefix_jit`` (which copies a ring whole), is
+served only while the source's ring still holds the ``sliding_window`` rows
+before the prefix's end (the source has written at most ``rows - window -
+2`` positions past it), else skipped and counted
+(``llm_prefix_reuse_skipped_total{reason="ring_overwritten"}``,
+``AdmitResult.path`` ``cold_ring``) and never served from overwritten
+rows; :meth:`SlotEngine.resume` goes by the same rule and otherwise
+cold-prefills; a drafter (a verify span's rejected rows would overwrite
+the rows a window behind them), a ``kv_arena`` and a
+:class:`~synapseml_tpu.serving.disagg.PrefillWorker` (both slice a slot's
+rows ``[0, span)``) are an error at construction, the last two for packed
+rows as well (``engine.kv_by_position``).
+
+**Expert layers** (a layer whose feed-forward kind is ``"experts"``,
 :mod:`~synapseml_tpu.models.llm.experts`): the program holds some of a
 layer's routed experts and computes their part of the result; a token that
 is a bucket's padding or an inactive slot's row routes nowhere.  Each
@@ -140,8 +167,8 @@ from .drafter import NgramDrafter
 from .kvtier import ChecksumError, RadixPrefixIndex, kvtier_metrics
 from .generate import sample_logits
 from .experts import stats_totals
-from .model import LlamaModel, init_cache
-from .pallas_attn import (dense_read_bytes, paged_geometry,
+from .model import MIXERS, RING_BLOCK, LlamaModel, init_cache, kv_pack
+from .pallas_attn import (PagedGeometry, dense_read_bytes, paged_geometry,
                           paged_live_tiles, paged_read_bytes,
                           resolve_attention_backend)
 from .pallas_gdn import resolve_recurrent_backend, slot_state_bytes
@@ -153,7 +180,7 @@ def _apply(model: LlamaModel, variables: Any, *args, **kw):
     the pass (:func:`~synapseml_tpu.models.llm.experts.stats_totals`),
     else None.  The counts leave a program inside the array its tokens or
     logits leave in, so reading them is no transfer of its own."""
-    if model.cfg.ffn != "experts":
+    if not model.cfg.has_experts:
         return (*model.apply(variables, *args, **kw), None)
     (logits, cache), state = model.apply(variables, *args, mutable=["stats"],
                                          **kw)
@@ -280,16 +307,22 @@ def _verify_step_jit(model: LlamaModel, variables: Any, cache: Any,
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_prefix_jit(cache: Any, src: jnp.ndarray, dst: jnp.ndarray,
-                     length: jnp.ndarray):
+                     length: Any):
     """Copy K/V positions ``[0, length)`` of slot ``src`` into slot
-    ``dst`` (the longest-common-prefix reuse transfer)."""
-    def cp(c):
+    ``dst`` (the longest-common-prefix reuse transfer).  ``length`` is a
+    count of positions, or (an engine whose kinds keep packed rows or a
+    ring: :meth:`SlotEngine._copy_length`) a tree like ``cache`` of each
+    entry's count of ROWS: a packed entry's flat rows, a ring whole."""
+    def cp(c, n):
         row = lax.dynamic_slice_in_dim(c, src, 1, axis=0)
         old = lax.dynamic_slice_in_dim(c, dst, 1, axis=0)
-        m = (jnp.arange(c.shape[1]) < length)[None, :, None, None]
+        m = (jnp.arange(c.shape[1]) < n)[None, :, None, None] \
+            if c.ndim == 4 else (jnp.arange(c.shape[1]) < n)[None, :, None]
         return lax.dynamic_update_slice_in_dim(
             c, jnp.where(m, row, old), dst, axis=0)
-    return jax.tree.map(cp, cache)
+    if isinstance(length, list):
+        return jax.tree.map(cp, cache, length)
+    return jax.tree.map(lambda c: cp(c, length), cache)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -354,7 +387,9 @@ class AdmitResult:
     (``cold``: all prefilled, ``reuse``: a device-resident prefix,
     ``restore``: the host arena, ``cold_recurrent``: a prefix was there to
     reuse and was prefilled again, because the model's recurrent state
-    after it was not)."""
+    after it was not, ``cold_ring``: a prefix was there and was prefilled
+    again, because the source slot's ring no longer held the window's rows
+    before the prefix's end)."""
     slot: int
     token: int
     finished: bool
@@ -382,6 +417,47 @@ class _Flight:
     epoch: np.ndarray         # their admission epochs at dispatch
     lengths: np.ndarray       # the lengths it was fed (other slots: 1)
     program: str
+
+
+@dataclasses.dataclass(frozen=True)
+class _KindCache:
+    """What the cache entries of ONE attention layer kind are: how many
+    layers, their window, the rows a slot keeps (a ring's, on one), the
+    heads and widths of a row, how the entry packs them, and the paged
+    kernel's geometry over it (None: the dense backend)."""
+    kind: str
+    layers: int
+    window: Optional[int]
+    rows: int
+    ring: bool
+    kv_heads: int
+    d_key: int
+    d_value: int
+    packed: bool
+    pack: int
+    #: heads and lanes the cache really holds a position (its padding
+    #: included), for the kernel's byte ledger
+    row_heads: int
+    geo: Optional[PagedGeometry] = None
+
+    @property
+    def geometry_args(self) -> Dict[str, Any]:
+        """:func:`paged_geometry`'s keywords for this kind."""
+        kw: Dict[str, Any] = {}
+        if self.packed:
+            kw.update(d_value=self.d_value, pack=self.pack)
+        if self.ring:
+            kw.update(most=RING_BLOCK)
+        return kw
+
+    def row_bytes(self, itemsize: int) -> int:
+        """K and V bytes of one position, unpadded: what attention needs."""
+        return self.kv_heads * (self.d_key + self.d_value) * itemsize
+
+    def held_row_bytes(self, itemsize: int) -> int:
+        """K and V bytes the cache holds a position a layer (a row's padding
+        heads included)."""
+        return self.row_heads * (self.d_key + self.d_value) * itemsize
 
 
 class SlotEngine:
@@ -422,18 +498,34 @@ class SlotEngine:
         # pow2 S bucket over pending + longest draft) — the VMEM gate
         # must price ITS q/scratch working set, not the S=1 step's
         spec_span = _next_pow2(1 + max(0, int(spec_draft_len)))
-        self.attention_backend = resolve_attention_backend(
-            attention_backend, max_len=self.max_len,
-            num_heads=self.cfg.num_heads,
-            num_kv_heads=self.cfg.num_kv_heads,
-            d_head=self.cfg.d_head, dtype=self.cfg.dtype,
-            max_query_span=spec_span)
+        #: one record an attention layer kind: its cache entry and geometry
+        self._kinds = self._describe_kinds()
+        backends = {resolve_attention_backend(
+            attention_backend, max_len=kc.rows,
+            num_heads=self.cfg.num_heads, num_kv_heads=kc.kv_heads,
+            d_head=kc.d_key, dtype=self.cfg.dtype,
+            max_query_span=spec_span, **kc.geometry_args)
+            for kc in self._kinds} or {resolve_attention_backend(
+                attention_backend, max_len=self.max_len,
+                num_heads=self.cfg.num_heads,
+                num_kv_heads=self.cfg.num_kv_heads, d_head=self.cfg.d_head,
+                dtype=self.cfg.dtype, max_query_span=spec_span)}
+        # 'auto' is paged where every kind has a geometry
+        self.attention_backend = backends.pop() if len(backends) == 1 \
+            else "dense"
         #: layers whose state cannot be sliced by token position (module
         #: docstring, "Two kinds of state")
         self.recurrent = self.cfg.num_recurrent_layers > 0
+        #: window layers on a ring: rows by position exist for the last
+        #: ``ring_rows`` positions only (module docstring, "On a ring")
+        self.ring = any(kc.ring for kc in self._kinds)
+        #: every attention entry is ``(slots, max_len, heads, d_head)`` rows
+        #: by position, which the host arena and a prefill worker slice
+        self.kv_by_position = not self.recurrent and not any(
+            kc.ring or kc.packed for kc in self._kinds)
         #: the model has expert layers: its programs return two counts
         #: with their tokens (:func:`_apply`)
-        self.experts = self.cfg.ffn == "experts"
+        self.experts = self.cfg.has_experts
         if self.recurrent:
             if spec_draft_len:
                 raise ValueError(
@@ -451,19 +543,43 @@ class SlotEngine:
             resolve_recurrent_backend(
                 self.attention_backend, self.cfg.linear_num_heads,
                 self.cfg.linear_key_head_dim, self.cfg.linear_value_head_dim)
-        self._paged_geo = (None if self.attention_backend == "dense"
-                          else paged_geometry(
-                              self.max_len, self.cfg.num_heads,
-                              self.cfg.num_kv_heads, self.cfg.d_head,
-                              self.cfg.dtype, max_query_span=spec_span))
+        if self.ring and spec_draft_len:
+            raise ValueError(
+                "spec_draft_len > 0 with window layers on a ring: a verify "
+                "step writes its whole drafted span, and on a ring the "
+                "rejected part's rows have overwritten the rows a window "
+                "behind them; the ring is sized for one written row a step")
+        if kv_arena is not None and not self.kv_by_position:
+            raise ValueError(
+                "kv_arena over a cache that is not rows by position (window "
+                "layers on a ring, or kinds that keep packed rows): the host "
+                "arena spills and restores a slot's rows [0, span), which a "
+                "ring no longer holds and a packed entry lays out otherwise")
+        if self.attention_backend != "dense":
+            self._kinds = [dataclasses.replace(kc, geo=paged_geometry(
+                kc.rows, self.cfg.num_heads, kc.kv_heads, kc.d_key,
+                self.cfg.dtype, max_query_span=spec_span,
+                **kc.geometry_args)) for kc in self._kinds]
+        #: the first kind's geometry (every kind's where the model has one
+        #: kind of cache entry): what the tuning table is consulted for
+        self._paged_geo = self._kinds[0].geo if self._kinds else None
         # tuned K/V tile: the ``paged_attn_tile`` tuning-table winner
         # for THIS cache geometry, admitted only through the same
         # divisibility/VMEM gate the ladder uses — no table (or a tile
         # the gate rejects) keeps the default geometry, so dispatch is
-        # program-key-identical to a table-less process
-        if self._paged_geo is not None:
+        # program-key-identical to a table-less process.  A model whose
+        # kinds differ in geometry keeps each kind's default
+        if self._paged_geo is not None and self._one_geometry:
             self._paged_geo = self._consult_paged_tile(
                 spec_span, self._paged_geo)
+            self._kinds = [dataclasses.replace(kc, geo=self._paged_geo)
+                           for kc in self._kinds]
+        #: the resolved K/V tile as the programs' static ``paged_tile``: one
+        #: number where every kind's geometry is one, else ``((kind, tile),
+        #: ...)``; None on the dense backend
+        self._paged_tile: Any = None if self._paged_geo is None \
+            else self._paged_geo.tile if self._one_geometry \
+            else tuple((kc.kind, kc.geo.tile) for kc in self._kinds)
         #: optional request-trace hook ``sink(slot, event, **attrs)`` —
         #: the serving loop installs one mapping slots to trace ids, and
         #: the engine reports per-slot step outcomes through it
@@ -580,7 +696,9 @@ class SlotEngine:
             "llm_prefix_reuse_skipped_total",
             "admissions and resumes that found a reusable prefix and "
             "prefilled it anyway (reason recurrent_state: the model keeps "
-            "a state that cannot be sliced by token position)",
+            "a state that cannot be sliced by token position; "
+            "ring_overwritten: the source slot's ring no longer holds the "
+            "window's rows before the prefix's end)",
             ("engine", "reason"))
         self._m_occ = reg.gauge(
             "llm_slot_occupancy", "active slots / total slots", ("engine",))
@@ -601,6 +719,22 @@ class SlotEngine:
             "engine holds beside its K/V cache (every slot, every "
             "linear-attention layer)", ("engine",)
         ).set(self.n_slots * self.slot_state_bytes, engine=name)
+        itemsize = np.dtype(self.cfg.dtype).itemsize
+        reserved = reg.gauge(
+            "llm_kv_cache_bytes_reserved",
+            "device bytes of K/V rows the engine's cache holds for one "
+            "attention layer kind (every slot, every layer of the kind; a "
+            "window layer on a ring holds its ring's rows)",
+            ("engine", "kind"))
+        self._m_kv_in_use = reg.gauge(
+            "llm_kv_cache_bytes_in_use",
+            "bytes of those rows that hold a live key or value of a slot in "
+            "use at the last step (a ring's rows fill up to the ring)",
+            ("engine", "kind"))
+        for kc in self._kinds:
+            reserved.set(kc.layers * self.n_slots * kc.rows
+                         * kc.held_row_bytes(itemsize),
+                         engine=name, kind=kc.kind)
         self._m_expert_pairs = reg.counter(
             "llm_expert_pairs_total",
             "(token, expert) pairs whose expert this program holds, computed "
@@ -616,7 +750,8 @@ class SlotEngine:
                 "llm_expert_weight_bytes_held",
                 "device bytes of the routed experts this program holds "
                 "(every expert layer)", ("engine",)
-            ).set(cfg.num_layers * cfg.experts_held_count * 3 * cfg.d_model
+            ).set(cfg.num_expert_layers * cfg.experts_held_count * 3
+                  * cfg.d_model
                   * (cfg.expert_d_ff or cfg.d_ff)
                   * np.dtype(cfg.dtype).itemsize, engine=name)
         #: the last read program's expert counts, for its span
@@ -698,27 +833,27 @@ class SlotEngine:
         absent/mismatched/stale or the winner fails the VMEM gate."""
         from .pallas_attn import paged_geometry_key
         from ...telemetry.tunetable import get_tuneplane
+        kc = self._kinds[0]
+
+        def geometry(tile):
+            return paged_geometry(
+                kc.rows, self.cfg.num_heads, kc.kv_heads, kc.d_key,
+                self.cfg.dtype, max_query_span=spec_span, tile=tile,
+                **kc.geometry_args)
 
         def _gate(winner):
             t = winner.get("tile")
             return (isinstance(t, int) and not isinstance(t, bool)
-                    and paged_geometry(
-                        self.max_len, self.cfg.num_heads,
-                        self.cfg.num_kv_heads, self.cfg.d_head,
-                        self.cfg.dtype, max_query_span=spec_span,
-                        tile=t) is not None)
+                    and geometry(t) is not None)
 
         winner = get_tuneplane().consult(
             "SlotEngine", "paged_attn_tile",
-            paged_geometry_key(self.max_len, self.cfg.num_kv_heads,
-                               self.cfg.d_head, self.cfg.dtype, spec_span),
+            paged_geometry_key(kc.rows, kc.kv_heads, kc.d_key,
+                               self.cfg.dtype, spec_span),
             validate=_gate)
         if winner is None:
             return default_geo
-        return paged_geometry(self.max_len, self.cfg.num_heads,
-                              self.cfg.num_kv_heads, self.cfg.d_head,
-                              self.cfg.dtype, max_query_span=spec_span,
-                              tile=int(winner["tile"]))
+        return geometry(int(winner["tile"]))
 
     def _consult_min_bucket(self) -> int:
         """``llm_bucket_grid`` winner for this ``max_len`` → the tuned
@@ -733,6 +868,49 @@ class SlotEngine:
                 and 1 <= w["min_bucket"] <= self.max_len
                 and (w["min_bucket"] & (w["min_bucket"] - 1)) == 0))
         return int(winner["min_bucket"]) if winner is not None else 8
+
+    # -- the cache by layer kind ---------------------------------------------
+    def _describe_kinds(self) -> List[_KindCache]:
+        """One :class:`_KindCache` an attention layer kind the model has, in
+        layer order (none for a model of linear-attention layers alone)."""
+        cfg, out = self.cfg, []
+        for kind in cfg.attention_layer_kinds:
+            a = cfg.attention(kind)
+            rows = MIXERS[kind].cache_rows(cfg, self.max_len)
+            window = cfg.sliding_window if kind == "sliding_attention" \
+                else None
+            packed = cfg.packed(kind)
+            out.append(_KindCache(
+                kind=kind, layers=cfg.layer_kinds.count(kind), window=window,
+                rows=rows, ring=window is not None and rows < self.max_len,
+                kv_heads=a.num_kv_heads, d_key=a.head_dim,
+                d_value=a.v_head_dim, packed=packed,
+                pack=kv_pack(a.head_dim, a.num_kv_heads) if packed else 1,
+                row_heads=a.num_kv_heads if packed else cfg.kv_cache_heads))
+        return out
+
+    @property
+    def _one_geometry(self) -> bool:
+        """Every kind's cache entry has the same rows, heads and widths (a
+        model of one kind, or of kinds that differ in their masks alone)."""
+        return len({(kc.rows, kc.kv_heads, kc.d_key, kc.d_value, kc.pack,
+                     kc.ring) for kc in self._kinds}) <= 1
+
+    def _copy_length(self, positions: int) -> Any:
+        """``_copy_prefix_jit``'s ``length`` for a prefix of ``positions``
+        tokens: the count itself where every entry is rows by position, else
+        each entry's count of rows (a packed entry's flat rows; a ring
+        whole: it is copied as it stands)."""
+        if self.kv_by_position or self.recurrent:
+            return positions
+        by_kind = {kc.kind: kc for kc in self._kinds}
+        out = []
+        for kind in self.cfg.layer_kinds:
+            kc = by_kind[kind]
+            per = (kc.kv_heads // kc.pack) if kc.packed else 1
+            n = kc.rows * per if kc.ring else positions * per
+            out.append({"k": n, "v": n})
+        return out
 
     # -- capacity ----------------------------------------------------------
     @property
@@ -869,13 +1047,27 @@ class SlotEngine:
             return None, 0
         return src, lcp
 
-    def _count_reuse_skipped(self) -> None:
-        """A prefix was there to reuse and is prefilled again: its K/V
-        rows could be copied, the recurrent state after its last token
-        was never kept."""
+    def _reuse_refused(self, src: int, lcp: int) -> Optional[str]:
+        """Why ``lcp`` tokens of slot ``src`` cannot be reused, or None:
+        ``recurrent_state`` (its K/V rows could be copied, the recurrent
+        state after its last token was never kept) or ``ring_overwritten``
+        (a window layer's ring holds the last ``rows`` positions the slot
+        wrote: the tail's first query needs the ``sliding_window`` rows
+        before ``lcp``, and the source has since written more than the
+        ring's spare rows past it; two of those are kept for the steps that
+        may be in flight over an active source)."""
+        if self.recurrent:
+            return "recurrent_state"
+        for kc in self._kinds:
+            if kc.ring and int(self.kv_len[src]) - lcp \
+                    > kc.rows - kc.window - 2:
+                return "ring_overwritten"
+        return None
+
+    def _count_reuse_skipped(self, reason: str) -> None:
+        """A prefix was there to reuse and is prefilled again."""
         self.prefix_reuse_skipped += 1
-        self._m_reuse_skipped.inc(1, engine=self.name,
-                                  reason="recurrent_state")
+        self._m_reuse_skipped.inc(1, engine=self.name, reason=reason)
 
     # -- admission ---------------------------------------------------------
     def _pick_slot(self) -> Optional[int]:
@@ -943,9 +1135,9 @@ class SlotEngine:
             # _best_prefix and _register_prefix scope themselves by it
             self._slot_tenant[slot] = tenant
             src, lcp = self._best_prefix(prompt, slot)
-            skipped = self.recurrent and src is not None
+            skipped = None if src is None else self._reuse_refused(src, lcp)
             if skipped:
-                self._count_reuse_skipped()
+                self._count_reuse_skipped(skipped)
                 src, lcp = None, 0
             restored = False
             if self.kv_arena is not None:
@@ -966,8 +1158,8 @@ class SlotEngine:
             if restored or (src is not None and lcp > 0):
                 if not restored and src != slot:
                     with self._program_region("prefix_copy"):
-                        self.cache = _copy_prefix_jit(self.cache, src, slot,
-                                                      lcp)
+                        self.cache = _copy_prefix_jit(
+                            self.cache, src, slot, self._copy_length(lcp))
                 # src == slot: in-place resume — the reclaimed slot
                 # already holds this conversation's prefix K/V, no copy
                 self.prefix_hits += 1
@@ -1023,7 +1215,8 @@ class SlotEngine:
             return AdmitResult(
                 slot, tok, finished, lcp, logits, bucket=pb, reason=reason,
                 path="restore" if restored else "reuse" if lcp
-                else "cold_recurrent" if skipped else "cold")
+                else {None: "cold", "recurrent_state": "cold_recurrent",
+                      "ring_overwritten": "cold_ring"}[skipped])
 
     def _count_experts(self, out: np.ndarray) -> np.ndarray:
         """Split what a program of a model with expert layers returned:
@@ -1149,7 +1342,9 @@ class SlotEngine:
         resort — all three paths reproduce the identical K/V, so the
         continuation is token-exact regardless.  A model with recurrent
         layers always takes the last: its state after the span was not
-        kept (counted in ``llm_prefix_reuse_skipped_total``).  Returns
+        kept (counted in ``llm_prefix_reuse_skipped_total``); so does a
+        slot whose window layers are on a ring once the source has written
+        past the ring's spare rows (:meth:`_reuse_refused`).  Returns
         the slot, or None when every slot is busy."""
         ids = np.asarray(ticket["ids"], np.int32).reshape(-1)
         span = int(ticket["kv_len"])
@@ -1177,13 +1372,15 @@ class SlotEngine:
             if src is not None:
                 dlcp = self._clamp_reuse(
                     int(min(dlcp, self.kv_len[src], span)), span)
-                if dlcp >= self.min_prefix and self.recurrent:
-                    self._count_reuse_skipped()     # rebuilt from 0
+                refused = self._reuse_refused(src, dlcp)
+                if dlcp >= self.min_prefix and refused:
+                    self._count_reuse_skipped(refused)   # rebuilt from 0
                 elif dlcp >= self.min_prefix:
                     if src != slot:
                         with self._program_region("prefix_copy"):
                             self.cache = _copy_prefix_jit(
-                                self.cache, src, slot, dlcp)
+                                self.cache, src, slot,
+                                self._copy_length(dlcp))
                     est = dlcp
         if est < span:
             # cold tail: rebuild K/V for ids[est:span]; the logits are
@@ -1253,9 +1450,8 @@ class SlotEngine:
         never price different geometries), and the per-slot live spans
         the byte ledger prices (an inactive slot's is 1)."""
         lengths = np.where(active, lengths, 1)
-        geo = self._paged_geo
         return {"attention_backend": self.attention_backend,
-                "paged_tile": None if geo is None else geo.tile}, lengths
+                "paged_tile": self._paged_tile}, lengths
 
     def _account_decode_bytes(self, spans: np.ndarray, served: int,
                               query_span: int = 1) -> None:
@@ -1268,26 +1464,33 @@ class SlotEngine:
         ``query_span``: the S of a verify step, whose ``spans`` include its
         S written positions."""
         itemsize = np.dtype(self.cfg.dtype).itemsize
-        layers = self.cfg.num_attention_layers
         if self._paged_geo is not None:
-            tile = self._paged_geo.tile
-            # (layers, window) of each attention kind: a window layer's
-            # walk starts at its window's first tile
-            kinds = [(layers - self.cfg.num_window_layers, None),
-                     (self.cfg.num_window_layers, self.cfg.sliding_window)]
-            nbytes = sum(paged_read_bytes(
-                spans, tile, self.cfg.kv_cache_heads, self.cfg.d_head,
-                itemsize, n, window, query_span) for n, window in kinds if n)
-            # the kernel makes one loop trip a tile it fetches: walked
-            # over live is 1.0 while no dead tile is walked
-            live = sum(n * paged_live_tiles(spans, tile, window, query_span)
-                       for n, window in kinds if n)
+            # each attention kind by its own geometry: a window layer's
+            # walk starts at its window's first tile, a packed row has its
+            # own lanes, a ring is walked by position like any row
+            nbytes = live = 0
+            for kc in self._kinds:
+                nbytes += paged_read_bytes(
+                    spans, kc.geo.tile, kc.row_heads, kc.d_key, itemsize,
+                    kc.layers, kc.window, query_span, d_value=kc.d_value,
+                    pack=kc.pack)
+                # the kernel makes one loop trip a tile it fetches: walked
+                # over live is 1.0 while no dead tile is walked
+                live += kc.layers * paged_live_tiles(
+                    spans, kc.geo.tile, kc.window, query_span)
             self._step_tiles = {"paged_tiles_live": live,
                                 "paged_tiles_walked": live}
         else:
-            nbytes = dense_read_bytes(
-                self.n_slots, self.max_len, self.cfg.num_kv_heads,
-                self.cfg.d_head, itemsize, layers)
+            nbytes = sum(dense_read_bytes(
+                self.n_slots, kc.rows, kc.kv_heads,
+                (kc.d_key + kc.d_value) / 2, itemsize, kc.layers)
+                for kc in self._kinds)
+        in_use = spans[spans > 1]
+        for kc in self._kinds:
+            self._m_kv_in_use.set(
+                kc.layers * int(np.minimum(in_use, kc.rows).sum())
+                * kc.held_row_bytes(itemsize),
+                engine=self.name, kind=kc.kind)
         self.decode_attn_bytes += nbytes
         self._m_decode_bytes.set(nbytes / max(1, served),
                                  engine=self.name,
@@ -1329,6 +1532,16 @@ class SlotEngine:
                     # what a window layer has to read of those spans
                     sp.set(kv_window_span_sum=int(np.minimum(
                         self.lengths[act], self.cfg.sliding_window).sum()))
+                itemsize = np.dtype(self.cfg.dtype).itemsize
+                for kc in self._kinds:
+                    # K and V bytes the kind's layers need this step: each
+                    # slot's span (its window's keys at most), unpadded
+                    keys = self.lengths[act] if kc.window is None else \
+                        np.minimum(self.lengths[act], kc.window)
+                    sp.set(**{"kv_bytes_" + kc.kind: kc.layers * int(
+                        keys.sum()) * kc.row_bytes(itemsize)})
+                    if kc.ring:
+                        sp.set(kv_ring_rows=kc.rows)
             events = None
             if self._drafter is not None:
                 with step_span("engine.step.draft"):

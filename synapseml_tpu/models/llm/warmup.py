@@ -145,10 +145,8 @@ def program_lattice(engine) -> List[ProgramSpec]:
     model, variables = engine.model, engine.variables
     n = engine.n_slots
     backend = engine.attention_backend
-    geo = engine._paged_geo
-
     step_kwargs = {"attention_backend": backend,
-                   "paged_tile": geo.tile if geo is not None else None}
+                   "paged_tile": engine._paged_tile}
 
     def decode_inputs():
         tokens = jnp.asarray(np.full(n, engine.pad_id, np.int32))
@@ -171,7 +169,8 @@ def program_lattice(engine) -> List[ProgramSpec]:
                              run_decode))
 
     def run_copy(cache):
-        cache = _copy_prefix_jit(cache, 0, min(1, n - 1), 1)
+        cache = _copy_prefix_jit(cache, 0, min(1, n - 1),
+                                 engine._copy_length(1))
         jax.block_until_ready(jax.tree.leaves(cache)[0])
         return cache
     if not engine.recurrent:       # such an engine never copies a prefix

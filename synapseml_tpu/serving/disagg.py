@@ -122,6 +122,12 @@ class PrefillWorker:
                 "layers: the handoff ships K/V rows by token position; a "
                 "recurrent state can be snapshotted at a position, not "
                 "sliced, and the engine builds no snapshots")
+        if not getattr(engine, "kv_by_position", True):
+            raise ValueError(
+                "a prefill worker over a cache that is not rows by position "
+                "(window layers on a ring, or kinds that keep packed rows): "
+                "the handoff ships a slot's rows [0, span), which a ring no "
+                "longer holds and a packed entry lays out otherwise")
         self.engine = engine
 
     def prefill(self, ids, tenant: str = "default"

@@ -737,6 +737,38 @@ def test_paged_kernel_compiles_for_the_v5e_at_128_heads_over_8(one_chip,
         rf"bf16\[{n},{max_len * kv},128\]\S* (copy|fusion)\(", text)
 
 
+@pytest.mark.parametrize("kind,rows,kv,most", [
+    ("full", 16384, 4, None),       # rows by position, two heads a row
+    ("ring", 256, 8, 128),          # a window layer's ring, sinks
+])
+def test_packed_paged_kernel_compiles_for_the_v5e_at_192_beside_128(
+        one_chip, kind, rows, kv, most):
+    """MiMo-V2.5's geometries: 24 slots, 64 query heads over 4 and 8 K/V
+    heads, keys 192 wide beside values 128 wide, packed two heads a row (384
+    and 256 lanes, no padding); the window layers' ring with the sink as one
+    more operand.  The packed cache IS the kernel's view: nothing of cache
+    size is copied or relaid."""
+    n, heads, dk, dv = 24, 64, 192, 128
+    geo = paged_geometry(rows, heads, kv, dk, jnp.bfloat16, d_value=dv,
+                         pack=2, most=most)
+    assert geo is not None and geo.tile == (128 if kind == "full" else 64)
+
+    def sd(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    flat = rows * kv // 2
+    extra = {} if kind == "full" else {
+        "window": 128, "ring": True, "sink": sd((heads,), jnp.float32)}
+    compiled = paged_decode_attention.lower(
+        sd((n, heads, dk)), sd((n, flat, 2 * dk)), sd((n, flat, 2 * dv)),
+        sd((n,), jnp.int32), tile=geo.tile, kv_heads=kv, pack=2,
+        **extra).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert not re.search(
+        rf"bf16\[{n},{flat},(384|256)\]\S* (copy|fusion)\(", text)
+
+
 @pytest.mark.parametrize("pairs,tm", [(192, 16), (8192, 256)],
                          ids=["decode", "prefill-chunk"])
 def test_expert_ffn_compiles_for_the_v5e_at_the_published_widths(

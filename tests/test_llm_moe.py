@@ -677,11 +677,93 @@ def test_the_expert_layer_is_exact_under_the_worst_imbalance(ref, backend):
     np.testing.assert_allclose(idle, shared, atol=5e-5 * np.abs(want).max())
 
 
+#: a layer whose router selects by score plus bias and has no shared expert,
+#: in the published keys of the family that has one (``mimo_v2``), at toy
+#: sizes: layer 1 of two, the reference ``benchmark/references/
+#: mimo-v2.5-l7-e16.py``
+BIASED = {
+    "model_type": "mimo_v2", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 96, "moe_intermediate_size": 32,
+    "num_hidden_layers": 2, "num_attention_heads": 8,
+    "num_key_value_heads": 2, "swa_num_key_value_heads": 4, "head_dim": 64,
+    "v_head_dim": 32, "layernorm_epsilon": 1e-5, "tie_word_embeddings": False,
+    "hybrid_layer_pattern": [0, 1], "moe_layer_freq": [0, 1],
+    "sliding_window": 8, "rope_theta": 10000000, "swa_rope_theta": 10000,
+    "partial_rotary_factor": 0.25, "attention_value_scale": 0.707,
+    "add_full_attention_sink_bias": False,
+    "add_swa_attention_sink_bias": True, "num_experts_per_tok": 4,
+    "scoring_func": "sigmoid", "norm_topk_prob": True,
+    "topk_method": "noaux_tc",
+    "n_routed_experts": 16, "router_experts": 16, "experts_first": 0}
+
+
+@pytest.fixture(scope="module")
+def biased_ref():
+    return harness.load_module(os.path.join(
+        ROOT, "benchmark", "references", "mimo-v2.5-l7-e16.py"))
+
+
+def _biased_experts(hc, w, h, backend):
+    """The program's expert layer under the biased router, for a share."""
+    cfg = LlamaConfig.from_hf(
+        dict(hc, n_routed_experts=hc["router_experts"]), dtype=jnp.float32,
+        max_len=MAX_LEN, experts_first=hc["experts_first"],
+        experts_held=hc["n_routed_experts"])
+    assert cfg.expert_selection_bias and cfg.num_shared_experts == 0
+    params = {k: jnp.asarray(w[k], jnp.float32) for k in (
+        "router", "router_bias", "experts_gate", "experts_up",
+        "experts_down")}
+    out, state = X.ExpertFFN(cfg).apply(
+        {"params": params}, h[None], jnp.ones((1, h.shape[0]), bool), backend,
+        mutable=["stats"])
+    return np.asarray(out)[0], X.stats_totals(state["stats"])
+
+
+@pytest.mark.parametrize("router", ["plain", "biased"])
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_the_shares_add_up_to_the_uncut_layer(ref, backend):
+def test_the_shares_add_up_to_the_uncut_layer(ref, biased_ref, backend,
+                                              router):
     """8 shares of 2 of 16 experts: their routed parts, with the shared
     experts counted once, are the reference's uncut layer; so is the
-    program's own uncut layer."""
+    program's own uncut layer.  ``biased``: 16 shares of ONE expert each
+    under a router that selects by score plus bias and weighs by the score,
+    no shared expert: the bias changes which experts a token takes, so a
+    share that left it out, or weighed by it, would not add up."""
+    if router == "biased":
+        h = jax.random.normal(jax.random.PRNGKey(9), (48, 64))
+        w_all = biased_ref.layer_weights(BIASED, SEED, 1)
+        want = np.asarray(biased_ref.experts(
+            h, w_all, k=4, first=jnp.asarray(0), quant=None)[0])
+        scale = np.abs(want).max()
+        got, stats = _biased_experts(BIASED, w_all, h, backend)
+        assert int(stats[0]) == 48 * 4
+        np.testing.assert_allclose(got, want, atol=5e-5 * scale)
+        # the bias decides: without it other experts are selected
+        plain_idx = jax.lax.top_k(jax.nn.sigmoid(
+            h @ jnp.asarray(w_all["router"], jnp.float32)), 4)[1]
+        idx = biased_ref.route(h, w_all["router"], w_all["router_bias"], k=4,
+                               quant=None)[0]
+        changed = np.mean(np.sort(np.asarray(plain_idx), -1)
+                          != np.sort(np.asarray(idx), -1))
+        assert changed > 0.1
+        total, pairs = np.zeros_like(want), 0
+        for s in range(16):
+            share = dict(BIASED, n_routed_experts=1, experts_first=s)
+            w = biased_ref.layer_weights(share, SEED, 1)
+            np.testing.assert_array_equal(
+                np.asarray(w["experts_gate"]),
+                np.asarray(w_all["experts_gate"])[s:s + 1])
+            np.testing.assert_array_equal(np.asarray(w["router_bias"]),
+                                          np.asarray(w_all["router_bias"]))
+            part, st = _biased_experts(share, w, h, backend)
+            np.testing.assert_allclose(part, np.asarray(biased_ref.experts(
+                h, w, k=4, first=jnp.asarray(s), quant=None)[0]),
+                atol=5e-5 * scale)
+            total += part
+            pairs += int(st[0])
+        assert pairs == 48 * 4                  # each pair on one chip
+        np.testing.assert_allclose(total, want, atol=1e-4 * scale)
+        return
     uncut = dict(SMALL, num_experts=16, experts_first=0)
     h, w_all = _layer_setup(ref, uncut)
     want = np.asarray(ref.experts(h, w_all, k=4, first=jnp.asarray(0), ns=2,
