@@ -616,98 +616,6 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> List[Dict]:
             for kind in cfg.layer_kinds]
 
 
-#: a prefill's ``(heads, S, T)`` float32 scores above this many bytes are
-#: never built: attention then runs in blocks over keys
-#: (:func:`blocked_attention`).  The dense models the benchmark serves stay
-#: under it (32 heads x 2048 x 2048: 0.5 GiB); 128 heads over a 5,632-row
-#: cache pass it from a 512-token bucket on
-_DENSE_SCORE_BYTES = 1 << 30
-
-
-def _block_len(n: int, most: int = 512) -> int:
-    """The largest power of two up to ``most`` that divides ``n`` (else
-    ``n`` itself: one block)."""
-    b = most
-    while b > 1 and n % b:
-        b //= 2
-    return b if b >= 8 else n
-
-
-def blocked_attention(q, k, v, positions, window: Optional[int], dtype,
-                      sink=None, key_offset=None):
-    """Causal softmax attention without the ``(heads, S, T)`` scores.
-
-    ``q (B, S, H, D)`` at ``positions (B, S)`` over ``k (B, T, KV, D)``,
-    ``v (B, T, KV, Dv)`` whose row ``j`` is key position ``j`` (``key_offset
-    + j`` where one is given; a row at a negative position is no key): query
-    ``i`` sees key ``j`` iff ``j <= i`` and, with a ``window``,
-    ``j > i - window``.  Blocks of queries in turn; for each, the key
-    blocks that hold a visible key (none past the causal edge, none before
-    the window) through an online softmax in float32, probabilities cast
-    to ``dtype`` before the product with ``v`` as the dense path casts
-    them.  ``sink (H,)``: a logit a query head that every query sees beside
-    its keys and that carries no value (the online softmax starts from it).
-    -> ``(B, S, H * Dv)``."""
-    B, S, H, D = q.shape
-    T, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
-    G = H // KV
-    bq, bk = _block_len(S), _block_len(T)
-    neg = jnp.finfo(jnp.float32).min
-    scale = 1.0 / np.sqrt(D)
-    qb = jnp.moveaxis(q.reshape(B, S // bq, bq, KV, G, D), 1, 0)
-    pb = jnp.moveaxis(positions.reshape(B, S // bq, bq), 1, 0)
-
-    def q_block(args):
-        qi, pos = args                         # (B, bq, KV, G, D), (B, bq)
-        def row(p):                            # the row of position p
-            return p if key_offset is None else p - key_offset
-        hi = row(jnp.max(pos)) // bk + 1
-        lo = 0 if window is None else \
-            jnp.maximum(row(jnp.min(pos)) - (window - 1), 0) // bk
-
-        def k_block(j, carry):
-            m, l, acc = carry
-            kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=1)
-            vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=1)
-            s = jnp.einsum("bskgd,btkd->bkgst", qi, kj,
-                           preferred_element_type=jnp.float32) * scale
-            kpos = j * bk + jnp.arange(bk)
-            if key_offset is not None:
-                kpos = kpos + key_offset
-            see = kpos[None, None, :] <= pos[:, :, None]        # (B, bq, bk)
-            if window is not None:
-                see &= kpos[None, None, :] > pos[:, :, None] - window
-            if key_offset is not None:
-                see &= kpos[None, None, :] >= 0
-            see = see[:, None, None]
-            m_new = jnp.maximum(m, jnp.max(jnp.where(see, s, neg), -1))
-            # a query with no visible key in this block adds nothing (its
-            # running max may still be ``neg``: exp(0) would count)
-            p = jnp.where(see, jnp.exp(s - m_new[..., None]), 0.0)
-            alpha = jnp.exp(m - m_new)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "bkgst,btkd->bkgsd", p.astype(dtype), vj,
-                preferred_element_type=jnp.float32)
-            return m_new, l * alpha + jnp.sum(p, -1), acc
-
-        if sink is None:
-            m0 = jnp.full((B, KV, G, bq), neg, jnp.float32)
-            l0 = jnp.zeros_like(m0)
-        else:                                  # the sink's own term: exp(0)
-            m0 = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
-                1, KV, G, 1), (B, KV, G, bq))
-            l0 = jnp.ones_like(m0)
-        _, l, acc = jax.lax.fori_loop(
-            lo, hi, k_block,
-            (m0, l0, jnp.zeros((B, KV, G, bq, Dv), jnp.float32)))
-        # every query sees its own key, so l >= 1
-        out = (acc / l[..., None]).astype(dtype)               # (B,KV,G,bq,Dv)
-        return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, bq, H * Dv)
-
-    out = jax.lax.map(q_block, (qb, pb))                       # (S/bq, B, bq, HDv)
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, H * Dv)
-
-
 def _ring_pass(ring, new, start, n_valid):
     """One pass at a scalar offset over a ring of ``R`` rows (position
     ``p`` lies in row ``p mod R``).  ``ring (B, R, ...)``; ``new (B, S,
@@ -765,6 +673,22 @@ class CausalAttention(nn.Module):
         n = rows * (a.num_kv_heads // f)
         return {"k": jnp.zeros((batch, n, f * a.head_dim), cfg.dtype),
                 "v": jnp.zeros((batch, n, f * a.v_head_dim), cfg.dtype)}
+
+    def _prefill_tile(self, attention_backend: str, cache, cache_index,
+                      S: int, T: int):
+        """The prefill kernel's tile for a pass of ``S`` queries over ``T``
+        key rows, or None: the pass is no prefill on a kernel backend
+        (no cache, per-slot offsets, the dense backend) or
+        :func:`~synapseml_tpu.models.llm.pallas_attn.prefill_geometry` has
+        none for the shape."""
+        if attention_backend not in ("paged", "interpret") or cache is None \
+                or jnp.ndim(cache_index) != 0:
+            return None
+        from .pallas_attn import prefill_geometry
+        a = self.cfg.attention(self.KIND)
+        return prefill_geometry(S, T, self.cfg.num_heads, a.num_kv_heads,
+                                a.head_dim, a.v_head_dim, self.cfg.dtype,
+                                self.window)
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict],
@@ -912,8 +836,8 @@ class CausalAttention(nn.Module):
             # scale with live tokens, not cache capacity (the
             # vector-cache_index step is the serving hot loop: S == 1
             # plain decode, S > 1 the speculative-verify span whose S
-            # queries amortize one span read; prefill and training
-            # stay dense, where the full-row read is the work).
+            # queries amortize one span read; a prefill pass takes the
+            # branch below, training the plain scores).
             # ``paged_tile`` is the engine-resolved geometry (the byte
             # ledger prices the same tile by construction; one tile, or a
             # tuple of (kind, tile) where the kinds' geometries differ);
@@ -951,19 +875,24 @@ class CausalAttention(nn.Module):
                 q, k_all, v_all, spans, tile=tile, kv_heads=KV,
                 interpret=(attention_backend == "interpret"),
                 window=window, **extra).reshape(B, S, H * Dv)
-        elif H * S * T * 4 > _DENSE_SCORE_BYTES \
-                and (cache is None or jnp.ndim(cache_index) == 0):
-            # without a cache key j is row j of this pass, whatever
-            # ``positions`` says (as the dense mask below has it)
-            rows = positions if cache is not None else \
-                jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        elif (tile := self._prefill_tile(attention_backend, cache,
+                                         cache_index, S, T)) is not None:
+            # a prefill pass on the engine's kernel backend, at a shape
+            # ``prefill_geometry`` has a tile for: one tiled causal kernel
+            # (query s sits at ``cache_index + s``, as the engine's
+            # programs place it; under the shape's threshold and off the
+            # TPU the plain scores below stay)
+            from .pallas_attn import prefill_attention
             extra = {}
             if sink is not None:
                 extra.update(sink=sink)
             if key_offset is not None:
                 extra.update(key_offset=key_offset)
-            out = blocked_attention(q, k_att, v_att, rows, window, cfg.dtype,
-                                    **extra)
+            out = prefill_attention(
+                q, k_att, v_att, cache_index,
+                S if valid_len is None else valid_len, bq=tile.bq,
+                bk=tile.bk, window=window,
+                interpret=(attention_backend == "interpret"), **extra)
         else:
             group = H // KV
             qg = q.reshape(B, S, KV, group, D)

@@ -632,3 +632,383 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
     else:
         out = out[..., :d_head]
     return out[:, 0] if squeeze else out
+
+
+# ---------------------------------------------------------------------------
+# prefill: one tiled causal kernel
+# ---------------------------------------------------------------------------
+
+#: rows ``bq x group`` of one score block the geometry aims at, and the
+#: most keys a block holds: a ``(1024, 512)`` float32 score block is 2 MiB,
+#: so scores, probabilities, their cast and the accumulator stay under
+#: ``_VMEM_BUDGET`` with K, V, q and the output double-buffered
+_PREFILL_ROWS = 1024
+_PREFILL_KEYS = 512
+#: the fewest keys a block holds where the keys have that many: a score
+#: block narrower than the 128 lanes wastes the rest of them
+_PREFILL_MIN_KEYS = 128
+
+#: the plain path's ``(heads, S, T)`` float32 scores under which a prefill
+#: stays on it.  Under this many bytes the scores' three passes stay cheap
+#: beside the kernel's fixed cost (PERF.md §6, PR 37: at 128 heads 32
+#: queries over 5,632 keys, 92 MB, and at Olmo's group-1 heads 512 over
+#: 1,536, 94 MB, the kernel loses; from 128 MiB on it wins on every kind
+#: measured), and a bucket that stays there traces no kernel: set-up
+_PREFILL_MIN_SCORE_BYTES = 128 << 20
+
+
+@dataclasses.dataclass(frozen=True)
+class PrefillGeometry:
+    """The prefill kernel's tile for one shape: ``bq`` query positions (all
+    ``group`` query heads of a K/V head ride one block: ``bq x group``
+    rows) by ``bk`` key rows; ``key_steps`` the key blocks one query block
+    can visit at most (the grid's last axis: every block of the keys on a
+    full layer, those a window can reach behind one); the VMEM working
+    set the gate admitted."""
+    bq: int
+    bk: int
+    key_steps: int
+    vmem_bytes: int
+
+
+def _pow2_divisor(n: int, most: int, least: int) -> Optional[int]:
+    """The largest power of two in ``[least, most]`` that divides ``n``."""
+    b = 1
+    while b * 2 <= most:
+        b *= 2
+    while b >= least:
+        if n % b == 0:
+            return b
+        b //= 2
+    return None
+
+
+def _prefill_key_steps(T: int, bq: int, bk: int,
+                       window: Optional[int]) -> int:
+    """Key blocks one query block can visit: a span of ``bq + window - 1``
+    keys at any alignment touches ``(span - 1) // bk + 2`` blocks."""
+    if window is None:
+        return T // bk
+    return min(T // bk, (bq + window - 2) // bk + 2)
+
+
+def prefill_geometry(S: int, T: int, H: int, KV: int, D: int, Dv: int,
+                     dtype: Any = jnp.bfloat16,
+                     window: Optional[int] = None
+                     ) -> Optional[PrefillGeometry]:
+    """The tile of :func:`prefill_attention` for ``S`` queries of ``H`` heads
+    over ``T`` key rows of ``KV`` heads (keys ``D`` wide, values ``Dv``),
+    behind a ``window`` or none: the one home of the kernel's VMEM
+    arithmetic, as :func:`paged_geometry` is of the decode kernel's.  None
+    means the plain dense path, and a shape gets it
+
+    - where the plain path's float32 scores ``H x S x T x 4`` stay under
+      ``_PREFILL_MIN_SCORE_BYTES``: the kernel does not win there (or not
+      by what tracing it into one more program costs at every start);
+    - where no block divides the shape (``S`` into ``bq`` positions a
+      sublane multiple, ``T`` into ``bk`` rows of at least 128 where ``T``
+      has them), or nothing fits VMEM.
+
+    One algorithm whose parameters follow the shape: ``bq`` is the largest
+    power of two that gives at most ``_PREFILL_ROWS`` rows ``bq x (H /
+    KV)``, ``bk`` the largest up to ``_PREFILL_KEYS`` (behind a window, up
+    to the window: a block wider than the window is mostly masked)."""
+    if H % KV or H * S * T * 4 < _PREFILL_MIN_SCORE_BYTES:
+        return None
+    itemsize = np.dtype(dtype).itemsize
+    sub = _sublane(dtype)
+    G = H // KV
+    most_keys = _PREFILL_KEYS if window is None else \
+        min(_PREFILL_KEYS, max(_PREFILL_MIN_KEYS,
+                               1 << (int(window) - 1).bit_length()))
+    bk = _pow2_divisor(T, most_keys,
+                       min(_PREFILL_MIN_KEYS, T) if T % 8 == 0 else T + 1)
+    if bk is None:
+        return None
+    d_pad, v_pad = _pad(D, 128), _pad(Dv, 128)
+
+    def need(bq):
+        rows = G * bq
+        return (2 * rows * (d_pad + v_pad) * itemsize       # q + out, x2 buf
+                + 2 * _pad(bk, sub) * (d_pad + v_pad) * itemsize  # K + V x2
+                + rows * v_pad * 4 + 2 * rows * 128 * 4      # acc, m, l
+                + rows * _pad(bk, 128) * (4 + 4 + itemsize))  # scores, p
+
+    bq = _pow2_divisor(S, max(sub, min(512, _PREFILL_ROWS // G)), sub)
+    while bq is not None and need(bq) > _VMEM_BUDGET:
+        bq = _pow2_divisor(S, bq // 2, sub) if bq // 2 >= sub else None
+    if bq is None:
+        return None
+    return PrefillGeometry(bq, bk, _prefill_key_steps(T, bq, bk, window),
+                           need(bq))
+
+
+def _prefill_block_bounds(i, bq: int, bk: int, window: Optional[int],
+                          qoff, plen, kmin, xp=jnp):
+    """First and last key block query block ``i`` visits, in rows of the
+    keys: the block of the first key its first query sees (none before
+    ``kmin``, none before the window) and the block of its last REAL
+    query's own row.  Written once for the kernel (traced scalars) and the
+    host's count (``xp=np``: nothing of it may reach the device, whose
+    queue an admission would then wait behind)."""
+    q_lo = qoff + i * bq
+    q_hi = qoff + xp.minimum((i + 1) * bq, plen) - 1
+    first = kmin if window is None else \
+        xp.maximum(kmin, q_lo - (window - 1))
+    return first // bk, q_hi // bk
+
+
+def prefill_key_blocks(geo: PrefillGeometry, S: int, start: int, plen: int,
+                       window: Optional[int] = None,
+                       key_offset: Optional[int] = None) -> int:
+    """(query block, key block) pairs ONE K/V head of one layer's kernel
+    call computes for ``plen`` real tokens of a bucket of ``S`` from
+    position ``start``: host arithmetic from the same bounds the kernel
+    walks (``engine.admit``'s ``prefill_key_blocks_visited``; with ``plen
+    = S`` the bucket's)."""
+    off = 0 if key_offset is None else int(key_offset)
+    i = np.arange(-(-int(plen) // geo.bq))
+    lo, hi = _prefill_block_bounds(i, geo.bq, geo.bk, window,
+                                   int(start) - off, int(plen),
+                                   max(0, -off), xp=np)
+    return int(np.sum(np.maximum(hi - lo + 1, 0)))
+
+
+def _make_prefill_kernel(group: int, bq: int, bk: int,
+                         key_steps: int, d_head: int,
+                         window: Optional[int], sink: bool, offset: bool):
+    """ONE query block: grid ``(B, KV, key_steps)``, the key axis innermost.
+    Blocks: ``q (1, 1, G, bq, D)`` (row ``g * bq + s`` of the score block
+    is query head ``g`` of the group at the block's position ``s``), ``k
+    (1, 1, bk, D)``, ``v (1, 1, bk, Dv)``, out ``(1, 1, G, bq, Dv)``.
+    ``scal`` (SMEM, prefetched): the key row of the block's first query,
+    how many of its queries are real, the first row that is a key.  Step
+    ``j`` takes key block ``first + j`` while that is at most ``last``
+    (:func:`_prefill_block_bounds`; the index map holds the block there
+    afterwards, so nothing more is fetched) and does nothing else; a block
+    with no real query takes none."""
+    neg = float(np.finfo(np.float32).min)
+    rows = group * bq
+    scale = 1.0 / np.sqrt(d_head)
+
+    def kernel(scal, q_ref, k_ref, v_ref, *refs):
+        sink_ref = refs[0] if sink else None
+        o_ref, acc_ref, m_ref, l_ref = refs[1:] if sink else refs
+        j = pl.program_id(2)
+        q_lo, plen, kmin = scal[0], scal[1], scal[2]
+        first, last = _prefill_block_bounds(0, bq, bk, window, q_lo, plen,
+                                            kmin)
+        kb = first + j
+
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            if sink:                    # the sink's own term: exp(0)
+                m_ref[...] = sink_ref[0]
+                l_ref[...] = jnp.ones_like(l_ref)
+            else:
+                m_ref[...] = jnp.full_like(m_ref, neg)
+                l_ref[...] = jnp.zeros_like(l_ref)
+
+        def update(masked: bool):
+            q = q_ref[0, 0].reshape(rows, q_ref.shape[-1])
+            logits = lax.dot_general(
+                q, k_ref[0, 0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # (rows, bk)
+            if masked:
+                r = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+                qrow = q_lo + lax.rem(r, bq)
+                c = kb * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+                seen = c <= qrow
+                if window is not None:
+                    seen = jnp.logical_and(seen, c > qrow - window)
+                if offset:
+                    seen = jnp.logical_and(seen, c >= kmin)
+                # a row with no key in this block holds the lowest number
+                # throughout: its exp(0) terms are wiped (alpha = 0) by
+                # the first block that holds one, and every real query
+                # sees its own key
+                logits = jnp.where(seen, logits, neg)
+            m_prev = m_ref[:, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)
+            pv = lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[...] = acc_ref[...] * alpha + pv
+            l_ref[:, 0:1] = l_ref[:, 0:1] * alpha \
+                + jnp.sum(p, -1, keepdims=True)
+            m_ref[:, 0:1] = m_new
+
+        active = jnp.logical_and(plen > 0, kb <= last)
+        # a block needs its mask where a key lies past the first query's
+        # own, before the last query's window, or before the first key
+        edge = kb * bk + (bk - 1) > q_lo
+        if window is not None:
+            edge = jnp.logical_or(edge, kb * bk < q_lo + bq - window)
+        if offset:
+            edge = jnp.logical_or(edge, kb * bk < kmin)
+
+        @pl.when(jnp.logical_and(active, edge))
+        def _edge():
+            update(True)
+
+        @pl.when(jnp.logical_and(active, jnp.logical_not(edge)))
+        def _inner():
+            update(False)
+
+        @pl.when(j == key_steps - 1)
+        def _out():
+            out = acc_ref[...] / jnp.maximum(l_ref[:, 0:1], 1e-30)
+            r = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            # a row past the real queries is zeros, never what VMEM held
+            o_ref[0, 0] = jnp.where(lax.rem(r, bq) < plen, out, 0.0).astype(
+                o_ref.dtype).reshape(o_ref.shape[2:])
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnames=("bk", "d_head", "window",
+                                             "offset", "interpret"))
+def prefill_query_block(scal: jnp.ndarray,     # (3,) int32
+                        qt: jnp.ndarray,       # (B, KV, G, bq, D)
+                        kt: jnp.ndarray,       # (B, KV, T, D)
+                        vt: jnp.ndarray,       # (B, KV, T, Dv)
+                        sink_rows: Optional[jnp.ndarray] = None, *,
+                        bk: int, d_head: int, window: Optional[int] = None,
+                        offset: bool = False,
+                        interpret: bool = False) -> jnp.ndarray:
+    """The prefill kernel over ONE block of ``bq`` query positions, K/V-head
+    major, lanes padded: -> ``(B, KV, G, bq, Dv)``.  ``scal``: the key row
+    of the block's first query, how many of its queries are real, the first
+    row that is a key.  :func:`prefill_attention` maps it over a pass's
+    query blocks.  It is the jitted entry because its shapes do not follow
+    the bucket: every prefill program of an engine whose keys are ``T``
+    rows calls the same one, so a process traces the kernel once a layer
+    kind, not once a bucket (set-up: PERF.md section 6, PR 37)."""
+    B, KV, G, bq, d_pad = qt.shape
+    T, v_pad = kt.shape[2], vt.shape[-1]
+    rows = G * bq
+    nk = T // bk
+    key_steps = _prefill_key_steps(T, bq, bk, window)
+
+    def key_block(b, h, j, scal):
+        first, last = _prefill_block_bounds(0, bq, bk, window, scal[0],
+                                            scal[1], scal[2])
+        # past the last block it visits the walk holds that block: a block
+        # index that does not change is not fetched again
+        blk = jnp.minimum(first + j, jnp.maximum(last, first))
+        return b, h, jnp.minimum(blk, nk - 1), 0
+
+    in_specs = [
+        pl.BlockSpec((1, 1, G, bq, d_pad), lambda b, h, j, s: (b, h, 0, 0, 0)),
+        pl.BlockSpec((1, 1, bk, d_pad), key_block),
+        pl.BlockSpec((1, 1, bk, v_pad), key_block),
+    ]
+    operands = [qt, kt, vt]
+    if sink_rows is not None:
+        operands.append(sink_rows)
+        in_specs.append(pl.BlockSpec((1, rows, 128),
+                                     lambda b, h, j, s: (h, 0, 0)))
+    return pl.pallas_call(
+        _make_prefill_kernel(G, bq, bk, key_steps, d_head, window,
+                             sink_rows is not None, offset),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, KV, key_steps),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, G, bq, v_pad),
+                                   lambda b, h, j, s: (b, h, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((rows, v_pad), jnp.float32),    # accumulator
+                pltpu.VMEM((rows, 128), jnp.float32),      # running max
+                pltpu.VMEM((rows, 128), jnp.float32),      # normaliser
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, bq, v_pad), qt.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="prefill_attention",
+        interpret=interpret,
+    )(scal, *operands)
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "kv_heads",
+                                             "window", "interpret"))
+def prefill_attention(q: jnp.ndarray,          # (B, S, H, D)
+                      k: jnp.ndarray,          # (B, T, KV.., D)
+                      v: jnp.ndarray,          # (B, T, KV.., Dv)
+                      start: jnp.ndarray,      # () position of query 0
+                      plen: jnp.ndarray,       # () real queries
+                      *, bq: int, bk: int,
+                      kv_heads: Optional[int] = None,
+                      window: Optional[int] = None,
+                      sink: Optional[jnp.ndarray] = None,
+                      key_offset: Optional[jnp.ndarray] = None,
+                      interpret: bool = False) -> jnp.ndarray:
+    """Causal softmax attention of one prefill pass as one tiled kernel:
+    -> ``(B, S, H * Dv)`` in ``q.dtype``.
+
+    Query ``s`` sits at position ``start + s``; the first ``plen`` are real
+    (the rest a bucket's padding: their rows come out as zeros).  Row ``j``
+    of ``k``/``v`` is key position ``j`` (``key_offset + j`` where one is
+    given, a ring's ``[rows before this pass | this pass]``; a row at a
+    negative position is no key), already holding this pass's own rows.
+    Query at ``p`` sees key ``j`` iff ``j <= p`` and, behind a ``window``,
+    ``j > p - window``.  ``kv_heads``: the heads of a row that are real, the
+    first ones (a cache row may be padded past them).  ``sink (H,)``: a logit
+    a query head that every query sees beside its keys and that carries no
+    value: the online softmax's first term.
+
+    All ``H / KV`` query heads of a K/V head in one contraction (rows ``bq
+    x group``), keys in blocks of ``bk``; operands in their own dtype,
+    products accumulated in float32, scores and the online softmax in
+    float32 in VMEM and never in HBM, probabilities cast to ``q.dtype``
+    before the product with V as the plain path casts them.  The pass's
+    query blocks go through :func:`prefill_query_block` in turn; a block
+    visits only the key blocks that hold a key one of its real queries
+    sees: none past its causal edge, none before its window, none past
+    ``start + plen`` (not fetched either), and a query block wholly past
+    ``plen`` none at all: a bucket pays for its real tokens.  ``bq``/``bk``
+    come from :func:`prefill_geometry`.  A width that is no multiple of
+    128 lanes is padded to one here (the copy into K/V-head-major order
+    carries it)."""
+    B, S, H, D = q.shape
+    T, Dv = k.shape[1], v.shape[-1]
+    KV = int(kv_heads or k.shape[2])
+    assert H % KV == 0 and S % bq == 0 and T % bk == 0, (H, KV, S, bq, T, bk)
+    G = H // KV
+    # K/V-head-major: a block is then whole (rows, lanes) tiles of one head
+    qt = jnp.transpose(q.reshape(B, S, KV, G, D), (0, 2, 3, 1, 4))
+    kt = jnp.swapaxes(k[:, :, :KV], 1, 2)                  # (B, KV, T, D)
+    vt = jnp.swapaxes(v[:, :, :KV], 1, 2)
+    d_pad, v_pad = _pad(D, 128), _pad(Dv, 128)
+    if d_pad != D:
+        qt = jnp.pad(qt, ((0, 0),) * 4 + ((0, d_pad - D),))
+        kt = jnp.pad(kt, ((0, 0),) * 3 + ((0, d_pad - D),))
+    if v_pad != Dv:
+        vt = jnp.pad(vt, ((0, 0),) * 3 + ((0, v_pad - Dv),))
+    off = jnp.int32(0) if key_offset is None else \
+        jnp.asarray(key_offset, jnp.int32)
+    q_row0 = jnp.asarray(start, jnp.int32) - off
+    plen = jnp.asarray(plen, jnp.int32)
+    kmin = jnp.maximum(-off, 0)
+    sink_rows = None
+    if sink is not None:
+        # each score row's sink logit, on every lane
+        per_row = jnp.repeat(sink.astype(jnp.float32).reshape(KV, G), bq, 1)
+        sink_rows = jnp.broadcast_to(per_row[:, :, None],
+                                     (KV, G * bq, 128))
+
+    def block(i):
+        scal = jnp.stack([q_row0 + i * bq, jnp.clip(plen - i * bq, 0, bq),
+                          kmin])
+        return prefill_query_block(
+            scal, lax.dynamic_slice_in_dim(qt, i * bq, bq, axis=3), kt, vt,
+            sink_rows, bk=bk, d_head=D, window=window,
+            offset=key_offset is not None, interpret=interpret)
+
+    out = lax.map(block, jnp.arange(S // bq, dtype=jnp.int32))
+    # (S / bq, B, KV, G, bq, Dv) -> (B, S / bq, bq, KV, G, Dv)
+    out = jnp.transpose(out[..., :Dv], (1, 0, 4, 2, 3, 5))
+    return out.reshape(B, S, H * Dv)

@@ -170,6 +170,7 @@ from .experts import stats_totals
 from .model import MIXERS, RING_BLOCK, LlamaModel, init_cache, kv_pack
 from .pallas_attn import (PagedGeometry, dense_read_bytes, paged_geometry,
                           paged_live_tiles, paged_read_bytes,
+                          prefill_geometry, prefill_key_blocks,
                           resolve_attention_backend)
 from .pallas_gdn import resolve_recurrent_backend, slot_state_bytes
 
@@ -202,8 +203,11 @@ def _prefill_slot_jit(model: LlamaModel, variables: Any, cache: Any,
     Padding rows of K/V are junk that is overwritten before it is read;
     a recurrent layer takes ``valid_len=plen`` and leaves its state as
     token ``plen - 1`` made it (from zeros where ``start`` is 0).
-    ``attention_backend`` only says how such a layer runs: prefill
-    attention is dense whatever it is."""
+    ``attention_backend`` says how such a layer runs, and on ``paged`` or
+    ``interpret`` attention is one tiled causal kernel wherever
+    :func:`~synapseml_tpu.models.llm.pallas_attn.prefill_geometry` has a
+    tile for the bucket (``plen`` and ``start`` reach it as scalars: a
+    bucket pays for its real tokens); elsewhere the plain scores."""
     pb = tokens.shape[0]
     row = jax.tree.map(
         lambda c: lax.dynamic_slice_in_dim(c, slot, 1, axis=0), cache)
@@ -735,6 +739,17 @@ class SlotEngine:
             reserved.set(kc.layers * self.n_slots * kc.rows
                          * kc.held_row_bytes(itemsize),
                          engine=name, kind=kc.kind)
+        self._m_prefill_attn = reg.counter(
+            "llm_prefill_attention_total",
+            "prefill passes by the path their attention took (tiled: the "
+            "Pallas kernel on every attention layer kind; dense: the plain "
+            "scores on every kind; mixed: the kernel on the kinds whose "
+            "shape has a tile)", ("engine", "path"))
+        #: bucket -> what its prefill program's attention runs as
+        #: (:meth:`_prefill_plan`)
+        self._prefill_plans: Dict[int, Tuple[str, Tuple]] = {}
+        #: the last prefill pass (bucket, start, real tokens), for its span
+        self._last_prefill: Tuple[int, int, int] = (0, 0, 0)
         self._m_expert_pairs = reg.counter(
             "llm_expert_pairs_total",
             "(token, expert) pairs whose expert this program holds, computed "
@@ -1119,7 +1134,8 @@ class SlotEngine:
             res = self._admit_into(slot, prompt, max_new, str(tenant))
             if sp.live:
                 sp.set(bucket=res.bucket, prompt_tokens=len(prompt),
-                       reused_tokens=res.reused_tokens, path=res.path)
+                       reused_tokens=res.reused_tokens, path=res.path,
+                       **self._prefill_attention_attrs())
                 if self.experts:
                     sp.set(expert_pairs_held=self._step_experts[
                         "expert_pairs_held"])
@@ -1180,6 +1196,7 @@ class SlotEngine:
                     jnp.asarray(padded), len(tail), slot, lcp,
                     attention_backend=self.attention_backend)
             logits = self._count_experts(np.asarray(last, np.float32))
+            self._account_prefill(pb, lcp, len(tail))
         with step_span("engine.admit.commit"):
             tok = self._sample_host(logits)
             plen = len(prompt)
@@ -1217,6 +1234,55 @@ class SlotEngine:
                 path="restore" if restored else "reuse" if lcp
                 else {None: "cold", "recurrent_state": "cold_recurrent",
                       "ring_overwritten": "cold_ring"}[skipped])
+
+    def _prefill_plan(self, pb: int) -> Tuple[str, Tuple]:
+        """How the prefill program of bucket ``pb`` runs its attention:
+        ``(path, ((kind record, tile), ...))``, the tiles those the model
+        itself asks :func:`prefill_geometry` for (a ring's keys are its rows
+        before the pass and the pass).  ``path``: ``tiled`` where every
+        kind has a tile, ``dense`` where none (always, off the kernel
+        backends), else ``mixed``."""
+        plan = self._prefill_plans.get(pb)
+        if plan is None:
+            tiles = () if self.attention_backend == "dense" else tuple(
+                (kc, prefill_geometry(
+                    pb, kc.rows + (pb if kc.ring else 0), self.cfg.num_heads,
+                    kc.kv_heads, kc.d_key, kc.d_value, self.cfg.dtype,
+                    kc.window)) for kc in self._kinds)
+            tiled = [geo is not None for _, geo in tiles]
+            plan = ("tiled" if tiled and all(tiled) else
+                    "mixed" if any(tiled) else "dense", tiles)
+            self._prefill_plans[pb] = plan
+        return plan
+
+    def _account_prefill(self, pb: int, start: int, plen: int) -> None:
+        """Count one prefill pass by its attention's path and keep what its
+        span will say (:meth:`_prefill_attention_attrs`)."""
+        self._m_prefill_attn.inc(1, engine=self.name,
+                                 path=self._prefill_plan(pb)[0])
+        self._last_prefill = (pb, start, plen)
+
+    def _prefill_attention_attrs(self) -> Dict[str, Any]:
+        """``engine.admit``'s account of the last prefill pass: its
+        attention's path, the (query block, key block) pairs the kernel
+        computed a K/V head over the layers that took it, and what the whole
+        bucket would have been: host arithmetic from the pass's start, its
+        real tokens, the bucket and the geometry, as ``paged_tiles_live``
+        is.  Computed only for a span that is recorded."""
+        pb, start, plen = self._last_prefill
+        path, tiles = self._prefill_plan(pb)
+        visited = bucket = 0
+        for kc, geo in tiles:
+            if geo is None:
+                continue
+            off = start - kc.rows if kc.ring else None
+            visited += kc.layers * prefill_key_blocks(
+                geo, pb, start, plen, kc.window, off)
+            bucket += kc.layers * prefill_key_blocks(
+                geo, pb, start, pb, kc.window, off)
+        return {"prefill_attention": path,
+                "prefill_key_blocks_visited": visited,
+                "prefill_key_blocks_bucket": bucket}
 
     def _count_experts(self, out: np.ndarray) -> np.ndarray:
         """Split what a program of a model with expert layers returned:
@@ -1395,6 +1461,7 @@ class SlotEngine:
                     self.model, self.variables, self.cache,
                     jnp.asarray(padded), len(tail), slot, est,
                     attention_backend=self.attention_backend)
+            self._account_prefill(pb, est, len(tail))
         ln = len(ids)
         self.ctx[slot, :ln] = ids
         self.lengths[slot] = ln

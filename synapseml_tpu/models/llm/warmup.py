@@ -63,13 +63,19 @@ __all__ = ["CompilePlane", "ProgramSpec", "REGISTERED_ENTRY_POINTS",
 #: module-level jitted entry points the lattice accounts for, per module
 #: (the completeness sweep's contract).  ``paged_decode_attention`` is
 #: covered THROUGH the decode/verify programs — the kernel is invoked
-#: inside their traces, never as its own serving-path dispatch.
+#: inside their traces, never as its own serving-path dispatch — and
+#: ``prefill_attention`` (and the one query block it maps,
+#: ``prefill_query_block``) through the prefill programs of the buckets
+#: ``prefill_geometry`` has a tile for: they add no program and no key,
+#: and the kernel is traced once a layer kind for ALL of an engine's
+#: buckets (a query block's shapes do not follow the bucket).
 REGISTERED_ENTRY_POINTS = {
     "synapseml_tpu.models.llm.slots": frozenset({
         "_prefill_slot_jit", "_decode_step_jit", "_verify_step_jit",
         "_copy_prefix_jit", "_restore_span_jit"}),
     "synapseml_tpu.models.llm.pallas_attn": frozenset({
-        "paged_decode_attention"}),
+        "paged_decode_attention", "prefill_attention",
+        "prefill_query_block"}),
     # the recurrence of linear-attention layers: inside the decode and
     # prefill programs as the paged kernel is inside decode
     "synapseml_tpu.models.llm.pallas_gdn": frozenset({

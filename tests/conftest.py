@@ -194,3 +194,20 @@ def devices8():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected >=8 simulated devices, got {devs}"
     return devs[:8]
+
+
+@pytest.fixture
+def every_prefill_tiled(monkeypatch):
+    """Toy prefills through the Pallas prefill kernel, in several blocks: the
+    threshold under which ``prefill_geometry`` keeps the plain path is put
+    at zero and its tile at 16 score rows by 16 keys at most.  The prefill
+    program's jit cache keys on (model, backend) and not on those numbers,
+    so it is emptied on both sides of the test."""
+    from synapseml_tpu.models.llm import pallas_attn, slots
+    monkeypatch.setattr(pallas_attn, "_PREFILL_MIN_SCORE_BYTES", 0)
+    monkeypatch.setattr(pallas_attn, "_PREFILL_ROWS", 16)
+    monkeypatch.setattr(pallas_attn, "_PREFILL_KEYS", 16)
+    monkeypatch.setattr(pallas_attn, "_PREFILL_MIN_KEYS", 8)
+    slots._prefill_slot_jit.clear_cache()
+    yield
+    slots._prefill_slot_jit.clear_cache()

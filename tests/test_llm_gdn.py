@@ -769,6 +769,43 @@ def test_packed_paged_kernel_compiles_for_the_v5e_at_192_beside_128(
         rf"bf16\[{n},{flat},(384|256)\]\S* (copy|fusion)\(", text)
 
 
+@pytest.mark.parametrize("name,S,T,H,KV,D,Dv,window,kind", [
+    ("mistral-bucket-2048", 2048, 2048, 32, 8, 128, 128, None, ""),
+    ("olmo-bucket-1536-rows-of-32", 1536, 1536, 30, 30, 128, 128, None, ""),
+    ("command-a-window-tail-1024", 1024, 5632, 128, 8, 128, 128, 4096, ""),
+    ("mimo-full-16384", 16384, 16384, 64, 4, 192, 128, None, "sink"),
+    ("mimo-ring-16384", 16384, 16640, 64, 8, 192, 128, 128, "sink,offset"),
+])
+def test_prefill_kernel_compiles_for_the_v5e_at_the_published_shapes(
+        one_chip, name, S, T, H, KV, D, Dv, window, kind):
+    """The prefill attention kernel at the tile ``prefill_geometry`` picks
+    for each serving configuration's largest bucket: group 4, group 1 over a
+    cache row padded to 32 heads, 128 heads over 8 behind a window, and
+    MiMo's two kinds (keys 192 wide padded to 256 lanes, a sink, a ring's
+    rows before the pass).  No score array ever exists outside the kernel."""
+    from synapseml_tpu.models.llm.pallas_attn import (prefill_attention,
+                                                      prefill_geometry)
+    geo = prefill_geometry(S, T, H, KV, D, Dv, jnp.bfloat16, window)
+    assert geo is not None
+
+    def sd(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    rows = 32 if KV == 30 else KV
+    extra = {}
+    if "sink" in kind:
+        extra["sink"] = sd((H,), jnp.float32)
+    if "offset" in kind:
+        extra["key_offset"] = sd((), jnp.int32)
+    compiled = prefill_attention.lower(
+        sd((1, S, H, D)), sd((1, T, rows, D)), sd((1, T, rows, Dv)),
+        sd((), jnp.int32), sd((), jnp.int32), bq=geo.bq, bk=geo.bk,
+        kv_heads=KV, window=window, **extra).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "prefill_attention" in text
+    # no (heads, S, T) float32 scores, whole or a key block wide
+    assert not re.search(rf"f32\[[0-9,]*{S},({T}|{geo.bk})\]", text)
+
+
 @pytest.mark.parametrize("pairs,tm", [(192, 16), (8192, 256)],
                          ids=["decode", "prefill-chunk"])
 def test_expert_ffn_compiles_for_the_v5e_at_the_published_widths(

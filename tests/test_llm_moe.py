@@ -586,16 +586,22 @@ def test_the_geometry_at_128_query_heads_over_8():
     assert wide is not None and wide.tile == 128
 
 
+@pytest.mark.pallas
 @pytest.mark.parametrize("window", [None, 24], ids=["full", "window"])
 @pytest.mark.parametrize("start", [0, 40], ids=["cold", "tail"])
 def test_prefill_attention_in_blocks_matches_the_dense_scores(window, start):
+    """The prefill kernel at this configuration's group, in blocks of 16
+    queries by 32 keys (``tests/test_llm_prefill_kernel.py`` has the other
+    kinds' cases)."""
+    from synapseml_tpu.models.llm.pallas_attn import prefill_attention
     B, S, T, H, KV, D = 1, 64, 128, 8, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (B, S, H, D))
     k = jax.random.normal(ks[1], (B, T, KV, D))
     v = jax.random.normal(ks[2], (B, T, KV, D))
     pos = (start + jnp.arange(S))[None]
-    got = M.blocked_attention(q, k, v, pos, window, jnp.float32)
+    got = prefill_attention(q, k, v, start, S, bq=16, bk=32, window=window,
+                            interpret=True)
     qg = q.reshape(B, S, KV, H // KV, D)
     s = jnp.einsum("bskgd,btkd->bkgst", qg, k) / np.sqrt(D)
     see = jnp.arange(T)[None, None, :] <= pos[:, :, None]
@@ -606,22 +612,31 @@ def test_prefill_attention_in_blocks_matches_the_dense_scores(window, start):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 
 
-def test_the_model_takes_the_blocks_where_the_scores_would_not_fit(
-        small, ref, monkeypatch):
-    """The same logits with the threshold at zero: every prefill in blocks."""
+@pytest.mark.pallas
+def test_the_model_takes_the_kernel_where_the_geometry_has_a_tile(
+        small, ref, every_prefill_tiled):
+    """The same logits with the threshold at zero and the kernels' backend:
+    every prefill pass, cold and behind a prefix, through the kernel; the
+    pass without a cache (training) stays on the plain scores."""
     cfg, model, variables = small
     ids = _prompt(48, 6)
     want = _ref_logits(ref, ids, np.arange(48))
-    monkeypatch.setattr(M, "_DENSE_SCORE_BYTES", 0)
     cache = jax.tree.map(lambda c: c[:1], init_cache(cfg, 1, MAX_LEN))
     lg, cache = model.apply(variables, jnp.asarray(ids[:32])[None],
                             positions=jnp.arange(32)[None], cache=cache,
-                            cache_index=0, valid_len=32)
+                            cache_index=0, valid_len=32,
+                            attention_backend="interpret")
     np.testing.assert_allclose(np.asarray(lg)[0], want[:32], atol=LOGIT_TOL)
     lg, _ = model.apply(variables, jnp.asarray(ids[32:])[None],
                         positions=(32 + jnp.arange(16))[None], cache=cache,
-                        cache_index=32, valid_len=16)
+                        cache_index=32, valid_len=16,
+                        attention_backend="interpret")
     np.testing.assert_allclose(np.asarray(lg)[0], want[32:], atol=LOGIT_TOL)
+    text = str(jax.make_jaxpr(lambda t: model.apply(
+        variables, t, positions=jnp.arange(32)[None], cache=cache,
+        cache_index=0, attention_backend="interpret"))(
+            jnp.asarray(ids[:32])[None]))
+    assert "prefill_attention" in text
     got = np.asarray(model.apply(variables, jnp.asarray(ids)[None]))[0]
     np.testing.assert_allclose(got, want, atol=LOGIT_TOL)
 

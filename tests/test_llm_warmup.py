@@ -130,6 +130,37 @@ class TestLatticeCompleteness:
             "verify_interpret_s2", "verify_interpret_s4"}
 
 
+    def test_the_prefill_kernel_adds_no_program_to_the_lattice(
+            self, tiny_model, every_prefill_tiled, monkeypatch):
+        """The lattice's program keys are the parent's: with every bucket's
+        prefill through the kernel the keys are those of the plain path;
+        and a query block's shapes do not follow the bucket, so two
+        buckets' programs, every layer of both, trace the kernel once."""
+        cfg, model, variables = tiny_model
+        eng = SlotEngine(model, variables, n_slots=2, max_len=64,
+                         attention_backend="interpret")
+        assert eng._prefill_plan(32)[0] == "tiled"
+        assert {s.key for s in program_lattice(eng)} == {
+            "decode_interpret", "prefix_copy",
+            "prefill_b8", "prefill_b16", "prefill_b32", "prefill_b64"}
+        from synapseml_tpu.models.llm import pallas_attn
+        from synapseml_tpu.models.llm.slots import _prefill_slot_jit
+        built = []
+        make = pallas_attn._make_prefill_kernel
+        monkeypatch.setattr(
+            pallas_attn, "_make_prefill_kernel",
+            lambda *a, **kw: built.append(a) or make(*a, **kw))
+        pallas_attn.prefill_query_block.clear_cache()
+        for pb in (32, 64):
+            text = str(jax.make_jaxpr(
+                lambda t: _prefill_slot_jit.__wrapped__(
+                    model, variables, eng.cache, t, 20, 0, 0,
+                    attention_backend="interpret"))(
+                        jnp.zeros((pb,), jnp.int32)))
+            assert text.count("name=prefill_attention") >= cfg.num_layers
+        assert len(built) == 1
+
+
 class TestZeroInLoopCompiles:
     def test_warmed_engine_serves_trace_with_zero_compiles(self,
                                                            tiny_model):

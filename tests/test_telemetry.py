@@ -407,7 +407,10 @@ class TestProgramSpans:
             "engine.admit.lookup", "engine.admit.prefill",
             "engine.admit.commit"]
         assert admit.attrs == {"bucket": res.bucket, "prompt_tokens": 7,
-                               "reused_tokens": 0, "path": "cold"}
+                               "reused_tokens": 0, "path": "cold",
+                               "prefill_attention": "dense",
+                               "prefill_key_blocks_visited": 0,
+                               "prefill_key_blocks_bucket": 0}
         first, step = tr.spans("engine.step")
         for s in (first, step):
             # the prepare is the next step's, the wait and commit this one's
