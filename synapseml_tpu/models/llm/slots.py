@@ -585,11 +585,12 @@ class SlotEngine:
             else self._paged_geo.tile if self._one_geometry \
             else tuple((kc.kind, kc.geo.tile) for kc in self._kinds)
         #: optional request-trace hook ``sink(slot, event, **attrs)`` —
-        #: the serving loop installs one mapping slots to trace ids, and
-        #: the engine reports per-slot step outcomes through it
-        #: (``decode`` with tokens=1, ``verify`` with drafted/accepted/
-        #: committed span sizes).  None costs one attribute check per
-        #: slot per step.
+        #: the serving loop installs one mapping slots to trace ids.  The
+        #: engine reports a slot's TRANSITIONS through it and nothing a
+        #: token: ``decode`` at the first step that gives the slot's
+        #: occupant a token (``tokens``: how many), and ``retired`` when
+        #: the occupant leaves the slot, with its totals (:meth:`_retire`).
+        #: None costs one attribute check a step.
         self.trace_sink = trace_sink
         #: key of the program that ran the step last returned (the
         #: ``engine.step`` span's ``program``)
@@ -639,6 +640,17 @@ class SlotEngine:
         self._retired_at = np.full(n, -np.inf)             # reclaim recency
         self._max_new = np.zeros(n, np.int64)
         self._generated = np.zeros(n, np.int64)
+        #: a slot's occupant since its admission or resume: steps that gave
+        #: it a token, the verify steps among them, positions drafted for
+        #: it and accepted (``trace_sink``'s ``retired`` hands them over)
+        self._slot_totals = np.zeros((n, 4), np.int64)
+        #: seconds the host has spent in a step's three parts since the
+        #: engine was built, kept beside the ``engine.step.*`` spans and
+        #: always (four clock reads a step): ``prepare`` is host arrays,
+        #: uploads and the dispatch's enqueue, ``wait`` the blocking read
+        #: of a step's tokens (the host's slack under the device's step
+        #: where steps overlap), ``commit`` the slot loop and retirement
+        self.phase_seconds = {"prepare": 0.0, "wait": 0.0, "commit": 0.0}
         #: the step dispatched and not yet read, and what tells its
         #: output apart from a slot's later occupant: a count of the
         #: slot's admissions and resumes
@@ -686,9 +698,6 @@ class SlotEngine:
         self._m_evict = reg.counter(
             "llm_evictions_total", "sequences retired from a slot",
             ("engine", "reason", "tenant"))
-        self._m_tokens = reg.counter(
-            "llm_engine_tokens_total", "tokens generated by the engine",
-            ("engine",))
         self._m_reuse = reg.counter(
             "llm_prefix_reuse_total", "admissions served a reused prefix",
             ("engine",))
@@ -1208,6 +1217,7 @@ class SlotEngine:
             self._epoch[slot] += 1
             self._max_new[slot] = max_new
             self._generated[slot] = 1
+            self._slot_totals[slot] = 0
             self._register_prefix(slot, prompt)
             if self._drafter is not None:
                 # (re)build the slot's n-gram tables from prompt + first
@@ -1220,7 +1230,6 @@ class SlotEngine:
             self.admissions += 1
             self._m_admit.inc(1, engine=self.name, tenant=tenant)
             self.tokens_generated += 1
-            self._m_tokens.inc(1, engine=self.name)
             finished, reason = self._finish_reason(slot, tok)
             if finished:
                 self._retire(slot, reason)
@@ -1307,11 +1316,27 @@ class SlotEngine:
         return False, None
 
     def _retire(self, slot: int, reason: str) -> None:
+        """The occupant leaves ``slot`` (``reason``: ``eos``, ``length``,
+        ``cancelled``, ``preempted``, ``reset``).  ``trace_sink`` gets
+        ``retired`` with what its stay added up to: ``tokens`` generated
+        (a resumed occupant's count from its first admission on),
+        ``steps`` that gave it a token since this admission or resume,
+        and under a drafter the ``verify_steps`` among them and the
+        positions ``drafted`` for it and ``accepted``."""
         self.active[slot] = False
         self._retired_at[slot] = time.monotonic()
         self.evictions += 1
         self._m_evict.inc(1, engine=self.name, reason=reason,
                           tenant=self._slot_tenant[slot])
+        if self.trace_sink is not None:
+            steps, verify, drafted, accepted = (
+                int(v) for v in self._slot_totals[slot])
+            spec = {} if self._drafter is None else {
+                "verify_steps": verify, "drafted": drafted,
+                "accepted": accepted}
+            self.trace_sink(slot, "retired", reason=reason,
+                            tokens=int(self._generated[slot]), steps=steps,
+                            **spec)
         span = int(self.kv_len[slot])
         if reason != "reset" and span >= self.min_prefix:
             # re-index the slot under its FULL retired context (prompt
@@ -1470,6 +1495,7 @@ class SlotEngine:
         self._epoch[slot] += 1
         self._max_new[slot] = int(ticket["max_new"])
         self._generated[slot] = int(ticket["generated"])
+        self._slot_totals[slot] = 0
         self._register_prefix(slot, ids[:span])
         if self._drafter is not None:
             self._spec_k[slot] = self._spec_k0
@@ -1635,7 +1661,6 @@ class SlotEngine:
         tps = len(events) / max(1, slots)
         self._tps_ewma = (tps if self._tps_ewma is None
                           else 0.8 * self._tps_ewma + 0.2 * tps)
-        self._m_tokens.inc(len(events), engine=self.name)
         self._m_occ.set(self.active_count / self.n_slots, engine=self.name)
         return events
 
@@ -1687,12 +1712,15 @@ class SlotEngine:
         step's tokens), then read its tokens and make them the engine's
         state."""
         overlapped = self._flight is not None   # only a step ahead waits there
+        t0 = time.perf_counter()
         flight = self._flight or self._dispatch(None)
         self._flight = (self._dispatch(flight) if self._drafter is None
                         else None)
+        t1 = time.perf_counter()
         with step_span("engine.step.wait"):
             # the step's one blocking call
             nxt = self._count_experts(np.asarray(flight.nxt))
+        t2 = time.perf_counter()
         with step_span("engine.step.commit"):
             self.last_program = flight.program
             if overlapped:
@@ -1700,6 +1728,7 @@ class SlotEngine:
                 self._m_overlap.inc(1, engine=self.name)
             live = self._live(flight)
             self._account_decode_bytes(flight.lengths, int(live.sum()))
+            self._count_step(live.astype(np.int64))
             events: List[StepEvent] = []
             for slot in np.flatnonzero(live):
                 slot = int(slot)
@@ -1712,15 +1741,32 @@ class SlotEngine:
                 self.tokens_generated += 1
                 if self._drafter is not None:
                     self._drafter.extend(slot, self.ctx[slot], ln, ln + 1)
-                if self.trace_sink is not None:
-                    self.trace_sink(slot, "decode", tokens=1)
                 finished, reason = self._finish_reason(slot, tok)
                 events.append(StepEvent(slot, tok, finished, reason))
             events = self._finish_step(events)
             ahead = self._flight
             if ahead is not None and not self._live(ahead).any():
                 self._flight = None   # an EOS emptied it: nothing waits on it
-            return events
+        self._count_phases(t0, t1, t2)
+        return events
+
+    def _count_step(self, tokens: np.ndarray) -> None:
+        """One step in the totals of the slots it gave a token (``tokens``:
+        how many, by slot); the first of an occupant's is its ``decode``
+        transition."""
+        live = tokens > 0
+        if self.trace_sink is not None:
+            for slot in np.flatnonzero(live & (self._slot_totals[:, 0] == 0)):
+                self.trace_sink(int(slot), "decode", tokens=int(tokens[slot]))
+        self._slot_totals[live, 0] += 1
+
+    def _count_phases(self, t0: float, t1: float, t2: float) -> None:
+        """A step's three parts into :attr:`phase_seconds`: prepare from
+        ``t0``, the wait from ``t1``, the commit from ``t2`` to now."""
+        sums = self.phase_seconds
+        sums["prepare"] += t1 - t0
+        sums["wait"] += t2 - t1
+        sums["commit"] += time.perf_counter() - t2
 
     # -- speculative decoding ----------------------------------------------
     def _spec_headroom(self) -> int:
@@ -1778,6 +1824,7 @@ class SlotEngine:
         exact-greedy prefix, commit accepted + 1 tokens through the
         slot_mask-gated scatter (already landed — only COMMITTED
         positions become attendable via ``lengths``/``kv_len``)."""
+        t0 = time.perf_counter()
         with step_span("engine.step.prepare"):
             idx = np.arange(self.n_slots)
             S = self._spec_bucket(max(len(d) for d in drafts.values()),
@@ -1802,14 +1849,18 @@ class SlotEngine:
                     step_span("engine.step.prepare.dispatch"):
                 self.cache, g = _verify_step_jit(
                     self.model, self.variables, self.cache, *step_in, **kw)
+        t1 = time.perf_counter()
         with step_span("engine.step.wait"):
             g = np.asarray(g)         # the step's one blocking call
             if self.experts:
                 self._count_experts(g[-1, :2])
                 g = g[:-1]
+        t2 = time.perf_counter()
         with step_span("engine.step.commit"):
-            return self._finish_step(
+            events = self._finish_step(
                 self._commit_verified(tokens, g, klen, lengths, S))
+        self._count_phases(t0, t1, t2)
+        return events
 
     def _commit_verified(self, tokens: np.ndarray, g: np.ndarray,
                          klen: np.ndarray, lengths: np.ndarray,
@@ -1819,6 +1870,7 @@ class SlotEngine:
         self.spec_steps += 1
         events: List[StepEvent] = []
         served = 0
+        committed = np.zeros(self.n_slots, np.int64)
         for slot in np.flatnonzero(self.active):
             slot = int(slot)
             ln = int(self.lengths[slot])
@@ -1848,6 +1900,8 @@ class SlotEngine:
             self._generated[slot] += c
             self.tokens_generated += c
             served += c
+            committed[slot] = c
+            self._slot_totals[slot, 1:] += (1, k_s, min(a, k_s))
             if k_s:
                 self.spec_drafted += k_s
                 self.spec_accepted += min(a, k_s)
@@ -1856,15 +1910,13 @@ class SlotEngine:
                     self._adapt_slot(slot, min(a, k_s) / k_s)
             if self._drafter is not None:
                 self._drafter.extend(slot, self.ctx[slot], ln, ln + c)
-            if self.trace_sink is not None:
-                self.trace_sink(slot, "verify", tokens=c, drafted=k_s,
-                                accepted=min(a, k_s) if k_s else 0)
             finished, reason = self._finish_reason(slot, int(commit[-1]))
             for j, tok in enumerate(commit):
                 last = j == c - 1
                 events.append(StepEvent(slot, int(tok),
                                         finished and last,
                                         reason if last else None))
+        self._count_step(committed)
         self._account_decode_bytes(lengths + (S - 1), max(1, served), S)
         return events
 

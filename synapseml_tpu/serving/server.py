@@ -49,9 +49,10 @@ import numpy as np
 from ..core.dataset import Dataset
 from ..core.pipeline import Transformer
 from ..resilience.health import HealthState, retry_after_from_depth
-from ..telemetry import (PROMETHEUS_CONTENT_TYPE, SERVING_TOKEN_LATENCY_BUCKETS,
-                         SERVING_TTFT_BUCKETS, check_sloz, get_registry,
-                         get_request_tracer, get_slo_store, render_json,
+from ..telemetry import (DEFAULT_BUCKETS, PROMETHEUS_CONTENT_TYPE,
+                         SERVING_TOKEN_LATENCY_BUCKETS, SERVING_TTFT_BUCKETS,
+                         check_sloz, get_registry, get_request_tracer,
+                         get_slo_store, get_tracer, render_json,
                          render_prometheus, step_span)
 from ..telemetry.flight import record as _flight_record
 
@@ -1272,12 +1273,182 @@ class _DecodeSeq:
     #: no pool armed, or the handoff has not run yet — it runs at most
     #: once per request; see serving.disagg.HANDOFF_OUTCOMES)
     handoff_outcome: Optional[str] = None
+    # -- the request's account (:class:`_LoopAccount`): snapshots of the
+    # loop's running totals, and what they have added up to so far
+    #: when the pump put it on the waiting list
+    waiting_at: float = 0.0
+    #: the loop's ``(hold_s, holds)`` at the last snapshot: on the waiting
+    #: list, then at the token its current run of steps follows
+    mark: Tuple[float, int] = (0.0, 0)
+    #: the token its current run of steps follows: the first, or the last
+    #: before a preemption
+    run_at: Optional[float] = None
+    #: index of the run's second step, once a step has given it a token
+    run_from: Optional[int] = None
+    steps: int = 0
+    stalled_s: float = 0.0
+    admissions_during: int = 0
+    #: its widest gap between two tokens: seconds, what the gap held,
+    #: how many admissions or resumes, the largest prefill bucket
+    gap: Tuple[float, str, int, int] = (0.0, "step", 0, 0)
+    #: what the engine said when the request left a slot
+    #: (``trace_sink``'s ``retired``), summed over its stays
+    totals: Dict[str, int] = field(default_factory=dict)
 
     @property
     def remaining(self) -> int:
         """Tokens left in this sequence's budget (the preemption
         victim tie-break: longest-remaining is cheapest to set aside)."""
         return max(0, int(self.max_new) - len(self.tokens))
+
+
+#: steps, and admissions, the loop's account remembers: a retiring request
+#: looks its widest gap up among them (one that lived through more steps
+#: is judged by its newest)
+_ACCOUNT_RING = 16384
+#: what a tick held beside its step; a tick that held several names the last
+_GAP_CAUSES = ("step", "compile_wait", "resume", "admit")
+
+
+class _LoopAccount:
+    """Where the decode loop's time goes: kept once a tick, published once
+    a request and once a second, nothing a token.  One clock,
+    ``time.monotonic()``; one thread, the loop's.
+
+    **Laps.**  The loop calls :meth:`lap` at every boundary between two
+    phases of a tick, and the time since the boundary before goes to the
+    phase that ends there: the phases of a second add up to the second,
+    whatever ran in between.  :meth:`take` hands the sums over for the
+    ``loop.account`` span and starts the next.
+
+    **Running totals.**  ``hold_s`` and ``holds``: seconds the loop has
+    spent inside ``engine.admit`` and ``engine.resume``, and how many
+    such calls gave a slot; ``steps``: decode steps.  A request
+    snapshots them on the waiting list, at its first token and when it
+    retires; the differences are its account (``behind_prefill_s``,
+    ``stalled_s``).
+
+    **A ring of the newest steps.**  Each step's period (the time
+    between two steps' returns: what a decoding request sees between
+    two tokens), what the tick held and how many holds, and each hold's
+    prefill bucket: where a retiring request finds its widest gap."""
+
+    PHASES = ("pump_s", "idle_s", "admit_s", "expire_s", "step_s", "emit_s")
+    COUNTS = ("ticks", "steps", "admissions", "tokens", "prompt_tokens")
+
+    def __init__(self):
+        self.lap_at = self.span_at = time.monotonic()
+        self.sums = dict.fromkeys(self.PHASES, 0.0)
+        self.counts = dict.fromkeys(self.COUNTS, 0)
+        #: the engine's ``phase_seconds`` when the last span was taken
+        self._engine_at: Dict[str, float] = {}
+        self.hold_s, self.holds, self.steps = 0.0, 0, 0
+        #: ``hold_s`` and ``holds`` when the last step returned
+        self.step_hold_s, self.step_holds = 0.0, 0
+        n = _ACCOUNT_RING
+        self._period = np.zeros(n)
+        self._cause = np.zeros(n, np.int8)
+        self._step_holds = np.zeros(n, np.int64)    # holds at its return
+        self._tick_holds = np.zeros(n, np.int64)    # holds inside its tick
+        self._hold_bucket = np.zeros(n, np.int64)
+        self._hold_cause = np.zeros(n, np.int8)
+        self._tick = [0, 0]                 # the tick's cause, its holds
+
+    def lap(self, phase: str) -> float:
+        now = time.monotonic()
+        self.sums[phase] += now - self.lap_at
+        self.lap_at = now
+        return now
+
+    def begin_tick(self) -> None:
+        self.counts["ticks"] += 1
+        self._tick = [0, 0]
+
+    def held_for_compile(self) -> None:
+        """A request stayed on the waiting list for a program that is
+        still compiling beside the loop."""
+        self._tick[0] = max(self._tick[0], _GAP_CAUSES.index("compile_wait"))
+
+    def hold(self, seconds: float, cause: str, bucket: int = 0) -> None:
+        """One ``engine.admit`` or ``engine.resume`` that gave a slot."""
+        i = self.holds % _ACCOUNT_RING
+        self._hold_bucket[i] = bucket
+        self._hold_cause[i] = code = _GAP_CAUSES.index(cause)
+        self.hold_s += seconds
+        self.holds += 1
+        self._tick[0] = max(self._tick[0], code)
+        self._tick[1] += 1
+
+    def step(self, period: float) -> None:
+        """The tick's step returned, ``period`` after the one before."""
+        i = self.steps % _ACCOUNT_RING
+        self._period[i] = period
+        self._cause[i], self._tick_holds[i] = self._tick
+        self._step_holds[i] = self.holds
+        self.steps += 1
+        self.counts["steps"] += 1
+        self.step_hold_s, self.step_holds = self.hold_s, self.holds
+
+    @staticmethod
+    def _places(first: int, last: int) -> np.ndarray:
+        """Ring places of the entries ``first`` up to ``last``, the newest
+        ``_ACCOUNT_RING`` of them at most."""
+        return np.arange(max(first, last - _ACCOUNT_RING),
+                         last) % _ACCOUNT_RING
+
+    def _held(self, first: int, last: int) -> Tuple[str, int, int]:
+        """(cause, holds, largest bucket) of the holds ``first`` up to
+        ``last``; ``step`` where there is none."""
+        idx = self._places(first, last)
+        if not len(idx):
+            return "step", 0, 0
+        return (_GAP_CAUSES[int(self._hold_cause[idx].max())], len(idx),
+                int(self._hold_bucket[idx].max()))
+
+    def held_since(self, holds: int) -> Tuple[str, int, int]:
+        """What the time from a snapshot at ``holds`` to the last step's
+        return held: (cause, holds, largest bucket)."""
+        if self.step_holds > holds:
+            return self._held(holds, self.step_holds)
+        # none: the last tick's own cause, unless that was a hold from
+        # before the snapshot (the request's own admission)
+        last = _GAP_CAUSES[int(self._cause[(self.steps - 1) % _ACCOUNT_RING])]
+        return last if last == "compile_wait" else "step", 0, 0
+
+    def widest(self, first: int, last: int
+               ) -> Optional[Tuple[float, str, int, int]]:
+        """The widest period among steps ``first`` up to ``last``:
+        (seconds, cause, holds in its tick, their largest bucket)."""
+        idx = self._places(first, last)
+        if not len(idx):
+            return None
+        j = int(idx[int(np.argmax(self._period[idx]))])
+        at, n = int(self._step_holds[j]), int(self._tick_holds[j])
+        cause, _, bucket = self._held(at - n, at)
+        if not n:
+            cause = _GAP_CAUSES[int(self._cause[j])]
+        return float(self._period[j]), cause, n, bucket
+
+    def take(self, engine_phases: Optional[Dict[str, float]]
+             ) -> Tuple[float, float, Dict[str, Any]]:
+        """(start, end, attributes) of the ``loop.account`` span that
+        ends at the last lap; the next one starts there.  The time
+        inside ``engine.step`` is split by the engine's own sums
+        (``SlotEngine.phase_seconds``); what they leave is
+        ``step_other_s``, all of it for an engine that keeps none."""
+        start, end, self.span_at = self.span_at, self.lap_at, self.lap_at
+        sums, self.sums = self.sums, dict.fromkeys(self.PHASES, 0.0)
+        attrs: Dict[str, Any] = self.counts
+        self.counts = dict.fromkeys(self.COUNTS, 0)
+        other = sums.pop("step_s")
+        for part in ("prepare", "wait", "commit"):
+            total = (engine_phases or {}).get(part, 0.0)
+            sums[f"step_{part}_s"] = total - self._engine_at.get(part, 0.0)
+            self._engine_at[part] = total
+            other -= sums[f"step_{part}_s"]
+        sums["step_other_s"] = other
+        attrs.update(sums)
+        return start, end, attrs
 
 
 class _DecodeLoop:
@@ -1312,7 +1483,10 @@ class _DecodeLoop:
     accepted-tokens-per-step EWMA, folded into the SLO projection —
     optional ``trace_sink``: when present and unset the loop
     installs its request-trace hook so the engine's per-slot
-    decode/verify outcomes land on the request timelines — and the
+    transitions (its first ``decode`` step) land on the request
+    timelines and its totals at retirement on the ``retired`` event;
+    optional ``phase_seconds``: the engine's own sums of a step's
+    prepare/wait/commit, which split the loop account's step time — and the
     optional compile plane: ``admission_ready(prompt_len)`` holds a
     request whose program is still compiling in queue instead of
     admitting it into a stall, and ``compile_plane`` exempts the
@@ -1324,10 +1498,16 @@ class _DecodeLoop:
 
     **Observability**: every request gets a ``trace_id`` at admission
     into the plane (or adopts the propagated ``X-SML-Trace-Id``) and a
-    sampled per-request timeline — queued → shed/admitted →
-    prefill(bucket) → decode/verify steps → retired/cancelled/expired
+    sampled per-request timeline of its transitions — queued →
+    shed/admitted → prefill(bucket) → first decode step →
+    retired/cancelled/expired, the terminal event carrying the totals
     — in the process :class:`~synapseml_tpu.telemetry.tracing.
-    RequestTraceStore` (served at ``GET /tracez``); TTFT, per-token
+    RequestTraceStore` (served at ``GET /tracez``); the request's
+    ``serving.request`` span carries its ACCOUNT (where its time went:
+    the two waits, its prefill, what it lost to other requests'
+    prefills while decoding, its widest gap between two tokens), and a
+    ``loop.account`` span once a second says where the loop's time went
+    (:class:`_LoopAccount`; ``docs/api/telemetry.md``); TTFT, per-token
     latency, occupancy, and admission/shed/retirement counts
     additionally feed the windowed SLO plane
     (:mod:`synapseml_tpu.telemetry.slo`, served at ``GET /sloz``) with
@@ -1402,6 +1582,9 @@ class _DecodeLoop:
         self._step_ewma: Optional[float] = None
         #: when the last step returned, while the engine has stayed busy
         self._stepped_at: Optional[float] = None
+        self._acct = _LoopAccount()
+        #: sequences in a slot that no step has given a token yet
+        self._fresh: List[_DecodeSeq] = []
         self._retired_window: List[float] = []
         # request-scoped tracing: the process store by default (so the
         # listener's /tracez sees this loop's requests); the sampling
@@ -1409,8 +1592,8 @@ class _DecodeLoop:
         self._tracer = request_tracer or get_request_tracer()
         if trace_sample_every is not None:
             self._tracer.sample_every = max(0, int(trace_sample_every))
-        # the engine reports per-slot step outcomes (decode/verify with
-        # span sizes) through its optional trace_sink hook; only claim
+        # the engine reports a slot's transitions and its totals at
+        # retirement through its optional trace_sink hook; only claim
         # an unset one — a caller-installed sink wins
         if getattr(engine, "trace_sink", "absent") is None:
             engine.trace_sink = self._engine_trace
@@ -1457,7 +1640,27 @@ class _DecodeLoop:
             ("api",), buckets=SERVING_TOKEN_LATENCY_BUCKETS)
         self._m_tokens = reg.counter(
             "llm_tokens_total", "tokens streamed/replied by the decode "
-            "loop", ("api",))
+            "loop (incremented once a second)", ("api",))
+        self._m_loop_s = reg.counter(
+            "llm_loop_seconds_total",
+            "the decode loop's wall time by phase, incremented once a "
+            "second: pump, idle (blocked with nothing to do), admit, "
+            "expire, step_prepare, step_wait (blocked on the device's "
+            "step: the host's slack), step_commit, step_other, emit",
+            ("api", "phase"))
+        self._m_stalled = reg.counter(
+            "llm_request_stalled_seconds_total",
+            "of llm_request_decode_seconds_total, the loop's wall time "
+            "inside OTHER requests' admissions and resumes between a "
+            "request's tokens, added at its retirement", ("api",))
+        self._m_decode = reg.counter(
+            "llm_request_decode_seconds_total",
+            "first token to last token, added at a request's retirement",
+            ("api",))
+        self._m_gap = reg.histogram(
+            "llm_request_token_gap_max_seconds",
+            "a request's widest gap between two tokens, observed at its "
+            "retirement", ("api",), buckets=DEFAULT_BUCKETS)
         self._m_sheds = reg.counter(
             "llm_sheds_total", "requests shed by the decode loop",
             ("api", "reason", "tenant"))
@@ -1483,10 +1686,19 @@ class _DecodeLoop:
     # -- request-scoped tracing -------------------------------------------
     def _engine_trace(self, slot: int, name: str, **attrs) -> None:
         """The engine's ``trace_sink``: map the slot back to its
-        sequence and append the step event to the request timeline
-        (cancelled-under-us slots and unsampled requests no-op)."""
+        sequence and append the transition to the request timeline
+        (cancelled-under-us slots and unsampled requests no-op).  The
+        engine's ``retired`` is no event here: its totals are kept for
+        the terminal event the loop writes, summed over the request's
+        stays in a slot."""
         seq = self._by_slot.get(slot)
-        if seq is not None and seq.trace_id is not None:
+        if seq is None:
+            return
+        if name == "retired":
+            for key in ("steps", "verify_steps", "drafted", "accepted"):
+                if key in attrs:
+                    seq.totals[key] = seq.totals.get(key, 0) + attrs[key]
+        elif seq.trace_id is not None:
             self._tracer.event(seq.trace_id, name, slot=slot, **attrs)
 
     @staticmethod
@@ -1521,7 +1733,10 @@ class _DecodeLoop:
         if self.engine.active_count or self._waiting:
             batch = self.api.poll(room)
         else:
+            self._acct.lap("pump_s")
             batch = self.api.get_batch(room, self.idle_timeout_s)
+            self._acct.lap("idle_s")
+        waiting_at = time.monotonic() if batch else 0.0
         for req in batch:
             try:
                 spec = self.input_parser(req)
@@ -1568,7 +1783,9 @@ class _DecodeLoop:
                 continue
             seq = _DecodeSeq(req, ids, max_new,
                              bool(spec.get("stream", False)),
-                             tenant=tenant, priority=prio)
+                             tenant=tenant, priority=prio,
+                             waiting_at=waiting_at,
+                             mark=(self._acct.hold_s, self._acct.holds))
             if session is not None:
                 seq.session = str(session)
             if resume:
@@ -1820,15 +2037,21 @@ class _DecodeLoop:
                 # restore + continue is token-exact (the PR 17 kvtier
                 # ticket contract), so pressure clearing auto-resumes
                 # the victim with zero wrong tokens
+                resume_at = time.monotonic()
                 slot = (self.engine.resume(seq.ticket)
                         if self.engine.free_slot_count > 0 else None)
                 if slot is None:
                     starved.append(seq)
                     keep.append(seq)
                     continue
+                # the others' account holds this resume; its own does not
+                own = time.monotonic() - resume_at
+                self._acct.hold(own, "resume")
+                seq.mark = (seq.mark[0] + own, seq.mark[1] + 1)
                 seq.ticket = None
                 seq.slot = slot
                 self._by_slot[slot] = seq
+                self._fresh.append(seq)
                 self._tracer.event(seq.trace_id, "resumed", slot=slot)
                 continue
             if ready_fn is not None and not ready_fn(len(seq.ids)):
@@ -1841,6 +2064,7 @@ class _DecodeLoop:
                     seq.compile_waited = True
                     self._tracer.event(seq.trace_id, "compile_wait",
                                        prompt_tokens=len(seq.ids))
+                self._acct.held_for_compile()
                 keep.append(seq)
                 continue
             if (self.ttft_slo_s is not None
@@ -1891,11 +2115,25 @@ class _DecodeLoop:
                 continue
             admitted += 1
             seq.slot = res.slot
-            seq.first_token_at = time.monotonic()
+            seq.first_token_at = seq.run_at = time.monotonic()
             ttft = seq.first_token_at - seq.req.enqueued_at
+            # the account so far: the two waits, what the second held,
+            # its own prefill; the snapshot its decoding is counted from
+            acct = self._acct
+            prefill_s = seq.first_token_at - admit_at
             self._tracer.annotate(
                 seq.trace_id, ttft_s=ttft,
-                queue_wait_s=admit_at - seq.req.enqueued_at)
+                queue_wait_s=admit_at - seq.req.enqueued_at,
+                listener_wait_s=seq.waiting_at - seq.req.enqueued_at,
+                slot_wait_s=admit_at - seq.waiting_at,
+                behind_prefill_s=acct.hold_s - seq.mark[0],
+                admissions_ahead=acct.holds - seq.mark[1],
+                prefill_s=prefill_s)
+            acct.hold(prefill_s, "admit", getattr(res, "bucket", 0))
+            acct.counts["admissions"] += 1
+            acct.counts["tokens"] += 1
+            acct.counts["prompt_tokens"] += len(seq.ids)
+            seq.mark = (acct.hold_s, acct.holds)
             self._m_ttft.observe(ttft, api=self.api.path)
             self._slo.observe_ttft(ttft)
             self._slo.count("admitted")
@@ -1923,6 +2161,7 @@ class _DecodeLoop:
                     self._tracer.finish(seq.trace_id, "expired")
                     continue
             self._by_slot[res.slot] = seq
+            self._fresh.append(seq)
             if self.journal is not None and seq.session is not None:
                 # (re)baseline the journal BEFORE the first token lands:
                 # for a resumed turn ids already embeds the committed
@@ -1970,6 +2209,7 @@ class _DecodeLoop:
             # cooldown, delaying the next legitimate eviction
             return
         self.qos.commit_preemption()
+        self._end_run(victim)
         self._by_slot.pop(victim.slot, None)
         victim.ticket = ticket
         victim.slot = None
@@ -1983,6 +2223,66 @@ class _DecodeLoop:
                        demand_priority=demand,
                        victim_remaining=victim.remaining,
                        pressure=snap)
+
+    # -- the request's account ---------------------------------------------
+    def _join_runs(self, stepped: Dict[int, int]) -> None:
+        """The sequences the step that just returned gave their first
+        token of a run (``stepped``: its tokens by slot): the gap that
+        token closes, from the one their run follows, is theirs alone;
+        every later one is a step's period, in the account's ring."""
+        acct, fresh = self._acct, []
+        for seq in self._fresh:
+            if self._by_slot.get(seq.slot) is not seq:
+                continue                    # cancelled or preempted since
+            if seq.slot not in stepped:
+                fresh.append(seq)           # the step in flight was not its
+                continue
+            gap = self._stepped_at - seq.run_at
+            if gap > seq.gap[0]:
+                seq.gap = (gap, *acct.held_since(seq.mark[1]))
+            seq.run_from = acct.steps
+        self._fresh = fresh
+
+    def _end_run(self, seq: _DecodeSeq) -> None:
+        """Close the run of steps that has been giving ``seq`` tokens, at
+        the last step's return: its steps, what the loop spent inside
+        other requests' admissions meanwhile, its widest gap."""
+        if seq.run_from is None:
+            return                  # no step of this run was its yet
+        acct = self._acct
+        widest = acct.widest(seq.run_from, acct.steps)
+        if widest is not None and widest[0] > seq.gap[0]:
+            seq.gap = widest
+        seq.steps += acct.steps - seq.run_from + 1
+        seq.stalled_s += acct.step_hold_s - seq.mark[0]
+        seq.admissions_during += acct.step_holds - seq.mark[1]
+        seq.mark = (acct.step_hold_s, acct.step_holds)
+        seq.run_at, seq.run_from = self._stepped_at, None
+
+    def _close_account(self, seq: _DecodeSeq) -> Dict[str, Any]:
+        """The decoding half of a request's account, for its span (the
+        waiting half was annotated at its admission); empty for a
+        request that never reached a slot.  ``stalled_s`` is the loop's
+        WALL time inside other requests' admissions: an admission first
+        waits for the step in flight, so it holds up to one step more
+        than the device's prefill time."""
+        if seq.first_token_at is None:
+            return {}
+        self._end_run(seq)
+        decode_s = seq.run_at - seq.first_token_at
+        out: Dict[str, Any] = {
+            "decode_s": decode_s, "steps": seq.steps,
+            "stalled_s": seq.stalled_s,
+            "admissions_during": seq.admissions_during}
+        self._m_decode.inc(decode_s, api=self.api.path)
+        self._m_stalled.inc(seq.stalled_s, api=self.api.path)
+        if seq.steps:
+            gap, cause, holds, bucket = seq.gap
+            out.update(gap_max_s=gap, gap_max_cause=cause)
+            if holds:
+                out.update(gap_max_admissions=holds, gap_max_bucket=bucket)
+            self._m_gap.observe(gap, api=self.api.path)
+        return out
 
     # -- token/retirement handling ----------------------------------------
     def _on_token(self, seq: _DecodeSeq, token: int, finished: bool,
@@ -1998,7 +2298,6 @@ class _DecodeLoop:
                                self.journal.append_tokens(s.session,
                                                           [int(t)]))
         seq.tokens.append(int(token))
-        self._m_tokens.inc(1, api=self.api.path)
         if seq.stream_obj is not None:
             seq.stream_obj.push(
                 json.dumps({"token": int(token)}).encode() + b"\n")
@@ -2018,10 +2317,12 @@ class _DecodeLoop:
         self._tenant_slo(seq.tenant).count("retired")
         if self._phase_slo is not None:
             self._phase_slo.count("retired")
+        account = self._close_account(seq)
         self._tracer.event(seq.trace_id, "retired",
-                           tokens=len(seq.tokens), reason=reason)
+                           tokens=len(seq.tokens), reason=reason,
+                           **{"steps": seq.steps, **seq.totals})
         self._tracer.finish(seq.trace_id, "retired",
-                            tokens=len(seq.tokens), reason=reason)
+                            tokens=len(seq.tokens), reason=reason, **account)
         if self.journal is not None and seq.session is not None:
             # compaction at retirement: the session's append history
             # collapses to one state record (bounded file), kept on
@@ -2069,7 +2370,8 @@ class _DecodeLoop:
                 self._m_errors.inc(1, api=self.api.path, kind=kind)
                 self._tracer.event(seq.trace_id, "cancelled", reason=kind)
                 self._tracer.finish(seq.trace_id, kind,
-                                    tokens=len(seq.tokens))
+                                    tokens=len(seq.tokens),
+                                    **self._close_account(seq))
         # a PARKED (preempted) sequence holds no slot but still owns a
         # reply window/stream — the same expiry rules drop its ticket
         live_parked: List[_DecodeSeq] = []
@@ -2085,7 +2387,8 @@ class _DecodeLoop:
                 self._m_errors.inc(1, api=self.api.path, kind=kind)
                 self._tracer.event(seq.trace_id, "cancelled", reason=kind)
                 self._tracer.finish(seq.trace_id, kind,
-                                    tokens=len(seq.tokens))
+                                    tokens=len(seq.tokens),
+                                    **self._close_account(seq))
             else:
                 live_parked.append(seq)
         self._parked = live_parked
@@ -2119,34 +2422,43 @@ class _DecodeLoop:
                 self._fail_inflight(e)
                 time.sleep(0.05)    # a persistently-broken engine must
                 #                     not spin the loop hot
+                self._acct.lap("idle_s")
 
     def _tick(self) -> None:
+        acct = self._acct
+        acct.begin_tick()
         with step_span("loop.tick"):
             with step_span("loop.pump"):
                 self._pump_queue()
+            acct.lap("pump_s")
             with step_span("loop.admit") as sp:
                 self._admit_waiting(sp)
+            acct.lap("admit_s")
             with step_span("loop.expire"):
                 self._cancel_expired()
                 self._export_slo()
+            since = acct.lap("expire_s")
             if not self.engine.active_count:
                 self._stepped_at = None
                 return
             # a step's period is the time between two steps' returns: the
             # engine may hand back a step the device finished under the
             # last tick's work, so the call alone says nothing of it
-            since = self._stepped_at or time.perf_counter()
+            since = self._stepped_at or since
             events = self.engine.step()
-            self._stepped_at = time.perf_counter()
+            self._stepped_at = acct.lap("step_s")
             dt = self._stepped_at - since
+            acct.step(dt)
             with step_span("loop.emit") as sp:
                 self._emit(events, dt)
                 if sp.live:
                     sp.set(events=len(events))
+            acct.lap("emit_s")
 
     def _emit(self, events, dt: float) -> None:
-        """A step's tokens to their requests: the latency observations,
-        the QoS charge, the stream push."""
+        """A step's tokens to their requests: the stream push a token;
+        the latency observations and the QoS charge once a step for each
+        tenant and latency, weighted by their tokens."""
         self._step_ewma = (dt if self._step_ewma is None
                            else 0.8 * self._step_ewma + 0.2 * dt)
         # a speculative engine commits a SPAN per slot per step: the
@@ -2157,22 +2469,29 @@ class _DecodeLoop:
         span: Dict[int, int] = {}
         for ev in events:
             span[ev.slot] = span.get(ev.slot, 0) + 1
+        if self._fresh:
+            self._join_runs(span)
+        tokens: Dict[Tuple[str, int], int] = {}   # by tenant and span
         for ev in events:
             seq = self._by_slot.get(ev.slot)
             if seq is None:         # cancelled under us
                 continue
-            tok_s = dt / span[ev.slot]
-            self._m_tok_lat.observe(tok_s, api=self.api.path)
-            self._slo.observe_token_latency(tok_s)
-            self._tenant_slo(seq.tenant).observe_token_latency(tok_s)
-            if self._phase_slo is not None:
-                self._phase_slo.observe_token_latency(tok_s)
-            # the DRR deficit is charged by COMMITTED tokens, one per
-            # step event — a speculative engine commits several per
-            # slot per step, so token-weighting (not request-counting)
-            # is what keeps the fair shares honest under spec decode
-            self.qos.charge(seq.tenant, 1)
+            key = (seq.tenant, span[ev.slot])
+            tokens[key] = tokens.get(key, 0) + 1
             self._on_token(seq, ev.token, ev.finished, ev.reason)
+        for (tenant, width), n in tokens.items():
+            tok_s = dt / width
+            self._m_tok_lat.observe_n(tok_s, n, api=self.api.path)
+            self._slo.observe_token_latency_n(tok_s, n)
+            self._tenant_slo(tenant).observe_token_latency_n(tok_s, n)
+            if self._phase_slo is not None:
+                self._phase_slo.observe_token_latency_n(tok_s, n)
+            # the DRR deficit is charged by COMMITTED tokens — a
+            # speculative engine commits several per slot per step, so
+            # token-weighting (not request-counting) is what keeps the
+            # fair shares honest under spec decode
+            self.qos.charge(tenant, n)
+            self._acct.counts["tokens"] += n
         if events and dt > 0:
             self._m_rps.set(len(events) / dt, api=self.api.path)
 
@@ -2183,9 +2502,10 @@ class _DecodeLoop:
         only ever sees busy instants, so a plane idle 59 s of every 60
         would read ~1.0 occupancy and the autoscaler consuming /sloz
         ("shrink on idle occupancy") would never scale it down."""
-        now = time.monotonic()
+        now = self._acct.lap("expire_s")
         if now - self._slo_export_at >= 1.0:
             self._slo_export_at = now
+            self._publish_account()
             self._slo.observe_occupancy(
                 self.engine.active_count / max(1, self.engine.n_slots))
             self._slo.export_gauges()
@@ -2197,6 +2517,21 @@ class _DecodeLoop:
                 self._phase_slo.export_gauges()
             for w in self._tenant_windows.values():
                 w.export_gauges()
+
+    def _publish_account(self) -> None:
+        """The loop's account of the second that ends at the last lap: one
+        ``loop.account`` span (always recorded; its clock is every span's)
+        and the same sums into ``llm_loop_seconds_total{api,phase}``."""
+        start, end, attrs = self._acct.take(
+            getattr(self.engine, "phase_seconds", None))
+        get_tracer().record("loop.account", end - start,
+                            start_ns=int(start * 1e9), api=self.api.path,
+                            **attrs)
+        for key, value in attrs.items():
+            if key.endswith("_s"):
+                self._m_loop_s.inc(max(0.0, value), api=self.api.path,
+                                   phase=key[:-2])
+        self._m_tokens.inc(attrs["tokens"], api=self.api.path)
 
     def _fail_inflight(self, e: Exception) -> None:
         """Answer every in-flight sequence 500 (streams get a final
@@ -2217,6 +2552,7 @@ class _DecodeLoop:
         for seq in self._parked:
             self._fail_seq(seq, e, body)
         self._parked = []
+        self._fresh = []
         self._m_errors.inc(1, api=self.api.path, kind="transform")
         # the engine's jitted programs donate their cache buffers: an
         # exception mid-call can leave the cache pointing at DELETED
@@ -2239,7 +2575,8 @@ class _DecodeLoop:
             seq.stream_obj.finish()
         else:
             self._safe_reply(seq.req.id, ServingReply(500, body))
-        self._tracer.finish(seq.trace_id, "error", error=str(e))
+        self._tracer.finish(seq.trace_id, "error", error=str(e),
+                            **self._close_account(seq))
 
     def stop(self) -> None:
         self._stop.set()

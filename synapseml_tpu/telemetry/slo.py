@@ -195,22 +195,28 @@ class WindowedHistogram(_SliceRing):
     def _fresh(self):
         # per-slice counts are NON-cumulative (one bisect + one
         # increment per observe — this sits on the serving hot path,
-        # once per token); merged() cumulates at read time, which is
+        # once a step and tenant); merged() cumulates at read time, which is
         # where the Prometheus-shaped view is actually needed
         return {"buckets": [0] * len(self.buckets), "sum": 0.0, "count": 0}
 
     def observe(self, value: float, now: Optional[float] = None) -> None:
+        self.observe_n(value, 1, now)
+
+    def observe_n(self, value: float, n: int,
+                  now: Optional[float] = None) -> None:
+        """``n`` observations of one ``value`` (a step's tokens) under
+        one lock."""
         value = float(value)
-        if math.isnan(value):
+        if math.isnan(value) or n <= 0:
             return
         now = time.monotonic() if now is None else now
         i = bisect.bisect_left(self.buckets, value)
         with self._lock:
             st = self._slot(now, self._fresh)
             if i < len(self.buckets):
-                st["buckets"][i] += 1
-            st["sum"] += value
-            st["count"] += 1
+                st["buckets"][i] += n
+            st["sum"] += value * n
+            st["count"] += n
 
     def merged(self, now: Optional[float] = None) -> Dict[str, Any]:
         """The window's CUMULATIVE buckets/sum/count (Prometheus
@@ -343,6 +349,11 @@ class SloWindow:
     def observe_token_latency(self, seconds: float,
                               now: Optional[float] = None) -> None:
         self._token.observe(seconds, now)
+
+    def observe_token_latency_n(self, seconds: float, n: int,
+                                now: Optional[float] = None) -> None:
+        """One latency for ``n`` tokens: a step's, once a step."""
+        self._token.observe_n(seconds, n, now)
 
     def observe_occupancy(self, fraction: float,
                           now: Optional[float] = None) -> None:

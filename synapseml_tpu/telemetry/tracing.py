@@ -49,6 +49,9 @@ __all__ = ["Span", "Tracer", "get_tracer", "span", "step_span",
 _ids = itertools.count(1)
 _tls = threading.local()
 _HOST = socket.gethostname()
+#: epoch seconds at ``time.monotonic() == 0``, read once: a span reads one
+#: clock, and its wall time (the Chrome export's) is derived from it
+_WALL_OFFSET_S = time.time() - time.monotonic()
 #: ``jax.profiler.TraceAnnotation`` once jax is imported — looked up in
 #: ``sys.modules`` so importing telemetry never drags in jax
 _annotation_cls = None
@@ -86,8 +89,8 @@ class Span:
     that a ``with`` block would have to re-indent), finished after."""
 
     __slots__ = ("name", "span_id", "parent_id", "trace_id", "start_ns",
-                 "end_ns", "start_wall_s", "attrs", "thread_id", "live",
-                 "_tracer", "_annotation")
+                 "end_ns", "attrs", "thread_id", "live", "_tracer",
+                 "_annotation")
 
     def __init__(self, tracer: Optional["Tracer"], name: str,
                  attrs: Optional[Dict[str, Any]] = None,
@@ -118,7 +121,6 @@ class Span:
         if _profiling():
             self._annotation = _annotation_cls(self.name)
             self._annotation.__enter__()
-        self.start_wall_s = time.time()
         self.start_ns = time.monotonic_ns()
         return self
 
@@ -152,6 +154,13 @@ class Span:
         """Add attributes; until the span closes."""
         if self.live:
             self.attrs.update(attrs)
+
+    @property
+    def start_wall_s(self) -> float:
+        """Epoch seconds of ``start_ns``, by the process's one offset
+        between the two clocks (taken at import: a wall clock stepped
+        since then is not followed)."""
+        return self.start_ns / 1e9 + _WALL_OFFSET_S
 
     @property
     def process_index(self) -> int:
@@ -196,7 +205,6 @@ class Tracer:
             self._appended += 1
 
     def record(self, name: str, duration_s: float, *,
-               start_wall_s: Optional[float] = None,
                start_ns: Optional[int] = None,
                parent_id: Optional[int] = None,
                trace_id: Optional[str] = None, **attrs) -> Span:
@@ -208,12 +216,8 @@ class Tracer:
         dur_ns = int(duration_s * 1e9)
         if start_ns is None:
             start_ns = time.monotonic_ns() - dur_ns
-        if start_wall_s is None:
-            start_wall_s = time.time() \
-                - (time.monotonic_ns() - start_ns) / 1e9
         sp = Span(self, name, attrs, trace_id)
         sp.span_id, sp.parent_id = next(_ids), parent_id
-        sp.start_wall_s = start_wall_s
         sp.start_ns, sp.end_ns = int(start_ns), int(start_ns) + dur_ns
         sp.thread_id = threading.get_ident()
         self._append(sp)
@@ -318,26 +322,37 @@ def mint_trace_id() -> str:
     return uuid.uuid4().hex
 
 
+#: the events that end a timeline; one place of ``max_events`` is theirs
+TERMINAL_EVENTS = frozenset(("retired", "shed", "cancelled"))
+
+
 class RequestTraceStore:
     """Bounded store of per-request event timelines — the serving
     plane's answer to "follow THIS request from router to retired
     slot" when an aggregate percentile goes bad.
 
-    One *trace* is one request's lifecycle: ``queued`` →
-    ``shed``/``admitted`` → ``prefill`` (with its bucket) →
-    ``decode``/``verify`` steps (with committed-span sizes) →
-    ``retired``/``cancelled``/``expired``.  Producers call
-    :meth:`begin` once (None ⇒ this request is not sampled — every
-    later call with a None id is a no-op attribute check), then
-    :meth:`event` per transition, then :meth:`finish` with the
-    outcome.  Finishing also records one ``serving.request`` span on
-    the process :class:`Tracer` (so request spans ride the existing
-    Chrome-trace/gang-plane export) and one ``request`` event on the
-    flight recorder (so a crash bundle names the requests in flight).
+    One *trace* is one request's lifecycle, told by its TRANSITIONS:
+    ``queued`` → ``shed``/``admitted`` → ``prefill`` (with its bucket)
+    → ``decode`` (its first decode step; ``preempted``/``resumed``,
+    ``compile_wait`` where they happen) → ``retired``/``cancelled``/
+    ``shed``.  Nothing is recorded a token: what the steps
+    between added up to (tokens, steps, a speculative engine's drafted
+    and accepted counts; the time lost to other requests' prefills and
+    the widest gap between two tokens) rides the terminal event and the
+    request's span.  Producers call :meth:`begin` once (None ⇒ this
+    request is not sampled — every later call with a None id is a no-op
+    attribute check), then :meth:`event` per transition, then
+    :meth:`finish` with the outcome.  Finishing also records one
+    ``serving.request`` span on the process :class:`Tracer` (so request
+    spans ride the existing Chrome-trace/gang-plane export) and one
+    ``request`` event on the flight recorder (so a crash bundle names
+    the requests in flight).
 
     Bounded on BOTH axes: at most ``max_traces`` timelines are
     retained (oldest evicted first) and at most ``max_events`` events
-    per timeline (later events are counted, not stored).  Sampling is
+    per timeline (later events are counted, not stored), the last place
+    kept for the terminal event (:data:`TERMINAL_EVENTS`), so a long
+    timeline never loses how its request ended.  Sampling is
     deterministic 1-in-``sample_every`` at :meth:`begin`; a PROPAGATED
     id (minted by an upstream hop) is always sampled, so a
     cross-replica request is never half-traced.  Thread-safe: the
@@ -376,7 +391,8 @@ class RequestTraceStore:
                 trace_id = mint_trace_id()
             self.sampled += 1
             self._traces[trace_id] = {
-                "trace_id": trace_id, "started_unix": time.time() - age,
+                "trace_id": trace_id,
+                "started_unix": _WALL_OFFSET_S + now - age,
                 "started_s": now - age, "attrs": dict(attrs),
                 "events": [], "dropped_events": 0,
                 "outcome": None, "duration_s": None}
@@ -387,14 +403,16 @@ class RequestTraceStore:
 
     def event(self, trace_id: Optional[str], name: str, **attrs) -> None:
         """Append one event (relative-time stamped).  Unknown/None ids
-        no-op — the unsampled request's fast path."""
+        no-op — the unsampled request's fast path.  The timeline's last
+        place is the terminal event's."""
         if trace_id is None:
             return
+        room = self.max_events - (name not in TERMINAL_EVENTS)
         with self._lock:
             tr = self._traces.get(trace_id)
             if tr is None:
                 return
-            if len(tr["events"]) >= self.max_events:
+            if len(tr["events"]) >= room:
                 tr["dropped_events"] += 1
                 self.dropped_events += 1
                 return
@@ -404,7 +422,7 @@ class RequestTraceStore:
 
     def annotate(self, trace_id: Optional[str], **attrs) -> None:
         """Attributes for the request's ``serving.request`` span (its
-        ``queue_wait_s``, ``ttft_s``), known before it finishes."""
+        waits, ``prefill_s``, ``ttft_s``), known before it finishes."""
         if trace_id is None:
             return
         with self._lock:
@@ -426,12 +444,11 @@ class RequestTraceStore:
             tr["outcome"] = outcome
             tr["duration_s"] = time.monotonic() - tr["started_s"]
             tr["attrs"].update(attrs)
-            started_wall, dur = tr["started_unix"], tr["duration_s"]
+            dur = tr["duration_s"]
             start_ns = int(tr["started_s"] * 1e9)
             span_attrs = {"outcome": outcome, **tr["attrs"]}
         get_tracer().record("serving.request", dur, start_ns=start_ns,
-                            start_wall_s=started_wall, trace_id=trace_id,
-                            **span_attrs)
+                            trace_id=trace_id, **span_attrs)
         try:
             from .flight import record as flight_record
             flight_record("request", trace_id=trace_id, outcome=outcome,

@@ -248,7 +248,7 @@ def test_the_loop_times_a_step_between_two_returns(tiny_model):
     a call may return a step the device finished under the last tick."""
     import time
 
-    from synapseml_tpu.serving.server import _DecodeLoop
+    from synapseml_tpu.serving.server import _DecodeLoop, _LoopAccount
 
     class Engine:
         n_slots, active_count, free_slot_count = 1, 1, 0
@@ -269,6 +269,7 @@ def test_the_loop_times_a_step_between_two_returns(tiny_model):
     loop.engine, loop.api = Engine(), Api()
     loop._waiting, loop._parked, loop._by_slot = [], [], {}
     loop._stepped_at, loop.idle_timeout_s, seen = None, 0.0, []
+    loop._acct = _LoopAccount()
     loop._admit_waiting = lambda sp: None
     loop._cancel_expired = loop._export_slo = lambda: None
     loop._emit = lambda events, dt: seen.append(dt)

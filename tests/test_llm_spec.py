@@ -496,8 +496,8 @@ def test_spec_acceptance_counts_real_drafts(tiny_model):
     """Acceptance divides by REAL drafted positions: a step on which
     the drafter had no match drafts nothing and dilutes nothing, and an
     accepted prefix can be no longer than its draft.  Tallied apart
-    from the engine's counters, through the per-slot ``verify`` events
-    of ``trace_sink``."""
+    from the engine's counters, in the per-slot totals that
+    ``trace_sink`` hands over at a slot's retirement."""
     cfg, model, variables = tiny_model
     rng = np.random.default_rng(0)
     base = rng.integers(1, cfg.vocab_size, 6).astype(np.int32)
@@ -512,11 +512,14 @@ def test_spec_acceptance_counts_real_drafts(tiny_model):
     while eng.active.any():
         eng.step()
     np.testing.assert_array_equal(eng.generated_ids(res.slot), ref[0])
-    verify = [a for event, a in seen if event == "verify"]
-    assert verify and eng.spec_steps > 0
-    assert all(0 <= a["accepted"] <= a["drafted"] <= 7 for a in verify)
-    assert eng.spec_drafted == sum(a["drafted"] for a in verify)
-    assert eng.spec_accepted == sum(a["accepted"] for a in verify)
+    assert [event for event, _ in seen] == ["decode", "retired"]
+    total = seen[-1][1]
+    assert total["verify_steps"] == eng.spec_steps > 0
+    assert 0 <= total["accepted"] <= total["drafted"] \
+        <= 7 * total["verify_steps"]
+    assert eng.spec_drafted == total["drafted"]
+    assert eng.spec_accepted == total["accepted"]
+    assert total["steps"] == eng.steps_run and total["tokens"] == 20
     assert 0.0 <= eng.spec_acceptance_rate <= 1.0
     assert eng.spec_acceptance_rate == pytest.approx(
         eng.spec_accepted / eng.spec_drafted)

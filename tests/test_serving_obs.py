@@ -283,9 +283,16 @@ class TestRequestTraceStore:
         for i in range(5):
             s.event(c, f"e{i}")
         tr = s.get(c)
-        assert len(tr["events"]) == 2
-        assert tr["dropped_events"] == 3
-        assert s.dropped_events == 3
+        # the cap's last place is the terminal event's: one transition
+        # is kept, four are counted
+        assert [e["name"] for e in tr["events"]] == ["e0"]
+        assert tr["dropped_events"] == 4
+        assert s.dropped_events == 4
+        s.event(c, "retired", tokens=7)
+        assert [e["name"] for e in s.get(c)["events"]] == ["e0", "retired"]
+        assert s.get(c)["events"][-1]["tokens"] == 7
+        s.event(c, "retired")                  # a second one has no place
+        assert len(s.get(c)["events"]) == 2 and s.dropped_events == 5
 
     def test_none_id_is_noop(self):
         s = RequestTraceStore()
@@ -490,7 +497,8 @@ class TestTracedServingExactness:
     def test_engine_token_exact_with_trace_sink(self, tiny_model):
         """The acceptance pin: greedy output through a fully-traced
         engine is token-identical to the dense path, and the sink saw
-        one decode event per slot-step."""
+        each slot's transitions and nothing a token: one ``decode`` at
+        its first step, one ``retired`` with the totals of its stay."""
         from synapseml_tpu.models.llm import SlotEngine, generate
         cfg, model, variables = tiny_model
         ids = _prompts(cfg, 3, 7, seed=40)
@@ -503,13 +511,18 @@ class TestTracedServingExactness:
         out = eng.run_to_completion()
         for i in range(3):
             np.testing.assert_array_equal(out[slots[i]], ref[i])
-        decodes = [s for s in seen if s[1] == "decode"]
-        assert len(decodes) == 3 * 9        # 9 decode steps per slot
-        assert all(a["tokens"] == 1 for _, _, a in decodes)
+        assert len(seen) == 3 * 2           # nothing for the 27 slot-steps
+        for slot in slots.values():
+            decode, retired = [(n, a) for s, n, a in seen if s == slot]
+            assert decode == ("decode", {"tokens": 1})
+            # 9 decode steps a slot; the prefill gave the first token
+            assert retired == ("retired", {"reason": "length", "tokens": 10,
+                                           "steps": 9})
 
     def test_spec_engine_token_exact_with_trace_sink(self, tiny_model):
         """Speculative engine under tracing: output stays exactly
-        greedy and verify events carry drafted/accepted span sizes."""
+        greedy and the slot's ``retired`` carries the drafted and
+        accepted totals of its verify steps."""
         from synapseml_tpu.models.llm import SlotEngine, generate
         cfg, model, variables = tiny_model
         rng = np.random.default_rng(41)
@@ -525,12 +538,18 @@ class TestTracedServingExactness:
         r = eng.admit(prompt, 16)
         eng.run_to_completion()
         np.testing.assert_array_equal(eng.generated_ids(r.slot), ref)
-        verifies = [a for _, name, a in seen if name == "verify"]
-        if verifies:                  # drafter hit at least once
-            assert all({"tokens", "drafted", "accepted"} <= set(a)
-                       for a in verifies)
-            assert all(a["tokens"] >= 1 and a["accepted"] <= a["drafted"]
-                       for a in verifies)
+        assert [name for _, name, _ in seen] == ["decode", "retired"]
+        first, total = seen[0][2], seen[1][2]
+        assert first["tokens"] >= 1
+        assert total["reason"] == "length" and total["tokens"] == 16
+        assert total["steps"] == eng.steps_run
+        assert total["verify_steps"] == eng.spec_steps <= total["steps"]
+        assert (total["drafted"], total["accepted"]) \
+            == (eng.spec_drafted, eng.spec_accepted)
+        assert total["accepted"] <= total["drafted"]
+        # every token is the prefill's, a step's own or an accepted draft
+        # (a last span the budget cut may accept more than it commits)
+        assert 16 <= 1 + total["steps"] + total["accepted"]
 
     def test_llmserver_timeline_and_propagated_id(self, tiny_model):
         """HTTP round-trip with tracing on: output token-exact, the
@@ -564,9 +583,13 @@ class TestTracedServingExactness:
                   if t["trace_id"] == tid][0]
             assert tr["outcome"] == "retired"
             names = [e["name"] for e in tr["events"]]
-            assert names[:3] == ["queued", "admitted", "prefill"]
-            assert names[-1] == "retired"
-            assert names.count("decode") == 5   # prefill emits token 1
+            # the transitions, and nothing a token: the prefill emits
+            # token 1, the first of five decode steps is the one event
+            assert names == ["queued", "admitted", "prefill", "decode",
+                             "retired"]
+            assert tr["events"][-1]["tokens"] == 6
+            assert tr["events"][-1]["steps"] == 5
+            assert tr["attrs"]["steps"] == 5
             # the SLO plane saw the request
             status, raw = _get(f"http://{host}:{port}/sloz")
             snap = json.loads(raw)

@@ -569,6 +569,338 @@ class TestProgramSpans:
         assert tr.spans("gbdt.train") == []
 
 
+# -- the request's account and the loop's ---------------------------------------
+
+class _ScriptApi:
+    """The listener's half of an API, driven by hand."""
+
+    def __init__(self):
+        import uuid
+        self.path = f"/acct-{uuid.uuid4().hex[:8]}"
+        self.max_queue, self.reply_timeout_s = 64, 60.0
+        self.queue, self.replies = [], {}
+
+    def poll(self, n):
+        out, self.queue = self.queue[:int(n)], self.queue[int(n):]
+        return out
+
+    get_batch = lambda self, n, timeout_s: self.poll(n)    # noqa: E731
+
+    def reply(self, rid, rep):
+        self.replies[rid] = rep
+        return True
+
+    def send(self, max_new, prompt=(1, 2, 3), tenant="default"):
+        from synapseml_tpu.serving.server import ServingRequest
+        req = ServingRequest(
+            id=f"r{len(self.replies) + len(self.queue)}-{id(self)}",
+            method="POST", path="/", headers={}, body=json.dumps(
+                {"ids": list(prompt), "max_new_tokens": max_new}).encode(),
+            enqueued_at=time.monotonic(), tenant=tenant)
+        self.queue.append(req)
+        return req
+
+
+class _ScriptEngine:
+    """Slots, budgets and a clock: ``admit`` sleeps the next scripted
+    prefill, ``step`` the scripted step and hands every slot a token.  It
+    keeps ``phase_seconds`` as ``SlotEngine`` does (all of a step its wait)
+    and leaves ``trace_sink`` to the loop."""
+
+    def __init__(self, n_slots, step_s=0.0, prefills=()):
+        self.n_slots, self.step_s = n_slots, step_s
+        self.prefills = list(prefills)
+        self.left = {}
+        self.trace_sink = None
+        self.phase_seconds = {"prepare": 0.0, "wait": 0.0, "commit": 0.0}
+
+    active_count = property(lambda self: len(self.left))
+    free_slot_count = property(lambda self: self.n_slots - len(self.left))
+
+    def min_remaining_tokens(self):
+        return None
+
+    def admit(self, ids, max_new):
+        import types
+        slot = min(set(range(self.n_slots)) - set(self.left))
+        seconds, bucket = self.prefills.pop(0) if self.prefills else (0.0, 8)
+        time.sleep(seconds)
+        if max_new > 1:
+            self.left[slot] = max_new - 1
+        return types.SimpleNamespace(slot=slot, token=7, bucket=bucket,
+                                     finished=max_new == 1, reason=None)
+
+    def step(self):
+        import types
+        t0 = time.perf_counter()
+        time.sleep(self.step_s)
+        events = []
+        for slot in sorted(self.left):
+            self.left[slot] -= 1
+            done = self.left[slot] == 0
+            events.append(types.SimpleNamespace(
+                slot=slot, token=7, finished=done,
+                reason="length" if done else None))
+            if done:
+                del self.left[slot]
+        self.phase_seconds["wait"] += time.perf_counter() - t0
+        return events
+
+    def cancel(self, slot):
+        self.left.pop(slot, None)
+
+
+def _hand_driven_loop(engine, api=None):
+    """A ``_DecodeLoop`` whose thread is stopped: ticks run by hand."""
+    from synapseml_tpu.serving.server import _DecodeLoop
+    from synapseml_tpu.telemetry import RequestTraceStore
+    api = api or _ScriptApi()
+    loop = _DecodeLoop(None, api, engine,
+                       input_parser=lambda req: json.loads(req.body),
+                       request_tracer=RequestTraceStore())
+    loop._stop.set()
+    loop._thread.join(timeout=5)
+    assert not loop._thread.is_alive()
+    return loop, api
+
+
+def _request_span(loop, req):
+    """The one ``serving.request`` span of ``req`` (by its API and the
+    listener's enqueue, where the span starts)."""
+    sp, = [sp for sp in get_tracer().spans("serving.request")
+           if sp.attrs.get("api") == loop.api.path
+           and sp.start_ns == int(req.enqueued_at * 1e9)]
+    return sp
+
+
+class TestAccounts:
+    STEP, PREFILL_A, PREFILL_B, PREFILL_C = 0.010, 0.020, 0.050, 0.030
+    #: what a sleep may overshoot by on a loaded machine
+    LATE = 0.05
+
+    @pytest.fixture(scope="class")
+    def served(self):
+        """A decodes alone, then B and C arrive together: B's prefill and
+        C's stand between two of A's tokens, and B's before C's own."""
+        engine = _ScriptEngine(3, self.STEP, [
+            (self.PREFILL_A, 16), (self.PREFILL_B, 64), (self.PREFILL_C, 32)])
+        loop, api = _hand_driven_loop(engine)
+        loop._publish_account()             # the account starts here
+        a = api.send(8)
+        loop._tick()
+        loop._tick()
+        b, c = api.send(3), api.send(3)
+        time.sleep(0.015)                   # in the listener's queue
+        t_wide = time.monotonic()
+        loop._tick()                        # the widest tick of A's life
+        wide = time.monotonic() - t_wide
+        while engine.active_count:
+            loop._tick()
+        loop._publish_account()
+        spans = {k: _request_span(loop, r) for k, r in
+                 (("a", a), ("b", b), ("c", c))}
+        return loop, spans, wide, get_tracer().spans("loop.account")[-1]
+
+    def test_the_accounts_identities(self, served):
+        _, spans, _, _ = served
+        for sp in spans.values():
+            at = sp.attrs
+            assert at["listener_wait_s"] + at["slot_wait_s"] \
+                == pytest.approx(at["queue_wait_s"], abs=1e-9)
+            assert at["queue_wait_s"] + at["prefill_s"] \
+                == pytest.approx(at["ttft_s"], abs=1e-9)
+            assert 0.0 <= at["stalled_s"] <= at["decode_s"]
+            assert at["ttft_s"] + at["decode_s"] <= sp.duration_s
+            assert at["gap_max_s"] <= at["decode_s"]
+            assert at["steps"] == at["tokens"] - 1
+            assert 0.0 <= at["behind_prefill_s"] <= at["slot_wait_s"]
+
+    def test_waits_and_what_stood_ahead(self, served):
+        _, spans, _, _ = served
+        a, b, c = (spans[k].attrs for k in "abc")
+        assert a["admissions_ahead"] == b["admissions_ahead"] == 0
+        assert a["behind_prefill_s"] == b["behind_prefill_s"] == 0.0
+        assert b["listener_wait_s"] >= 0.015 > b["slot_wait_s"]
+        # C stood on the waiting list through B's prefill
+        assert c["admissions_ahead"] == 1
+        assert self.PREFILL_B <= c["behind_prefill_s"] <= c["slot_wait_s"] \
+            < self.PREFILL_B + self.LATE
+        assert self.PREFILL_A <= a["prefill_s"] < self.PREFILL_A + self.LATE
+        assert self.PREFILL_C <= c["prefill_s"] < self.PREFILL_C + self.LATE
+
+    def test_the_widest_gap_and_its_cause(self, served):
+        _, spans, wide, _ = served
+        a, b, c = (spans[k].attrs for k in "abc")
+        # A's widest gap is the period that held both prefills and a
+        # step (and the test's own 15 ms between two ticks)
+        scripted = self.PREFILL_B + self.PREFILL_C + self.STEP
+        assert scripted <= a["gap_max_s"] <= wide + 0.015 + self.LATE
+        assert a["gap_max_cause"] == "admit"
+        assert a["gap_max_admissions"] == 2 and a["gap_max_bucket"] == 64
+        assert a["admissions_during"] == 2
+        assert self.PREFILL_B + self.PREFILL_C <= a["stalled_s"] \
+            < scripted + self.LATE
+        # B's first gap holds C's prefill; C's own gaps are plain steps
+        assert b["gap_max_cause"] == "admit"
+        assert (b["gap_max_admissions"], b["gap_max_bucket"]) == (1, 32)
+        assert self.PREFILL_C <= b["stalled_s"] < self.PREFILL_C + self.LATE
+        assert b["admissions_during"] == 1
+        assert c["gap_max_cause"] == "step" and c["stalled_s"] == 0.0
+        assert "gap_max_admissions" not in c
+        assert self.STEP <= c["gap_max_s"] < self.STEP + self.LATE
+
+    def test_the_registry_holds_the_same_sums(self, served):
+        loop, spans, _, _ = served
+        reg, api = get_registry(), loop.api.path
+        stalled = sum(sp.attrs["stalled_s"] for sp in spans.values())
+        decode = sum(sp.attrs["decode_s"] for sp in spans.values())
+        assert reg.get("llm_request_stalled_seconds_total").value(api=api) \
+            == pytest.approx(stalled)
+        assert reg.get("llm_request_decode_seconds_total").value(api=api) \
+            == pytest.approx(decode)
+        gaps = reg.get("llm_request_token_gap_max_seconds").stats(api=api)
+        assert gaps["count"] == 3
+        assert gaps["sum"] == pytest.approx(
+            sum(sp.attrs["gap_max_s"] for sp in spans.values()))
+
+    def test_loop_account_phases_add_up(self, served):
+        loop, spans, _, account = served
+        at = account.attrs
+        phases = {k: v for k, v in at.items() if k.endswith("_s")}
+        assert sorted(phases) == sorted(
+            ["pump_s", "idle_s", "admit_s", "expire_s", "step_prepare_s",
+             "step_wait_s", "step_commit_s", "step_other_s", "emit_s"])
+        assert sum(phases.values()) == pytest.approx(account.duration_s,
+                                                     abs=1e-6)
+        assert all(v >= -1e-6 for v in phases.values())
+        assert at["api"] == loop.api.path
+        assert at["admissions"] == 3 and at["prompt_tokens"] == 9
+        assert at["tokens"] == 8 + 3 + 3
+        assert at["steps"] == 7 == spans["a"].attrs["steps"]
+        assert at["ticks"] == at["steps"]
+        # the scripted engine spends a step in its wait, the loop an
+        # admission in the prefill
+        assert 7 * self.STEP <= at["step_wait_s"] < 7 * (self.STEP + self.LATE)
+        assert 0.0 <= at["step_other_s"] < self.LATE
+        assert 0.1 <= at["admit_s"] < 0.1 + 3 * self.LATE
+        reg = get_registry().get("llm_loop_seconds_total")
+        assert reg.value(api=loop.api.path, phase="step_wait") \
+            == pytest.approx(at["step_wait_s"])
+        assert reg.value(api=loop.api.path, phase="admit") \
+            >= at["admit_s"]
+        assert get_registry().get("llm_tokens_total").value(
+            api=loop.api.path) == 14
+
+    def test_a_long_timeline_keeps_its_end(self):
+        """1,000 tokens: the timeline is its transitions, the terminal
+        event carries the totals, nothing is dropped, and the store is
+        called once a transition, never a token."""
+        loop, api = _hand_driven_loop(_ScriptEngine(1))
+        calls = []
+        event = loop._tracer.event
+        loop._tracer.event = lambda tid, name, **a: (
+            calls.append(name), event(tid, name, **a))[1]
+        req = api.send(1000)
+        loop._tick()
+        while loop.engine.active_count:
+            loop._tick()
+        tr, = loop._tracer.traces(5)
+        assert calls == ["queued", "admitted", "prefill", "retired"]
+        assert [e["name"] for e in tr["events"]] == calls
+        assert tr["dropped_events"] == 0 and tr["outcome"] == "retired"
+        assert tr["events"][-1]["tokens"] == 1000
+        assert tr["events"][-1]["steps"] == 999
+        sp = _request_span(loop, req)
+        assert sp.attrs["steps"] == 999 and sp.attrs["tokens"] == 1000
+
+    def test_a_request_that_ends_at_its_first_token(self):
+        loop, api = _hand_driven_loop(_ScriptEngine(1))
+        req = api.send(1)
+        loop._tick()
+        at = _request_span(loop, req).attrs
+        assert at["outcome"] == "retired" and at["tokens"] == 1
+        assert at["decode_s"] == 0.0 and at["steps"] == 0
+        assert "gap_max_s" not in at and at["stalled_s"] == 0.0
+
+    @pytest.mark.parametrize("values", [
+        [(0.004, 32)], [(0.011, 20), (0.0055, 12)],
+        [(2.0, 3), (0.0001, 1), (float("nan"), 4), (0.01, 0)]])
+    def test_weighted_observation_is_the_per_token_loop(self, values):
+        from synapseml_tpu.telemetry import (SERVING_TOKEN_LATENCY_BUCKETS,
+                                             WindowedHistogram)
+        reg = MetricsRegistry()
+        one = reg.histogram("one", "", ("api",),
+                            buckets=SERVING_TOKEN_LATENCY_BUCKETS)
+        many = reg.histogram("many", "", ("api",),
+                             buckets=SERVING_TOKEN_LATENCY_BUCKETS)
+        w_one = WindowedHistogram(SERVING_TOKEN_LATENCY_BUCKETS)
+        w_many = WindowedHistogram(SERVING_TOKEN_LATENCY_BUCKETS)
+        for value, n in values:
+            for _ in range(n):
+                one.observe(value, api="/t")
+                w_one.observe(value, now=100.0)
+            many.observe_n(value, n, api="/t")
+            w_many.observe_n(value, n, now=100.0)
+        a, b = one.stats(api="/t"), many.stats(api="/t")
+        assert a["buckets"] == b["buckets"] and a["count"] == b["count"]
+        assert a["sum"] == pytest.approx(b["sum"], rel=1e-12)
+        a, b = w_one.merged(now=100.0), w_many.merged(now=100.0)
+        assert a["buckets"] == b["buckets"] and a["count"] == b["count"]
+        assert a["sum"] == pytest.approx(b["sum"], rel=1e-12)
+
+    def test_a_step_of_32_slots_costs_no_event_and_one_observation(
+            self, monkeypatch):
+        """The real engine under the loop, no profiler: one decode step
+        of 32 slots (two tenants) calls ``RequestTraceStore.event`` not
+        once and each histogram at most once a tenant, and leaves the
+        histograms as 32 single observations would."""
+        import jax
+        import jax.numpy as jnp
+        from synapseml_tpu.models.llm import (LlamaConfig, LlamaModel,
+                                              SlotEngine)
+        from synapseml_tpu.telemetry import Histogram, WindowedHistogram
+        cfg = LlamaConfig.tiny(num_layers=1, max_len=32, dtype=jnp.float32)
+        model = LlamaModel(cfg)
+        variables = model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((2, 8), jnp.int32))
+        engine = SlotEngine(model, variables, n_slots=32, max_len=32,
+                            name="t-acct-32")
+        loop, api = _hand_driven_loop(engine)
+        for i in range(32):
+            api.send(6, prompt=(3 + i % 5, 4, 5),
+                     tenant="even" if i % 2 == 0 else "odd")
+        loop._tick()                # 32 admissions and the first step
+        loop._tick()
+        assert engine.active_count == 32 and not loop._fresh
+        before = loop._m_tok_lat.stats(api=api.path)
+        events, observed = [], []
+        monkeypatch.setattr(loop._tracer, "event",
+                            lambda *a, **k: events.append(a))
+        for cls in (Histogram, WindowedHistogram):
+            real = cls.observe_n
+            monkeypatch.setattr(cls, "observe_n", (
+                lambda self, value, n, *a, _real=real, **k: (
+                    observed.append((id(self), n)),
+                    _real(self, value, n, *a, **k))[1]))
+        loop._tick()
+        monkeypatch.undo()
+        assert events == []
+        by_histogram = {}
+        for which, n in observed:
+            by_histogram.setdefault(which, []).append(n)
+        # the API's histogram, its SLO window, the two tenants' windows
+        assert sorted(map(sorted, by_histogram.values())) \
+            == [[16], [16], [16, 16], [16, 16]]
+        after = loop._m_tok_lat.stats(api=api.path)
+        assert after["count"] - before["count"] == 32
+        while engine.active_count:
+            loop._tick()
+        tr = loop._tracer.traces(1)[0]
+        assert [e["name"] for e in tr["events"]] == [
+            "queued", "admitted", "prefill", "decode", "retired"]
+        assert tr["events"][-1]["steps"] == 5 == tr["attrs"]["steps"]
+
+
 # -- artifact writer ---------------------------------------------------------
 
 class TestArtifact:
