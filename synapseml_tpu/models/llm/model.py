@@ -601,6 +601,53 @@ def _dense(features, axes, name, dtype, quant: str = "none"):
                         nn.initializers.truncated_normal(0.02), axes))
 
 
+def projection_fold_cut(rows: int, width: int) -> bool:
+    """Whether a projection of ``rows`` rows (``B * S`` of the pass) from
+    ``width`` input features is kept from folding its split into heads (or,
+    at the output projection, the merge of heads before it) into the product.
+
+    Folded, XLA reads the weight ``(width, heads * d)`` as ``[heads, d,
+    width]`` and relays all of it into that order on every call; cut, the
+    product reads the weight as it is stored and any relayout falls on the
+    activation, ``rows x heads * d``.  So cut where the activation is the
+    smaller, ``rows < width``: every decode step and the shorter prefills; a
+    prefill of more rows than the width (16,384 tokens over 4,096) keeps the
+    fold and relays the weight, the cheaper there."""
+    return rows < width
+
+
+def projection_layout(cfg: "LlamaConfig", rows: int) -> Dict[str, int]:
+    """``{"cut": n, "kept": m}``: the projection sites of a pass of ``rows``
+    rows over every layer of ``cfg``, by :func:`projection_fold_cut`."""
+    out = {"cut": 0, "kept": 0}
+    for kind in cfg.layer_kinds:
+        for _, width in MIXERS[kind].projections(cfg):
+            out["cut" if projection_fold_cut(rows, width) else "kept"] += 1
+    return out
+
+
+def _project(x, features, axes, name, cfg: "LlamaConfig", proj: str):
+    """``_dense(features)(x)``, a product whose result is split into heads,
+    through :func:`_fold_barrier`."""
+    y = _dense(features, axes, name, cfg.dtype, cfg.weight_quant)(x)
+    return _fold_barrier(y, x.shape[-1], proj)
+
+
+def _fold_barrier(y, width: int, proj: str):
+    """``y`` as it is, or behind ``lax.optimization_barrier`` (an identity
+    XLA cannot fold a reshape through) where :func:`projection_fold_cut`
+    says so for ``y``'s rows and ``width``; counted at trace time."""
+    from ...telemetry import get_registry
+    cut = projection_fold_cut(int(np.prod(y.shape[:-1])), int(width))
+    get_registry().counter(
+        "llm_projection_layout_total",
+        "attention projection sites traced, by site (q, k, v, o) and by "
+        "whether the product was kept from folding the split into heads "
+        "(cut) or not (kept)", ("proj", "choice")).inc(
+            proj=proj, choice="cut" if cut else "kept")
+    return jax.lax.optimization_barrier(y) if cut else y
+
+
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> List[Dict]:
     """Per-layer cache pytree: each layer's entry is what its mixer kind
     declares (``MIXERS[kind].cache_entry``): K/V rows by token position
@@ -662,6 +709,14 @@ class CausalAttention(nn.Module):
         return max_len
 
     @classmethod
+    def projections(cls, cfg: LlamaConfig) -> Tuple[Tuple[str, int], ...]:
+        """``(site, input width)`` of each product that meets a split into
+        heads or their merge: what :func:`_fold_barrier` decides at."""
+        a = cfg.attention(cls.KIND)
+        return (("q", cfg.d_model), ("k", cfg.d_model), ("v", cfg.d_model),
+                ("o", cfg.num_heads * a.v_head_dim))
+
+    @classmethod
     def cache_entry(cls, cfg: LlamaConfig, batch: int, max_len: int) -> Dict:
         rows = cls.cache_rows(cfg, max_len)
         if not cfg.packed(cls.KIND):
@@ -706,12 +761,9 @@ class CausalAttention(nn.Module):
         B, S, _ = x.shape
         H, KV, D, Dv = (cfg.num_heads, a.num_kv_heads, a.head_dim,
                         a.v_head_dim)
-        q = _dense(H * D, ("embed", "heads"), "q_proj", cfg.dtype,
-                   cfg.weight_quant)(x)
-        k = _dense(KV * D, ("embed", "kv"), "k_proj", cfg.dtype,
-                   cfg.weight_quant)(x)
-        v = _dense(KV * Dv, ("embed", "kv"), "v_proj", cfg.dtype,
-                   cfg.weight_quant)(x)
+        q = _project(x, H * D, ("embed", "heads"), "q_proj", cfg, "q")
+        k = _project(x, KV * D, ("embed", "kv"), "k_proj", cfg, "k")
+        v = _project(x, KV * Dv, ("embed", "kv"), "v_proj", cfg, "v")
         if cfg.qk_norm:
             q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
@@ -912,8 +964,8 @@ class CausalAttention(nn.Module):
                 probs = probs[..., :-1]
             out = jnp.einsum("bkgst,btkd->bskgd", probs, v_att)
             out = out.reshape(B, S, H * Dv)
-        out = _dense(cfg.d_model, ("heads", "embed"), "o_proj",
-                     cfg.dtype, cfg.weight_quant)(out)
+        out = _dense(cfg.d_model, ("heads", "embed"), "o_proj", cfg.dtype,
+                     cfg.weight_quant)(_fold_barrier(out, H * Dv, "o"))
         return out, new_cache
 
 
@@ -942,6 +994,13 @@ class GatedDeltaNet(nn.Module):
     leaves both exactly as they were, and a pass from position 0 starts
     from zeros, not from what the slot's last tenant left."""
     cfg: LlamaConfig
+
+    @staticmethod
+    def projections(cfg: LlamaConfig) -> Tuple[Tuple[str, int], ...]:
+        """No site (:meth:`CausalAttention.projections`): in the programs
+        the v5e compiler builds, none of this layer's projection weights is
+        relayed, so none of its products is kept from folding."""
+        return ()
 
     @staticmethod
     def cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> Dict:

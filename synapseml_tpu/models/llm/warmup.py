@@ -50,7 +50,7 @@ import numpy as np
 from ...parallel.compilecache import (cache_stats, compile_label,
                                       install_compile_listeners)
 from ...telemetry import get_registry, span
-from .model import init_cache
+from .model import init_cache, projection_layout
 from .slots import (_copy_prefix_jit, _decode_program_key,
                     _decode_step_jit, _next_pow2, _prefill_program_key,
                     _prefill_slot_jit, _restore_program_key,
@@ -125,10 +125,12 @@ class ProgramSpec:
     """One row of the program lattice: a stable key (the metric/trace
     label), its kind, and a closure running the real jitted entry point
     once against scratch state (takes and returns the scratch cache —
-    the jitted programs donate their cache argument)."""
+    the jitted programs donate their cache argument); ``rows``, the rows
+    of the model's pass (``B * S``; 0: a program with no pass)."""
     key: str
     kind: str                      # prefill | decode | verify | prefix_copy
     run: Callable[[Any], Any]
+    rows: int = 0
 
 
 def program_lattice(engine) -> List[ProgramSpec]:
@@ -172,7 +174,7 @@ def program_lattice(engine) -> List[ProgramSpec]:
         jax.block_until_ready(nxt)
         return cache
     specs.append(ProgramSpec(_decode_program_key(backend), "decode",
-                             run_decode))
+                             run_decode, n))
 
     def run_copy(cache):
         cache = _copy_prefix_jit(cache, 0, min(1, n - 1),
@@ -196,7 +198,7 @@ def program_lattice(engine) -> List[ProgramSpec]:
                 jax.block_until_ready(g)
                 return cache
             specs.append(ProgramSpec(_verify_program_key(backend, s),
-                                     "verify", run_verify))
+                                     "verify", run_verify, n * s))
             s *= 2
 
     for pb in engine._buckets:
@@ -208,7 +210,7 @@ def program_lattice(engine) -> List[ProgramSpec]:
             jax.block_until_ready(last)
             return cache
         specs.append(ProgramSpec(_prefill_program_key(pb), "prefill",
-                                 run_prefill))
+                                 run_prefill, pb))
 
     if getattr(engine, "kv_arena", None) is not None:
         # host-restore programs: one per prefill bucket (the restored
@@ -410,7 +412,11 @@ class CompilePlane:
     def _run_spec(self, spec: ProgramSpec, cache):
         t0 = time.monotonic()
         before = cache_stats()["compiles"]
-        with span("llm.warmup.program", key=spec.key) as sp:
+        fold = projection_layout(self.engine.cfg, spec.rows) if spec.rows \
+            else {"cut": 0, "kept": 0}
+        with span("llm.warmup.program", key=spec.key,
+                  projections_fold_cut=fold["cut"],
+                  projections_fold_kept=fold["kept"]) as sp:
             with compile_label(spec.key):
                 cache = spec.run(cache)
             sp.set(seconds=round(time.monotonic() - t0, 4),

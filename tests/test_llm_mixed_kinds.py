@@ -24,9 +24,10 @@ window, window, full with layer 0 dense):
 - what the engine does on a ring: a prefix reuse past the ring's spare rows is
   skipped and counted, one inside them is served; a drafter, a host arena and a
   prefill worker are refused at construction;
-- the three other serving configurations trace to the same decode-step and
-  prefill programs as before this description existed (a digest of the jaxpr's
-  text, recorded on the parent commit).
+- the three other serving configurations trace to the decode-step and prefill
+  programs recorded (a digest of the jaxpr's text: before this description
+  existed, and again where a later change to shared code meant to change
+  them).
 
 Tolerances.  Program and reference both compute in float32 from the same
 bfloat16-rounded weights and differ in summation order over 4 layers and a
@@ -785,20 +786,27 @@ def test_the_geometry_by_kind_at_the_published_widths():
 #: another configuration's programs changes its compile-cache keys and its
 #: set-up time on the chip (PERF.md section 6, PR 34).  PR 37: the prefill
 #: digests stay as recorded: the prefill kernel engages from 128 MiB of
-#: plain scores on (``pallas_attn._PREFILL_MIN_SCORE_BYTES``), never here
+#: plain scores on (``pallas_attn._PREFILL_MIN_SCORE_BYTES``), never here.
+#: All twelve re-recorded by the change that reads projection weights where
+#: they lie (PERF.md section 6): an attention projection no longer folds its
+#: split into heads (or o_proj the merge before it) into the product where
+#: the pass has fewer rows than the projection's input width
+#: (``model.projection_fold_cut``), which every toy program here has; the
+#: numbers are the earlier programs' bit for bit
+#: (``test_llm_projection_layout.py``)
 PARENT_PROGRAMS = {
-    "mistral.dense.decode": "3f98919f3029c047",
-    "mistral.dense.prefill": "3dcdf7839e5b76a2",
-    "mistral.interpret.decode": "c91c5640ab1cadd2",
-    "mistral.interpret.prefill": "3dcdf7839e5b76a2",
-    "olmo.dense.decode": "a5104937d3269b54",
-    "olmo.dense.prefill": "32bd1e7b1fdb3ea6",
-    "olmo.interpret.decode": "39bbc01a7fc01cd9",
-    "olmo.interpret.prefill": "37b8e3cfb362073a",
-    "command-a-plus.dense.decode": "33e4ab00fbd2d245",
-    "command-a-plus.dense.prefill": "1d0577f87ef44269",
-    "command-a-plus.interpret.decode": "bc62087a6202ff8a",
-    "command-a-plus.interpret.prefill": "8d99629b8b5d825f",
+    "mistral.dense.decode": "9df418197930cc84",
+    "mistral.dense.prefill": "6c9b6188b7425e10",
+    "mistral.interpret.decode": "1604f4dd6ccdc82a",
+    "mistral.interpret.prefill": "6c9b6188b7425e10",
+    "olmo.dense.decode": "68a7768112821080",
+    "olmo.dense.prefill": "1717a26bccdb00bf",
+    "olmo.interpret.decode": "d449403bbce79a33",
+    "olmo.interpret.prefill": "d83960202535cb63",
+    "command-a-plus.dense.decode": "174f570516c5549a",
+    "command-a-plus.dense.prefill": "2c3ed2369903b1f9",
+    "command-a-plus.interpret.decode": "cf5c4b69d54785c0",
+    "command-a-plus.interpret.prefill": "da0cab6447737f02",
 }
 
 
