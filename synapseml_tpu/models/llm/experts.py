@@ -9,7 +9,10 @@ layer is told which ones it holds (``LlamaConfig.experts_first``,
   ``norm_topk_prob`` the selected scores are normalised over all the
   selected, held here or not; with ``expert_selection_bias`` the choice is
   by ``score + bias``, a learned float32 bias an expert, and the weights are
-  the scores without it);
+  the scores without it; with ``expert_groups`` the choice is among the
+  experts of a token's ``expert_groups_kept`` best groups, a group scored by
+  its two largest selection values; ``routed_scaling_factor`` multiplies
+  the weights last);
 - the (token, expert) pairs whose expert is held are grouped by expert and
   go through one grouped product, :func:`expert_ffn`, that reads an
   expert's weights only if it has a pair.  No capacity: a group is as long
@@ -214,6 +217,20 @@ def _routed(x, expert, weight, held, w_gate, w_up, w_down, backend: str):
     return jnp.sum(pair.reshape(T, k, -1) * weight[..., None], axis=1)
 
 
+def _kept_groups(sel: jnp.ndarray, groups: int, kept: int) -> jnp.ndarray:
+    """Group-limited selection (DeepSeek-V3's): the experts lie in
+    ``groups`` equal groups by index, a group scores the sum of its two
+    largest selection values, and the experts outside a token's ``kept``
+    best groups are out of its choice.  ``sel (T, E)`` -> the same values
+    there, the lowest float32 elsewhere."""
+    T, E = sel.shape
+    g = sel.reshape(T, groups, E // groups)
+    _, best = lax.top_k(jnp.sum(lax.top_k(g, 2)[0], -1), kept)   # (T, kept)
+    keep = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+    return jnp.where(jnp.repeat(keep, E // groups, axis=1), sel,
+                     jnp.finfo(jnp.float32).min)
+
+
 def stats_totals(stats) -> jnp.ndarray:
     """``[expert_pairs_held, experts_touched]`` summed over the layers of a
     pass's ``"stats"`` collection: int32 ``(2,)``."""
@@ -256,16 +273,23 @@ class ExpertFFN(nn.Module):
                     precision=lax.Precision.HIGHEST).astype(jnp.float32)
         score = jax.nn.sigmoid(r) if cfg.expert_selection == "sigmoid" \
             else jax.nn.softmax(r, axis=-1)
+        sel = score
         if cfg.expert_selection_bias:
             # selected by score + bias, weighed by the score alone
             bias = self.param("router_bias", nn.initializers.zeros_init(),
                               (E,), jnp.float32)
-            _, idx = lax.top_k(score + bias, k)
-            top = jnp.take_along_axis(score, idx, axis=-1)
-        else:
+            sel = score + bias
+        if cfg.expert_groups > 1:
+            sel = _kept_groups(sel, cfg.expert_groups, cfg.expert_groups_kept)
+        if sel is score:
             top, idx = lax.top_k(score, k)                        # (T, k)
+        else:
+            _, idx = lax.top_k(sel, k)
+            top = jnp.take_along_axis(score, idx, axis=-1)
         weight = top / jnp.sum(top, -1, keepdims=True) \
             if cfg.norm_topk_prob else top
+        if cfg.routed_scaling_factor != 1.0:
+            weight = weight * cfg.routed_scaling_factor
         held = (idx >= first) & (idx < first + H) & valid.reshape(T, 1)
         for name, count in (
                 ("expert_pairs_held", jnp.sum(held, dtype=jnp.int32)),

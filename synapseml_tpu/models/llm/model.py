@@ -17,7 +17,12 @@ block, LayerNorm, a head width that is not ``d_model / num_heads``,
 interleaved rotary embeddings on some layer kinds and none on others, and
 what ONE layer kind's attention overrides (:class:`AttentionKind`: its K/V
 heads, a key wider than the value, rotary embeddings on part of a head with
-the kind's own theta, a sink logit a head, a scale on the values).
+the kind's own theta, a sink logit a head, a scale on the values).  So are
+``latent_attention`` layers (:class:`LatentAttention`: multi-head latent
+attention, whose cache entry is one latent row a position for all heads,
+with YaRN's rotary embedding) and a router that selects among groups of
+experts and scales their weights (``expert_groups``,
+``routed_scaling_factor``).
 
 The reference has no LLM training/serving of its own — its OpenAI stages
 call out to a remote service (reference: cognitive/.../openai/OpenAI.scala
@@ -175,8 +180,46 @@ class LlamaConfig:
     #: the router selects by ``score + bias`` (a learned float32 bias an
     #: expert) and weighs by ``score`` alone
     expert_selection_bias: bool = False
+    #: group-limited selection: the router's experts lie in
+    #: ``expert_groups`` equal groups by index, a group scores the sum of its
+    #: two largest selection values, and a token selects among the experts
+    #: of its ``expert_groups_kept`` best groups alone (1: no groups)
+    expert_groups: int = 1
+    expert_groups_kept: int = 1
+    #: the routed experts' weights are multiplied by it, after any
+    #: normalisation
+    routed_scaling_factor: float = 1.0
+    # latent attention (``latent_attention`` layers, :class:`LatentAttention`):
+    # the query's latent width (None: q from the residual), the K/V
+    # latent's, a head's part without and with the rotary embedding, and a
+    # value head's width
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: scaled rotary embeddings of latent attention: None, or a ``yarn``
+    #: mapping (``factor``, ``original_max_position_embeddings``,
+    #: ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``; kept as
+    #: sorted pairs): :func:`yarn_rope`
+    rope_scaling: Optional[Any] = None
 
     def __post_init__(self):
+        if self.rope_scaling is not None:
+            self.rope_scaling = tuple(sorted(dict(self.rope_scaling).items()))
+            kind = dict(self.rope_scaling).get(
+                "type", dict(self.rope_scaling).get("rope_type"))
+            if kind != "yarn":
+                raise ValueError(f"rope_scaling of type {kind!r}: only yarn "
+                                 "is supported")
+            if set(self.attention_layer_kinds) - {"latent_attention"}:
+                raise ValueError("rope_scaling acts on latent_attention "
+                                 "layers alone")
+        if self.expert_groups_kept > self.expert_groups \
+                or self.num_experts % max(1, self.expert_groups):
+            raise ValueError(
+                f"{self.num_experts} experts in {self.expert_groups} groups, "
+                f"{self.expert_groups_kept} kept")
         if self.attention_kinds is not None:
             pairs = dict(self.attention_kinds)
             unknown = set(pairs) - {"full_attention", "sliding_attention"}
@@ -220,6 +263,8 @@ class LlamaConfig:
                 and not self.sliding_window:
             raise ValueError("sliding_attention layers need sliding_window")
         for kind in self.attention_layer_kinds:
+            if kind == "latent_attention":
+                continue
             a = self.attention(kind)
             if self.num_heads % a.num_kv_heads:
                 raise ValueError(
@@ -244,6 +289,14 @@ class LlamaConfig:
                     f"are not among the router's {self.num_experts}")
             if self.weight_quant != "none":
                 raise ValueError("expert layers have no int8 path")
+        if "latent_attention" in self.layer_kinds:
+            if not (self.kv_lora_rank and self.qk_rope_head_dim
+                    and self.qk_nope_head_dim and self.v_head_dim):
+                raise ValueError("latent_attention layers need kv_lora_rank, "
+                                 "qk_nope_head_dim, qk_rope_head_dim and "
+                                 "v_head_dim")
+            if self.weight_quant != "none":
+                raise ValueError("latent attention has no int8 path")
 
     @property
     def d_head(self) -> int:
@@ -310,9 +363,10 @@ class LlamaConfig:
 
     @property
     def num_attention_layers(self) -> int:
-        """Layers that keep K/V rows by position (full and window)."""
+        """Layers that keep K/V rows by position (full, window, latent)."""
         return self.layer_kinds.count("full_attention") \
-            + self.num_window_layers
+            + self.num_window_layers \
+            + self.layer_kinds.count("latent_attention")
 
     @property
     def num_window_layers(self) -> int:
@@ -334,8 +388,17 @@ class LlamaConfig:
         ``mimo_v2`` brings attention by layer kind (``hybrid_layer_pattern``
         1: ``sliding_attention`` with the ``swa_*`` keys, 0:
         ``full_attention``), ``v_head_dim``, ``partial_rotary_factor``,
-        ``attention_value_scale``, the sink logits, a feed-forward kind by
-        layer (``moe_layer_freq``) and the ``noaux_tc`` selection bias."""
+        ``attention_value_scale``, the sink logits and a feed-forward kind by
+        layer (``moe_layer_freq``).  A config with ``kv_lora_rank`` (``axk1``,
+        the DeepSeek-V3 family) is every layer ``latent_attention`` with the
+        ``q_lora_rank``, ``qk_*_head_dim`` and ``v_head_dim`` keys, rotary
+        pairs ``(2i, 2i + 1)`` and a ``yarn`` ``rope_scaling``.  The router's
+        keys are read for every family: ``n_routed_experts`` or
+        ``num_experts``, ``scoring_func``, ``n_group`` and ``topk_group``,
+        ``routed_scaling_factor``, ``topk_method`` ``noaux_tc`` (the selection
+        bias).  A key whose mechanism this description does not have is
+        refused, whatever the family (:func:`_refuse_unhonoured`)."""
+        _refuse_unhonoured(hc)
         if hc.get("model_type") == "mimo_v2":
             hc, kw = _mimo_v2_keys(hc), {**_mimo_v2_args(hc), **kw}
         rope = hc.get("rope_parameters") or {}
@@ -343,13 +406,15 @@ class LlamaConfig:
             else hc.get("rope_theta", 10_000.0)   # HF's default (Llama-1/2)
         olmo = hc.get("model_type") == "olmo_hybrid"
         cohere = hc.get("model_type") == "cohere2_moe"
+        latent = bool(hc.get("kv_lora_rank"))
+        n = hc["num_hidden_layers"]
         eps = hc.get("rms_norm_eps")
         layer_norm = eps is None and hc.get("layer_norm_eps") is not None
         if eps is None:
             eps = hc.get("layer_norm_eps", hc.get("layernorm_epsilon", 1e-5))
         args = dict(
             vocab_size=hc["vocab_size"], d_model=hc["hidden_size"],
-            num_layers=hc["num_hidden_layers"],
+            num_layers=n,
             num_heads=hc["num_attention_heads"],
             num_kv_heads=hc.get("num_key_value_heads",
                                 hc["num_attention_heads"]),
@@ -369,14 +434,31 @@ class LlamaConfig:
             rope_layers=("sliding_attention",) if cohere else None,
             sliding_window=hc.get("sliding_window"),
             logit_scale=float(hc.get("logit_scale", 1.0)))
-        if hc.get("num_experts"):
+        if latent:
             args.update(
-                ffn="experts", num_experts=hc["num_experts"],
+                layer_types=("latent_attention",) * n, rope_style="interleaved",
+                q_lora_rank=hc.get("q_lora_rank"),
+                kv_lora_rank=hc["kv_lora_rank"],
+                qk_nope_head_dim=hc["qk_nope_head_dim"],
+                qk_rope_head_dim=hc["qk_rope_head_dim"],
+                v_head_dim=hc["v_head_dim"],
+                rope_scaling=_rope_scaling(hc))
+        experts = hc.get("num_experts") or hc.get("n_routed_experts")
+        if experts:
+            args.update(
+                ffn="experts", num_experts=experts,
                 num_experts_per_tok=hc["num_experts_per_tok"],
-                num_shared_experts=hc.get("num_shared_experts", 0),
+                num_shared_experts=hc.get("num_shared_experts",
+                                          hc.get("n_shared_experts") or 0),
                 expert_d_ff=hc.get("moe_intermediate_size"),
-                expert_selection=hc.get("expert_selection_fn", "softmax"),
-                norm_topk_prob=bool(hc.get("norm_topk_prob", False)))
+                expert_selection=hc.get("expert_selection_fn",
+                                        hc.get("scoring_func", "softmax")),
+                norm_topk_prob=bool(hc.get("norm_topk_prob", False)),
+                expert_selection_bias=hc.get("topk_method") == "noaux_tc",
+                expert_groups=int(hc.get("n_group") or 1),
+                expert_groups_kept=int(hc.get("topk_group") or 1),
+                routed_scaling_factor=float(
+                    hc.get("routed_scaling_factor") or 1.0))
         if "linear_num_value_heads" in hc:
             if hc.get("linear_num_key_heads") != hc["linear_num_value_heads"]:
                 raise ValueError("linear layers with fewer key heads than "
@@ -388,12 +470,13 @@ class LlamaConfig:
                 linear_conv_kernel_dim=hc.get("linear_conv_kernel_dim", 4),
                 linear_allow_neg_eigval=bool(
                     hc.get("linear_allow_neg_eigval", False)))
-        n = hc["num_hidden_layers"]
         dense = set(range(int(hc.get("first_k_dense_replace") or 0))) \
             | set(hc.get("mlp_only_layers") or ())
         freq = hc.get("moe_layer_freq")
         if isinstance(freq, (list, tuple)):
             dense |= {i for i, on in enumerate(freq) if not on}
+        elif freq:                              # every freq-th layer
+            dense |= {i for i in range(n) if i % int(freq)}
         if args.get("ffn") == "experts" and dense:
             args["ffn_types"] = tuple(
                 "dense" if i in dense else "experts" for i in range(n))
@@ -423,29 +506,66 @@ class LlamaConfig:
         return LlamaConfig(**kw)
 
 
+#: keys of mechanisms this description does not have, and the values
+#: under which they say nothing: any other value is refused by
+#: :meth:`LlamaConfig.from_hf`, whatever the family
+_UNHONOURED = (
+    ("index_topk", (None,)),                      # sparse selection of keys
+    ("hc_mult", (None, 1)),                       # several residual streams
+    ("enable_ihc", (None, False)),
+    ("num_nextn_predict_layers", (None, 0)),      # multi-token prediction
+    ("hybrid_block_size", (None,)),
+    ("topk_method", (None, "none", "greedy", "noaux_tc")),
+)
+
+
+def _refuse_unhonoured(hc: Dict[str, Any]) -> None:
+    """A ``ValueError`` for a key whose shape the description cannot
+    honour, rather than a model that silently leaves its mechanism out."""
+    for key, plain in _UNHONOURED:
+        if hc.get(key) not in plain:
+            raise ValueError(f"{key}={hc[key]!r} is not supported")
+    if int(hc.get("n_shared_experts") or 0) > 1:
+        raise ValueError(f"n_shared_experts={hc['n_shared_experts']}: shared "
+                         "experts of this spelling are summed, ExpertFFN "
+                         "averages them")
+    for key in ("rope_scaling", "rope_parameters"):
+        kind = (hc.get(key) or {}).get(
+            "rope_type", (hc.get(key) or {}).get("type"))
+        if kind not in (None, "default") and not (
+                kind == "yarn" and hc.get("kv_lora_rank")):
+            raise ValueError(f"{key} of type {kind!r} is not supported")
+
+
+def _rope_scaling(hc: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+    """A ``yarn`` ``rope_scaling`` as :class:`LlamaConfig` keeps it."""
+    rs = hc.get("rope_scaling") or {}
+    if rs.get("type", rs.get("rope_type")) != "yarn":
+        return None
+    return {"type": "yarn", "factor": float(rs["factor"]),
+            "original_max_position_embeddings":
+                int(rs["original_max_position_embeddings"]),
+            "beta_fast": float(rs.get("beta_fast", 32)),
+            "beta_slow": float(rs.get("beta_slow", 1)),
+            "mscale": float(rs.get("mscale", 1)),
+            "mscale_all_dim": float(rs.get("mscale_all_dim", 0))}
+
+
 def _mimo_v2_keys(hc: Dict[str, Any]) -> Dict[str, Any]:
     """A ``mimo_v2`` config's keys under the names :meth:`LlamaConfig.from_hf`
     reads from every family."""
-    for key, plain in (("routed_scaling_factor", (None, 1, 1.0)),
-                       ("n_group", (None, 1)), ("topk_group", (None, 1)),
-                       ("n_shared_experts", (None, 0)),
-                       ("hybrid_block_size", (None,))):
-        if hc.get(key) not in plain:
-            raise ValueError(f"mimo_v2 {key}={hc[key]!r} is not supported")
     if hc.get("swa_num_attention_heads",
               hc["num_attention_heads"]) != hc["num_attention_heads"]:
         raise ValueError("window layers with another number of query heads "
                          "than full layers are not supported")
     return dict(
         hc, layer_types=["sliding_attention" if w else "full_attention"
-                         for w in hc["hybrid_layer_pattern"]],
-        num_experts=hc.get("n_routed_experts"), num_shared_experts=0,
-        expert_selection_fn=hc.get("scoring_func", "softmax"))
+                         for w in hc["hybrid_layer_pattern"]])
 
 
 def _mimo_v2_args(hc: Dict[str, Any]) -> Dict[str, Any]:
-    """What a ``mimo_v2`` config says of attention by layer kind, and of the
-    router, as :class:`LlamaConfig` arguments."""
+    """What a ``mimo_v2`` config says of attention by layer kind, as
+    :class:`LlamaConfig` arguments."""
     factor = float(hc.get("partial_rotary_factor", 1.0))
     scale = float(hc.get("attention_value_scale") or 1.0)
 
@@ -458,11 +578,9 @@ def _mimo_v2_args(hc: Dict[str, Any]) -> Dict[str, Any]:
             rotary_dim=int(d * factor),
             rope_theta=float(hc.get(pre + "rope_theta", hc["rope_theta"])),
             sink=bool(hc.get(sink_key, False)), value_scale=scale)
-    return dict(
-        attention_kinds={
-            "full_attention": kind("", "add_full_attention_sink_bias"),
-            "sliding_attention": kind("swa_", "add_swa_attention_sink_bias")},
-        expert_selection_bias=hc.get("topk_method") == "noaux_tc")
+    return dict(attention_kinds={
+        "full_attention": kind("", "add_full_attention_sink_bias"),
+        "sliding_attention": kind("swa_", "add_swa_attention_sink_bias")})
 
 
 class RMSNorm(nn.Module):
@@ -502,22 +620,62 @@ def rope_frequencies(d_head: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, d_head, 2, np.float32) / d_head))
 
 
+def yarn_rope(d: int, theta: float, scaling: Dict[str, Any]
+              ) -> Tuple[np.ndarray, float, float]:
+    """YaRN's rotary embedding for heads of ``d`` rotated dims: ``(inverse
+    frequencies (d/2,) float32, the scale of cos and sin, the factor on the
+    softmax scale)``.  Pair ``i``'s base frequency ``f_i = theta^(-2i/d)``
+    becomes ``f_i / factor * r_i + f_i * (1 - r_i)``, ``r_i`` the ramp
+    ``clip((i - low) / (high - low), 0, 1)`` between the dims that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context; cos and
+    sin are scaled by ``m(mscale) / m(mscale_all_dim)`` and the softmax
+    scale by ``m(mscale_all_dim)^2``, ``m(a) = 0.1 a ln(factor) + 1``
+    (DeepSeek-V3's ``yarn_get_mscale``; 1 where ``a`` is 0 or the factor at
+    most 1)."""
+    s = dict(scaling)
+    factor, orig = float(s["factor"]), float(
+        s["original_max_position_embeddings"])
+
+    def dim_of(turns):              # the dim that turns ``turns`` times
+        return d * np.log(orig / (turns * 2 * np.pi)) / (2 * np.log(theta))
+    low = max(np.floor(dim_of(float(s.get("beta_fast", 32)))), 0)
+    high = min(np.ceil(dim_of(float(s.get("beta_slow", 1)))), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0, 1)
+    base = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    inv = base / factor * ramp + base * (1 - ramp)
+
+    def m(a):
+        return 1.0 if factor <= 1 or not a else 0.1 * a * np.log(factor) + 1
+    return (inv.astype(np.float32),
+            m(float(s.get("mscale", 1))) / m(float(s.get("mscale_all_dim", 0))),
+            m(float(s.get("mscale_all_dim", 0))) ** 2)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
                theta: float, style: str = "half",
-               rotary_dim: Optional[int] = None) -> jnp.ndarray:
+               rotary_dim: Optional[int] = None,
+               freqs: Optional[np.ndarray] = None,
+               mscale: float = 1.0) -> jnp.ndarray:
     """x: (B, S, H, D); positions: (B, S) absolute token positions.
     ``style`` "half" rotates the pairs ``(i, i + D/2)``, "interleaved" the
     pairs ``(2i, 2i + 1)``; pair ``i`` turns by ``theta^(-2i/D)`` a
-    position in both.  ``rotary_dim`` under ``D``: the first ``rotary_dim``
-    dims are rotated as a head of that width, the rest pass untouched."""
+    position in both, or by ``freqs[i]`` where given, with cos and sin
+    scaled by ``mscale`` (:func:`yarn_rope`).  ``rotary_dim`` under ``D``:
+    the first ``rotary_dim`` dims are rotated as a head of that width, the
+    rest pass untouched."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         turned = apply_rope(x[..., :rotary_dim], positions, theta, style)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
-    inv = jnp.asarray(rope_frequencies(d, theta))          # (D/2,)
+    inv = jnp.asarray(rope_frequencies(d, theta) if freqs is None
+                      else freqs)                      # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * inv   # (B, S, D/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     if style == "interleaved":
         # out[2i] = x[2i] cos_i - x[2i+1] sin_i, out[2i+1] = x[2i+1] cos_i +
         # x[2i] sin_i, with each element's partner brought beside it by a
@@ -1154,14 +1312,268 @@ class SlidingAttention(CausalAttention):
         return ring if max_len >= 2 * ring else max_len
 
 
+def _plain_attention(q, k, v, qpos, kpos, scale: float, dtype):
+    """Softmax attention by plain products: ``q (B, S, H, D)``, ``k (B, T,
+    KV, D)``, ``v (B, T, KV, Dv)`` with ``H / KV`` query heads a K/V head;
+    query at ``qpos (B or 1, S)`` sees key at ``kpos (B or 1, T)`` iff
+    ``kpos <= qpos``.  Scores and softmax in float32, the probabilities
+    cast to ``dtype`` before they meet the values, as the kernels do.
+    -> ``(B, S, H, Dv)``."""
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, S, KV, H // KV, D)
+    logits = jnp.einsum("bskgd,btkd->bkgst", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    seen = (kpos[:, None, :] <= qpos[:, :, None])[:, None, None]
+    logits = jnp.where(seen, logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+    return out.reshape(B, S, H, v.shape[-1])
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2 and V3's MLA; ``axk1``).
+    ``h`` a token's normed residual, ``H`` heads::
+
+        q = rms_q(h W_DQ) W_UQ         per head [q_nope (dn) | q_pe (dr)]
+        [c (r) | k_pe (dr)] = h W_DKV,  c <- rms_kv(c)
+        q_pe, k_pe turned by the rotary embedding (``rope_style``; YaRN where
+            ``rope_scaling`` says so: :func:`yarn_rope`); ONE k_pe for all
+            heads
+        [k_nope_h (dn) | v_h (dv)] = c W_UKV,h,  k_h = [k_nope_h | k_pe]
+        s_h,ij = (q_h,i . k_h,j) * sigma,  sigma = (dn + dr)^-0.5 times
+            YaRN's factor;  causal
+        out = concat_h(softmax_j(s_h) v_h) W_O
+
+    The cache entry is the LATENT ``[c | k_pe]`` by position, one row of
+    :func:`latent_lanes` lanes for all heads (``r + dr`` padded to whole lane
+    tiles: 576 -> 640 at A.X-K1's widths), not heads.  Two forms compute the
+    same attention from it:
+
+    - EXPANDED: the rows are multiplied out to every head's ``k`` and ``v``
+      and attended as ``H`` K/V heads (``prefill_attention`` at keys ``dn +
+      dr`` wide, values ``dv``).  A prefill from position 0 expands its own
+      rows (the training pass too); a tail after a cached prefix expands
+      every row of the slot;
+    - ABSORBED: ``W_UK`` folds into the query, ``q~_h = q_nope_h W_UK,h^T``
+      (``r`` wide), the scores are ``[q~_h | q_pe_h] . [c_j | k_pe_j]``, the
+      values the rows' first ``r`` lanes, and ``W_UV`` unfolds the result,
+      ``o_h = (sum_j p_h,ij c_j) W_UV,h``: one K/V head of the row's width
+      for all ``H`` query heads.  Every decode step runs it
+      (:func:`~synapseml_tpu.models.llm.pallas_attn.latent_decode_attention`
+      on the kernel backends); a tail after a cached prefix runs it where
+      :func:`~synapseml_tpu.models.llm.pallas_attn.latent_prefill_form` says
+      it costs less than expanding the prefix.
+
+    A prefill pass at an offset holds both: ``lax.cond`` on the offset picks
+    the first form at 0 and the tail's form after it, so a bucket is still
+    one program."""
+    cfg: LlamaConfig
+
+    KIND = "latent_attention"
+
+    @classmethod
+    def cache_rows(cls, cfg: LlamaConfig, max_len: int) -> int:
+        return max_len
+
+    @classmethod
+    def projections(cls, cfg: LlamaConfig) -> Tuple[Tuple[str, int], ...]:
+        """The query's up-projection and the output projection: the two
+        products that meet a split into heads or their merge."""
+        return (("q", cfg.q_lora_rank or cfg.d_model),
+                ("o", cfg.num_heads * cfg.v_head_dim))
+
+    @classmethod
+    def cache_entry(cls, cfg: LlamaConfig, batch: int, max_len: int) -> Dict:
+        return {"latent": jnp.zeros((batch, max_len, latent_lanes(cfg)),
+                                    cfg.dtype)}
+
+    @staticmethod
+    def scale(cfg: LlamaConfig) -> float:
+        """``sigma``: ``(dn + dr)^-0.5``, times YaRN's factor where set."""
+        s = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+        if cfg.rope_scaling is not None:
+            s *= yarn_rope(cfg.qk_rope_head_dim, cfg.rope_theta,
+                           cfg.rope_scaling)[2]
+        return float(s)
+
+    @staticmethod
+    def geometry(cfg: LlamaConfig, form: str, S: int, T: int):
+        """The prefill kernel's tile for one form of a pass of ``S`` queries
+        over ``T`` key rows (``prefill_geometry``; None: plain products)."""
+        from .pallas_attn import prefill_geometry
+        H = cfg.num_heads
+        if form == "absorbed":
+            return prefill_geometry(S, T, H, 1, latent_lanes(cfg),
+                                    cfg.kv_lora_rank, cfg.dtype)
+        return prefill_geometry(S, T, H, H, cfg.qk_nope_head_dim
+                                + cfg.qk_rope_head_dim, cfg.v_head_dim,
+                                cfg.dtype)
+
+    @staticmethod
+    def tail_form(cfg: LlamaConfig, S: int, T: int) -> str:
+        """The form of a pass of ``S`` queries after a cached prefix in an
+        entry of ``T`` rows."""
+        from .pallas_attn import latent_prefill_form
+        return latent_prefill_form(S, T, cfg.num_heads, cfg.kv_lora_rank,
+                                   cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                                   cfg.v_head_dim, latent_lanes(cfg))
+
+    @nn.compact
+    def __call__(self, x, positions, cache: Optional[Dict],
+                 cache_index: Optional[jnp.ndarray],
+                 slot_mask: Optional[jnp.ndarray] = None,
+                 attention_backend: str = "dense",
+                 paged_tile: Optional[Any] = None,
+                 valid_len: Optional[jnp.ndarray] = None):
+        cfg = self.cfg
+        B, S, _ = x.shape
+        H, dn, dr, dv, r = (cfg.num_heads, cfg.qk_nope_head_dim,
+                            cfg.qk_rope_head_dim, cfg.v_head_dim,
+                            cfg.kv_lora_rank)
+        L, dt = latent_lanes(cfg), cfg.dtype
+        # the kernels serve passes over a cache; a pass without one
+        # (training) takes plain products, as a full layer's does
+        kernels = attention_backend in ("paged", "interpret") \
+            and cache is not None
+        interpret = attention_backend == "interpret"
+        if cfg.q_lora_rank:
+            cq = RMSNorm(cfg.rms_norm_eps, dt, name="q_a_norm")(_dense(
+                cfg.q_lora_rank, ("embed", None), "q_a_proj", dt)(x))
+            q = _project(cq, H * (dn + dr), (None, "heads"), "q_b_proj",
+                         cfg, "q")
+        else:
+            q = _project(x, H * (dn + dr), ("embed", "heads"), "q_proj",
+                         cfg, "q")
+        q = q.reshape(B, S, H, dn + dr)
+        kv = _dense(r + dr, ("embed", None), "kv_a_proj", dt)(x)
+        c = RMSNorm(cfg.rms_norm_eps, dt, name="kv_a_norm")(kv[..., :r])
+        freqs, mscale = None, 1.0
+        if cfg.rope_scaling is not None:
+            freqs, mscale, _ = yarn_rope(dr, cfg.rope_theta, cfg.rope_scaling)
+
+        def turn(a):
+            return apply_rope(a, positions, cfg.rope_theta, cfg.rope_style,
+                              freqs=freqs, mscale=mscale)
+        q_nope, q_pe = q[..., :dn], turn(q[..., dn:])
+        k_pe = turn(kv[:, :, None, r:])[:, :, 0]
+        w = self.param("kv_b_proj", nn.with_partitioning(
+            nn.initializers.truncated_normal(0.02), (None, "heads", None)),
+            (r, H, dn + dv)).astype(dt)
+        w_uk, w_uv = w[..., :dn], w[..., dn:]
+        scale = self.scale(cfg)
+        # this pass's latent rows as the entry lays them
+        row = jnp.concatenate(
+            [c, k_pe, jnp.zeros((B, S, L - r - dr), dt)], -1).astype(dt)
+
+        def expanded(rows, start, T):
+            """Every head's k and v from ``rows (B, T, L)`` (key ``j`` at
+            position ``j``), queries at ``start + s``."""
+            k = jnp.einsum("btc,chd->bthd", rows[..., :r], w_uk)
+            k = jnp.concatenate([k, jnp.broadcast_to(
+                rows[:, :, None, r:r + dr], (B, T, H, dr))], -1)
+            v = jnp.einsum("btc,chd->bthd", rows[..., :r], w_uv)
+            qe = jnp.concatenate([q_nope, q_pe], -1)
+            geo = self.geometry(cfg, "expanded", S, T) if kernels else None
+            return self._attend(qe, k, v, start, T, geo, scale, valid_len,
+                                interpret).reshape(B, S, H, dv)
+
+        def folded():
+            """``[q_nope W_UK^T | q_pe | zeros]``: a query as wide as a row."""
+            return jnp.concatenate([
+                jnp.einsum("bshd,chd->bshc", q_nope, w_uk).astype(dt), q_pe,
+                jnp.zeros((B, S, H, L - r - dr), dt)], -1)
+
+        def absorbed(rows, start, T):
+            geo = self.geometry(cfg, "absorbed", S, T) if kernels else None
+            u = self._attend(folded(), rows[:, :, None], rows[:, :, None, :r],
+                             start, T, geo, scale, valid_len, interpret)
+            return jnp.einsum("bshc,chd->bshd", u.reshape(B, S, H, r), w_uv)
+
+        new_cache = None
+        if cache is None:
+            out = expanded(row, 0, S)
+        elif jnp.ndim(cache_index) == 0:
+            # a prefill pass at an offset: its rows land by position, then
+            # the first pass attends over its own rows, a tail over the slot's
+            rows = jax.lax.dynamic_update_slice(cache["latent"], row,
+                                                (0, cache_index, 0))
+            new_cache = {"latent": rows}
+            T = rows.shape[1]
+            tail = absorbed if self.tail_form(cfg, S, T) == "absorbed" \
+                else expanded
+            # a pass as long as the entry can only start at 0
+            out = expanded(row, 0, S) if S >= T else jax.lax.cond(
+                cache_index == 0, lambda: expanded(row, 0, S),
+                lambda: tail(rows, cache_index, T))
+        else:
+            # per-slot positions (decode, a verify span): rows written by
+            # the gated scatter a full layer uses, the absorbed form read
+            wpos = cache_index[:, None] + jnp.arange(S)[None, :]
+            bidx = jnp.arange(B)[:, None]
+            w_row = row
+            if slot_mask is not None:
+                w_row = jnp.where(slot_mask[:, None, None], row,
+                                  cache["latent"][bidx, wpos])
+            rows = cache["latent"].at[bidx, wpos].set(w_row)
+            new_cache = {"latent": rows}
+            qa = folded()
+            if kernels:
+                from .pallas_attn import latent_decode_attention, \
+                    paged_geometry
+                tile = dict(paged_tile)[self.KIND] \
+                    if isinstance(paged_tile, tuple) else paged_tile
+                if tile is None:            # a direct apply: the default
+                    tile = paged_geometry(rows.shape[1], H, 1, r + dr, dt,
+                                          max_query_span=S, latent=True).tile
+                # the last query's key count (``paged_decode_attention``'s)
+                spans = positions[:, -1].astype(jnp.int32) + 1
+                u = latent_decode_attention(
+                    qa, rows, spans, tile=int(tile), rank=r, scale=scale,
+                    interpret=interpret)
+            else:
+                T = rows.shape[1]
+                u = _plain_attention(qa, rows[:, :, None],
+                                     rows[:, :, None, :r], positions,
+                                     jnp.arange(T)[None], scale, dt)
+            out = jnp.einsum("bshc,chd->bshd", u, w_uv)
+        out = out.reshape(B, S, H * dv).astype(dt)
+        out = _dense(cfg.d_model, ("heads", "embed"), "o_proj", dt)(
+            _fold_barrier(out, H * dv, "o"))
+        return out, new_cache
+
+    @staticmethod
+    def _attend(q, k, v, start, T, geo, scale, valid_len, interpret):
+        """One prefill pass's attention, queries at ``start + s`` over key
+        rows ``0 .. T - 1``: the prefill kernel at ``geo``, else plain
+        products.  -> ``(B, S, H * Dv)``."""
+        B, S = q.shape[:2]
+        if geo is not None:
+            from .pallas_attn import prefill_attention
+            return prefill_attention(
+                q, k, v, start, S if valid_len is None else valid_len,
+                bq=geo.bq, bk=geo.bk, scale=scale, interpret=interpret)
+        qpos = (start + jnp.arange(S))[None]
+        out = _plain_attention(q, k, v, qpos, jnp.arange(T)[None], scale,
+                               q.dtype)
+        return out.reshape(B, S, -1)
+
+
+def latent_lanes(cfg: LlamaConfig) -> int:
+    """Lanes of a latent cache row: ``kv_lora_rank + qk_rope_head_dim``
+    rounded up to whole tiles of 128 (576 -> 640: the last 64 are zeros)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
 #: mixer kind (a ``layer_types`` entry) -> its class; each declares its
 #: own cache entry and takes the same call
 MIXERS = {"full_attention": CausalAttention,
           "sliding_attention": SlidingAttention,
-          "linear_attention": GatedDeltaNet}
+          "linear_attention": GatedDeltaNet,
+          "latent_attention": LatentAttention}
 #: the name of a kind's parameters inside a block
 _MIXER_NAME = {"full_attention": "attn", "sliding_attention": "attn",
-               "linear_attention": "gdn"}
+               "linear_attention": "gdn", "latent_attention": "attn"}
 
 
 def _swiglu(cfg: LlamaConfig, h, valid, backend):

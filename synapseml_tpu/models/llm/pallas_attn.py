@@ -135,7 +135,8 @@ def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
                    max_query_span: int = 1,
                    tile: Optional[int] = None, *,
                    d_value: Optional[int] = None, pack: int = 1,
-                   most: Optional[int] = None) -> Optional[PagedGeometry]:
+                   most: Optional[int] = None,
+                   latent: bool = False) -> Optional[PagedGeometry]:
     """The VMEM gate: pick the key-tile length for a
     ``(max_len, num_kv_heads, d_head)`` cache row, or None when no
     geometry fits (the 'auto' backend then stays dense — the
@@ -172,7 +173,12 @@ def paged_geometry(max_len: int, num_heads: int, num_kv_heads: int,
     packed row holds side by side (``model.kv_pack``: the lanes are then
     ``pack`` widths, the flat rows ``num_kv_heads / pack`` a position), and
     ``most`` the largest tile allowed (a ring's block: the position tiles a
-    step walks must be distinct tiles of the ring)."""
+    step walks must be distinct tiles of the ring).  ``latent``: the cache
+    is latent rows, ``d_head`` wide, for :func:`latent_decode_attention`
+    (:func:`_latent_geometry`)."""
+    if latent:
+        return _latent_geometry(max_len, num_heads, d_head, dtype,
+                                max_query_span, tile)
     itemsize = np.dtype(dtype).itemsize
     sub = _sublane(dtype)
     s = max(1, int(max_query_span))
@@ -635,6 +641,214 @@ def paged_decode_attention(q: jnp.ndarray,      # (B, H, D) | (B, S, H, D)
 
 
 # ---------------------------------------------------------------------------
+# decode over latent rows
+# ---------------------------------------------------------------------------
+
+#: a latent tile is keys and values at once, so it takes the bytes of the
+#: paged kernel's K tile and V tile together
+_LATENT_TILE_BYTES = 2 * _TILE_BYTES
+
+
+def _latent_geometry(max_len: int, num_heads: int, width: int, dtype: Any,
+                     max_query_span: int = 1, tile: Optional[int] = None
+                     ) -> Optional[PagedGeometry]:
+    """:func:`paged_geometry` for :func:`latent_decode_attention`: rows of
+    ``width`` lanes padded to 128, ``S * H`` query rows; the value is the
+    row's first lanes, so the ring holds one tile a buffer and the
+    accumulator is counted at the row's width.  Of the candidates that fit,
+    the largest whose tile is at most ``_LATENT_TILE_BYTES``."""
+    itemsize = np.dtype(dtype).itemsize
+    sub = _sublane(dtype)
+    lanes = _pad(width, 128)
+    q_rows = _pad(max(1, int(max_query_span)) * num_heads, 8)
+
+    def need(cand):
+        return (_RING * _pad(cand, sub) * lanes * itemsize       # the ring
+                + 4 * q_rows * lanes * itemsize                 # q, out x2
+                + q_rows * lanes * 4 + 2 * q_rows * 128 * 4     # acc, m, l
+                + q_rows * _pad(cand, 128) * (4 + 4 + itemsize))  # scores, p
+
+    fits = [c for c in (_TILE_CANDIDATES if tile is None else (int(tile),))
+            if c > 0 and c % sub == 0 and max_len % c == 0
+            and c <= max_len // 2 and need(c) <= _VMEM_BUDGET]
+    if not fits:
+        return None
+    small = [c for c in fits if c * lanes * itemsize <= _LATENT_TILE_BYTES]
+    cand = small[0] if small else fits[-1]
+    return PagedGeometry(cand, max_len // cand, need(cand))
+
+
+def _make_latent_kernel(s_len: int, heads: int, q_rows: int, tile: int,
+                        total_tiles: int, v_lanes: int, scale: float):
+    """Grid ``(n_slots,)``; q block ``(1, q_rows, lanes)`` (query position
+    ``j``'s head ``h`` at row ``j * heads + h``, rows past ``S * heads``
+    padding), the latent rows whole in HBM ``(n_slots, max_len, lanes)``, a
+    ring of ``_RING`` tiles and the DMA cursor as in
+    :func:`_make_decode_kernel`.  All query rows against a tile in ONE
+    contraction over its lanes; the values are the tile's first
+    ``v_lanes`` lanes, the same bytes, fetched once."""
+    neg = float(np.finfo(np.float32).min)
+
+    def kernel(spans_ref, q_ref, c_hbm, o_ref, cbuf, sem, cur_ref, acc_ref,
+               m_ref, l_ref):
+        s = pl.program_id(0)
+        n_slots = pl.num_programs(0)
+        span = spans_ref[s]
+
+        def live_tiles(slot):
+            return jnp.clip(lax.div(spans_ref[slot] + (tile - 1), tile), 1,
+                            total_tiles)
+
+        def copy(slot, t, buf):
+            return pltpu.make_async_copy(c_hbm.at[slot, pl.ds(t * tile, tile)],
+                                         cbuf.at[buf], sem.at[buf])
+
+        def issue():
+            issued, slot, t = cur_ref[1], cur_ref[2], cur_ref[3]
+
+            @pl.when(slot < n_slots)
+            def _():
+                copy(slot, t, lax.rem(issued, _RING)).start()
+            last = t + 1 >= live_tiles(jnp.minimum(slot, n_slots - 1))
+            cur_ref[1] = issued + 1
+            cur_ref[2] = jnp.where(last, slot + 1, slot)
+            cur_ref[3] = jnp.where(last, 0, t + 1)
+
+        @pl.when(s == 0)
+        def _first():
+            for i in range(4):
+                cur_ref[i] = 0
+            for _ in range(_RING - 1):
+                issue()
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, neg)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        # query j attends keys < span - (S - 1) + j (the in-span causal
+        # mask); a padding row is the last query's
+        r = lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0)
+        limit = span - (s_len - 1) + jnp.minimum(r // heads, s_len - 1)
+        col = lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        q = q_ref[0]
+
+        def tile_body(t, carry):
+            issue()
+            buf = lax.rem(cur_ref[0], _RING)
+            copy(s, t, buf).wait()
+            rows = cbuf[buf]                                 # (tile, lanes)
+            logits = lax.dot_general(
+                q, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (q_rows, tile)
+            logits = jnp.where(t * tile + col < limit, logits, neg)
+            m_prev = m_ref[:, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)
+            pv = lax.dot_general(
+                p.astype(rows.dtype), rows[:, :v_lanes],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            acc_ref[...] = acc_ref[...] * alpha + pv
+            l_ref[:, 0:1] = l_ref[:, 0:1] * alpha \
+                + jnp.sum(p, -1, keepdims=True)
+            m_ref[:, 0:1] = m_new
+            cur_ref[0] = cur_ref[0] + 1
+            return carry
+
+        lax.fori_loop(0, live_tiles(s), tile_body, 0)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, 0:1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "rank", "scale",
+                                             "interpret"))
+def latent_decode_attention(q: jnp.ndarray,       # (B, S, H, lanes)
+                            rows: jnp.ndarray,    # (B, max_len, lanes)
+                            spans: jnp.ndarray,   # (B,) int32
+                            *, tile: int, rank: int, scale: float,
+                            interpret: bool = False) -> jnp.ndarray:
+    """Latent attention's decode step in its absorbed form (``model
+    .LatentAttention``), reading each slot's live latent rows only: ->
+    ``(B, S, H, rank)`` in ``q.dtype``, ``sum_j p_h,ij c_j``.
+
+    ``q`` is ``[q~_h | q_pe_h | zeros]`` a query head, as wide as a cache row;
+    the score of key ``j`` is ``q . rows[j] * scale`` and the value its first
+    ``rank`` lanes (``c_j``).  ``spans`` as :func:`paged_decode_attention`
+    takes them: the LAST query attends keys ``[0, spans[b])``, the rows of
+    this step already written.  Grid ``(n_slots,)``: a slot's ``ceil(span /
+    tile)`` live tiles arrive through a ring of ``_RING`` VMEM buffers whose
+    DMA cursor runs over slot boundaries (the paged kernel's walk), and all ``S * H``
+    query rows meet a tile in one contraction over its lanes, products in
+    float32, operands in the cache's dtype, the probabilities cast to it
+    before they meet the values.  Its name in a device trace is
+    ``latent_decode_attention``."""
+    B, S, H, lanes = q.shape
+    T = rows.shape[1]
+    assert rows.shape[-1] == lanes and lanes % 128 == 0, (q.shape, rows.shape)
+    q_rows = _pad(S * H, 8)
+    v_lanes = min(_pad(rank, 128), lanes)
+    q2 = q.reshape(B, S * H, lanes)
+    if q_rows != S * H:
+        q2 = jnp.pad(q2, ((0, 0), (0, q_rows - S * H), (0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, q_rows, lanes), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, q_rows, v_lanes), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((_RING, tile, lanes), rows.dtype),   # the ring
+            pltpu.SemaphoreType.DMA((_RING,)),
+            pltpu.SMEM((4,), jnp.int32),                    # the DMA cursor
+            pltpu.VMEM((q_rows, v_lanes), jnp.float32),     # accumulator
+            pltpu.VMEM((q_rows, 128), jnp.float32),         # running max
+            pltpu.VMEM((q_rows, 128), jnp.float32),         # normaliser
+        ])
+    out = pl.pallas_call(
+        _make_latent_kernel(S, H, q_rows, tile, T // tile, v_lanes,
+                            float(scale)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, q_rows, v_lanes), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="latent_decode_attention",
+        interpret=interpret,
+    )(spans.astype(jnp.int32), q2, rows)
+    return out[:, :S * H, :rank].reshape(B, S, H, rank)
+
+
+#: the absorbed form's cost a product against the expanded form's: what
+#: :func:`latent_prefill_form` weighs the two counts by (PERF.md section 6
+#: has the chip's readings of both forms)
+_ABSORBED_COST = 1.0
+
+
+def latent_prefill_form(S: int, T: int, H: int, rank: int, d_nope: int,
+                        d_rope: int, d_v: int, lanes: int) -> str:
+    """Which form of latent attention a prefill pass of ``S`` queries after
+    a cached prefix takes, over an entry of ``T`` rows: ``"absorbed"`` where
+    its products, weighed by ``_ABSORBED_COST``, are fewer than the expanded
+    form's, else ``"expanded"``.  The one home of the rule
+    (``model.LatentAttention`` and the engine's span both ask it).
+
+    Expanded: every row multiplied out to ``H`` heads' keys and values
+    (``T * rank * H * (d_nope + d_v)`` products), then ``S x T`` scores and
+    values a head at the keys' and values' widths.  Absorbed: the query
+    folded and the result unfolded (``2 * S * H * rank * d`` products), then
+    ``S x T`` scores over a row's ``lanes`` and values over ``rank`` a head.
+    The expansion grows with the prefix alone, the absorbed scores with
+    the tail times the prefix: at equal cost absorbed below ``S* = T rank
+    (d_nope + d_v) / (T (lanes + rank - d_nope - d_rope - d_v) + rank (d_nope
+    + d_v))`` queries, 156 at A.X-K1's widths over 17,920 rows."""
+    expand = 2 * T * rank * H * (d_nope + d_v) \
+        + 2 * S * T * H * (d_nope + d_rope + d_v)
+    absorb = 2 * S * H * rank * (d_nope + d_v) \
+        + 2 * S * T * H * (lanes + rank)
+    return "absorbed" if absorb * _ABSORBED_COST < expand else "expanded"
+
+
+# ---------------------------------------------------------------------------
 # prefill: one tiled causal kernel
 # ---------------------------------------------------------------------------
 
@@ -727,20 +941,30 @@ def prefill_geometry(S: int, T: int, H: int, KV: int, D: int, Dv: int,
         return None
     d_pad, v_pad = _pad(D, 128), _pad(Dv, 128)
 
-    def need(bq):
+    def need(bq, bk):
         rows = G * bq
         return (2 * rows * (d_pad + v_pad) * itemsize       # q + out, x2 buf
                 + 2 * _pad(bk, sub) * (d_pad + v_pad) * itemsize  # K + V x2
                 + rows * v_pad * 4 + 2 * rows * 128 * 4      # acc, m, l
                 + rows * _pad(bk, 128) * (4 + 4 + itemsize))  # scores, p
 
-    bq = _pow2_divisor(S, max(sub, min(512, _PREFILL_ROWS // G)), sub)
-    while bq is not None and need(bq) > _VMEM_BUDGET:
-        bq = _pow2_divisor(S, bq // 2, sub) if bq // 2 >= sub else None
+    def fit(bk):
+        bq = _pow2_divisor(S, max(sub, min(512, _PREFILL_ROWS // G)), sub)
+        while bq is not None and need(bq, bk) > _VMEM_BUDGET:
+            bq = _pow2_divisor(S, bq // 2, sub) if bq // 2 >= sub else None
+        return bq
+
+    bq = fit(bk)
+    # where no query block fits beside the widest key block (a row of 640
+    # lanes for 64 query heads: latent attention's absorbed form), narrower
+    # key blocks, while they keep the 128 lanes
+    while bq is None and bk // 2 >= _PREFILL_MIN_KEYS and T % (bk // 2) == 0:
+        bk //= 2
+        bq = fit(bk)
     if bq is None:
         return None
     return PrefillGeometry(bq, bk, _prefill_key_steps(T, bq, bk, window),
-                           need(bq))
+                           need(bq, bk))
 
 
 def _prefill_block_bounds(i, bq: int, bk: int, window: Optional[int],
@@ -776,7 +1000,8 @@ def prefill_key_blocks(geo: PrefillGeometry, S: int, start: int, plen: int,
 
 def _make_prefill_kernel(group: int, bq: int, bk: int,
                          key_steps: int, d_head: int,
-                         window: Optional[int], sink: bool, offset: bool):
+                         window: Optional[int], sink: bool, offset: bool,
+                         scale: Optional[float] = None):
     """ONE query block: grid ``(B, KV, key_steps)``, the key axis innermost.
     Blocks: ``q (1, 1, G, bq, D)`` (row ``g * bq + s`` of the score block
     is query head ``g`` of the group at the block's position ``s``), ``k
@@ -789,7 +1014,7 @@ def _make_prefill_kernel(group: int, bq: int, bk: int,
     with no real query takes none."""
     neg = float(np.finfo(np.float32).min)
     rows = group * bq
-    scale = 1.0 / np.sqrt(d_head)
+    scale = 1.0 / np.sqrt(d_head) if scale is None else scale
 
     def kernel(scal, q_ref, k_ref, v_ref, *refs):
         sink_ref = refs[0] if sink else None
@@ -870,7 +1095,7 @@ def _make_prefill_kernel(group: int, bq: int, bk: int,
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "d_head", "window",
-                                             "offset", "interpret"))
+                                             "offset", "interpret", "scale"))
 def prefill_query_block(scal: jnp.ndarray,     # (3,) int32
                         qt: jnp.ndarray,       # (B, KV, G, bq, D)
                         kt: jnp.ndarray,       # (B, KV, T, D)
@@ -878,7 +1103,8 @@ def prefill_query_block(scal: jnp.ndarray,     # (3,) int32
                         sink_rows: Optional[jnp.ndarray] = None, *,
                         bk: int, d_head: int, window: Optional[int] = None,
                         offset: bool = False,
-                        interpret: bool = False) -> jnp.ndarray:
+                        interpret: bool = False,
+                        scale: Optional[float] = None) -> jnp.ndarray:
     """The prefill kernel over ONE block of ``bq`` query positions, K/V-head
     major, lanes padded: -> ``(B, KV, G, bq, Dv)``.  ``scal``: the key row
     of the block's first query, how many of its queries are real, the first
@@ -913,7 +1139,7 @@ def prefill_query_block(scal: jnp.ndarray,     # (3,) int32
                                      lambda b, h, j, s: (h, 0, 0)))
     return pl.pallas_call(
         _make_prefill_kernel(G, bq, bk, key_steps, d_head, window,
-                             sink_rows is not None, offset),
+                             sink_rows is not None, offset, scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, KV, key_steps),
@@ -934,7 +1160,7 @@ def prefill_query_block(scal: jnp.ndarray,     # (3,) int32
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk", "kv_heads",
-                                             "window", "interpret"))
+                                             "window", "interpret", "scale"))
 def prefill_attention(q: jnp.ndarray,          # (B, S, H, D)
                       k: jnp.ndarray,          # (B, T, KV.., D)
                       v: jnp.ndarray,          # (B, T, KV.., Dv)
@@ -945,7 +1171,8 @@ def prefill_attention(q: jnp.ndarray,          # (B, S, H, D)
                       window: Optional[int] = None,
                       sink: Optional[jnp.ndarray] = None,
                       key_offset: Optional[jnp.ndarray] = None,
-                      interpret: bool = False) -> jnp.ndarray:
+                      interpret: bool = False,
+                      scale: Optional[float] = None) -> jnp.ndarray:
     """Causal softmax attention of one prefill pass as one tiled kernel:
     -> ``(B, S, H * Dv)`` in ``q.dtype``.
 
@@ -972,7 +1199,7 @@ def prefill_attention(q: jnp.ndarray,          # (B, S, H, D)
     ``plen`` none at all: a bucket pays for its real tokens.  ``bq``/``bk``
     come from :func:`prefill_geometry`.  A width that is no multiple of
     128 lanes is padded to one here (the copy into K/V-head-major order
-    carries it)."""
+    carries it).  ``scale``: the scores' factor (None: ``D^-0.5``)."""
     B, S, H, D = q.shape
     T, Dv = k.shape[1], v.shape[-1]
     KV = int(kv_heads or k.shape[2])
@@ -1006,7 +1233,7 @@ def prefill_attention(q: jnp.ndarray,          # (B, S, H, D)
         return prefill_query_block(
             scal, lax.dynamic_slice_in_dim(qt, i * bq, bq, axis=3), kt, vt,
             sink_rows, bk=bk, d_head=D, window=window,
-            offset=key_offset is not None, interpret=interpret)
+            offset=key_offset is not None, interpret=interpret, scale=scale)
 
     out = lax.map(block, jnp.arange(S // bq, dtype=jnp.int32))
     # (S / bq, B, KV, G, bq, Dv) -> (B, S / bq, bq, KV, G, Dv)

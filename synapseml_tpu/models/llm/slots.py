@@ -443,11 +443,16 @@ class _KindCache:
     #: included), for the kernel's byte ledger
     row_heads: int
     geo: Optional[PagedGeometry] = None
+    #: latent rows (``model.LatentAttention``): one head, ``d_key`` the
+    #: latent's width, no separate value (the row's first lanes)
+    latent: bool = False
 
     @property
     def geometry_args(self) -> Dict[str, Any]:
         """:func:`paged_geometry`'s keywords for this kind."""
         kw: Dict[str, Any] = {}
+        if self.latent:
+            kw.update(latent=True)
         if self.packed:
             kw.update(d_value=self.d_value, pack=self.pack)
         if self.ring:
@@ -460,7 +465,9 @@ class _KindCache:
 
     def held_row_bytes(self, itemsize: int) -> int:
         """K and V bytes the cache holds a position a layer (a row's padding
-        heads included)."""
+        heads, and a latent row's padding lanes, included)."""
+        if self.latent:
+            return -(-self.d_key // 128) * 128 * itemsize
         return self.row_heads * (self.d_key + self.d_value) * itemsize
 
 
@@ -526,7 +533,7 @@ class SlotEngine:
         #: every attention entry is ``(slots, max_len, heads, d_head)`` rows
         #: by position, which the host arena and a prefill worker slice
         self.kv_by_position = not self.recurrent and not any(
-            kc.ring or kc.packed for kc in self._kinds)
+            kc.ring or kc.packed or kc.latent for kc in self._kinds)
         #: the model has expert layers: its programs return two counts
         #: with their tokens (:func:`_apply`)
         self.experts = self.cfg.has_experts
@@ -556,9 +563,10 @@ class SlotEngine:
         if kv_arena is not None and not self.kv_by_position:
             raise ValueError(
                 "kv_arena over a cache that is not rows by position (window "
-                "layers on a ring, or kinds that keep packed rows): the host "
-                "arena spills and restores a slot's rows [0, span), which a "
-                "ring no longer holds and a packed entry lays out otherwise")
+                "layers on a ring, kinds that keep packed rows, or latent "
+                "rows): the host arena spills and restores a slot's K/V rows "
+                "[0, span), which a ring no longer holds and a packed or "
+                "latent entry lays out otherwise")
         if self.attention_backend != "dense":
             self._kinds = [dataclasses.replace(kc, geo=paged_geometry(
                 kc.rows, self.cfg.num_heads, kc.kv_heads, kc.d_key,
@@ -754,9 +762,17 @@ class SlotEngine:
             "Pallas kernel on every attention layer kind; dense: the plain "
             "scores on every kind; mixed: the kernel on the kinds whose "
             "shape has a tile)", ("engine", "path"))
-        #: bucket -> what its prefill program's attention runs as
-        #: (:meth:`_prefill_plan`)
-        self._prefill_plans: Dict[int, Tuple[str, Tuple]] = {}
+        #: (bucket, from position 0) -> what a prefill pass's attention runs
+        #: as (:meth:`_prefill_plan`)
+        self._prefill_plans: Dict[Tuple[int, bool], Tuple[str, Tuple]] = {}
+        self._m_latent_prefill = reg.counter(
+            "llm_latent_prefill_total",
+            "prefill passes of a model with latent attention layers by the "
+            "form their attention took: cold (from position 0, the pass's "
+            "own rows expanded), expanded (a tail after a cached prefix, "
+            "every row of the slot expanded to all heads), absorbed (a tail "
+            "after a cached prefix over the latent rows themselves)",
+            ("engine", "form"))
         #: the last prefill pass (bucket, start, real tokens), for its span
         self._last_prefill: Tuple[int, int, int] = (0, 0, 0)
         self._m_expert_pairs = reg.counter(
@@ -899,6 +915,14 @@ class SlotEngine:
         layer order (none for a model of linear-attention layers alone)."""
         cfg, out = self.cfg, []
         for kind in cfg.attention_layer_kinds:
+            if kind == "latent_attention":
+                width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+                out.append(_KindCache(
+                    kind=kind, layers=cfg.layer_kinds.count(kind), window=None,
+                    rows=self.max_len, ring=False, kv_heads=1, d_key=width,
+                    d_value=0, packed=False, pack=1, row_heads=1,
+                    latent=True))
+                continue
             a = cfg.attention(kind)
             rows = MIXERS[kind].cache_rows(cfg, self.max_len)
             window = cfg.sliding_window if kind == "sliding_attention" \
@@ -924,7 +948,8 @@ class SlotEngine:
         """``_copy_prefix_jit``'s ``length`` for a prefix of ``positions``
         tokens: the count itself where every entry is rows by position, else
         each entry's count of rows (a packed entry's flat rows; a ring
-        whole: it is copied as it stands)."""
+        whole: it is copied as it stands; a latent entry's rows by
+        position)."""
         if self.kv_by_position or self.recurrent:
             return positions
         by_kind = {kc.kind: kc for kc in self._kinds}
@@ -933,7 +958,7 @@ class SlotEngine:
             kc = by_kind[kind]
             per = (kc.kv_heads // kc.pack) if kc.packed else 1
             n = kc.rows * per if kc.ring else positions * per
-            out.append({"k": n, "v": n})
+            out.append({"latent": n} if kc.latent else {"k": n, "v": n})
         return out
 
     # -- capacity ----------------------------------------------------------
@@ -1244,31 +1269,59 @@ class SlotEngine:
                 else {None: "cold", "recurrent_state": "cold_recurrent",
                       "ring_overwritten": "cold_ring"}[skipped])
 
-    def _prefill_plan(self, pb: int) -> Tuple[str, Tuple]:
-        """How the prefill program of bucket ``pb`` runs its attention:
-        ``(path, ((kind record, tile), ...))``, the tiles those the model
-        itself asks :func:`prefill_geometry` for (a ring's keys are its rows
-        before the pass and the pass).  ``path``: ``tiled`` where every
-        kind has a tile, ``dense`` where none (always, off the kernel
-        backends), else ``mixed``."""
-        plan = self._prefill_plans.get(pb)
+    def _latent_form(self, pb: int, start: int) -> str:
+        """The form of latent attention a pass of bucket ``pb`` from
+        ``start`` takes (``model.LatentAttention``: ``cold`` from 0, else
+        the tail's by :func:`~synapseml_tpu.models.llm.pallas_attn
+        .latent_prefill_form`)."""
+        return "cold" if start == 0 else MIXERS["latent_attention"].tail_form(
+            self.cfg, pb, self.max_len)
+
+    def _prefill_plan(self, pb: int, start: int = 0) -> Tuple[str, Tuple]:
+        """How a prefill pass of bucket ``pb`` from ``start`` runs its
+        attention: ``(path, ((kind record, tile), ...))``, the tiles those
+        the model itself asks :func:`prefill_geometry` for (a ring's keys are
+        its rows before the pass and the pass; a latent kind's by its form,
+        over the pass's own rows from 0, else over the slot's).  ``path``:
+        ``tiled`` where every kind has a tile, ``dense`` where none (always,
+        off the kernel backends), else ``mixed``."""
+        key = (pb, start == 0 and self._latent_kind is not None)
+        plan = self._prefill_plans.get(key)
         if plan is None:
             tiles = () if self.attention_backend == "dense" else tuple(
-                (kc, prefill_geometry(
-                    pb, kc.rows + (pb if kc.ring else 0), self.cfg.num_heads,
-                    kc.kv_heads, kc.d_key, kc.d_value, self.cfg.dtype,
-                    kc.window)) for kc in self._kinds)
+                (kc, self._pass_geometry(kc, pb, start)) for kc in self._kinds)
             tiled = [geo is not None for _, geo in tiles]
             plan = ("tiled" if tiled and all(tiled) else
                     "mixed" if any(tiled) else "dense", tiles)
-            self._prefill_plans[pb] = plan
+            self._prefill_plans[key] = plan
         return plan
 
+    def _pass_geometry(self, kc: _KindCache, pb: int, start: int):
+        """The prefill kernel's tile for kind ``kc`` in a pass of bucket
+        ``pb`` from ``start``, as the model asks for it (None: plain)."""
+        if kc.latent:
+            form = self._latent_form(pb, start)
+            return MIXERS[kc.kind].geometry(
+                self.cfg, "expanded" if form == "cold" else form, pb,
+                pb if form == "cold" else kc.rows)
+        return prefill_geometry(
+            pb, kc.rows + (pb if kc.ring else 0), self.cfg.num_heads,
+            kc.kv_heads, kc.d_key, kc.d_value, self.cfg.dtype, kc.window)
+
+    @property
+    def _latent_kind(self) -> Optional[_KindCache]:
+        """The latent attention kind's record, where the model has one."""
+        return next((kc for kc in self._kinds if kc.latent), None)
+
     def _account_prefill(self, pb: int, start: int, plen: int) -> None:
-        """Count one prefill pass by its attention's path and keep what its
-        span will say (:meth:`_prefill_attention_attrs`)."""
+        """Count one prefill pass by its attention's path (and a latent
+        kind's form) and keep what its span will say
+        (:meth:`_prefill_attention_attrs`)."""
         self._m_prefill_attn.inc(1, engine=self.name,
-                                 path=self._prefill_plan(pb)[0])
+                                 path=self._prefill_plan(pb, start)[0])
+        if self._latent_kind is not None:
+            self._m_latent_prefill.inc(1, engine=self.name,
+                                       form=self._latent_form(pb, start))
         self._last_prefill = (pb, start, plen)
 
     def _prefill_attention_attrs(self) -> Dict[str, Any]:
@@ -1279,19 +1332,30 @@ class SlotEngine:
         real tokens, the bucket and the geometry, as ``paged_tiles_live``
         is.  Computed only for a span that is recorded."""
         pb, start, plen = self._last_prefill
-        path, tiles = self._prefill_plan(pb)
+        path, tiles = self._prefill_plan(pb, start)
         visited = bucket = 0
         for kc, geo in tiles:
             if geo is None:
                 continue
             off = start - kc.rows if kc.ring else None
+            # a latent pass from 0 attends over its own rows, from 0
+            at = 0 if kc.latent and start == 0 else start
             visited += kc.layers * prefill_key_blocks(
-                geo, pb, start, plen, kc.window, off)
+                geo, pb, at, plen, kc.window, off)
             bucket += kc.layers * prefill_key_blocks(
-                geo, pb, start, pb, kc.window, off)
-        return {"prefill_attention": path,
-                "prefill_key_blocks_visited": visited,
-                "prefill_key_blocks_bucket": bucket}
+                geo, pb, at, pb, kc.window, off)
+        out = {"prefill_attention": path,
+               "prefill_key_blocks_visited": visited,
+               "prefill_key_blocks_bucket": bucket}
+        kc = self._latent_kind
+        if kc is not None:
+            # rows multiplied out to every head, over the kind's layers: the
+            # pass's own from 0, every row of the slot for an expanded tail
+            form = self._latent_form(pb, start)
+            rows = {"cold": pb, "expanded": kc.rows, "absorbed": 0}[form]
+            out.update(latent_prefill_form=form,
+                       latent_rows_expanded=kc.layers * rows)
+        return out
 
     def _count_experts(self, out: np.ndarray) -> np.ndarray:
         """Split what a program of a model with expert layers returned:
@@ -1562,6 +1626,7 @@ class SlotEngine:
             # walk starts at its window's first tile, a packed row has its
             # own lanes, a ring is walked by position like any row
             nbytes = live = 0
+            self._step_tiles = {}
             for kc in self._kinds:
                 nbytes += paged_read_bytes(
                     spans, kc.geo.tile, kc.row_heads, kc.d_key, itemsize,
@@ -1569,10 +1634,13 @@ class SlotEngine:
                     pack=kc.pack)
                 # the kernel makes one loop trip a tile it fetches: walked
                 # over live is 1.0 while no dead tile is walked
-                live += kc.layers * paged_live_tiles(
+                tiles = kc.layers * paged_live_tiles(
                     spans, kc.geo.tile, kc.window, query_span)
-            self._step_tiles = {"paged_tiles_live": live,
-                                "paged_tiles_walked": live}
+                live += tiles
+                if kc.latent:       # the latent kernel's walk, of them
+                    self._step_tiles["latent_tiles_walked"] = tiles
+            self._step_tiles.update(paged_tiles_live=live,
+                                    paged_tiles_walked=live)
         else:
             nbytes = sum(dense_read_bytes(
                 self.n_slots, kc.rows, kc.kv_heads,

@@ -61,7 +61,8 @@ __all__ = ["CompilePlane", "ProgramSpec", "REGISTERED_ENTRY_POINTS",
            "engine_jit_cache_size", "jit_entry_points", "program_lattice"]
 
 #: module-level jitted entry points the lattice accounts for, per module
-#: (the completeness sweep's contract).  ``paged_decode_attention`` is
+#: (the completeness sweep's contract).  ``paged_decode_attention`` (and
+#: ``latent_decode_attention``, its sibling over latent rows) is
 #: covered THROUGH the decode/verify programs — the kernel is invoked
 #: inside their traces, never as its own serving-path dispatch — and
 #: ``prefill_attention`` (and the one query block it maps,
@@ -74,8 +75,8 @@ REGISTERED_ENTRY_POINTS = {
         "_prefill_slot_jit", "_decode_step_jit", "_verify_step_jit",
         "_copy_prefix_jit", "_restore_span_jit"}),
     "synapseml_tpu.models.llm.pallas_attn": frozenset({
-        "paged_decode_attention", "prefill_attention",
-        "prefill_query_block"}),
+        "paged_decode_attention", "latent_decode_attention",
+        "prefill_attention", "prefill_query_block"}),
     # the recurrence of linear-attention layers: inside the decode and
     # prefill programs as the paged kernel is inside decode
     "synapseml_tpu.models.llm.pallas_gdn": frozenset({

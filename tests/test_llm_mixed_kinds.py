@@ -210,10 +210,14 @@ def test_from_hf_reads_attention_by_kind_and_a_feed_forward_by_layer(small):
         LlamaConfig.tiny(attention_kinds={"linear_attention": {}})
     with pytest.raises(ValueError, match="ffn_types"):
         LlamaConfig.tiny(ffn_types=("dense",) * 3)
-    with pytest.raises(ValueError, match="routed_scaling_factor"):
-        LlamaConfig.from_hf(dict(hf, routed_scaling_factor=2.5))
-    with pytest.raises(ValueError, match="n_group"):
-        LlamaConfig.from_hf(dict(hf, n_group=8))
+    # the router's grouping and scaling are read for every family
+    assert LlamaConfig.from_hf(dict(hf, routed_scaling_factor=2.5)
+                               ).routed_scaling_factor == 2.5
+    grouped = LlamaConfig.from_hf(dict(hf, n_group=8, topk_group=2))
+    assert (grouped.expert_groups, grouped.expert_groups_kept) == (8, 2)
+    assert (every.expert_groups, every.routed_scaling_factor) == (1, 1.0)
+    with pytest.raises(ValueError, match="hybrid_block_size"):
+        LlamaConfig.from_hf(dict(hf, hybrid_block_size=4))
 
 
 def test_the_parameter_tree_is_the_configuration_files_map(small):
@@ -807,6 +811,12 @@ PARENT_PROGRAMS = {
     "command-a-plus.dense.prefill": "2c3ed2369903b1f9",
     "command-a-plus.interpret.decode": "cf5c4b69d54785c0",
     "command-a-plus.interpret.prefill": "da0cab6447737f02",
+    # the toy MiMo description above at bfloat16, recorded on the commit
+    # before latent attention existed (263d8a0)
+    "mimo.dense.decode": "75e46bcec4b4a0e7",
+    "mimo.dense.prefill": "f822b820323e3f56",
+    "mimo.interpret.decode": "09e20e75381bea4b",
+    "mimo.interpret.prefill": "2042e62f6874f1df",
 }
 
 
@@ -828,7 +838,9 @@ def _toy_configurations():
             tie_embeddings=True, ffn="experts", num_experts=16,
             num_experts_per_tok=4, num_shared_experts=2,
             expert_selection="sigmoid", norm_topk_prob=True, experts_first=4,
-            experts_held=8)}
+            experts_held=8),
+        "mimo": dataclasses.replace(program_config(SMALL),
+                                    dtype=jnp.bfloat16)}
 
 
 def _program_digests(cfg, backend):
@@ -866,7 +878,8 @@ def _program_digests(cfg, backend):
 
 
 @pytest.mark.parametrize("backend", ["dense", "interpret"])
-@pytest.mark.parametrize("name", ["mistral", "olmo", "command-a-plus"])
+@pytest.mark.parametrize("name", ["mistral", "olmo", "command-a-plus",
+                                  "mimo"])
 def test_the_other_configurations_trace_to_the_parents_programs(name,
                                                                 backend):
     got = _program_digests(_toy_configurations()[name], backend)
