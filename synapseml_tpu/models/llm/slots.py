@@ -59,9 +59,10 @@ Mechanics:
   TPU when the geometry fits VMEM), and with it how a recurrent layer runs (the kernels of
   :mod:`~synapseml_tpu.models.llm.pallas_gdn` where attention is paged,
   a ``lax.scan`` where it is dense).
-- **prefill-into-slot** — the prompt is padded to a power-of-two bucket
-  (bounded compile count), its K/V lands in ONE slot row (sliced out,
-  filled batch-1, written back), and the true-last-token logits come
+- **prefill-into-slot** — the prompt is padded to a bucket of
+  :func:`prefill_buckets` (powers of two and one bucket in the top
+  octave: a bounded compile count), its K/V lands in ONE slot row (sliced
+  out, filled batch-1, written back), and the true-last-token logits come
   back for the first sampled token.  ``start > 0`` resumes a prefill
   after a prefix copy.
 - **prefix reuse** — every slot's context (prompt plus generated
@@ -340,6 +341,32 @@ def _restore_span_jit(cache: Any, rows: Any, slot: jnp.ndarray):
     def wr(c, r):
         return lax.dynamic_update_slice(c, r[None], (slot, 0, 0, 0))
     return jax.tree.map(wr, cache, rows)
+
+
+#: from a bucket of this many rows on, a prefill pass of every served
+#: configuration is bound by its products (some 4x the v5e's ridge of about
+#: 240 rows a bfloat16 weight), so a padded row costs what a real one does
+_MID_BUCKET_FROM = 1024
+
+
+def prefill_buckets(max_len: int, floor: int) -> Tuple[int, ...]:
+    """The prefill bucket lattice of an engine: ``floor`` doubled while it
+    is under ``max_len``, then ``max_len``, so the prefill compiles
+    O(log max_len) programs however ragged the traffic.  Where the last
+    doubling ``p`` is ``_MID_BUCKET_FROM`` or more and ``3p/2`` is under
+    ``max_len``, one bucket of ``3p/2`` goes between them: the top octave
+    is where padding costs the most rows, and each bucket is one more
+    program to warm."""
+    buckets = []
+    b = max(1, int(floor))
+    while b < max_len:
+        buckets.append(b)
+        b *= 2
+    if buckets and buckets[-1] >= _MID_BUCKET_FROM \
+            and 3 * buckets[-1] // 2 < max_len:
+        buckets.append(3 * buckets[-1] // 2)
+    buckets.append(max_len)
+    return tuple(buckets)
 
 
 def _next_pow2(n: int) -> int:
@@ -625,20 +652,13 @@ class SlotEngine:
                          if self.spec_draft_len else None)
         self._key = jax.random.PRNGKey(seed)
         self.cache = init_cache(self.cfg, self.n_slots, self.max_len)
-        # prompt-length buckets: powers of two, so the prefill compiles
-        # O(log max_len) programs however ragged the traffic.  The grid
+        # prompt-length buckets (:func:`prefill_buckets`).  The grid
         # floor defaults to 8; an explicit min_bucket wins outright, and
         # the None sentinel consults the ``llm_bucket_grid`` tuning
         # table (absent/mismatched table → 8)
         if min_bucket is None:
             min_bucket = self._consult_min_bucket()
-        buckets = []
-        b = max(1, int(min_bucket))
-        while b < self.max_len:
-            buckets.append(b)
-            b *= 2
-        buckets.append(self.max_len)
-        self._buckets = tuple(buckets)
+        self._buckets = prefill_buckets(self.max_len, min_bucket)
         # host-side slot state (one serving loop owns these, no locks)
         n = self.n_slots
         self.ctx = np.zeros((n, self.max_len), np.int32)   # incl. pending tok
@@ -762,6 +782,11 @@ class SlotEngine:
             "Pallas kernel on every attention layer kind; dense: the plain "
             "scores on every kind; mixed: the kernel on the kinds whose "
             "shape has a tile)", ("engine", "path"))
+        self._m_prefill_rows = reg.counter(
+            "llm_prefill_rows_total",
+            "rows prefill passes computed, by whether they held a prompt "
+            "token (real) or the bucket's padding past it (padding)",
+            ("engine", "rows"))
         #: (bucket, from position 0) -> what a prefill pass's attention runs
         #: as (:meth:`_prefill_plan`)
         self._prefill_plans: Dict[Tuple[int, bool], Tuple[str, Tuple]] = {}
@@ -1169,6 +1194,8 @@ class SlotEngine:
             if sp.live:
                 sp.set(bucket=res.bucket, prompt_tokens=len(prompt),
                        reused_tokens=res.reused_tokens, path=res.path,
+                       padding_rows=res.bucket - (len(prompt)
+                                                  - res.reused_tokens),
                        **self._prefill_attention_attrs())
                 if self.experts:
                     sp.set(expert_pairs_held=self._step_experts[
@@ -1315,10 +1342,12 @@ class SlotEngine:
 
     def _account_prefill(self, pb: int, start: int, plen: int) -> None:
         """Count one prefill pass by its attention's path (and a latent
-        kind's form) and keep what its span will say
+        kind's form) and its rows, and keep what its span will say
         (:meth:`_prefill_attention_attrs`)."""
         self._m_prefill_attn.inc(1, engine=self.name,
                                  path=self._prefill_plan(pb, start)[0])
+        self._m_prefill_rows.inc(plen, engine=self.name, rows="real")
+        self._m_prefill_rows.inc(pb - plen, engine=self.name, rows="padding")
         if self._latent_kind is not None:
             self._m_latent_prefill.inc(1, engine=self.name,
                                        form=self._latent_form(pb, start))

@@ -2,9 +2,9 @@
 
 Every compiled program a :class:`~synapseml_tpu.models.llm.slots.
 SlotEngine` can ever need is enumerable from its STATIC config: one
-prefill per power-of-two prompt bucket, one decode step, one verify per
-span bucket ``S`` when speculative decoding is armed, and the
-prefix-copy transfer.
+prefill per prompt bucket (``slots.prefill_buckets``), one decode step,
+one verify per span bucket ``S`` when speculative decoding is armed, and
+the prefix-copy transfer.
 Orca/vLLM-class schedulers treat that finite lattice as something to
 warm *before admission*, not to discover lazily inside the decode loop
 — a lazy first hit stalls every active slot for the full XLA compile

@@ -408,6 +408,7 @@ class TestProgramSpans:
             "engine.admit.commit"]
         assert admit.attrs == {"bucket": res.bucket, "prompt_tokens": 7,
                                "reused_tokens": 0, "path": "cold",
+                               "padding_rows": res.bucket - 7,
                                "prefill_attention": "dense",
                                "prefill_key_blocks_visited": 0,
                                "prefill_key_blocks_bucket": 0}
