@@ -20,7 +20,10 @@ layer is told which ones it holds (``LlamaConfig.experts_first``,
   imbalance.  The pairs of absent experts are left out, and nothing stands
   in for the exchange that would carry them to their chips: the partial
   sum is the layer's result, here and in the benchmark's reference alike;
-- the shared experts take every token, are averaged, and counted once;
+- the shared experts take every token, are averaged, and counted once; they
+  may have a width of their own (``shared_expert_d_ff``) and a sigmoid gate
+  a token, ``sigmoid(x w_sg)`` (``shared_expert_gate``: Qwen3-Next's one
+  shared expert);
 - a token that is not real (a bucket's padding, an inactive slot's row)
   routes nowhere: it reaches no expert's weights and no counter.
 
@@ -39,10 +42,13 @@ long, and the serving cell's tokens per second fell 7.0% (``PERF.md``
 section 6).  ``ragged_dot`` stays for an engine that runs off the TPU,
 where the only other way to run the kernel is the interpreter.
 
-The layer sows two counts into the ``"stats"`` collection, which the
-engine makes mutable and reads with a step's tokens: ``expert_pairs_held``
-(pairs computed here) and ``experts_touched`` (held experts with at least
-one pair: the weights a memory-bound step reads).
+The layer sows three counts into the ``"stats"`` collection, which the
+engine makes mutable and reads with a step's tokens (:data:`EXPERT_COUNTS`):
+``expert_pairs_held`` (pairs computed here), ``experts_touched`` (held
+experts with at least one pair: the weights a memory-bound step reads) and
+``expert_tiles_active`` (the grouped product's tiles of
+:func:`expert_row_tile` rows that hold a pair: the tiles it computed, pairs
+and padding rows together).
 """
 
 from __future__ import annotations
@@ -69,6 +75,9 @@ _ROUTER_DTYPE = jnp.float32
 #: bytes of one weight block ``(tk, tn)`` the kernel streams a grid step
 _BLOCK_BYTES = 4 * 1024 * 1024
 
+#: what an expert layer sows, in the order :func:`stats_totals` returns them
+EXPERT_COUNTS = ("expert_pairs_held", "experts_touched", "expert_tiles_active")
+
 
 def _row_tile(pairs: int, held: int) -> int:
     """Rows of one tile of the grouped product: a tile belongs to one
@@ -79,6 +88,13 @@ def _row_tile(pairs: int, held: int) -> int:
     while t < 256 and t * held < pairs:
         t *= 2
     return t
+
+
+def expert_row_tile(cfg, tokens: int) -> int:
+    """Rows of a tile of the grouped product in a pass of ``tokens`` tokens
+    (a longer pass goes through it in chunks of :data:`_CHUNK_TOKENS`)."""
+    return _row_tile(min(int(tokens), _CHUNK_TOKENS) * cfg.num_experts_per_tok,
+                     cfg.experts_held_count)
 
 
 def _divisor(n: int, cands) -> int:
@@ -175,7 +191,8 @@ def _routed(x, expert, weight, held, w_gate, w_up, w_down, backend: str):
     """The held experts' terms for one chunk of tokens.  ``x (T, d)``;
     ``expert (T, k)`` the selected experts as indices into the held ones;
     ``weight (T, k)`` float32; ``held (T, k)`` the pairs computed here.
-    -> ``(T, d)`` float32: ``sum_j held weight_j E_{expert_j}(x)``."""
+    -> ``(terms, tiles)``: ``(T, d)`` float32 ``sum_j held weight_j
+    E_{expert_j}(x)``, and the tiles computed, int32 ``()``."""
     T, k = expert.shape
     H = w_gate.shape[0]
     P = T * k
@@ -214,7 +231,8 @@ def _routed(x, expert, weight, held, w_gate, w_up, w_down, backend: str):
     # rows of tiles nobody computed hold whatever the buffer held
     pair = jnp.where(held.reshape(P, 1), y[jnp.minimum(row, tiles * tm - 1)],
                      0.0)
-    return jnp.sum(pair.reshape(T, k, -1) * weight[..., None], axis=1)
+    return (jnp.sum(pair.reshape(T, k, -1) * weight[..., None], axis=1),
+            n_active[0])
 
 
 def _kept_groups(sel: jnp.ndarray, groups: int, kept: int) -> jnp.ndarray:
@@ -232,22 +250,27 @@ def _kept_groups(sel: jnp.ndarray, groups: int, kept: int) -> jnp.ndarray:
 
 
 def stats_totals(stats) -> jnp.ndarray:
-    """``[expert_pairs_held, experts_touched]`` summed over the layers of a
-    pass's ``"stats"`` collection: int32 ``(2,)``."""
-    tot = {"expert_pairs_held": 0, "experts_touched": 0}
+    """:data:`EXPERT_COUNTS` summed over the layers of a pass's ``"stats"``
+    collection: int32 ``(3,)``."""
+    tot = dict.fromkeys(EXPERT_COUNTS, 0)
     for path, leaf in jax.tree_util.tree_leaves_with_path(stats):
         name = getattr(path[-1], "key", None)
         if name in tot:
             tot[name] = tot[name] + leaf
-    return jnp.stack([jnp.asarray(tot["expert_pairs_held"], jnp.int32),
-                      jnp.asarray(tot["experts_touched"], jnp.int32)])
+    return jnp.stack([jnp.asarray(tot[n], jnp.int32) for n in EXPERT_COUNTS])
 
 
 class ExpertFFN(nn.Module):
     """``(B, S, d) -> (B, S, d)``: the held routed experts' weighted terms
-    plus the average of the shared experts (module docstring).  ``valid
-    (B, S)``: the tokens that are real."""
+    plus the average of the shared experts, gated where the configuration
+    says so (module docstring).  ``valid (B, S)``: the tokens that are
+    real."""
     cfg: "object"
+
+    def _count(self, name: str, count) -> None:
+        # the last pass's count, not a tuple that grows
+        self.sow("stats", name, count, reduce_fn=lambda _, new: new,
+                 init_fn=lambda: jnp.zeros((), jnp.int32))
 
     @nn.compact
     def __call__(self, h, valid, backend: str = "dense"):
@@ -291,20 +314,15 @@ class ExpertFFN(nn.Module):
         if cfg.routed_scaling_factor != 1.0:
             weight = weight * cfg.routed_scaling_factor
         held = (idx >= first) & (idx < first + H) & valid.reshape(T, 1)
-        for name, count in (
-                ("expert_pairs_held", jnp.sum(held, dtype=jnp.int32)),
-                ("experts_touched", jnp.sum(jnp.any(
-                    held[..., None] & (idx[..., None] - first
-                                       == jnp.arange(H)), axis=(0, 1)),
-                    dtype=jnp.int32))):
-            # the last pass's count, not a tuple that grows
-            self.sow("stats", name, count, reduce_fn=lambda _, new: new,
-                     init_fn=lambda: jnp.zeros((), jnp.int32))
+        self._count("expert_pairs_held", jnp.sum(held, dtype=jnp.int32))
+        self._count("experts_touched", jnp.sum(jnp.any(
+            held[..., None] & (idx[..., None] - first == jnp.arange(H)),
+            axis=(0, 1)), dtype=jnp.int32))
 
         expert = idx - first
         if T <= _CHUNK_TOKENS:
-            routed = _routed(x, expert, weight, held, w_gate, w_up, w_down,
-                             backend)
+            routed, tiles = _routed(x, expert, weight, held, w_gate, w_up,
+                                    w_down, backend)
         else:
             n = -(-T // _CHUNK_TOKENS)
             pad = n * _CHUNK_TOKENS - T
@@ -312,23 +330,32 @@ class ExpertFFN(nn.Module):
             def chunks(a):
                 a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
                 return a.reshape((n, _CHUNK_TOKENS) + a.shape[1:])
-            routed = lax.map(
+            routed, tiles = lax.map(
                 lambda c: _routed(*c, w_gate, w_up, w_down, backend),
                 (chunks(x), chunks(expert), chunks(weight), chunks(held)))
             routed = routed.reshape(n * _CHUNK_TOKENS, d)[:T]
+            tiles = jnp.sum(tiles)
         out = routed.astype(cfg.dtype).reshape(B, S, d)
+        self._count("expert_tiles_active", tiles)
 
         ns = cfg.num_shared_experts
         if ns:
             # the shared experts side by side are one SwiGLU of width
-            # ns * F whose down-projection sums them
+            # ns * F (or their own width) whose down-projection sums them
             def dense(n, axes, name):
                 return nn.Dense(n, use_bias=False, dtype=cfg.dtype,
                                 name=name, kernel_init=nn.with_partitioning(
                                     init, axes))
-            g = dense(ns * F, ("embed", "mlp"), "shared_gate")(h)
-            u = dense(ns * F, ("embed", "mlp"), "shared_up")(h)
+            Fs = cfg.shared_expert_d_ff or ns * F
+            g = dense(Fs, ("embed", "mlp"), "shared_gate")(h)
+            u = dense(Fs, ("embed", "mlp"), "shared_up")(h)
             shared = dense(d, ("mlp", "embed"), "shared_down")(
                 nn.silu(g) * u)
+            if cfg.shared_expert_gate:
+                # sigmoid(x w_sg) a token, in float32
+                w_sg = param("shared_expert_gate", (d, 1), ("embed", None))
+                gate = jax.nn.sigmoid(jnp.dot(
+                    h.astype(jnp.float32), w_sg.astype(jnp.float32)))
+                shared = (shared.astype(jnp.float32) * gate).astype(cfg.dtype)
             out = out + shared * jnp.asarray(1.0 / ns, cfg.dtype)
         return out

@@ -22,7 +22,13 @@ the kind's own theta, a sink logit a head, a scale on the values).  So are
 attention, whose cache entry is one latent row a position for all heads,
 with YaRN's rotary embedding) and a router that selects among groups of
 experts and scales their weights (``expert_groups``,
-``routed_scaling_factor``).
+``routed_scaling_factor``).  So is the ``qwen3_next`` family: linear layers
+with fewer key heads than value heads (``linear_num_key_heads``), a
+sigmoid gate on attention's output (``attn_output_gate``), q/k norms by
+head (``qk_head_norm``), zero-centred norms (``norm="zero_centred"``),
+rotary embeddings on part of every head (``partial_rotary_factor``) and a
+shared expert of its own width behind a sigmoid gate
+(``shared_expert_d_ff``, ``shared_expert_gate``).
 
 The reference has no LLM training/serving of its own — its OpenAI stages
 call out to a remote service (reference: cognitive/.../openai/OpenAI.scala
@@ -65,7 +71,8 @@ class AttentionKind:
     """What the attention of ONE layer kind (a ``layer_types`` entry) differs
     in from the description's defaults; ``None``: the default
     (``LlamaConfig.attention`` fills them in).  A kind that has such an entry
-    keeps its K/V rows packed (:func:`kv_pack`)."""
+    keeps its K/V rows packed (:func:`kv_pack`), and so does one whose
+    unpacked rows would be relaid (``LlamaConfig.packed``)."""
     #: K/V heads (default ``num_kv_heads``)
     num_kv_heads: Optional[int] = None
     #: width of a query and a key head (default ``d_head``)
@@ -126,10 +133,22 @@ class LlamaConfig:
     #: RMSNorm with a learned scale over the whole width of q and of k
     #: before the split into heads (OLMo 2 and 3)
     qk_norm: bool = False
-    # linear-attention layers (gated delta rule): heads, their key and
-    # value sizes, taps of the causal depthwise convolution, and whether
-    # beta spans (0, 2) (``linear_allow_neg_eigval``) or (0, 1)
+    #: the model's norm (``norm``) over each head of q and of k after the
+    #: split, one scale of the head's width for every head (Qwen3-Next)
+    qk_head_norm: bool = False
+    #: ``q_proj`` yields ``[q_h | g_h]`` a head, and the attention's output
+    #: is multiplied by ``sigmoid(g)`` before ``o_proj`` (Qwen3-Next)
+    attn_output_gate: bool = False
+    #: share of a head's dims that take the rotary embedding, the first
+    #: ones (``partial_rotary_factor``); a kind's ``rotary_dim`` overrides it
+    partial_rotary_factor: float = 1.0
+    # linear-attention layers (gated delta rule): value heads, key heads
+    # (None: as many as value heads; fewer: value head ``j`` reads key head
+    # ``j // (value heads / key heads)``), their key and value sizes, taps
+    # of the causal depthwise convolution, and whether beta spans (0, 2)
+    # (``linear_allow_neg_eigval``) or (0, 1)
     linear_num_heads: int = 0
+    linear_num_key_heads: Optional[int] = None
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
@@ -137,7 +156,8 @@ class LlamaConfig:
     #: width of one attention head; None: ``d_model // num_heads``
     head_dim: Optional[int] = None
     #: "rms": RMSNorm; "layer": LayerNorm (mean subtracted, a learned scale,
-    #: no bias), both with ``rms_norm_eps``
+    #: no bias), both with ``rms_norm_eps``; "zero_centred": RMSNorm whose
+    #: scale is ``1 + w``, ``w`` initialised at 0 (Qwen3-Next)
     norm: str = "rms"
     #: "half": rotary pairs ``(i, i + d/2)`` (Llama, GPT-NeoX);
     #: "interleaved": pairs ``(2i, 2i + 1)`` (GPT-J)
@@ -163,6 +183,12 @@ class LlamaConfig:
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0
     expert_d_ff: Optional[int] = None
+    #: width of the shared experts side by side (None: ``num_shared_experts
+    #: * expert_d_ff``), and whether their sum is multiplied by
+    #: ``sigmoid(x w_sg)``, a learned ``(d_model, 1)`` gate a token
+    #: (Qwen2-MoE, Qwen3-Next: one shared expert of its own width)
+    shared_expert_d_ff: Optional[int] = None
+    shared_expert_gate: bool = False
     expert_selection: str = "softmax"
     norm_topk_prob: bool = False
     #: which routed experts THIS program holds: ``experts_held`` of them
@@ -253,8 +279,19 @@ class LlamaConfig:
             self.rope_layers = tuple(self.rope_layers)
         if self.norm_order not in ("pre", "post", "parallel"):
             raise ValueError(f"norm_order={self.norm_order!r}")
-        if self.norm not in ("rms", "layer"):
+        if self.norm not in ("rms", "layer", "zero_centred"):
             raise ValueError(f"norm={self.norm!r}")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm and qk_head_norm: q and k take one "
+                             "norm, over the whole width or by head")
+        if not 0.0 < self.partial_rotary_factor <= 1.0:
+            raise ValueError(
+                f"partial_rotary_factor={self.partial_rotary_factor}")
+        if "linear_attention" in self.layer_kinds \
+                and self.linear_num_heads % self.linear_key_heads:
+            raise ValueError(
+                f"{self.linear_num_heads} linear value heads do not divide "
+                f"into {self.linear_key_heads} key heads")
         if self.rope_style not in ("half", "interleaved"):
             raise ValueError(f"rope_style={self.rope_style!r}")
         if self.ffn not in FFNS:
@@ -303,6 +340,12 @@ class LlamaConfig:
         return self.head_dim or self.d_model // self.num_heads
 
     @property
+    def linear_key_heads(self) -> int:
+        """Key heads of a linear-attention layer (value heads where none is
+        named)."""
+        return self.linear_num_key_heads or self.linear_num_heads
+
+    @property
     def experts_held_count(self) -> int:
         """Routed experts this program holds (all where none is named)."""
         return self.num_experts if self.experts_held is None \
@@ -321,9 +364,13 @@ class LlamaConfig:
         return self.ffn_kinds.count("experts")
 
     def packed(self, kind: str) -> bool:
-        """Whether ``kind`` has an entry in ``attention_kinds`` (and keeps
-        its K/V rows packed)."""
-        return kind in dict(self.attention_kinds or ())
+        """Whether ``kind`` keeps its K/V rows packed: it has an entry in
+        ``attention_kinds``, or an unpacked row of the description's heads
+        would be relaid every step (:func:`~synapseml_tpu.models.llm
+        .pallas_attn.row_relaid`: 2 K/V heads of 256)."""
+        from .pallas_attn import row_relaid
+        return kind in dict(self.attention_kinds or ()) \
+            or row_relaid(self.kv_cache_heads, self.d_head)
 
     def attention(self, kind: str) -> AttentionKind:
         """The attention of layer kind ``kind`` with every default filled
@@ -336,7 +383,8 @@ class LlamaConfig:
             self.rope_theta if roped else None
         return AttentionKind(
             num_kv_heads=o.num_kv_heads or self.num_kv_heads, head_dim=d,
-            v_head_dim=o.v_head_dim or d, rotary_dim=o.rotary_dim or d,
+            v_head_dim=o.v_head_dim or d,
+            rotary_dim=o.rotary_dim or int(d * self.partial_rotary_factor),
             rope_theta=theta, sink=o.sink, value_scale=o.value_scale)
 
     @property
@@ -396,8 +444,18 @@ class LlamaConfig:
         keys are read for every family: ``n_routed_experts`` or
         ``num_experts``, ``scoring_func``, ``n_group`` and ``topk_group``,
         ``routed_scaling_factor``, ``topk_method`` ``noaux_tc`` (the selection
-        bias).  A key whose mechanism this description does not have is
-        refused, whatever the family (:func:`_refuse_unhonoured`)."""
+        bias), ``decoder_sparse_step`` and ``mlp_only_layers``, and a shared
+        expert of its own width behind a sigmoid gate
+        (``shared_expert_intermediate_size``).  ``partial_rotary_factor`` is
+        read for every family, and so is ``full_attention_interval`` (layer
+        ``i`` full attention iff ``(i + 1) % interval == 0``, the others
+        ``linear_attention``) where ``layer_types`` is not given.
+        ``qwen3_next`` brings the family's zero-centred norms, q/k norms by
+        head and the sigmoid gate on attention's output; its linear layers
+        may have fewer key heads than value heads
+        (``linear_num_key_heads``).  A key whose mechanism this description
+        does not have is refused, whatever the family
+        (:func:`_refuse_unhonoured`)."""
         _refuse_unhonoured(hc)
         if hc.get("model_type") == "mimo_v2":
             hc, kw = _mimo_v2_keys(hc), {**_mimo_v2_args(hc), **kw}
@@ -406,8 +464,18 @@ class LlamaConfig:
             else hc.get("rope_theta", 10_000.0)   # HF's default (Llama-1/2)
         olmo = hc.get("model_type") == "olmo_hybrid"
         cohere = hc.get("model_type") == "cohere2_moe"
+        qwen_next = hc.get("model_type") == "qwen3_next"
+        if qwen_next and hc.get("use_sliding_window"):
+            raise ValueError("use_sliding_window=True is not supported for "
+                             "qwen3_next: its full-attention layers see "
+                             "every position")
         latent = bool(hc.get("kv_lora_rank"))
         n = hc["num_hidden_layers"]
+        layer_types = hc.get("layer_types")
+        if layer_types is None and hc.get("full_attention_interval"):
+            every = int(hc["full_attention_interval"])
+            layer_types = ["linear_attention" if (i + 1) % every
+                           else "full_attention" for i in range(n)]
         eps = hc.get("rms_norm_eps")
         layer_norm = eps is None and hc.get("layer_norm_eps") is not None
         if eps is None:
@@ -423,12 +491,16 @@ class LlamaConfig:
             rope_theta=None if theta is None else float(theta),
             rms_norm_eps=float(eps),
             tie_embeddings=bool(hc.get("tie_word_embeddings", False)),
-            layer_types=hc.get("layer_types"),
+            layer_types=layer_types,
             norm_order="post" if olmo else "parallel"
             if hc.get("use_parallel_block") else "pre",
             qk_norm=olmo or bool(hc.get("use_qk_norm", False)),
+            qk_head_norm=qwen_next, attn_output_gate=qwen_next,
+            partial_rotary_factor=float(
+                hc.get("partial_rotary_factor") or 1.0),
             head_dim=hc.get("head_dim"),
-            norm="layer" if layer_norm else "rms",
+            norm="layer" if layer_norm else "zero_centred" if qwen_next
+            else "rms",
             rope_style="interleaved"
             if hc.get("position_embedding_type") == "rope_gptj" else "half",
             rope_layers=("sliding_attention",) if cohere else None,
@@ -459,12 +531,20 @@ class LlamaConfig:
                 expert_groups_kept=int(hc.get("topk_group") or 1),
                 routed_scaling_factor=float(
                     hc.get("routed_scaling_factor") or 1.0))
+            if hc.get("shared_expert_intermediate_size"):
+                args.update(num_shared_experts=1, shared_expert_gate=True,
+                            shared_expert_d_ff=int(
+                                hc["shared_expert_intermediate_size"]))
         if "linear_num_value_heads" in hc:
-            if hc.get("linear_num_key_heads") != hc["linear_num_value_heads"]:
-                raise ValueError("linear layers with fewer key heads than "
-                                 "value heads are not supported")
+            nv = int(hc["linear_num_value_heads"])
+            nk = int(hc.get("linear_num_key_heads") or nv)
+            if nv % nk:
+                raise ValueError(
+                    f"linear_num_value_heads={nv} is not a multiple of "
+                    f"linear_num_key_heads={nk}")
             args.update(
-                linear_num_heads=hc["linear_num_value_heads"],
+                linear_num_heads=nv,
+                linear_num_key_heads=None if nk == nv else nk,
                 linear_key_head_dim=hc["linear_key_head_dim"],
                 linear_value_head_dim=hc["linear_value_head_dim"],
                 linear_conv_kernel_dim=hc.get("linear_conv_kernel_dim", 4),
@@ -477,6 +557,8 @@ class LlamaConfig:
             dense |= {i for i, on in enumerate(freq) if not on}
         elif freq:                              # every freq-th layer
             dense |= {i for i in range(n) if i % int(freq)}
+        step = int(hc.get("decoder_sparse_step") or 1)
+        dense |= {i for i in range(n) if (i + 1) % step}
         if args.get("ffn") == "experts" and dense:
             args["ffn_types"] = tuple(
                 "dense" if i in dense else "experts" for i in range(n))
@@ -584,13 +666,19 @@ def _mimo_v2_args(hc: Dict[str, Any]) -> Dict[str, Any]:
 
 
 class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``; ``zero_centred``: ``* (1 +
+    w)`` with ``w`` initialised at 0 (Qwen3-Next's form)."""
     eps: float
     dtype: Any
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.with_partitioning(
-            nn.initializers.ones, ("embed",)), (x.shape[-1],))
+            nn.initializers.zeros if self.zero_centred
+            else nn.initializers.ones, ("embed",)), (x.shape[-1],))
+        if self.zero_centred:
+            scale = 1.0 + scale.astype(jnp.float32)
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
         normed = x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)
         return (normed * scale).astype(self.dtype)
@@ -612,8 +700,10 @@ class LayerNorm(nn.Module):
 
 
 def _norm(cfg: "LlamaConfig", name: str):
-    cls = LayerNorm if cfg.norm == "layer" else RMSNorm
-    return cls(cfg.rms_norm_eps, cfg.dtype, name=name)
+    if cfg.norm == "layer":
+        return LayerNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+    return RMSNorm(cfg.rms_norm_eps, cfg.dtype,
+                   zero_centred=cfg.norm == "zero_centred", name=name)
 
 
 def rope_frequencies(d_head: int, theta: float) -> np.ndarray:
@@ -851,7 +941,9 @@ class CausalAttention(nn.Module):
     keeps its cache PACKED: flat rows ``(slots, rows * KV / f, f * width)``,
     ``f`` heads side by side (:func:`kv_pack`), the layout the paged kernel
     reads, so that a 192-wide key costs no lane of padding and the step
-    relays nothing; every other kind keeps ``(slots, rows, KV, d_head)``."""
+    relays nothing; so does a kind whose unpacked rows would be relaid
+    (``LlamaConfig.packed``); every other kind keeps ``(slots, rows, KV,
+    d_head)``."""
     cfg: LlamaConfig
 
     #: the layer kind (``layer_types`` entry) this class serves
@@ -919,13 +1011,22 @@ class CausalAttention(nn.Module):
         B, S, _ = x.shape
         H, KV, D, Dv = (cfg.num_heads, a.num_kv_heads, a.head_dim,
                         a.v_head_dim)
-        q = _project(x, H * D, ("embed", "heads"), "q_proj", cfg, "q")
+        gate = None
+        if cfg.attn_output_gate:
+            # [q_h | g_h] a head: the gate is a query head's twin
+            qg = _project(x, 2 * H * D, ("embed", "heads"), "q_proj", cfg,
+                          "q").reshape(B, S, H, 2 * D)
+            q, gate = qg[..., :D], qg[..., D:].reshape(B, S, H * D)
+        else:
+            q = _project(x, H * D, ("embed", "heads"), "q_proj", cfg, "q")
         k = _project(x, KV * D, ("embed", "kv"), "k_proj", cfg, "k")
         v = _project(x, KV * Dv, ("embed", "kv"), "v_proj", cfg, "v")
         if cfg.qk_norm:
             q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
+        if cfg.qk_head_norm:
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         if a.rope_theta is not None:
             q = apply_rope(q, positions, a.rope_theta, cfg.rope_style,
                            a.rotary_dim)
@@ -1122,6 +1223,9 @@ class CausalAttention(nn.Module):
                 probs = probs[..., :-1]
             out = jnp.einsum("bkgst,btkd->bskgd", probs, v_att)
             out = out.reshape(B, S, H * Dv)
+        if gate is not None:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(cfg.dtype)
         out = _dense(cfg.d_model, ("heads", "embed"), "o_proj", cfg.dtype,
                      cfg.weight_quant)(_fold_barrier(out, H * Dv, "o"))
         return out, new_cache
@@ -1143,6 +1247,11 @@ class GatedDeltaNet(nn.Module):
     k L2-normalised, ``alpha = exp(-exp(A_log) softplus(x w_a + dt_bias))``
     and ``beta = sigmoid(x w_b)``, doubled where ``linear_allow_neg_eigval``;
     the output is RMS-normalised per head and gated by ``silu(x W_g)``.
+    With fewer key heads than value heads (``linear_num_key_heads``, Qwen3-
+    Next's 16 for 32) q and k are computed, convolved and normalised by key
+    head, and value head ``j`` reads key head ``j // r``, ``r`` value heads
+    a key head; the state, ``v``, the gates and the output are by value
+    head.
 
     Its cache entry is the state (packed as
     :mod:`~synapseml_tpu.models.llm.pallas_gdn` lays it out) and the last
@@ -1168,7 +1277,7 @@ class GatedDeltaNet(nn.Module):
         return {"state": jnp.zeros((batch,) + state_shape(H, dk, dv),
                                    jnp.float32),
                 "conv": jnp.zeros((batch, cfg.linear_conv_kernel_dim - 1,
-                                   H * (2 * dk + dv)), cfg.dtype)}
+                                   linear_conv_channels(cfg)), cfg.dtype)}
 
     @nn.compact
     def __call__(self, x, positions, cache: Optional[Dict],
@@ -1180,8 +1289,8 @@ class GatedDeltaNet(nn.Module):
         from . import pallas_gdn as gdn
         cfg = self.cfg
         B, S, _ = x.shape
-        H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
-                     cfg.linear_value_head_dim)
+        H, Hk, dk, dv = (cfg.linear_num_heads, cfg.linear_key_heads,
+                         cfg.linear_key_head_dim, cfg.linear_value_head_dim)
         taps = cfg.linear_conv_kernel_dim
         pack = gdn.gdn_pack(H, dv)
         per_slot = cache is not None and jnp.ndim(cache_index) != 0
@@ -1194,7 +1303,8 @@ class GatedDeltaNet(nn.Module):
 
         def proj(n, name, axes=("embed", "heads")):
             return _dense(n, axes, name, cfg.dtype, cfg.weight_quant)(x)
-        mixed = jnp.concatenate([proj(H * dk, "q_proj"), proj(H * dk, "k_proj"),
+        mixed = jnp.concatenate([proj(Hk * dk, "q_proj"),
+                                 proj(Hk * dk, "k_proj"),
                                  proj(H * dv, "v_proj")], axis=-1)
         C = mixed.shape[-1]
 
@@ -1225,9 +1335,9 @@ class GatedDeltaNet(nn.Module):
             lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, taps - 1, 0)
         )(ext, n_valid)
 
-        q, k, v = jnp.split(conv, [H * dk, 2 * H * dk], axis=-1)
-        q = _l2norm(q.reshape(B, S, H, dk)) * (dk ** -0.5)
-        k = _l2norm(k.reshape(B, S, H, dk))
+        q, k, v = jnp.split(conv, [Hk * dk, 2 * Hk * dk], axis=-1)
+        q = _l2norm(q.reshape(B, S, Hk, dk)) * (dk ** -0.5)
+        k = _l2norm(k.reshape(B, S, Hk, dk))
         v = v.reshape(B, S, H, dv)
         a_log = self.param("A_log", nn.initializers.zeros_init(), (H,),
                            jnp.float32)
@@ -1253,6 +1363,8 @@ class GatedDeltaNet(nn.Module):
             state, o = st[None], o[None]
         else:
             valid = jnp.arange(S)[None, :] < n_valid[:, None]
+            if Hk != H:                   # value head j reads key head j // r
+                q, k = (jnp.repeat(a, H // Hk, axis=2) for a in (q, k))
             o, st = gdn.gated_delta_scan(
                 q, k, v, alpha, beta, gdn.unpack_state(state, pack), valid)
             state = gdn.pack_state(st, pack)
@@ -1269,6 +1381,14 @@ class GatedDeltaNet(nn.Module):
         new_cache = None if cache is None else {"state": state,
                                                 "conv": new_window}
         return out, new_cache
+
+
+def linear_conv_channels(cfg: LlamaConfig) -> int:
+    """Channels of a linear-attention layer's convolution: q and k by key
+    head, v by value head (``2 x 16 x 128 + 32 x 128`` = 8,192 at
+    Qwen3-Next's widths)."""
+    return cfg.linear_key_heads * 2 * cfg.linear_key_head_dim \
+        + cfg.linear_num_heads * cfg.linear_value_head_dim
 
 
 def ring_rows(window: int) -> int:

@@ -119,6 +119,20 @@ def cache_row_heads(num_kv_heads: int, dtype) -> int:
             else _pad(num_kv_heads, sub))
 
 
+def row_relaid(row_heads: int, d_head: int) -> bool:
+    """Whether an unpacked cache row of ``row_heads`` heads of ``d_head``
+    (``(slots, rows, heads, d_head)``) is NO bitcast of the kernel's flat
+    rows ``(slots, rows * heads, d_head)``: a head of several whole lane
+    tiles, and more than one head but no whole tile of 8 sublanes.  The
+    decode program compiled for the v5e then relays the whole cache into
+    the flat order every layer and step (2 heads of 256: temporaries the
+    size of the cache; one head, heads one lane tile wide, or 8 heads of
+    256 keep theirs under 2 MB).  Such a kind keeps packed rows
+    (``LlamaConfig.packed``)."""
+    return (d_head > 128 and d_head % 128 == 0 and row_heads > 1
+            and row_heads % 8 != 0)
+
+
 def _chunk_rows(tile: int, row_heads: int, q_rows: int) -> int:
     """Flat K/V rows one contraction takes: the whole tile, halved while
     the ``(q_rows, C)`` logits pass ``_CHUNK_ELEMS`` and the halves stay

@@ -36,6 +36,12 @@ of 128 (2 at 192: ``(15, 96, 384)``); :func:`pack_state` and
 ``k`` enter transposed, ``(d_k, heads)``, and a head's column is broadcast
 along the lanes of its part of the tile.
 
+**Key heads.**  Where a layer has fewer key heads than value heads
+(Qwen3-Next: 16 for 32), q and k enter by KEY head, ``(d_k, key heads)``,
+and value head ``j`` broadcasts key head ``j // (heads / key heads)``'s
+column: the state, v, the gates and the output stay by value head, and no
+repeated copy of q and k is made.
+
 Correctness runs in interpret mode on the CPU against :func:`gated_delta_scan`
 (``tests/test_llm_gdn.py``); the same tests compile both kernels for the v5e
 at the published geometry.  The kernels' byte and operation counts are the
@@ -166,22 +172,25 @@ def gated_delta_scan(q, k, v, alpha, beta, state, valid=None):
 # ---------------------------------------------------------------------------
 
 def _token_update(s_read, s_write, o_write, qT, kT, v, a, b, *, groups: int,
-                  pack: int, d_k: int, d_v: int) -> None:
+                  pack: int, d_k: int, d_v: int, ratio: int = 1) -> None:
     """One token through every head group of one slot.  ``qT``/``kT``
-    ``(d_k, heads)``; ``v``, ``a``, ``b`` ``(groups, width)`` (the gates
-    repeated over each head's lanes); ``s_read(g)``/``s_write(g, S)``
-    move a group's ``(d_k, width)`` tile, ``o_write(g, row)`` its
-    ``(1, width)`` output."""
+    ``(d_k, key heads)``, value head ``j`` reading key head ``j // ratio``;
+    ``v``, ``a``, ``b`` ``(groups, width)`` (the gates repeated over each
+    head's lanes); ``s_read(g)``/``s_write(g, S)`` move a group's ``(d_k,
+    width)`` tile, ``o_write(g, row)`` its ``(1, width)`` output."""
     width = pack * d_v
     lane = lax.broadcasted_iota(jnp.int32, (d_k, width), 1)
 
     def spread(colsT, g):
-        # head e of the group owns lanes [e*d_v, (e+1)*d_v): its column,
-        # d_k down the sublanes, broadcast along them
-        out = jnp.broadcast_to(colsT[:, g * pack:g * pack + 1], (d_k, width))
+        # head e of the group owns lanes [e*d_v, (e+1)*d_v): its key head's
+        # column, d_k down the sublanes, broadcast along them
+        def col(e):
+            c = (g * pack + e) // ratio
+            return colsT[:, c:c + 1]
+        out = jnp.broadcast_to(col(0), (d_k, width))
         for e in range(1, pack):
-            col = colsT[:, g * pack + e:g * pack + e + 1]
-            out = jnp.where(lane >= e * d_v, col, out)
+            c = col(e)
+            out = jnp.where(lane >= e * d_v, c, out)
         return out
 
     for g in range(groups):
@@ -194,7 +203,7 @@ def _token_update(s_read, s_write, o_write, qT, kT, v, a, b, *, groups: int,
         o_write(g, jnp.sum(spread(qT, g) * S, axis=0, keepdims=True))
 
 
-def _decode_kernel(groups: int, pack: int, d_k: int, d_v: int):
+def _decode_kernel(groups: int, pack: int, d_k: int, d_v: int, ratio: int):
     def kernel(visit_ref, act_ref, s_ref, qT_ref, kT_ref, v_ref, a_ref,
                b_ref, so_ref, o_ref):
         i = pl.program_id(0)
@@ -209,7 +218,8 @@ def _decode_kernel(groups: int, pack: int, d_k: int, d_v: int):
                 o_ref[0, g:g + 1, :] = row
             _token_update(lambda g: s_ref[0, g], s_write, o_write,
                           qT_ref[0], kT_ref[0], v_ref[0], a_ref[0], b_ref[0],
-                          groups=groups, pack=pack, d_k=d_k, d_v=d_v)
+                          groups=groups, pack=pack, d_k=d_k, d_v=d_v,
+                          ratio=ratio)
 
         @pl.when(jnp.logical_not(active))
         def _idle():
@@ -227,8 +237,8 @@ def _decode_kernel(groups: int, pack: int, d_k: int, d_v: int):
 
 @functools.partial(jax.jit, static_argnames=("pack", "interpret"))
 def gated_delta_decode(state: jnp.ndarray,    # (N, groups, d_k, width) f32
-                       q: jnp.ndarray,        # (N, H, d_k) f32
-                       k: jnp.ndarray,        # (N, H, d_k) f32
+                       q: jnp.ndarray,        # (N, Hk, d_k) f32
+                       k: jnp.ndarray,        # (N, Hk, d_k) f32
                        v: jnp.ndarray,        # (N, H, d_v) f32
                        alpha: jnp.ndarray,    # (N, H) f32
                        beta: jnp.ndarray,     # (N, H) f32
@@ -237,9 +247,10 @@ def gated_delta_decode(state: jnp.ndarray,    # (N, groups, d_k, width) f32
                        interpret: bool = False):
     """One token for every active slot: -> ``(state, o (N, H, d_v) f32)``,
     the state updated in place.  An inactive slot's state is untouched and
-    its output row is zero."""
-    N, H, d_k = q.shape
-    d_v = v.shape[-1]
+    its output row is zero.  ``q`` and ``k`` by key head: value head ``j``
+    reads key head ``j // (H / Hk)``."""
+    N, Hk, d_k = q.shape
+    H, d_v = v.shape[1:]
     groups, width = H // pack, pack * d_v
     assert state.shape == (N, groups, d_k, width), (state.shape, q.shape)
     act = active.astype(jnp.int32)
@@ -261,15 +272,15 @@ def gated_delta_decode(state: jnp.ndarray,    # (N, groups, d_k, width) f32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(N,),
         in_specs=[pl.BlockSpec((1, groups, d_k, width), st),
-                  pl.BlockSpec((1, d_k, H), own),
-                  pl.BlockSpec((1, d_k, H), own),
+                  pl.BlockSpec((1, d_k, Hk), own),
+                  pl.BlockSpec((1, d_k, Hk), own),
                   pl.BlockSpec((1, groups, width), own),
                   pl.BlockSpec((1, groups, width), own),
                   pl.BlockSpec((1, groups, width), own)],
         out_specs=[pl.BlockSpec((1, groups, d_k, width), st),
                    pl.BlockSpec((1, groups, width), own)])
     new_state, o = pl.pallas_call(
-        _decode_kernel(groups, pack, d_k, d_v),
+        _decode_kernel(groups, pack, d_k, d_v, H // Hk),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
                    jax.ShapeDtypeStruct((N, groups, width), jnp.float32)],
@@ -284,7 +295,8 @@ def gated_delta_decode(state: jnp.ndarray,    # (N, groups, d_k, width) f32
     return new_state, o.reshape(N, H, d_v)
 
 
-def _prefill_kernel(groups: int, pack: int, d_k: int, d_v: int, chunk: int):
+def _prefill_kernel(groups: int, pack: int, d_k: int, d_v: int, chunk: int,
+                    ratio: int):
     def kernel(plen_ref, s0_ref, qT_ref, kT_ref, v_ref, a_ref, b_ref,
                so_ref, o_ref, s_scr):
         c = pl.program_id(0)
@@ -308,7 +320,7 @@ def _prefill_kernel(groups: int, pack: int, d_k: int, d_v: int, chunk: int):
                 _token_update(lambda g: s_scr[g], s_write, o_write,
                               qT_ref[t], kT_ref[t], v_ref[t], a_ref[t],
                               b_ref[t], groups=groups, pack=pack, d_k=d_k,
-                              d_v=d_v)
+                              d_v=d_v, ratio=ratio)
                 return carry
             lax.fori_loop(0, jnp.minimum(left, chunk), token, 0)
 
@@ -320,8 +332,8 @@ def _prefill_kernel(groups: int, pack: int, d_k: int, d_v: int, chunk: int):
 
 @functools.partial(jax.jit, static_argnames=("pack", "interpret"))
 def gated_delta_prefill(state: jnp.ndarray,   # (groups, d_k, width) f32
-                        q: jnp.ndarray,       # (T, H, d_k) f32
-                        k: jnp.ndarray,       # (T, H, d_k) f32
+                        q: jnp.ndarray,       # (T, Hk, d_k) f32
+                        k: jnp.ndarray,       # (T, Hk, d_k) f32
                         v: jnp.ndarray,       # (T, H, d_v) f32
                         alpha: jnp.ndarray,   # (T, H) f32
                         beta: jnp.ndarray,    # (T, H) f32
@@ -330,9 +342,10 @@ def gated_delta_prefill(state: jnp.ndarray,   # (groups, d_k, width) f32
                         interpret: bool = False):
     """One slot's bucket of ``T`` tokens, of which the first ``plen`` are
     real: -> ``(state after token plen-1, o (T, H, d_v) f32)``; the output
-    rows at or after ``plen`` are zero."""
-    T, H, d_k = q.shape
-    d_v = v.shape[-1]
+    rows at or after ``plen`` are zero.  ``q`` and ``k`` by key head, as
+    :func:`gated_delta_decode` takes them."""
+    T, Hk, d_k = q.shape
+    H, d_v = v.shape[1:]
     groups, width = H // pack, pack * d_v
     assert state.shape == (groups, d_k, width), (state.shape, q.shape)
     chunk = PREFILL_CHUNK if T % PREFILL_CHUNK == 0 else T
@@ -351,8 +364,8 @@ def gated_delta_prefill(state: jnp.ndarray,   # (groups, d_k, width) f32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(T // chunk,),
         in_specs=[pl.BlockSpec((groups, d_k, width), whole),
-                  pl.BlockSpec((chunk, d_k, H), live),
-                  pl.BlockSpec((chunk, d_k, H), live),
+                  pl.BlockSpec((chunk, d_k, Hk), live),
+                  pl.BlockSpec((chunk, d_k, Hk), live),
                   pl.BlockSpec((chunk, groups, width), live),
                   pl.BlockSpec((chunk, groups, width), live),
                   pl.BlockSpec((chunk, groups, width), live)],
@@ -361,7 +374,7 @@ def gated_delta_prefill(state: jnp.ndarray,   # (groups, d_k, width) f32
                                 lambda c, plen: (c, 0, 0))],
         scratch_shapes=[pltpu.VMEM((groups, d_k, width), jnp.float32)])
     new_state, o = pl.pallas_call(
-        _prefill_kernel(groups, pack, d_k, d_v, chunk),
+        _prefill_kernel(groups, pack, d_k, d_v, chunk, H // Hk),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
                    jax.ShapeDtypeStruct((T, groups, width), jnp.float32)],

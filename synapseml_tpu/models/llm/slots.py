@@ -133,10 +133,11 @@ rows as well (``engine.kv_by_position``).
 :mod:`~synapseml_tpu.models.llm.experts`): the program holds some of a
 layer's routed experts and computes their part of the result; a token that
 is a bucket's padding or an inactive slot's row routes nowhere.  Each
-program returns two counts with its tokens or logits (pairs computed here,
-held experts touched), which become attributes of ``engine.step`` and
-``engine.admit`` and the counters ``llm_expert_pairs_total`` and
-``llm_experts_touched_total``.
+program returns three counts with its tokens or logits (pairs computed
+here, held experts touched, tiles of the grouped product computed), which
+become attributes of ``engine.step`` and ``engine.admit`` and the counters
+``llm_expert_pairs_total``, ``llm_experts_touched_total`` and
+``llm_expert_rows_total`` (the tiles' rows, pairs and padding).
 
 Junk-write safety: padded prefill rows and pre-copy leftovers only ever
 land at positions strictly beyond a slot's current length; decode writes
@@ -167,8 +168,9 @@ from ...telemetry.flight import record as _flight_record
 from .drafter import NgramDrafter
 from .kvtier import ChecksumError, RadixPrefixIndex, kvtier_metrics
 from .generate import sample_logits
-from .experts import stats_totals
-from .model import MIXERS, RING_BLOCK, LlamaModel, init_cache, kv_pack
+from .experts import EXPERT_COUNTS, expert_row_tile, stats_totals
+from .model import (MIXERS, RING_BLOCK, LlamaModel, init_cache, kv_pack,
+                    linear_conv_channels)
 from .pallas_attn import (PagedGeometry, dense_read_bytes, paged_geometry,
                           paged_live_tiles, paged_read_bytes,
                           prefill_geometry, prefill_key_blocks,
@@ -199,7 +201,7 @@ def _prefill_slot_jit(model: LlamaModel, variables: Any, cache: Any,
     bucket length) into row ``slot`` starting at position ``start``.
     Returns ``(new_cache, last_logits (V,) f32)`` where ``last_logits``
     is the row for the prompt's true last token; a model with expert
-    layers appends its two counts (:func:`_apply`) as float32.
+    layers appends its counts (:func:`_apply`) as float32.
 
     Padding rows of K/V are junk that is overwritten before it is read;
     a recurrent layer takes ``valid_len=plen`` and leaves its state as
@@ -295,8 +297,8 @@ def _verify_step_jit(model: LlamaModel, variables: Any, cache: Any,
     before it is ever attendable).  Greedy only: acceptance compares
     argmax, which is exactly the temperature-0 sampling rule.
     ``paged_num_tiles``: accepted and ignored, as in
-    :func:`_decode_step_jit`.  A model with expert layers appends one row
-    whose first two entries are its counts (:func:`_apply`; they count the
+    :func:`_decode_step_jit`.  A model with expert layers appends the rows
+    whose first entries are its counts (:func:`_apply`; they count the
     drafted tokens too, rejected or not: each was routed)."""
     positions = (lengths - 1)[:, None] + jnp.arange(tokens.shape[1])[None, :]
     logits, cache, counts = _apply(model, variables, tokens,
@@ -306,7 +308,10 @@ def _verify_step_jit(model: LlamaModel, variables: Any, cache: Any,
                                    paged_tile=paged_tile)
     g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     if counts is not None:
-        g = jnp.concatenate([g, jnp.pad(counts, (0, g.shape[1] - 2))[None]])
+        rows = -(-counts.shape[0] // g.shape[1])
+        g = jnp.concatenate([g, jnp.pad(
+            counts, (0, rows * g.shape[1] - counts.shape[0])).reshape(
+                rows, g.shape[1])])
     return cache, g
 
 
@@ -561,7 +566,7 @@ class SlotEngine:
         #: by position, which the host arena and a prefill worker slice
         self.kv_by_position = not self.recurrent and not any(
             kc.ring or kc.packed or kc.latent for kc in self._kinds)
-        #: the model has expert layers: its programs return two counts
+        #: the model has expert layers: its programs return three counts
         #: with their tokens (:func:`_apply`)
         self.experts = self.cfg.has_experts
         if self.recurrent:
@@ -684,8 +689,9 @@ class SlotEngine:
         #: slot's admissions and resumes
         self._flight: Optional[_Flight] = None
         self._epoch = np.zeros(n, np.int64)
-        # ``prev_nxt`` of a first step (an expert model's carries two counts)
-        self._no_prev = jnp.zeros(n + (2 if self.experts else 0), jnp.int32)
+        # ``prev_nxt`` of a first step (an expert model's carries its counts)
+        self._no_prev = jnp.zeros(
+            n + (len(EXPERT_COUNTS) if self.experts else 0), jnp.int32)
         # radix prefix indices over slot contexts, ONE PER TENANT:
         # longest_prefix is exact by construction (tokens, not hashes),
         # so reuse finds the TRUE longest match with no candidate probe
@@ -745,14 +751,13 @@ class SlotEngine:
             "llm_slot_occupancy", "active slots / total slots", ("engine",))
         #: bytes of recurrent state and convolution window one slot holds
         #: over all linear-attention layers (0 for a model without them)
+        #: (the state by value heads, the window's q and k by key heads)
         self.slot_state_bytes = self.cfg.num_recurrent_layers * \
             slot_state_bytes(
                 self.cfg.linear_num_heads, self.cfg.linear_key_head_dim,
                 self.cfg.linear_value_head_dim,
                 self.cfg.linear_conv_kernel_dim - 1,
-                self.cfg.linear_num_heads * (
-                    2 * self.cfg.linear_key_head_dim
-                    + self.cfg.linear_value_head_dim),
+                linear_conv_channels(self.cfg),
                 np.dtype(self.cfg.dtype).itemsize)
         reg.gauge(
             "llm_recurrent_state_bytes",
@@ -809,6 +814,12 @@ class SlotEngine:
             "held experts with at least one pair, summed over layers and "
             "over decode steps and prefills: the expert weights read",
             ("engine",))
+        self._m_expert_rows = reg.counter(
+            "llm_expert_rows_total",
+            "rows of the tiles the grouped expert product computed, summed "
+            "over layers and over decode steps and prefills: rows=pairs "
+            "held a (token, expert) pair, rows=padding filled a tile past "
+            "its expert's pairs", ("engine", "rows"))
         if self.experts:
             cfg = self.cfg
             reg.gauge(
@@ -1198,8 +1209,9 @@ class SlotEngine:
                                                   - res.reused_tokens),
                        **self._prefill_attention_attrs())
                 if self.experts:
-                    sp.set(expert_pairs_held=self._step_experts[
-                        "expert_pairs_held"])
+                    sp.set(**{k: self._step_experts[k] for k in (
+                        "expert_pairs_held", "expert_tiles_active",
+                        "expert_tile_rows")})
             return res
 
     def _admit_into(self, slot: int, prompt: np.ndarray, max_new: int,
@@ -1256,7 +1268,7 @@ class SlotEngine:
                     self.model, self.variables, self.cache,
                     jnp.asarray(padded), len(tail), slot, lcp,
                     attention_backend=self.attention_backend)
-            logits = self._count_experts(np.asarray(last, np.float32))
+            logits = self._count_experts(np.asarray(last, np.float32), pb)
             self._account_prefill(pb, lcp, len(tail))
         with step_span("engine.admit.commit"):
             tok = self._sample_host(logits)
@@ -1386,18 +1398,29 @@ class SlotEngine:
                        latent_rows_expanded=kc.layers * rows)
         return out
 
-    def _count_experts(self, out: np.ndarray) -> np.ndarray:
+    def _count_experts(self, out: np.ndarray, tokens: int,
+                       whole: bool = False) -> np.ndarray:
         """Split what a program of a model with expert layers returned:
-        its last two entries are the pass's counts (:func:`_apply`).
+        its last entries are the pass's counts (:func:`_apply`), of a pass
+        of ``tokens`` tokens (``whole``: ``out`` is the counts alone).
         -> the tokens or logits alone."""
         if not self.experts:
             return out
-        pairs, touched = int(out[-2]), int(out[-1])
-        self._step_experts = {"expert_pairs_held": pairs,
-                              "experts_touched": touched}
+        n = len(EXPERT_COUNTS)
+        counts = dict(zip(EXPERT_COUNTS, (int(c) for c in out[-n:])))
+        pairs = counts["expert_pairs_held"]
+        # rows of the tiles computed: each tile is one expert's pairs and
+        # the padding that fills it
+        rows = counts["expert_tiles_active"] * expert_row_tile(self.cfg,
+                                                               tokens)
+        self._step_experts = dict(counts, expert_tile_rows=rows)
         self._m_expert_pairs.inc(pairs, engine=self.name)
-        self._m_experts_touched.inc(touched, engine=self.name)
-        return out[:-2]
+        self._m_experts_touched.inc(counts["experts_touched"],
+                                    engine=self.name)
+        self._m_expert_rows.inc(pairs, engine=self.name, rows="pairs")
+        self._m_expert_rows.inc(rows - pairs, engine=self.name,
+                                rows="padding")
+        return out if whole else out[:-n]
 
     # -- stepping ----------------------------------------------------------
     def _finish_reason(self, slot: int,
@@ -1816,7 +1839,7 @@ class SlotEngine:
         t1 = time.perf_counter()
         with step_span("engine.step.wait"):
             # the step's one blocking call
-            nxt = self._count_experts(np.asarray(flight.nxt))
+            nxt = self._count_experts(np.asarray(flight.nxt), self.n_slots)
         t2 = time.perf_counter()
         with step_span("engine.step.commit"):
             self.last_program = flight.program
@@ -1950,8 +1973,10 @@ class SlotEngine:
         with step_span("engine.step.wait"):
             g = np.asarray(g)         # the step's one blocking call
             if self.experts:
-                self._count_experts(g[-1, :2])
-                g = g[:-1]
+                rows = -(-len(EXPERT_COUNTS) // S)
+                self._count_experts(g[-rows:].reshape(-1)[
+                    :len(EXPERT_COUNTS)], self.n_slots * S, whole=True)
+                g = g[:-rows]
         t2 = time.perf_counter()
         with step_span("engine.step.commit"):
             events = self._finish_step(

@@ -1199,13 +1199,21 @@ class _TokenStream:
     tick and cancels the slot instead of decoding the full budget for
     nobody (the streaming counterpart of the non-stream reply-window
     expiry).  An abandoned stream drops further pushes so the queue
-    cannot grow behind a dead connection."""
+    cannot grow behind a dead connection.
+
+    A pull takes every chunk already queued, joined into one: the writer
+    pays its executor round trip and its socket write once a pull, not
+    once a token, so a writer that fell behind the decode loop catches up
+    instead of holding a finished request's client (and so its next
+    request) for seconds.  A writer that keeps up pulls one token at a
+    time, as before."""
 
     _DONE = object()
 
     def __init__(self):
         self._q: "Queue" = Queue()
         self.abandoned = False
+        self._ended = False
 
     def push(self, chunk: bytes) -> None:
         if not self.abandoned:
@@ -1221,10 +1229,23 @@ class _TokenStream:
         return self
 
     def __next__(self):
+        if self._ended:
+            raise StopIteration
         item = self._q.get()
         if item is self._DONE:
+            self._ended = True
             raise StopIteration
-        return item
+        parts = [item]
+        while True:
+            try:
+                more = self._q.get_nowait()
+            except Empty:
+                break
+            if more is self._DONE:
+                self._ended = True
+                break
+            parts.append(more)
+        return parts[0] if len(parts) == 1 else b"".join(parts)
 
 
 @dataclass

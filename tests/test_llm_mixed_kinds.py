@@ -797,7 +797,12 @@ def test_the_geometry_by_kind_at_the_published_widths():
 #: the pass has fewer rows than the projection's input width
 #: (``model.projection_fold_cut``), which every toy program here has; the
 #: numbers are the earlier programs' bit for bit
-#: (``test_llm_projection_layout.py``)
+#: (``test_llm_projection_layout.py``).  The expert configurations' eight
+#: re-recorded when the expert layer began to return a third count, the
+#: grouped product's tiles (``experts.EXPERT_COUNTS``): their programs gain
+#: that count's slice of each layer's tile total, its sum over the layers and
+#: one more int32 in the output, and nothing else changes (the primitives of
+#: the two jaxprs differ by those alone)
 PARENT_PROGRAMS = {
     "mistral.dense.decode": "9df418197930cc84",
     "mistral.dense.prefill": "6c9b6188b7425e10",
@@ -807,16 +812,16 @@ PARENT_PROGRAMS = {
     "olmo.dense.prefill": "1717a26bccdb00bf",
     "olmo.interpret.decode": "d449403bbce79a33",
     "olmo.interpret.prefill": "d83960202535cb63",
-    "command-a-plus.dense.decode": "174f570516c5549a",
-    "command-a-plus.dense.prefill": "2c3ed2369903b1f9",
-    "command-a-plus.interpret.decode": "cf5c4b69d54785c0",
-    "command-a-plus.interpret.prefill": "da0cab6447737f02",
+    "command-a-plus.dense.decode": "1482454ce00e996e",
+    "command-a-plus.dense.prefill": "f8308bdb18ea92a5",
+    "command-a-plus.interpret.decode": "0aa81f5513b4f4ef",
+    "command-a-plus.interpret.prefill": "70e09111e39ee1fe",
     # the toy MiMo description above at bfloat16, recorded on the commit
     # before latent attention existed (263d8a0)
-    "mimo.dense.decode": "75e46bcec4b4a0e7",
-    "mimo.dense.prefill": "f822b820323e3f56",
-    "mimo.interpret.decode": "09e20e75381bea4b",
-    "mimo.interpret.prefill": "2042e62f6874f1df",
+    "mimo.dense.decode": "7107c730da701f85",
+    "mimo.dense.prefill": "5a5afe0acf8e8ad2",
+    "mimo.interpret.decode": "d3f0f48c87668693",
+    "mimo.interpret.prefill": "9b74b1b0e21359e4",
 }
 
 

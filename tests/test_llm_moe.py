@@ -678,15 +678,17 @@ def test_the_expert_layer_is_exact_under_the_worst_imbalance(ref, backend):
     want = np.asarray(ref.experts(h, w, k=4, first=jnp.asarray(4), ns=2,
                                   quant=None))
     got, stats = _program_experts(SMALL, w, h, backend)
-    assert list(np.asarray(stats)) == [48 * 4, 4]
+    # pairs, experts touched, tiles: 48 pairs an expert in tiles of 32 rows
+    assert X._row_tile(48 * 4, 8) == 32
+    assert list(np.asarray(stats)) == [48 * 4, 4, 4 * 2]
     np.testing.assert_allclose(got, want, atol=5e-5 * np.abs(want).max())
     # tokens that are not real route nowhere
     valid = (jnp.arange(48) < 10)[None]
     got, stats = _program_experts(SMALL, w, h, backend, valid)
-    assert list(np.asarray(stats)) == [10 * 4, 4]
+    assert list(np.asarray(stats)) == [10 * 4, 4, 4]
     idle, stats = _program_experts(SMALL, w, h, backend,
                                    jnp.zeros((1, 48), bool))
-    assert list(np.asarray(stats)) == [0, 0]
+    assert list(np.asarray(stats)) == [0, 0, 0]
     shared = np.asarray(ref.swiglu(h, w["shared_gate"], w["shared_up"],
                                    w["shared_down"], None)) / 2
     np.testing.assert_allclose(idle, shared, atol=5e-5 * np.abs(want).max())

@@ -401,6 +401,25 @@ class TestLLMServer:
         finally:
             srv.close()
 
+    def test_token_stream_pull_takes_every_queued_chunk(self):
+        """A pull joins what the decode loop queued since the last one, so
+        a writer that fell behind pays one round trip for the lot; the end
+        ends the stream after what came before it."""
+        from synapseml_tpu.serving.server import _TokenStream
+        s = _TokenStream()
+        s.push(b'{"token": 1}\n')
+        assert next(s) == b'{"token": 1}\n'
+        for t in (2, 3, 4):
+            s.push(b'{"token": %d}\n' % t)
+        assert next(s) == b'{"token": 2}\n{"token": 3}\n{"token": 4}\n'
+        s.push(b'{"token": 5}\n')
+        s.push(b'{"done": true}\n')
+        s.finish()
+        s.push(b"after the end\n")
+        assert list(s) == [b'{"token": 5}\n{"done": true}\n']
+        with pytest.raises(StopIteration):
+            next(s)
+
     def test_prompt_text_with_tokenizer(self, tiny_model):
         from synapseml_tpu.models.dl.tokenizer import WordTokenizer
         from synapseml_tpu.serving import LLMServer
