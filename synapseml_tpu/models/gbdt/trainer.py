@@ -334,7 +334,11 @@ def depthwise_hist_plan(num_features: int, n_rows: int, p: "GrowthParams",
 
     → ``two_level``; ``ft``, ``feature_groups``, ``chunk`` and
     ``grid_steps_per_pass`` of a wave's pass (0 off the pallas path, or
-    where no tile fits VMEM and the caller must not take it).
+    where no tile fits VMEM and the caller must not take it);
+    ``split_columns``, how a wave reads the columns its splits route by:
+    ``indexed`` on the pallas path (the root, every fused wave and the
+    last wave name them by index and read them where they lie), else
+    ``per_row`` (the XLA path gathers each row's own column).
     ``num_features`` and ``n_rows`` are what ONE device's grower sees
     (bundled columns, padded rows of its shard)."""
     from .pallas_hist import fused_geometry
@@ -353,7 +357,8 @@ def depthwise_hist_plan(num_features: int, n_rows: int, p: "GrowthParams",
     groups = -(-num_features // ft) if ft else 0
     return dict(two_level=bool(tl), ft=ft, feature_groups=groups,
                 chunk=chunk,
-                grid_steps_per_pass=(n_rows // chunk) * groups if ft else 0)
+                grid_steps_per_pass=(n_rows // chunk) * groups if ft else 0,
+                split_columns="indexed" if ft else "per_row")
 
 
 def _pool_coarse(hist, Bc: int, shift: int):
@@ -1315,17 +1320,17 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
 
     # root: one batched pass with every row in slot 0.  On the pallas path
     # this rides the FUSED kernel with a degenerate all-left split of leaf 0
-    # (t1=B → every row left, child id 0 → node ids unchanged): the fused
-    # kernel computes its slot mask once per chunk instead of once per
-    # (feature-tile, chunk) step (an earlier round's reading, 1M x 28 on
-    # another chip: some 25% under the nodes kernel for the same histograms)
+    # on column 0 (t1=B → every row left whatever its value, child id 0 →
+    # node ids unchanged): the fused kernel computes its slot mask once per
+    # chunk instead of once per (feature-tile, chunk) step (an earlier
+    # round's reading, 1M x 28 on another chip: some 25% under the nodes
+    # kernel for the same histograms)
     if use_pallas:
         from .pallas_hist import route_and_hist_pallas
         jv = jnp.full((S,), JUNK, jnp.int32)
         _, root_hists = route_and_hist_pallas(
             bins_pl, jnp.zeros(N, jnp.int32), jv.at[0].set(0),
-            jnp.take(bins_t, jnp.zeros(S, jnp.int32), axis=0),
-            jnp.full((S,), B, jnp.int32),
+            jnp.zeros(S, jnp.int32), jnp.full((S,), B, jnp.int32),
             jnp.full((S,), -1, jnp.int32), jnp.full((S,), B, jnp.int32),
             jnp.ones(S, jnp.int32), jnp.zeros(S, jnp.int32),
             jnp.zeros(S, jnp.int32), vals8, scales, S, B,
@@ -1421,12 +1426,13 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
         leaves_after = (s["num_nodes"] + 1) // 2 + n_valid
         lf = None
         if use_pallas:
-            from .pallas_hist import route_and_hist_pallas
+            from .pallas_hist import route_and_hist_pallas, route_rows_pallas
 
+            # the split columns go by index, never copied: each kernel
+            # reads them where they lie in the binned matrix
             def fused_wave(_):
                 out = route_and_hist_pallas(
-                    bins_pl, s["node_id"], parents,
-                    jnp.take(bins_t, rt_col, axis=0), rt_t1, rt_lo,
+                    bins_pl, s["node_id"], parents, rt_col, rt_t1, rt_lo,
                     rt_hi, rt_df, l_ids, r_ids, vals8, scales, S, B,
                     hist_shift=(SH if tl else 0),
                     sel_k=(sel_k if tl else None),
@@ -1441,17 +1447,14 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
             def route_only(_):
                 # this wave fills the leaf budget: its child histograms can
                 # never feed another split, so skip the one-hot pass (one of
-                # five full-data passes per 31-leaf tree) and route in plain
-                # XLA from the gathered split-column rows.  Child pick
-                # stats (sum_g/h/c) come from the parent pick, not from
-                # these histograms, so zeros are safe.
-                sel = jnp.take(bins_t, rt_col, axis=0)
-                inleaf = s["node_id"][None, :] == parents[:, None]   # (S, N)
-                gl = _route_left(sel, rt_t1[:, None], rt_lo[:, None],
-                                 rt_hi[:, None], rt_df[:, None])
-                new = (jnp.sum(jnp.where(inleaf & gl, l_ids[:, None], 0), 0)
-                       + jnp.sum(jnp.where(inleaf & ~gl, r_ids[:, None], 0), 0)
-                       + jnp.where(jnp.any(inleaf, 0), 0, s["node_id"]))
+                # five full-data passes per 31-leaf tree) and route alone:
+                # the fused pass's routing in one pass over the matrix and
+                # node_id.  Child pick stats (sum_g/h/c) come from the
+                # parent pick, not from these histograms, so zeros are safe.
+                new = route_rows_pallas(
+                    bins_pl, s["node_id"], parents, rt_col, rt_t1, rt_lo,
+                    rt_hi, rt_df, l_ids, r_ids,
+                    interpret=(use_pallas == "interpret"))
                 zf_ = (jnp.zeros((S, K, B, 3), jnp.float32) if tl
                        else jnp.zeros(0, jnp.float32))
                 return new, jnp.zeros((S, F, Bh, 3), jnp.float32), zf_

@@ -289,6 +289,175 @@ def test_fused_pass_is_the_integer_reference_bit_for_bit(
     assert want[..., 6].sum() > 0           # rows did reach a histogram
 
 
+def _splits(rng, F, B, S, live, ranged):
+    """Slot parameters of a wave: ``live`` slots split leaves 0.., the
+    rest are JUNK (leaf 61, matching no row, their column out of range:
+    the kernel must clamp it, never read past the matrix); ``ranged``
+    gives every slot an EFB-style range and default direction."""
+    JUNK = 61
+    leaf = np.where(np.arange(S) < live, np.arange(S), JUNK).astype(np.int32)
+    cols = np.where(np.arange(S) < live, rng.integers(0, F, S),
+                    F + 5).astype(np.int32)
+    t1 = rng.integers(0, B, S).astype(np.int32)
+    rlo = np.full(S, -1, np.int32)
+    rhi = np.full(S, B, np.int32)
+    dflt = np.ones(S, np.int32)
+    if ranged:
+        rlo = rng.integers(-1, B // 2, S).astype(np.int32)
+        rhi = (rlo + rng.integers(1, B // 2, S)).astype(np.int32)
+        dflt = rng.integers(0, 2, S).astype(np.int32)
+    l_id = (100 + 2 * np.arange(S)).astype(np.int32)
+    return dict(leaf=leaf, cols=cols, t1=t1, rlo=rlo, rhi=rhi, dflt=dflt,
+                l_id=l_id, r_id=l_id + 1)
+
+
+def _ragged_tile_cols(F, live, cols):
+    """At 60 features (two groups of 30) the live slots split the last
+    rows of the groups' ragged last tiles; any other width keeps its
+    random columns."""
+    return np.array([29, 59, 24][:live], np.int32) if F == 60 \
+        else cols[:live]
+
+
+@pytest.mark.parametrize("F,B,shift,K,S,live,N,ranged,root", [
+    # the cell: one feature group, rows read from the chunk's whole block
+    pytest.param(28, 256, 3, 8, 16, 12, 8192, False, False,
+                 id="one-group-two-level-refined"),
+    pytest.param(28, 256, 0, 0, 16, 12, 4096, False, False,
+                 id="four-groups-plain"),
+    # five groups of 8 for four slots: a tile of 8 rows a slot
+    pytest.param(40, 256, 0, 0, 4, 3, 4096, False, False,
+                 id="more-groups-than-slots"),
+    # two groups of 30: a slot's tile may be the group's last, rows 24-29
+    pytest.param(60, 16, 0, 0, 4, 3, 4096, False, False,
+                 id="a-ragged-last-tile"),
+    pytest.param(9, 200, 0, 0, 4, 4, 4096, True, False,
+                 id="efb-ranged-splits"),
+    pytest.param(28, 256, 3, 8, 16, 1, 8192, True, False,
+                 id="junk-slots"),
+    # the root's pass: every row left of leaf 0 whatever column 0 holds
+    pytest.param(28, 256, 3, 0, 16, 1, 8192, False, True,
+                 id="root-degenerate-split"),
+])
+def test_index_form_is_the_rows_form_bit_for_bit(
+        F, B, shift, K, S, live, N, ranged, root):
+    """``sel`` as (S,) column indices gives, bit for bit, what the (S, N)
+    rows of those columns give: ``new_node_id``, the coarse-or-plain
+    accumulator and the refined one, and both are the numpy reference."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+
+    rng = np.random.default_rng(F * 100 + live + 7 * root)
+    bins = rng.integers(0, B - 1, (F, N)).astype(np.int32)
+    node_id = rng.integers(0, live + 1, N).astype(np.int32)
+    sp = _splits(rng, F, B, S, live, ranged)
+    sp["cols"][:live] = _ragged_tile_cols(F, live, sp["cols"])
+    if root:
+        node_id[:] = 0
+        sp.update(cols=np.zeros(S, np.int32), t1=np.full(S, B, np.int32),
+                  l_id=np.zeros(S, np.int32), r_id=np.zeros(S, np.int32))
+    grad = rng.normal(size=N).astype(np.float32)
+    vals, _ = ph.prep_hist_vals_rows(
+        jnp.asarray(grad), jnp.asarray(np.abs(grad) + 0.2),
+        jnp.asarray((rng.random(N) < 0.9).astype(np.float32)))
+    sel_k = bins[rng.choice(F, K, replace=False)] if K else None
+    rows = bins[np.clip(sp["cols"], 0, F - 1)]
+    run = jax.jit(functools.partial(
+        ph._route_and_hist_int, n_slots=S, total_bins=B, hist_shift=shift,
+        interpret=True, hist_chunk=0))
+
+    def call(sel):
+        return run(jnp.asarray(bins), jnp.asarray(node_id),
+                   jnp.asarray(sp["leaf"]), jnp.asarray(sel),
+                   *(jnp.asarray(sp[k]) for k in ("t1", "rlo", "rhi", "dflt",
+                                                  "l_id", "r_id")),
+                   vals, sel_k=None if sel_k is None else jnp.asarray(sel_k))
+    by_index, by_rows = call(sp["cols"]), call(rows)
+    assert len(by_index) == len(by_rows) == (3 if K else 2)
+    for a, b in zip(by_index, by_rows):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    Bh = ph.coarse_bins(B, shift) if shift else B
+    want_id, want, _ = _int_reference(
+        bins, node_id, sp["leaf"], rows, sp["t1"], sp["rlo"], sp["rhi"],
+        sp["dflt"], sp["l_id"], sp["r_id"], np.asarray(vals)[:8].T, S, Bh,
+        shift, sel_k, B)
+    np.testing.assert_array_equal(np.asarray(by_index[0])[0], want_id)
+    np.testing.assert_array_equal(
+        np.asarray(by_index[1]).reshape(F, Bh, S, 8), want)
+    assert want[..., 6].sum() > 0
+
+
+@pytest.mark.parametrize("F,B,S,live,N,ft,ranged", [
+    pytest.param(28, 256, 16, 12, 8192, 28, False, id="one-group"),
+    pytest.param(28, 256, 16, 12, 4096, 7, False, id="tiles-of-7"),
+    pytest.param(9, 200, 4, 4, 3072, 3, True, id="efb-ranged-splits"),
+    pytest.param(28, 256, 16, 1, 8192, 28, True, id="junk-slots"),
+    pytest.param(40, 256, 4, 3, 4096, 8, False,
+                 id="more-groups-than-slots"),
+    pytest.param(60, 16, 4, 3, 4096, 30, False, id="a-ragged-last-tile"),
+])
+def test_the_last_waves_routing_is_route_left(F, B, S, live, N, ft, ranged):
+    """``route_rows_pallas`` (the wave that fills the leaf budget) moves
+    every row exactly as the XLA reference ``_route_left`` does, from the
+    flat matrix or its (G, ft, N) tiles."""
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+    from synapseml_tpu.models.gbdt.trainer import _route_left
+
+    rng = np.random.default_rng(N + live + ft)
+    bins = rng.integers(0, B - 1, (F, N)).astype(np.int32)
+    node_id = rng.integers(0, live + 2, N).astype(np.int32)
+    sp = _splits(rng, F, B, S, live, ranged)
+    sp["cols"][:live] = _ragged_tile_cols(F, live, sp["cols"])
+    src = jnp.asarray(bins)
+    if ft != F:
+        src = ph.feature_tiles(src, ft)
+    new = ph.route_rows_pallas(
+        src, jnp.asarray(node_id), *(jnp.asarray(sp[k]) for k in (
+            "leaf", "cols", "t1", "rlo", "rhi", "dflt", "l_id", "r_id")),
+        interpret=True)
+    want = node_id.copy()
+    for j in range(live):
+        gl = np.asarray(_route_left(bins[sp["cols"][j]], sp["t1"][j],
+                                    sp["rlo"][j], sp["rhi"][j],
+                                    sp["dflt"][j]))
+        want = np.where(node_id == sp["leaf"][j],
+                        np.where(gl, sp["l_id"][j], sp["r_id"][j]), want)
+    np.testing.assert_array_equal(np.asarray(new), want)
+    assert (want != node_id).any()
+
+
+def test_the_wave_program_copies_no_split_columns():
+    """The depth-wise grower at the two-level geometry, lowered for the
+    TPU: no gather makes an (S, N) matrix of split columns (the root, the
+    fused waves and the last wave all name them by index), and both
+    kernels are in the program."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt.trainer import (GrowthParams,
+                                                   grow_tree_depthwise)
+
+    N, F, B, S = 8192, 28, 256, 16
+    p = GrowthParams(num_leaves=31, total_bins=B, two_level="on",
+                     refine_k=8)
+    sd = jax.ShapeDtypeStruct
+    f32 = sd((N,), jnp.float32)
+    text = grow_tree_depthwise.trace(
+        sd((F, N), jnp.int32), f32, f32, f32, sd((F,), jnp.bool_),
+        sd((F, B - 1), jnp.float32), sd((F,), jnp.int32), 0.1, p,
+        use_pallas=True, n_slots=S).lower(
+            lowering_platforms=("tpu",)).as_text()
+    gathers = re.findall(r"stablehlo\.gather.*-> (tensor<[^>]*>)", text)
+    assert gathers, "expected the refined features' and the picks' gathers"
+    assert f"tensor<{S}x{N}xi32>" not in gathers, gathers
+    assert "route_and_hist_pallas" in text and "route_rows_pallas" in text
+
+
 @pytest.mark.parametrize("F,B,S,shift,K,chunk_override,want", [
     # the cell: the tile a step builds is 28 x 32 coarse rows beside the
     # 8 x 256 refined block, so ONE feature group, one step a chunk
@@ -303,6 +472,11 @@ def test_fused_pass_is_the_integer_reference_bit_for_bit(
     # a tuned chunk starts the search elsewhere and still shrinks to fit
     pytest.param(28, 256, 16, 3, 8, 1024, (28, 1024), id="tuned-1024"),
     pytest.param(28, 256, 16, 3, 8, 8192, (28, 2048), id="tuned-too-big"),
+    # the split columns' routing block is asked of the compiler beside the
+    # budget: a wide matrix keeps the geometry it had when the columns
+    # arrived as a (16, N) copy (12 groups of 7 near the budget, 8 of 32)
+    pytest.param(84, 256, 16, 0, 0, 0, (7, 1024), id="near-the-budget"),
+    pytest.param(256, 64, 16, 0, 0, 0, (32, 2048), id="wide-low-bin"),
     # VMEM cannot hold it: the callers fall back (scatter path, or
     # full-resolution growth for an uncapped refine_features)
     pytest.param(400, 256, 16, 0, 0, 0, None, id="wide-matrix"),
@@ -345,8 +519,10 @@ def test_a_tuned_hist_chunk_still_goes_through_hist_chunk_ok():
 
 def test_the_fit_span_says_what_the_geometry_chose():
     """``gbdt.fit`` carries the depth-wise grower's plan: on the CPU no
-    pallas tile (zeros), and whether the histograms are two-level; the
-    plan itself at the cell's shapes is one step a chunk."""
+    pallas tile (zeros) and each row's split column gathered per row,
+    and whether the histograms are two-level; the plan itself at the
+    cell's shapes is one step a chunk, its split columns read by
+    index."""
     from synapseml_tpu import telemetry
     from synapseml_tpu.models.gbdt.trainer import (GrowthParams,
                                                    depthwise_hist_plan)
@@ -355,11 +531,11 @@ def test_the_fit_span_says_what_the_geometry_chose():
     assert depthwise_hist_plan(28, 12_001_280, p, 16, bundled=False,
                                use_pallas=True) == dict(
         two_level=True, ft=28, feature_groups=1, chunk=2048,
-        grid_steps_per_pass=5860)
+        grid_steps_per_pass=5860, split_columns="indexed")
     assert depthwise_hist_plan(28, 12_001_280, p, 16, bundled=True,
                                use_pallas=True) == dict(
         two_level=False, ft=7, feature_groups=4, chunk=2048,
-        grid_steps_per_pass=23440)
+        grid_steps_per_pass=23440, split_columns="indexed")
     X, y = _data(n=4_000, F=12)
     train(X, y, BoostingConfig(objective="binary", num_iterations=2,
                                num_leaves=7, max_bin=255,
@@ -368,6 +544,7 @@ def test_the_fit_span_says_what_the_geometry_chose():
     assert a["hist_two_level"] is True
     assert (a["hist_ft"], a["hist_feature_groups"], a["hist_chunk"],
             a["hist_grid_steps_per_pass"]) == (0, 0, 0, 0)
+    assert a["hist_split_columns"] == "per_row"
 
 
 def test_fused_refine_vmem_gate():

@@ -853,3 +853,55 @@ def test_fused_histogram_pass_compiles_for_the_v5e_with_no_copy_of_the_bins(
     assert "tpu_custom_call" in text and "route_and_hist_pallas" in text
     assert re.search(rf"s32\[1,{F},{N}\]\S* bitcast\(", text)
     assert not re.search(rf"s32\[\d+,\d+,{N}\]\S* (copy|fusion)\(", text)
+
+
+def test_split_columns_are_read_by_index_on_the_v5e(one_chip):
+    """At the boosting cell's shapes a wave's fused pass with its 16 split
+    columns named by index, and the last wave's routing pass, compile for
+    the chip with the binned matrix reaching each as a bitcast: no
+    (16, N) matrix of split columns and no copy of the bins."""
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+    N, F, B, S = 12_001_280, 28, 256, 16
+
+    def sd(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fused = ph.route_and_hist_pallas.lower(
+        sd((F, N)), sd((N,)), sd((S,)), sd((S,)), *[sd((S,))] * 6,
+        sd((32, N), jnp.int8), sd((2,), jnp.float32), n_slots=S,
+        total_bins=B, hist_shift=3, sel_k=sd((8, N))).compile().as_text()
+    route = ph.route_rows_pallas.lower(
+        sd((F, N)), sd((N,)), *[sd((S,))] * 8).compile().as_text()
+    for text, name in ((fused, "route_and_hist_pallas"),
+                       (route, "route_rows_pallas")):
+        assert "tpu_custom_call" in text and name in text
+        assert re.search(rf"s32\[1,{F},{N}\]\S* bitcast\(", text)
+        assert f"s32[{S},{N}]" not in text
+        assert not re.search(rf"s32\[\d+,\d+,{N}\]\S* (copy|fusion)\(", text)
+
+
+@pytest.mark.parametrize("F,B", [
+    pytest.param(256, 64, id="eight-groups-of-32"),
+    pytest.param(84, 256, id="twelve-groups-of-7"),
+    pytest.param(1580, 16, id="79-groups-of-20"),
+])
+def test_wide_matrices_route_by_index_on_the_v5e(one_chip, F, B):
+    """Where the binned matrix has many feature groups, both kernels still
+    compile for the chip at the histogram geometry they had when the split
+    columns arrived as a (16, N) copy: routing reads the chunk's whole
+    block, or a tile of 8 rows a slot where that is fewer, and the fused
+    pass asks for those blocks beside its budget."""
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+    N, S = 1_048_576, 16
+
+    def sd(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fused = ph.route_and_hist_pallas.lower(
+        sd((F, N)), sd((N,)), sd((S,)), sd((S,)), *[sd((S,))] * 6,
+        sd((32, N), jnp.int8), sd((2,), jnp.float32), n_slots=S,
+        total_bins=B).compile().as_text()
+    route = ph.route_rows_pallas.lower(
+        sd((F, N)), sd((N,)), *[sd((S,))] * 8).compile().as_text()
+    for text, name in ((fused, "route_and_hist_pallas"),
+                       (route, "route_rows_pallas")):
+        assert "tpu_custom_call" in text and name in text
+        assert f"s32[{S},{N}]" not in text
