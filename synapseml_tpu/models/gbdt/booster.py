@@ -1855,7 +1855,8 @@ def _train(fit_span, X, y, config, sample_weight, valid, feature_names, mesh,
         # what the depth-wise grower will build its histograms with (the
         # grower decides by the same function at trace time): hist_ft,
         # hist_feature_groups, hist_chunk, hist_grid_steps_per_pass,
-        # hist_two_level, hist_split_columns on the gbdt.fit span
+        # hist_two_level, hist_split_columns, hist_refined_columns,
+        # hist_coarse_rows on the gbdt.fit span
         from .trainer import depthwise_hist_plan
         fit_span.set(**{f"hist_{k}": v for k, v in depthwise_hist_plan(
             _hist_F, N // max(row_shards, 1), _sargs[0],

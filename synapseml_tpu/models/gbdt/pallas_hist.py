@@ -335,15 +335,21 @@ def _vals_layout(vals: jnp.ndarray, N: int, chunk: int):
             (((1,), (1,)), ((), ())), True)
 
 
-def _make_hist_nodes_kernel(ft: int, shift: int, dims):
-    def kernel(bins_ref, slot_ref, vals_ref, out_ref, oh_ref):
+def _make_hist_nodes_kernel(ft: int, shift: int, dims, indexed: bool = False):
+    def kernel(*refs):
         """Grid (G, N//chunk) — c fastest.  bins block (1, ft, C) int32;
         slot block (1, C) int32 (row's node slot, -1 = no slot); vals block
         (C, 8) int8 limbs or channel rows (32, C) (:func:`_slot_values`); out
         block (1, ft·B, S·8) int32 revisited
         across the chunk dim — per-TILE residency keeps VMEM use
         F-independent (a fully resident accumulator scales with F and
-        stops compiling near F≈60 at B=256)."""
+        stops compiling near F≈60 at B=256).  ``indexed``: a
+        scalar-prefetched ``feats`` comes first, the bins block is the
+        matrix's whole (1, F, C) and step ``f`` reads its rows
+        ``feats[f·ft + k]``."""
+        feats_ref = refs[0] if indexed else None
+        bins_ref, slot_ref, vals_ref, out_ref, oh_ref = refs[int(indexed):]
+        f = pl.program_id(0)
         c = pl.program_id(1)
 
         @pl.when(c == 0)
@@ -353,7 +359,7 @@ def _make_hist_nodes_kernel(ft: int, shift: int, dims):
         B = oh_ref.shape[0] * 4 // ft
         S = out_ref.shape[2] // SLOT_LANES
         for k in range(ft):
-            b = bins_ref[0, k, :]
+            b = bins_ref[0, feats_ref[f * ft + k] if indexed else k, :]
             if shift:
                 # two-level mode: coarse (bin >> shift) histograms
                 b = b >> shift
@@ -442,10 +448,19 @@ def build_hist_nodes_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
                             total_bins: int,
                             hist_shift: int = 0,
                             interpret: bool = False,
-                            hist_chunk: int = 0) -> jnp.ndarray:
+                            hist_chunk: int = 0,
+                            feats: jnp.ndarray = None) -> jnp.ndarray:
     """→ (n_slots, F, Bh, 3) float32 [grad, hess, count] histograms
     (Bh = :func:`coarse_bins` when ``hist_shift`` > 0 — the leaf-wise
     grower's two-level coarse build).
+
+    ``feats`` (K,) int32 builds the histograms of those K rows of
+    ``bins_t`` only (→ (n_slots, K, Bh, 3)), read by scalar-prefetched
+    index from the matrix's (1, F, C) block where they lie: no copy of
+    the rows is made.  ``bins_t`` is then (F, N) or the one-group
+    (1, F, N) view; the depth-wise grower's root fine build at one
+    feature group.  The block is F rows where the gathered rows were K:
+    a read that hides under the build's int8 products.
 
     ``hist_chunk`` overrides the ladder's rows-per-chunk (the tuned
     ``gbdt_hist_chunk`` winner, threaded from
@@ -455,7 +470,18 @@ def build_hist_nodes_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
     compile to every later candidate."""
     B = total_bins
     Bh = coarse_bins(B, hist_shift) if hist_shift else B
-    bins_r, F, G, ft, N = _bins_tiles(bins_t, B)
+    if feats is None:
+        bins_r, F, G, ft, N = _bins_tiles(bins_t, B)
+    else:
+        bins_r = bins_t[None] if bins_t.ndim == 2 else bins_t
+        assert bins_r.shape[0] == 1, (
+            f"feats need the (F, N) matrix or its one-group view, not "
+            f"{bins_r.shape}")
+        N = bins_r.shape[2]
+        F = feats.shape[0]
+        ft = _feat_tile(F, _tile_for(B)[0])
+        G = F // ft
+        feats = jnp.clip(feats.astype(jnp.int32), 0, bins_r.shape[1] - 1)
     _, chunk = _tile_for(B)
     if hist_chunk:
         chunk = int(hist_chunk)
@@ -466,20 +492,39 @@ def build_hist_nodes_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
     assert N % chunk == 0, f"N={N} must be a multiple of {chunk}"
     VN = n_slots * SLOT_LANES
     vblock, vix, dims, _ = _vals_layout(vals, N, chunk)
-
-    out = pl.pallas_call(
-        _make_hist_nodes_kernel(ft, hist_shift, dims),
-        grid=(G, N // chunk),
-        in_specs=[
-            pl.BlockSpec((1, ft, chunk), lambda f, c: (f, 0, c)),
-            pl.BlockSpec((1, chunk), lambda f, c: (0, c)),
-            pl.BlockSpec(vblock, lambda f, c: vix(c)),
-        ],
-        out_specs=pl.BlockSpec((1, ft * Bh, VN), lambda f, c: (f, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, ft * Bh, VN), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((ft * Bh // 4, chunk), jnp.int32)],
-        interpret=interpret,
-    )(bins_r, slot[None, :], vals)
+    if feats is None:
+        bins_spec = pl.BlockSpec((1, ft, chunk), lambda f, c, *_: (f, 0, c))
+    else:
+        rows = bins_r.shape[1]
+        assert (ft * Bh * chunk + _rows_block_bytes(rows, chunk)
+                <= _VMEM_BUDGET), f"a (1, {rows}, {chunk}) block and the " \
+            "one-hot scratch do not fit VMEM"
+        bins_spec = pl.BlockSpec((1, rows, chunk), lambda f, c, *_: (0, 0, c))
+    in_specs = [
+        bins_spec,
+        pl.BlockSpec((1, chunk), lambda f, c, *_: (0, c)),
+        pl.BlockSpec(vblock, lambda f, c, *_: vix(c)),
+    ]
+    out_spec = pl.BlockSpec((1, ft * Bh, VN), lambda f, c, *_: (f, 0, 0))
+    scratch = [pltpu.VMEM((ft * Bh // 4, chunk), jnp.int32)]
+    kernel = _make_hist_nodes_kernel(ft, hist_shift, dims,
+                                     indexed=feats is not None)
+    out_shape = jax.ShapeDtypeStruct((G, ft * Bh, VN), jnp.int32)
+    if feats is None:
+        out = pl.pallas_call(
+            kernel, grid=(G, N // chunk), in_specs=in_specs,
+            out_specs=out_spec, out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret,
+        )(bins_r, slot[None, :], vals)
+    else:
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(G, N // chunk),
+                in_specs=in_specs, out_specs=out_spec,
+                scratch_shapes=scratch),
+            out_shape=out_shape, interpret=interpret,
+        )(feats, bins_r, slot[None, :], vals)
 
     # (G, ft·Bh, S·8) → (F, Bh, S, 8) → (S, F, Bh, 3)
     out = out.reshape(G * ft, Bh, n_slots, SLOT_LANES)[:F]
@@ -525,6 +570,16 @@ def build_hist_nodes_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
 # One (1, 1, C) block a slot on an (F, 1, N) view would fetch 16 rows
 # only, but that view is not free: XLA relays it out to (1, 128) tiles, a
 # copy of the whole matrix.
+#
+# The two-level pass's refined features go by index too where one feature
+# group holds the tile: the histogram block reads them as rows of the tile
+# (``fk``) and builds the other features' coarse rows (``coarse``) only,
+# so each feature's one-hot is built once a chunk, at the one resolution
+# the grower reads: 20 x 32 + 8 x 256 rows at the cell's shapes where
+# every feature's coarse rows beside the refined block were 28 x 32 +
+# 8 x 256.  The refined features' coarse histograms are pooled from their
+# fine ones after the call.  With more than one group a refined column may
+# not be in the step's tile, so they come as a gathered (K, N) block.
 
 
 def _route_rows(row, nid, leaf_ref, t1_ref, rlo_ref, rhi_ref, dflt_ref,
@@ -592,24 +647,32 @@ def _routing_rows(route, n_slots: int) -> int:
 
 
 def _make_fused_kernel(ft: int, shift: int, refine: bool, n_route: int,
-                       read, dims):
+                       read, dims, indexed: bool = False):
     """``refine=True`` (two-level mode) adds a second histogram output:
-    full-resolution histograms of K pre-gathered refined-feature rows
-    (``selk``), built at f==0 from the SAME slot-masked value matrix the
-    coarse tiles use — one bins read, one routing, one vn build for both
-    levels."""
+    full-resolution histograms of K refined-feature rows, built at f==0
+    from the SAME slot-masked value matrix the coarse tiles use — one
+    bins read, one routing, one vn build for both levels.  The rows come
+    as a pre-gathered ``selk`` block, or, ``indexed``, from the one-group
+    histogram tile by the prefetched ``fk``, and then the coarse block
+    builds only the prefetched ``coarse`` features (the unrefined ones):
+    each feature's one-hot is built once, at the resolution the grower
+    reads."""
     def kernel(cols_ref, leaf_ref, t1_ref, rlo_ref, rhi_ref, dflt_ref,
                lid_ref, rid_ref, *refs):
         """Grid (N//chunk, G) — f fastest.  Scalars: ``cols`` (S,), the
-        row of the routing block each slot routes by, and the splits.
+        row of the routing block each slot routes by, and the splits;
+        ``indexed``: ``coarse`` (F − K,) and ``fk`` (K,), rows of the
+        histogram tile.
         Blocks: ``n_route`` routing blocks (:func:`_routing_blocks`, whose
         ``read`` names their rows),
         bins (1, ft, C) (histogram tile), nid (1, C), vals (C, 8) int8
         limbs or channel rows (32, C) (:func:`_slot_values`); outputs:
         newid (1, C) and the resident histogram accumulator
-        (G, ft·B, S·8) int32."""
+        (G, ft·B, S·8) int32 (``indexed``: (1, (F − K)·B, S·8))."""
         refs = list(refs)
-        selk_ref = refs.pop(0) if refine else None
+        coarse_ref, fk_ref = (refs.pop(0), refs.pop(0)) if indexed else \
+            (None, None)
+        selk_ref = refs.pop(0) if refine and not indexed else None
         route_refs = [refs.pop(0) for _ in range(n_route)]
         bins_ref, nid_ref, vals_ref, newid_ref, out_ref = refs[:5]
         outf_ref = refs.pop(5) if refine else None
@@ -617,6 +680,8 @@ def _make_fused_kernel(ft: int, shift: int, refine: bool, n_route: int,
         ohf_ref = refs[7] if refine else None
         c = pl.program_id(0)
         f = pl.program_id(1)
+        # the histogram tile's rows the coarse block builds
+        n_coarse = coarse_ref.shape[0] if indexed else ft
 
         @pl.when((c == 0) & (f == 0))
         def _init():
@@ -624,7 +689,7 @@ def _make_fused_kernel(ft: int, shift: int, refine: bool, n_route: int,
             if refine:
                 outf_ref[...] = jnp.zeros_like(outf_ref)
 
-        B = oh_ref.shape[0] * 4 // ft
+        B = oh_ref.shape[0] * 4 // n_coarse
         S = out_ref.shape[2] // SLOT_LANES
 
         @pl.when(f == 0)
@@ -639,17 +704,18 @@ def _make_fused_kernel(ft: int, shift: int, refine: bool, n_route: int,
                 # fine-K histograms off the SAME slot-masked values: the
                 # separate refine pass re-read bins, re-derived slots and
                 # re-built vn — here it costs one extra one-hot + matmul
-                K = selk_ref.shape[0]
+                K = fk_ref.shape[0] if indexed else selk_ref.shape[0]
                 Bf = ohf_ref.shape[0] * 4 // K
                 for k in range(K):
-                    _store_onehot(ohf_ref, k, selk_ref[k, :], Bf)
+                    _store_onehot(ohf_ref, k, bins_ref[0, fk_ref[k], :]
+                                  if indexed else selk_ref[k, :], Bf)
                 fcontrib = lax.dot_general(
                     ohf_ref.bitcast(jnp.int8)[...], vn_ref[...], dims,
                     preferred_element_type=jnp.int32)
                 outf_ref[...] += fcontrib[None]
 
-        for k in range(ft):
-            b = bins_ref[0, k, :]
+        for k in range(n_coarse):
+            b = bins_ref[0, coarse_ref[k] if indexed else k, :]
             if shift:
                 # two-level mode: histogram at COARSE (bin >> shift)
                 # resolution while routing stays at fine resolution: the
@@ -698,20 +764,52 @@ def _split_columns(bins_r, sel, n_slots: int):
     return jnp.clip(sel.astype(jnp.int32), 0, G * ft - 1), bins_r
 
 
+def _refined_features(sel_k, F: int):
+    """The index form's features → (coarse (F − K,), fk (K,)) int32: the
+    unrefined features in order and the refined ones, clamped into the
+    matrix (the kernel reads VMEM unchecked)."""
+    fk = jnp.clip(sel_k.astype(jnp.int32), 0, F - 1)
+    keep = jnp.ones(F, jnp.bool_).at[fk].set(False)
+    return jnp.nonzero(keep, size=F - fk.shape[0])[0].astype(jnp.int32), fk
+
+
+def _with_refined_coarse(out, outf, coarse, fk, B: int, Bh: int,
+                         shift: int):
+    """The index form's accumulators → the (1, F·Bh, S·8) coarse
+    accumulator of all F features: the unrefined features' rows where
+    ``coarse`` names them, the refined features' pooled from their fine
+    rows in groups of ``1 << shift``.  Integer sums, exact and
+    order-free, so bit for bit what the coarse block would have built."""
+    VN = out.shape[-1]
+    g = 1 << shift
+    fine = jnp.pad(outf.reshape(-1, B, VN), ((0, 0), (0, Bh * g - B), (0, 0)))
+    pooled = fine.reshape(-1, Bh, g, VN).sum(2, dtype=jnp.int32)
+    F = coarse.shape[0] + fk.shape[0]
+    full = (jnp.zeros((F, Bh, VN), jnp.int32)
+            .at[coarse].set(out.reshape(-1, Bh, VN)).at[fk].set(pooled))
+    return full.reshape(1, F * Bh, VN)
+
+
 def _route_and_hist_int(bins_t, node_id, leaf, sel, t1, rlo, rhi, dflt,
                         l_id, r_id, vals, n_slots, total_bins, hist_shift,
                         sel_k, interpret, hist_chunk):
     """The fused pass's raw outputs: (new_node_id (1, N) int32, coarse or
     plain accumulator (G, ft·Bh, S·8) int32[, refined accumulator
     (1, K·B, S·8) int32]).  Integer sums of int8 products: the order of
-    rows, chunks and feature tiles cannot change a bit of them."""
+    rows, chunks and feature tiles cannot change a bit of them, nor
+    whether ``sel_k`` comes as rows or as indices."""
     B = total_bins
     Bh = coarse_bins(B, hist_shift) if hist_shift else B
     refine = sel_k is not None
+    indexed = refine and sel_k.ndim == 1
     K = sel_k.shape[0] if refine else 0
     bins_r, chunk = fused_tiles(bins_t, n_slots, B, hist_shift, K,
                                 hist_chunk)
     G, ft, N = bins_r.shape
+    assert not indexed or (G == 1 and ft > K), (
+        f"refined features by index need one feature group holding more "
+        f"than the K={K} of them, not ({G}, {ft})")
+    n_coarse = ft - K if indexed else ft
     VN = n_slots * SLOT_LANES
     vblock, vix, dims, rows = _vals_layout(vals, N, chunk)
     cols, route = _split_columns(bins_r, sel, n_slots)
@@ -723,31 +821,37 @@ def _route_and_hist_int(bins_t, node_id, leaf, sel, t1, rlo, rhi, dflt,
     ]
     out_specs = [
         pl.BlockSpec((1, chunk), lambda c, f, *_: (0, c)),
-        pl.BlockSpec((G, ft * Bh, VN), lambda c, f, *_: (0, 0, 0)),
+        pl.BlockSpec((G, n_coarse * Bh, VN), lambda c, f, *_: (0, 0, 0)),
     ]
     out_shape = [jax.ShapeDtypeStruct((1, N), jnp.int32),
-                 jax.ShapeDtypeStruct((G, ft * Bh, VN), jnp.int32)]
-    scratch = [pltpu.VMEM((ft * Bh // 4, chunk), jnp.int32),  # one-hot words
+                 jax.ShapeDtypeStruct((G, n_coarse * Bh, VN), jnp.int32)]
+    scratch = [pltpu.VMEM((n_coarse * Bh // 4, chunk),
+                          jnp.int32),                       # one-hot words
                pltpu.VMEM((VN, chunk) if rows else (chunk, VN),
                           jnp.int8)]                        # _slot_values
     operands = route_ops + [bins_r, node_id[None, :], vals]
+    scalars = [cols, leaf, t1, rlo, rhi, dflt, l_id, r_id]
     if refine:
-        in_specs.insert(0, pl.BlockSpec((K, chunk), lambda c, f, *_: (0, c)))
         out_specs.append(pl.BlockSpec((1, K * B, VN),
                                       lambda c, f, *_: (0, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((1, K * B, VN), jnp.int32))
         scratch.append(pltpu.VMEM((K * B // 4, chunk), jnp.int32))
+    if indexed:
+        coarse, fk = _refined_features(sel_k, ft)
+        scalars += [coarse, fk]
+    elif refine:
+        in_specs.insert(0, pl.BlockSpec((K, chunk), lambda c, f, *_: (0, c)))
         operands.insert(0, sel_k)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=8,
+        num_scalar_prefetch=len(scalars),
         grid=(N // chunk, G),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         _make_fused_kernel(ft, hist_shift, refine, len(route_ops), read,
-                           dims),
+                           dims, indexed),
         grid_spec=grid_spec,
         out_shape=out_shape,
         # fused_geometry's budget leaves the routing blocks out
@@ -755,7 +859,11 @@ def _route_and_hist_int(bins_t, node_id, leaf, sel, t1, rlo, rhi, dflt,
             vmem_limit_bytes=_SCOPED_VMEM + _rows_block_bytes(
                 _routing_rows(route, n_slots), chunk)),
         interpret=interpret,
-    )(cols, leaf, t1, rlo, rhi, dflt, l_id, r_id, *operands)
+    )(*scalars, *operands)
+    if indexed:
+        return (res[0], _with_refined_coarse(res[1], res[2], coarse, fk, B,
+                                             Bh, hist_shift), res[2])
+    return res
 
 
 @functools.partial(jax.jit, static_argnames=("n_slots", "total_bins",
@@ -776,7 +884,7 @@ def route_and_hist_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
                           n_slots: int,
                           total_bins: int,
                           hist_shift: int = 0,
-                          sel_k: jnp.ndarray = None,   # (K, N) int32 refined
+                          sel_k: jnp.ndarray = None,   # (K,) | (K, N) refined
                           interpret: bool = False,
                           hist_chunk: int = 0):
     """One pass: → (new_node_id (N,), hists (n_slots, F, Bh, 3)[,
@@ -794,10 +902,17 @@ def route_and_hist_pallas(bins_t: jnp.ndarray,   # (F, N) | (G, ft, N) int32
 
     ``hist_shift`` > 0 (two-level mode) histograms at the COARSE
     ``bin >> hist_shift`` resolution (Bh = :func:`coarse_bins`) while
-    routing stays at fine resolution.  ``sel_k`` (the refined features'
-    pre-gathered bin rows) additionally builds their FULL-resolution
-    histograms in the same pass, off the same routing and slot-masked
-    value matrix — one bins read and one vn build for both levels.
+    routing stays at fine resolution.  ``sel_k`` names the refined
+    features, whose FULL-resolution histograms the same pass builds off
+    the same routing and slot-masked value matrix — one bins read and
+    one vn build for both levels.  As (K,) int32 indices (where one
+    feature group holds the tile: what the depth-wise grower passes
+    there) they are read where they lie in the tile, and the coarse
+    block builds the F − K other features only: the refined features'
+    coarse histograms are their fine ones pooled, integer sums equal bit
+    for bit to a coarse build.  As the (K, N) matrix of their rows (more
+    than one group, or a caller that has the rows) the coarse block
+    builds every feature.  The shape decides.
 
     ``bins_t`` as (G, ft, N) must be :func:`feature_tiles` at the ``ft``
     :func:`fused_geometry` returns for THESE arguments (the tile follows

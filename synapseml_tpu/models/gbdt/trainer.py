@@ -338,10 +338,17 @@ def depthwise_hist_plan(num_features: int, n_rows: int, p: "GrowthParams",
     ``split_columns``, how a wave reads the columns its splits route by:
     ``indexed`` on the pallas path (the root, every fused wave and the
     last wave name them by index and read them where they lie), else
-    ``per_row`` (the XLA path gathers each row's own column).
+    ``per_row`` (the XLA path gathers each row's own column);
+    ``refined_columns``, how the two-level refined features are read:
+    ``indexed`` where one feature group holds the pallas tile (the
+    root's fine build and every fused wave name them by index), else
+    ``gathered`` (a (K, N) copy a tree), ``none`` off two-level;
+    ``coarse_rows``, the one-hot rows a chunk of a wave's coarse block
+    (the refined features' left out where they are indexed; 0 off
+    two-level or off the pallas path).
     ``num_features`` and ``n_rows`` are what ONE device's grower sees
     (bundled columns, padded rows of its shard)."""
-    from .pallas_hist import fused_geometry
+    from .pallas_hist import coarse_bins, fused_geometry
     B, K, SH = p.total_bins, p.refine_k, TWO_LEVEL_SHIFT
     mono = p.monotone_constraints is not None and any(p.monotone_constraints)
     geo_tl = (fused_geometry(num_features, B, n_slots, p.hist_chunk,
@@ -355,10 +362,15 @@ def depthwise_hist_plan(num_features: int, n_rows: int, p: "GrowthParams",
         num_features, B, n_slots, p.hist_chunk)
     ft, chunk = geo or (0, 0)
     groups = -(-num_features // ft) if ft else 0
+    refined = ("none" if not tl else
+               "indexed" if use_pallas and groups == 1 else "gathered")
+    coarse_rows = (0 if not (tl and ft) else coarse_bins(B, SH) * (
+        num_features - K if refined == "indexed" else num_features))
     return dict(two_level=bool(tl), ft=ft, feature_groups=groups,
                 chunk=chunk,
                 grid_steps_per_pass=(n_rows // chunk) * groups if ft else 0,
-                split_columns="indexed" if ft else "per_row")
+                split_columns="indexed" if ft else "per_row",
+                refined_columns=refined, coarse_rows=coarse_rows)
 
 
 def _pool_coarse(hist, Bc: int, shift: int):
@@ -436,13 +448,18 @@ def _tl_final_pick(cg, ccum, f_hists, topk, sum_g, sum_h, sum_c, depth,
 
 def _tl_root_pick(root_hist, root_g, root_h, root_c, num_bins, num_bins_c,
                   feature_mask, p: GrowthParams, shift: int, K: int,
-                  bins_t, B: int, use_pallas, build_fine_root, ar):
+                  bins_t, B: int, use_pallas, build_fine_root, ar,
+                  gather: bool = True):
     """Shared two-level ROOT setup for both growers: coarse gains → the
     per-tree top-K feature set → gathered/prepared refined-feature
     layouts → root fine histograms → merged root pick.
 
-    ``build_fine_root(bins_kp) -> (1, K, B, 3)`` is the grower-specific
-    fine build (fused-path tiles vs flat XLA ids both prepared here).
+    ``build_fine_root(topk, bins_kp) -> (1, K, B, 3)`` is the
+    grower-specific fine build (fused-path tiles vs flat XLA ids both
+    prepared here).  ``gather=False`` (the depth-wise grower where one
+    feature group holds the pallas tile: its kernels read the refined
+    features by index) copies nothing: ``sel_k`` and ``bins_kp`` are
+    None.
     → (topk, sel_k, bins_kp, root_fine, (bg, bf, bb, bgl, bhl, bcl))."""
     z1 = jnp.zeros((1,), jnp.int32)
     ninf1 = jnp.full((1,), -jnp.inf)
@@ -451,16 +468,18 @@ def _tl_root_pick(root_hist, root_g, root_h, root_c, num_bins, num_bins_c,
         root_hist[None], root_g[None], root_h[None], root_c[None],
         z1, ninf1, inf1, num_bins_c, feature_mask, p)
     topk = lax.top_k(fgain0[0], K)[1].astype(jnp.int32)
-    # gather + layout the K refined feature rows ONCE per tree (a
-    # contiguous feature-axis row copy, NOT the pathological per-row
-    # gather); the split loops close over the result
-    sel_k = jnp.take(bins_t, topk, axis=0)
-    if use_pallas:
-        from .pallas_hist import prepare_feature_tiles
-        bins_kp = prepare_feature_tiles(sel_k, B, K)
-    else:
-        bins_kp = sel_k + (jnp.arange(K, dtype=jnp.int32) * B)[:, None]
-    root_fine = ar(build_fine_root(bins_kp))               # (1, K, B, 3)
+    sel_k = bins_kp = None
+    if gather:
+        # gather + layout the K refined feature rows ONCE per tree (a
+        # contiguous feature-axis row copy, NOT the pathological per-row
+        # gather); the split loops close over the result
+        sel_k = jnp.take(bins_t, topk, axis=0)
+        if use_pallas:
+            from .pallas_hist import prepare_feature_tiles
+            bins_kp = prepare_feature_tiles(sel_k, B, K)
+        else:
+            bins_kp = sel_k + (jnp.arange(K, dtype=jnp.int32) * B)[:, None]
+    root_fine = ar(build_fine_root(topk, bins_kp))         # (1, K, B, 3)
     rbest = _tl_final_pick(cg0, ccum0, root_fine, topk,
                            root_g[None], root_h[None], root_c[None],
                            z1, ninf1, inf1, num_bins, feature_mask,
@@ -888,7 +907,7 @@ def grow_tree(bins_t: jnp.ndarray,          # (F, N) int32 (transposed bins)
         topk, sel_k, bins_kp, root_fine, rbest0 = _tl_root_pick(
             root_hist, root_g, root_h, root_c, num_bins, num_bins_c,
             feature_mask, p, SH, K, bins_t, B, use_pallas,
-            lambda bkp: build_fine_k(bkp, row_valid), ar)
+            lambda _, bkp: build_fine_k(bkp, row_valid), ar)
 
     # per-node state
     zi = jnp.zeros(M, jnp.int32)
@@ -1263,6 +1282,9 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
     plan = depthwise_hist_plan(F, N, p, S, bundled=bundle_map is not None,
                                use_pallas=bool(use_pallas))
     tl = plan["two_level"]
+    # the refined features by index: no (K, N) copy a tree, and the
+    # waves' coarse block leaves them out
+    indexed = plan["refined_columns"] == "indexed"
     _tl_gauge("depthwise", tl)
     SH = TWO_LEVEL_SHIFT
     Bc = coarse_bins(B, SH)
@@ -1302,19 +1324,22 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
     vpick = jax.vmap(lambda h, g, hh, c, d, lo, hi: pick(
         h, g, hh, c, node_depth=d, node_lo=lo, node_hi=hi))
 
-    def build_fine_k(bins_kp, slot_vec, n_slots_):
+    def build_fine_k(bins_kp, slot_vec, n_slots_, feats=None):
         """Full-resolution histograms of the refined features for the
         two-level refine pass.  ``bins_kp`` is the PRE-GATHERED and
         pre-tiled (pallas) / pre-flattened (XLA) K-feature bin matrix —
         prepared once per tree right after the root picks ``topk`` so the
         wave loop never re-materializes the copy (XLA cannot hoist it out
-        of while_loop)."""
+        of while_loop) — or None, and the pallas build reads the
+        ``feats`` rows where they lie in the one-group tile."""
         if use_pallas:
             from .pallas_hist import build_hist_nodes_pallas
+            src, feats = ((bins_pl, feats) if bins_kp is None
+                          else (bins_kp, None))
             return build_hist_nodes_pallas(
-                bins_kp, slot_vec, vals8, scales, n_slots_, B,
+                src, slot_vec, vals8, scales, n_slots_, B,
                 interpret=(use_pallas == "interpret"),
-                hist_chunk=p.hist_chunk)
+                hist_chunk=p.hist_chunk, feats=feats)
         return _build_hist_nodes_xla(bins_kp, grad, hess, row_valid,
                                      slot_vec, n_slots_, K, B)
 
@@ -1361,7 +1386,8 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
         topk, sel_k, bins_kp, root_fine, rbest0 = _tl_root_pick(
             root_hist, root_g, root_h, root_c, num_bins, num_bins_c,
             feature_mask, p, SH, K, bins_t, B, use_pallas,
-            lambda bkp: build_fine_k(bkp, rslot0, 1), ar)
+            lambda tk, bkp: build_fine_k(bkp, rslot0, 1, tk), ar,
+            gather=not indexed)
         bg, bf_, bb, bgl, bhl, bcl = rbest0
     else:
         bg, bf_, bb, bgl, bhl, bcl = pick(
@@ -1435,7 +1461,7 @@ def grow_tree_depthwise(bins_t: jnp.ndarray,     # (F, N) int32
                     bins_pl, s["node_id"], parents, rt_col, rt_t1, rt_lo,
                     rt_hi, rt_df, l_ids, r_ids, vals8, scales, S, B,
                     hist_shift=(SH if tl else 0),
-                    sel_k=(sel_k if tl else None),
+                    sel_k=((topk if indexed else sel_k) if tl else None),
                     interpret=(use_pallas == "interpret"),
                     hist_chunk=p.hist_chunk)
                 # under tl the SAME pass also emits the refined features'

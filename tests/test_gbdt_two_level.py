@@ -67,6 +67,42 @@ def test_two_level_interpret_matches_xla():
                                    rtol=1e-4, atol=1e-4, err_msg=f)
 
 
+def test_two_level_two_groups_interpret_matches_xla():
+    """At 40 features the two-level tile is two groups of 20, so the
+    refined features come as a gathered (K, N) block beside every
+    feature's coarse rows (the parent's program): the pallas kernels
+    still grow the XLA fallback's tree."""
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt.trainer import (
+        GrowthParams, default_n_slots, depthwise_hist_plan,
+        grow_tree_depthwise)
+
+    rng = np.random.default_rng(8)
+    N, F, B = 8192, 40, 256
+    bins_t = rng.integers(0, B, (F, N)).astype(np.int32)
+    grad = rng.normal(size=N).astype(np.float32)
+    hess = (np.abs(grad) * 0.5 + 0.2).astype(np.float32)
+    p = GrowthParams(num_leaves=15, min_data_in_leaf=5.0, total_bins=B,
+                     two_level="on", refine_k=4)
+    S = default_n_slots(15)
+    plan = depthwise_hist_plan(F, N, p, S, bundled=False, use_pallas=True)
+    assert (plan["feature_groups"], plan["refined_columns"]) == (2,
+                                                                "gathered")
+    ub = np.sort(rng.normal(size=(F, B - 1)).astype(np.float32), axis=1)
+    args = (jnp.asarray(bins_t), jnp.asarray(grad), jnp.asarray(hess),
+            jnp.ones(N, jnp.float32), jnp.ones(F, bool), jnp.asarray(ub),
+            jnp.full(F, B, jnp.int32), 0.1)
+    t_x, nid_x = grow_tree_depthwise(*args, p=p, use_pallas=False,
+                                     n_slots=S)
+    t_p, nid_p = grow_tree_depthwise(*args, p=p, use_pallas="interpret",
+                                     n_slots=S)
+    np.testing.assert_array_equal(np.asarray(nid_x), np.asarray(nid_p))
+    for f in ("split_feature", "left_child", "right_child", "num_nodes"):
+        np.testing.assert_array_equal(np.asarray(getattr(t_x, f)),
+                                      np.asarray(getattr(t_p, f)),
+                                      err_msg=f)
+
+
 def test_coarse_kernel_equals_pooled_fine():
     """route_and_hist_pallas with hist_shift=2 == the full-resolution
     histograms pooled over each coarse (bin >> 2) group — the in-kernel
@@ -390,6 +426,115 @@ def test_index_form_is_the_rows_form_bit_for_bit(
     assert want[..., 6].sum() > 0
 
 
+def _refined_set(kind, F, K, rng):
+    """The refined features a tree's root may pick, by where they lie."""
+    return {"feature-0": np.r_[0, rng.choice(np.arange(1, F), K - 1,
+                                              replace=False)],
+            "last-feature": np.r_[rng.choice(F - 1, K - 1, replace=False),
+                                  F - 1],
+            "contiguous": np.arange(K) + (F - K) // 2,
+            "scattered": rng.choice(F, K, replace=False)}[kind].astype(
+                np.int32)
+
+
+@pytest.mark.parametrize("F,B,K,S,live,kind", [
+    # the cell's shapes: 28 x 256, shift 3, 8 refined, 16 slots
+    pytest.param(28, 256, 8, 16, 1, "feature-0", id="feature-0-live1"),
+    pytest.param(28, 256, 8, 16, 2, "last-feature", id="last-feature-live2"),
+    pytest.param(28, 256, 8, 16, 8, "contiguous", id="contiguous-live8"),
+    pytest.param(28, 256, 8, 16, 16, "scattered", id="scattered-live16"),
+    # one unrefined feature; and 200 bins, whose fine rows pool into a
+    # coarse width padded past them
+    pytest.param(9, 256, 8, 16, 8, "scattered", id="k-plus-one"),
+    pytest.param(9, 200, 4, 4, 3, "last-feature", id="odd-bins-4-slots"),
+])
+def test_refined_by_index_is_the_gathered_form_bit_for_bit(F, B, K, S, live,
+                                                           kind):
+    """``sel_k`` as (K,) indices (the coarse block builds the F - K other
+    features; the refined features' coarse rows pooled from their fine
+    rows) gives, bit for bit, what the (K, N) rows of those features
+    give: ``new_node_id``, the coarse accumulator of all F features and
+    the refined one; and the coarse one is the numpy reference."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+
+    shift, N = 3, 8192
+    rng = np.random.default_rng(F * 100 + B + live)
+    bins = rng.integers(0, B, (F, N)).astype(np.int32)
+    node_id = rng.integers(0, live + 1, N).astype(np.int32)
+    sp = _splits(rng, F, B, S, live, ranged=True)
+    grad = rng.normal(size=N).astype(np.float32)
+    vals, _ = ph.prep_hist_vals_rows(
+        jnp.asarray(grad), jnp.asarray(np.abs(grad) + 0.2),
+        jnp.asarray((rng.random(N) < 0.9).astype(np.float32)))
+    topk = _refined_set(kind, F, K, rng)
+    run = jax.jit(functools.partial(
+        ph._route_and_hist_int, n_slots=S, total_bins=B, hist_shift=shift,
+        interpret=True, hist_chunk=0))
+
+    def call(sel_k):
+        return run(jnp.asarray(bins), jnp.asarray(node_id),
+                   *(jnp.asarray(sp[k]) for k in ("leaf", "cols", "t1", "rlo",
+                                                  "rhi", "dflt", "l_id",
+                                                  "r_id")),
+                   vals, sel_k=jnp.asarray(sel_k))
+    by_index, by_rows = call(topk), call(bins[topk])
+    assert len(by_index) == len(by_rows) == 3
+    for a, b in zip(by_index, by_rows):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    Bh = ph.coarse_bins(B, shift)
+    rows = bins[np.clip(sp["cols"], 0, F - 1)]
+    want_id, want, want_f = _int_reference(
+        bins, node_id, sp["leaf"], rows, sp["t1"], sp["rlo"], sp["rhi"],
+        sp["dflt"], sp["l_id"], sp["r_id"], np.asarray(vals)[:8].T, S, Bh,
+        shift, bins[topk], B)
+    np.testing.assert_array_equal(np.asarray(by_index[0])[0], want_id)
+    np.testing.assert_array_equal(
+        np.asarray(by_index[1]).reshape(F, Bh, S, 8), want)
+    np.testing.assert_array_equal(
+        np.asarray(by_index[2]).reshape(K, B, S, 8), want_f)
+    assert want[topk, ..., 6].sum() > 0      # refined rows were pooled
+
+
+@pytest.mark.parametrize("F,B,K,view", [
+    pytest.param(28, 256, 8, True, id="cell-one-group-view"),
+    pytest.param(28, 256, 8, False, id="cell-flat-matrix"),
+    pytest.param(9, 200, 4, True, id="odd-bins"),
+    # 12 refined features at 256 bins: two steps of 6 a chunk
+    pytest.param(28, 256, 12, False, id="two-feature-steps"),
+])
+def test_the_root_fine_build_by_index_is_the_gathered_build(F, B, K, view):
+    """``build_hist_nodes_pallas`` with ``feats`` reads the K rows where
+    they lie in the matrix and builds, bit for bit, the histograms the
+    gathered (K, N) rows give."""
+    import jax.numpy as jnp
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+
+    N = 4096
+    rng = np.random.default_rng(F + B + K)
+    bins = rng.integers(0, B, (F, N)).astype(np.int32)
+    grad = rng.normal(size=N).astype(np.float32)
+    mask = (rng.random(N) < 0.9).astype(np.float32)
+    vals, scales = ph.prep_hist_vals_rows(
+        jnp.asarray(grad), jnp.asarray(np.abs(grad) + 0.2), jnp.asarray(mask))
+    slot = jnp.asarray(np.where(mask > 0, 0, -1).astype(np.int32))
+    topk = rng.choice(F, K, replace=False).astype(np.int32)
+    src = ph.feature_tiles(jnp.asarray(bins), F) if view else \
+        jnp.asarray(bins)
+    by_index = ph.build_hist_nodes_pallas(src, slot, vals, scales, 1, B,
+                                          interpret=True,
+                                          feats=jnp.asarray(topk))
+    gathered = ph.build_hist_nodes_pallas(jnp.asarray(bins[topk]), slot,
+                                          vals, scales, 1, B, interpret=True)
+    assert by_index.shape == (1, K, B, 3)
+    np.testing.assert_array_equal(np.asarray(by_index), np.asarray(gathered))
+    assert float(np.asarray(by_index)[..., 2].sum()) == K * mask.sum()
+
+
 @pytest.mark.parametrize("F,B,S,live,N,ft,ranged", [
     pytest.param(28, 256, 16, 12, 8192, 28, False, id="one-group"),
     pytest.param(28, 256, 16, 12, 4096, 7, False, id="tiles-of-7"),
@@ -430,32 +575,54 @@ def test_the_last_waves_routing_is_route_left(F, B, S, live, N, ft, ranged):
     assert (want != node_id).any()
 
 
-def test_the_wave_program_copies_no_split_columns():
-    """The depth-wise grower at the two-level geometry, lowered for the
-    TPU: no gather makes an (S, N) matrix of split columns (the root, the
-    fused waves and the last wave all name them by index), and both
-    kernels are in the program."""
-    import re
-
+def _lowered_depthwise(F, N, B, S, K):
+    """The depth-wise grower at two-level, lowered for the TPU → text."""
     import jax
     import jax.numpy as jnp
     from synapseml_tpu.models.gbdt.trainer import (GrowthParams,
                                                    grow_tree_depthwise)
-
-    N, F, B, S = 8192, 28, 256, 16
     p = GrowthParams(num_leaves=31, total_bins=B, two_level="on",
-                     refine_k=8)
+                     refine_k=K)
     sd = jax.ShapeDtypeStruct
     f32 = sd((N,), jnp.float32)
-    text = grow_tree_depthwise.trace(
+    return grow_tree_depthwise.trace(
         sd((F, N), jnp.int32), f32, f32, f32, sd((F,), jnp.bool_),
         sd((F, B - 1), jnp.float32), sd((F,), jnp.int32), 0.1, p,
         use_pallas=True, n_slots=S).lower(
             lowering_platforms=("tpu",)).as_text()
+
+
+def test_the_wave_program_copies_no_split_columns():
+    """The depth-wise grower at the two-level geometry, lowered for the
+    TPU: no gather makes an (S, N) matrix of split columns (the root, the
+    fused waves and the last wave all name them by index), nor an (8, N)
+    matrix of the refined features (one group holds the tile, so the
+    root's fine build and the waves read them by index), and the three
+    kernels are in the program."""
+    import re
+    N, F, B, S = 8192, 28, 256, 16
+    text = _lowered_depthwise(F, N, B, S, 8)
     gathers = re.findall(r"stablehlo\.gather.*-> (tensor<[^>]*>)", text)
-    assert gathers, "expected the refined features' and the picks' gathers"
+    assert gathers, "expected the picks' gathers"
     assert f"tensor<{S}x{N}xi32>" not in gathers, gathers
+    assert f"tensor<8x{N}xi32>" not in gathers, gathers
     assert "route_and_hist_pallas" in text and "route_rows_pallas" in text
+    assert "build_hist_nodes_pallas" in text
+
+
+def test_two_groups_keep_the_gathered_refined_block():
+    """At 50 features the two-level tile is two groups of 25 and a
+    refined column may lie outside a step's tile: the wave program keeps
+    the parent's form, the refined rows gathered once a tree into an
+    (8, N) block that the fused pass reads beside every feature's coarse
+    rows."""
+    import re
+    N, F, B, S = 8192, 50, 256, 16
+    text = _lowered_depthwise(F, N, B, S, 8)
+    gathers = re.findall(r"stablehlo\.gather.*-> (tensor<[^>]*>)", text)
+    assert f"tensor<8x{N}xi32>" in gathers, gathers
+    assert f"tensor<{S}x{N}xi32>" not in gathers, gathers
+    assert "route_and_hist_pallas" in text
 
 
 @pytest.mark.parametrize("F,B,S,shift,K,chunk_override,want", [
@@ -531,11 +698,18 @@ def test_the_fit_span_says_what_the_geometry_chose():
     assert depthwise_hist_plan(28, 12_001_280, p, 16, bundled=False,
                                use_pallas=True) == dict(
         two_level=True, ft=28, feature_groups=1, chunk=2048,
-        grid_steps_per_pass=5860, split_columns="indexed")
+        grid_steps_per_pass=5860, split_columns="indexed",
+        refined_columns="indexed", coarse_rows=640)
     assert depthwise_hist_plan(28, 12_001_280, p, 16, bundled=True,
                                use_pallas=True) == dict(
         two_level=False, ft=7, feature_groups=4, chunk=2048,
-        grid_steps_per_pass=23440, split_columns="indexed")
+        grid_steps_per_pass=23440, split_columns="indexed",
+        refined_columns="none", coarse_rows=0)
+    # two groups of 25: the refined rows gathered, every feature coarse
+    two = depthwise_hist_plan(50, 12_001_280, p, 16, bundled=False,
+                              use_pallas=True)
+    assert (two["feature_groups"], two["refined_columns"],
+            two["coarse_rows"]) == (2, "gathered", 1600)
     X, y = _data(n=4_000, F=12)
     train(X, y, BoostingConfig(objective="binary", num_iterations=2,
                                num_leaves=7, max_bin=255,
@@ -545,6 +719,8 @@ def test_the_fit_span_says_what_the_geometry_chose():
     assert (a["hist_ft"], a["hist_feature_groups"], a["hist_chunk"],
             a["hist_grid_steps_per_pass"]) == (0, 0, 0, 0)
     assert a["hist_split_columns"] == "per_row"
+    assert (a["hist_refined_columns"], a["hist_coarse_rows"]) == ("gathered",
+                                                                  0)
 
 
 def test_fused_refine_vmem_gate():

@@ -879,6 +879,32 @@ def test_split_columns_are_read_by_index_on_the_v5e(one_chip):
         assert not re.search(rf"s32\[\d+,\d+,{N}\]\S* (copy|fusion)\(", text)
 
 
+def test_refined_features_are_read_by_index_on_the_v5e(one_chip):
+    """At the boosting cell's shapes a wave's fused pass with its 8
+    refined features named by index (the coarse block building the other
+    20) and the root's fine build by index compile for the chip with the
+    binned matrix reaching each as a bitcast: no (8, N) matrix of refined
+    columns and no copy of the bins."""
+    from synapseml_tpu.models.gbdt import pallas_hist as ph
+    N, F, B, S, K = 12_001_280, 28, 256, 16, 8
+
+    def sd(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fused = ph.route_and_hist_pallas.lower(
+        sd((F, N)), sd((N,)), sd((S,)), sd((S,)), *[sd((S,))] * 6,
+        sd((32, N), jnp.int8), sd((2,), jnp.float32), n_slots=S,
+        total_bins=B, hist_shift=3, sel_k=sd((K,))).compile().as_text()
+    root = ph.build_hist_nodes_pallas.lower(
+        sd((F, N)), sd((N,)), sd((32, N), jnp.int8), sd((2,), jnp.float32),
+        n_slots=1, total_bins=B, feats=sd((K,))).compile().as_text()
+    for text, name in ((fused, "route_and_hist_pallas"),
+                       (root, "build_hist_nodes_pallas")):
+        assert "tpu_custom_call" in text and name in text
+        assert re.search(rf"s32\[1,{F},{N}\]\S* bitcast\(", text)
+        assert f"s32[{K},{N}]" not in text
+        assert not re.search(rf"s32\[\d+,\d+,{N}\]\S* (copy|fusion)\(", text)
+
+
 @pytest.mark.parametrize("F,B", [
     pytest.param(256, 64, id="eight-groups-of-32"),
     pytest.param(84, 256, id="twelve-groups-of-7"),
